@@ -20,11 +20,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -32,6 +27,7 @@ from heat_tpu.core import devices
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     # force the accelerator policy so the demo shows planar everywhere
     # (on the real TPU this is already the default)
     ht.use_complex("planar")

@@ -21,11 +21,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -42,6 +37,7 @@ def synthetic_task(n: int = 2048, d: int = 32, classes: int = 4, seed: int = 0):
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=80)
     ap.add_argument("--global-skip", type=int, default=4)

@@ -17,11 +17,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -36,6 +31,7 @@ def feature_pipeline(x):
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     ht.random.seed(0)
     x = ht.random.randn(200_000, 64, split=0)
 

@@ -15,11 +15,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -27,6 +22,7 @@ from heat_tpu.utils.data.spherical import create_spherical_dataset
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--samples", type=int, default=5000, help="samples per cluster")
     args = ap.parse_args()

@@ -15,11 +15,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -28,6 +23,7 @@ from heat_tpu.classification import KNeighborsClassifier
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--neighbours", type=int, default=5)
     args = ap.parse_args()

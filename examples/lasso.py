@@ -16,11 +16,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 import heat_tpu as ht
@@ -29,6 +24,7 @@ from heat_tpu.regression import Lasso
 
 
 def main() -> None:
+    ht.utils.place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--lam", type=float, default=0.1)
     ap.add_argument("--max-iter", type=int, default=100)

@@ -241,7 +241,7 @@ class _RepInterp:
             elif name == "scan":
                 self._scan(eqn, get, facts)
                 continue
-            elif name in ("pjit", "closed_call", "core_call", "remat",
+            elif name in ("jit", "closed_call", "core_call", "remat",
                           "checkpoint", "custom_jvp_call", "custom_vjp_call",
                           "custom_vjp_call_jaxpr"):
                 sub = self._first_matching_sub(eqn)
@@ -510,9 +510,10 @@ def scan_jaxpr_divergence(closed, label: str = "") -> List[Finding]:
                         break
                 if body is None:
                     continue
-                in_names = eqn.params.get("in_names") or ()
+                # replicated <=> the operand's PartitionSpec names no mesh axis
+                in_specs = eqn.params.get("in_specs") or ()
                 in_facts = [
-                    not (in_names[k] if k < len(in_names) else {})
+                    all(e is None for e in (in_specs[k] if k < len(in_specs) else ()))
                     for k in range(len(body.invars))
                 ]
                 _RepInterp(findings, label).run(body, in_facts)
@@ -560,7 +561,7 @@ def scan_hlo_congruence(text: str) -> List[Finding]:
         if defect is None:
             continue
         stamp = planned_reshard_plan_id(full_line)
-        blessed = ring_schedule_module(full_line)
+        blessed = ring_schedule_module(full_line, text)
         # dedup WITHIN a severity class only — a blessed/stamped line
         # must never mask a later hand-rolled hang with the same defect
         key = (op, defect, bool(stamp or blessed))

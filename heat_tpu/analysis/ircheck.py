@@ -89,7 +89,7 @@ from ._dtypes import (
 def _walk_jaxprs(jaxpr):
     """Yield every eqn of ``jaxpr`` and its nested sub-jaxprs (pjit /
     scan / cond / shard_map bodies)."""
-    from jax.extend import core as jex_core  # jaxpr types live here on 0.4.x
+    from jax.extend import core as jex_core
 
     todo = [jaxpr]
     seen = set()
@@ -329,7 +329,7 @@ def check(
             # design — info, keyed on source_file since shard_map bodies
             # carry no stampable named scope. Hand-rolled loops in user
             # code still fall through to full severity.
-            blessed = ring_schedule_module(full_line)
+            blessed = ring_schedule_module(full_line, text)
             if blessed is not None:
                 findings.append(
                     Finding(
@@ -423,7 +423,7 @@ def check(
                 )
             )
             continue
-        blessed = ring_schedule_module(full_line)
+        blessed = ring_schedule_module(full_line, text)
         if blessed is not None:
             findings.append(
                 Finding(
@@ -525,7 +525,7 @@ def check(
         # jnp.where/clip/round wrap their select/round bodies in nested
         # pjit eqns: the outer walk continues through the pjit's OWN
         # invars (the operands), which is exactly the dataflow step
-        "pjit", "custom_jvp_call", "custom_vjp_call",
+        "jit", "custom_jvp_call", "custom_vjp_call",
     }
     seen_narrow = set()
     # ONE producer map over every (sub-)jaxpr: vars are unique objects,
@@ -622,7 +622,7 @@ def check(
                 # sub-jaxpr's outvars: step inside (index-matched) so a
                 # convert hiding in a nested jit wrapper is reached,
                 # while the wrapper's unrelated sibling outputs are not
-                if name in ("pjit", "custom_jvp_call", "custom_vjp_call"):
+                if name in ("jit", "custom_jvp_call", "custom_vjp_call"):
                     stack.extend((u, depth + 1) for u in _sub_outvar_for(src, v))
 
     # ---- SL105: aliasable output not donated ---------------------------

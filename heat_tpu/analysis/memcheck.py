@@ -45,7 +45,7 @@ SL303     warning   a replicated value at least ``min_bytes`` large
 
 The interpreter walks nested jaxprs (pjit / custom_* / shard_map
 bodies). Inside ``shard_map`` the body avals ARE the per-device local
-shapes, so bytes are taken at face value and ``in_names``/``out_names``
+shapes, so bytes are taken at face value and ``in_specs``/``out_specs``
 decide replication; outside, a value's local bytes are its global aval
 bytes divided by its propagated sharding factor. ``scan``/``while``/
 ``cond`` bodies are scanned for collective events but treated as opaque
@@ -130,10 +130,11 @@ def _closed_of(val):
     return out
 
 
-def _spec_is_replicated(names) -> bool:
-    """A shard_map in_names/out_names entry with no mesh axes means the
-    body sees (or produces) the full value on every device."""
-    return not names
+def _spec_is_replicated(spec) -> bool:
+    """A shard_map in_specs/out_specs entry (a PartitionSpec) that names
+    no mesh axis means the body sees (or produces) the full value on
+    every device."""
+    return all(e is None for e in spec)
 
 
 class _Fact:
@@ -239,7 +240,7 @@ class _Interp:
 
         if name == "shard_map":
             self._shard_map(eqn)
-        elif name in ("pjit", "closed_call", "core_call", "remat",
+        elif name in ("jit", "closed_call", "core_call", "remat",
                       "checkpoint", "custom_jvp_call", "custom_vjp_call",
                       "custom_vjp_call_jaxpr"):
             self._call(eqn, local_avals)
@@ -319,14 +320,14 @@ class _Interp:
             if subs:
                 body = subs[0]
                 break
-        in_names = eqn.params.get("in_names") or ()
-        out_names = eqn.params.get("out_names") or ()
+        in_names = eqn.params.get("in_specs") or ()
+        out_names = eqn.params.get("out_specs") or ()
         if body is None or len(body.invars) != len(eqn.invars):
             self._default(eqn, False, [f for f in (self._fact_of(v) for v in eqn.invars) if f])
             return
         in_facts = []
         for k, sv in enumerate(body.invars):
-            names = in_names[k] if k < len(in_names) else {}
+            names = in_names[k] if k < len(in_names) else ()
             in_facts.append(
                 _Fact(
                     _aval_bytes(getattr(sv, "aval", None)),  # body avals are LOCAL
@@ -336,7 +337,7 @@ class _Interp:
         out_facts = self.run(body, in_facts, local_avals=True, bind_to=list(eqn.invars))
         ev = self._event()
         for k, var in enumerate(eqn.outvars):
-            names = out_names[k] if k < len(out_names) else {}
+            names = out_names[k] if k < len(out_names) else ()
             local = (
                 out_facts[k].local_bytes
                 if k < len(out_facts)

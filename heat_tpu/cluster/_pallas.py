@@ -35,14 +35,9 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pragma: no cover - present in all TPU-capable jax builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 __all__ = ["fused_assign_program", "pallas_available"]
 
@@ -52,8 +47,7 @@ def pallas_available() -> bool:
     opt-in path; auto-selection stays on the XLA-fused formulation, which
     measures at the bandwidth bound — see module docstring)."""
     return (
-        pltpu is not None
-        and jax.default_backend() == "tpu"
+        jax.default_backend() == "tpu"
         and jax.device_count() == 1
         and not jax.config.jax_enable_x64  # Mosaic rejects x64-mode traces
     )
@@ -70,7 +64,12 @@ def _make_kernel(tm: int, n: int, k: int):
         f1 = jnp.float32(1.0)
         f0 = jnp.float32(0.0)
         i = pl.program_id(0)
-        x = x_ref[:].astype(jnp.float32)          # (TM, d)
+        # the grid's last tile may reach past row n: what it reads there is
+        # unspecified, so those rows are zeroed before any arithmetic
+        # (0 * NaN would poison the accumulator matmul) and masked below
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        valid = (i.astype(jnp.int32) * jnp.int32(tm) + row_ids) < jnp.int32(n)
+        x = jnp.where(valid, x_ref[:].astype(jnp.float32), f0)  # (TM, d)
         c = c_ref[:].astype(jnp.float32)          # (k, d)
         x2 = jnp.sum(x * x, axis=1, keepdims=True)
         c2 = jnp.sum(c * c, axis=1, keepdims=True).T
@@ -83,8 +82,6 @@ def _make_kernel(tm: int, n: int, k: int):
         labels = jnp.min(
             jnp.where(d2 == dmin, col_ids, jnp.int32(k)), axis=1, keepdims=True
         )
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-        valid = (i.astype(jnp.int32) * jnp.int32(tm) + row_ids) < jnp.int32(n)
         onehot = col_ids == labels
         onehot = jnp.where(valid & onehot, f1, f0)
         ones = jnp.where(valid, f1, f0)
@@ -107,11 +104,10 @@ def fused_assign_program(n: int, d: int, k: int, jdtype: str, interpret: bool = 
     """Compiled fused-assignment pass: (x (n,d), centers (k,d)) →
     (sums (k,d) f32, counts (k,) f32, inertia () f32)."""
     tm = max(8, min(1024, _round_up(min(n, 1024), 8)))
-    npad = _round_up(n, tm)
     kernel = _make_kernel(tm, n, k)
     call = pl.pallas_call(
         kernel,
-        grid=(npad // tm,),
+        grid=(pl.cdiv(n, tm),),
         in_specs=[
             pl.BlockSpec((tm, d), lambda i: (i, 0), memory_space=_VMEM),
             pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=_VMEM),
@@ -124,9 +120,8 @@ def fused_assign_program(n: int, d: int, k: int, jdtype: str, interpret: bool = 
     def run(x, centers):
         # x64 is off on TPU by platform policy, so Mosaic's grid/index
         # machinery traces with 32-bit scalars; the forced-x64
-        # configuration is gated out in pallas_available
-        if npad != n:
-            x = jnp.pad(x, ((0, npad - n), (0, 0)))
+        # configuration is gated out in pallas_available. The last tile
+        # is masked in the kernel: no padded copy of x is made
         acc = call(x.astype(jnp.dtype(jdtype)), centers.astype(jnp.dtype(jdtype)))
         return acc[:, :d], acc[:, d], jnp.sum(acc[:, d + 1])
 

@@ -64,7 +64,7 @@ def _cmatmul_program(
     once, in fixed ring order) and bit-identical between the sequential
     and pipelined issue orders."""
     from ...kernels import cmatmul as _cm
-    from .._jax_compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
     from jax.sharding import PartitionSpec as _P
 
     p = mesh.devices.size

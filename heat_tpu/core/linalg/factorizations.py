@@ -56,7 +56,7 @@ from typing import Optional, Tuple
 
 from .. import types
 from .. import _padding
-from .._jax_compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from ...kernels import cmatmul as _cm

@@ -40,7 +40,7 @@ from typing import Optional, Tuple, Union
 
 from .. import types
 from .. import _padding
-from .._jax_compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in

@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ._jax_compat import pcast, shard_map
+from jax import shard_map
+from jax.lax import pcast
 
 from typing import Callable, Optional, Tuple
 
@@ -272,7 +273,7 @@ def _oddeven_sort_values_program(mesh: Mesh, axis_name: str, ndim: int, split: i
             v = jnp.where(in_pair, jnp.where(is_low, lo, hi), v)
         return v
 
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -335,7 +336,9 @@ def _oddeven_sort_program(mesh: Mesh, axis_name: str, ndim: int, split: int, idx
             i = jnp.where(in_pair, jnp.where(is_low, lo_i, hi_i), i)
         return v, i
 
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=(spec, spec))
+    # check_vma=False: the local block sort may be a pallas_call, whose
+    # outputs carry no varying-mesh-axes annotation
+    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=(spec, spec), check_vma=False)
     return jax.jit(fn)
 
 
@@ -433,7 +436,7 @@ def _columnsort_program(mesh: Mesh, axis_name: str, ndim: int, split: int, idx_d
         return res[0] if idt is None else res
 
     out_specs = spec if idt is None else (spec, spec)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=out_specs)
+    fn = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

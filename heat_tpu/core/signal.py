@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from ._jax_compat import shard_map
+from jax import shard_map
 
 from . import types
 from .dndarray import DNDarray

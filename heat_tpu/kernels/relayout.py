@@ -34,12 +34,12 @@ portable reference) and a **Pallas tiled-copy kernel** that streams
 flat VMEM blocks and performs the narrow-shape reinterpretation in
 registers, so both HBM faces of the copy are full-lane 1-D streams
 (``interpret=True`` runs the identical kernel logic on CPU, so tier-1
-exercises it without a TPU). Dispatch follows the PR-4 sort-kernel
-pattern: ``HEAT_TPU_RELAYOUT_KERNEL=0`` forces the XLA formulation
-everywhere (the escape hatch), ``=1`` forces the Pallas kernel where
-serviceable, and the default ``auto`` keeps XLA off-TPU and AUTOTUNES
-on TPU with the XLA formulation as the oracle/floor — a kernel that
-loses on the real chip can never regress a workload.
+exercises it without a TPU). Dispatch: ``HEAT_TPU_RELAYOUT_KERNEL=0``
+forces the XLA formulation everywhere, ``=1`` forces the Pallas kernel
+where serviceable, and the default ``auto`` stays on the XLA
+formulation: the installed TPU compiler refuses the kernels' in-register
+reshape (``AUTO_REFUSAL``), so they are off the ``auto`` path until a
+toolchain accepts them.
 
 ``lane_fill`` is the cost-model term the redistribution planner learns
 from this module: the fraction of VREG lanes a buffer with the given
@@ -50,23 +50,16 @@ of the HBM amplification a copy through that layout pays.
 from __future__ import annotations
 
 import functools
-import time
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..core import gates as _gates
 
-try:  # pragma: no cover — present in all TPU-capable jax builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pl = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 __all__ = [
     "LANES",
@@ -183,7 +176,7 @@ def _pack_call(n_blocks: int, b: int, c_in: int, c_out: int, p: int, dtype_name:
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((1, b * c_in), lambda g: (g, 0), memory_space=_VMEM)],
+        in_specs=[pl.BlockSpec((1, 1, b * c_in), lambda g: (g, 0, 0), memory_space=_VMEM)],
         out_specs=pl.BlockSpec((p, b * cpp), lambda g: (0, g), memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((p, n_blocks * b * cpp), dt),
         interpret=interpret,
@@ -199,14 +192,14 @@ def _unpack_call(n_blocks: int, b: int, c_in: int, c_out: int, p: int, dtype_nam
         xb = jnp.transpose(i_ref[...].reshape(p, b, cpp), (1, 0, 2)).reshape(b, c_in)
         if c_out != c_in:
             xb = xb[:, :c_out]
-        o_ref[...] = xb.reshape(1, b * c_out)
+        o_ref[...] = xb.reshape(1, 1, b * c_out)
 
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((p, b * cpp), lambda g: (0, g), memory_space=_VMEM)],
-        out_specs=pl.BlockSpec((1, b * c_out), lambda g: (g, 0), memory_space=_VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, b * c_out), dt),
+        out_specs=pl.BlockSpec((1, 1, b * c_out), lambda g: (g, 0, 0), memory_space=_VMEM),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, 1, b * c_out), dt),
         interpret=interpret,
     )
 
@@ -214,8 +207,8 @@ def _unpack_call(n_blocks: int, b: int, c_in: int, c_out: int, p: int, dtype_nam
 def pallas_serviceable(rows: int, c_in: int, c_out: int, p: int) -> bool:
     """Shape-level predicate: would the Pallas tiled-copy kernel serve
     this pack/unpack? (A 1-row block always divides ``rows``, so this
-    is mostly a ``pl``-availability and VMEM-residency gate.)"""
-    if pl is None or rows <= 0 or p <= 0:
+    is mostly a VMEM-residency gate.)"""
+    if rows <= 0 or p <= 0:
         return False
     c_max = max(c_in, c_out)
     return 0 < c_max <= _BLOCK_ELEMS
@@ -225,7 +218,7 @@ def _pack_rows_pallas(x, rows, c_in, c_out, p):
     b = _block_rows(rows, max(c_in, c_out))
     interpret = jax.default_backend() != "tpu"
     return _pack_call(rows // b, b, c_in, c_out, p, jnp.dtype(x.dtype).name, interpret)(
-        x.reshape(rows // b, b * c_in)
+        x.reshape(rows // b, 1, b * c_in)
     )
 
 
@@ -237,79 +230,45 @@ def _unpack_rows_pallas(x, rows, c_in, c_out, p):
 
 
 # ---------------------------------------------------------------------- #
-# dispatch (HEAT_TPU_RELAYOUT_KERNEL + TPU autotune, XLA as the floor)   #
+# dispatch (HEAT_TPU_RELAYOUT_KERNEL; the XLA formulation serves auto)   #
 # ---------------------------------------------------------------------- #
+#: why ``auto`` never picks the Pallas kernels on the TPU backend: the
+#: installed Mosaic (jax 0.9.0 / libtpu 0.0.34) refuses the in-register
+#: reinterpretation the kernels exist for. Pinned by
+#: tests/test_chip_compile.py, which fails when a toolchain accepts it.
+AUTO_REFUSAL = (
+    "Mosaic: infer-vector-layout: unsupported shape cast "
+    "(tpu.reshape of a flat lane vector to a narrow-minor block)"
+)
+
 _DECISIONS: dict = {}
 
 
 def last_decisions() -> dict:
-    """Copy of the dispatcher's cached path decisions (and autotune
-    timings where one ran): {(op, rows, c_in, c_out, p, dtype): {...}}."""
+    """Copy of the dispatcher's path decisions and their reasons:
+    {(op, rows, c_in, c_out, p, dtype): {"impl": …, "why": …}}."""
     return {k: dict(v) for k, v in _DECISIONS.items()}
 
 
-def _sync_scalar(x) -> None:
-    np.asarray(jax.device_get(x[(0,) * x.ndim] if x.ndim else x))
-
-
-def _autotune(op: str, rows: int, c_in: int, c_out: int, p: int, dtype_name: str) -> str:
-    """Time the XLA formulation against the Pallas kernel once per
-    shape signature on the real chip and cache the winner. The XLA
-    formulation (the current direct path) is the oracle/floor: ties and
-    lowering failures keep it."""
-    key = (op, rows, c_in, c_out, p, dtype_name)
-    if key in _DECISIONS:
-        return _DECISIONS[key]["impl"]
-    if op == "pack":
-        x = jnp.zeros((rows * c_in,), jnp.dtype(dtype_name))
-        forms = {"xla": _pack_rows_xla, "pallas": _pack_rows_pallas}
-    else:
-        x = jnp.zeros((p, rows * (c_in // p)), jnp.dtype(dtype_name))
-        forms = {"xla": _unpack_rows_xla, "pallas": _unpack_rows_pallas}
-    timings = {}
-    for impl, form in forms.items():
-        if impl == "pallas" and not pallas_serviceable(rows, c_in, c_out, p):
-            continue
-        try:
-            fn = jax.jit(functools.partial(form, rows=rows, c_in=c_in, c_out=c_out, p=p))
-            _sync_scalar(fn(x))  # compile + warm
-            best = float("inf")
-            for _ in range(2):
-                t0 = time.perf_counter()
-                _sync_scalar(fn(x))
-                best = min(best, time.perf_counter() - t0)
-            timings[impl] = best
-        except Exception:  # pragma: no cover — lowering failed on this backend
-            timings[impl] = float("inf")
-    impl = "pallas" if timings.get("pallas", float("inf")) < timings.get("xla", float("inf")) else "xla"
-    _DECISIONS[key] = {"impl": impl, "timings": timings, "autotuned": True}
-    return impl
-
-
-def decide(op: str, rows: int, c_in: int, c_out: int, p: int, dtype_name: str, concrete: bool = True) -> str:
+def decide(op: str, rows: int, c_in: int, c_out: int, p: int, dtype_name: str) -> str:
     """The implementation (``"xla"``/``"pallas"``) serving this
     pack/unpack signature under the current mode. Called eagerly by the
-    executor at program-build time so the decision is fixed before the
-    body traces (autotune never runs under a trace)."""
+    executor at program-build time so the decision is part of the
+    program cache key. ``=1`` forces the Pallas kernel (interpret mode
+    off-TPU; on the TPU backend the compiler's refusal then raises);
+    ``auto`` stays on the XLA formulation, see :data:`AUTO_REFUSAL`."""
     mode = _mode()
-    serviceable = pallas_serviceable(rows, c_in, c_out, p)
     if mode == "0":
-        return "xla"
-    if mode == "1":
-        if not serviceable:
-            _inc("relayout.kernel.fallback")
-            return "xla"
-        return "pallas"
-    # auto: XLA off-TPU; autotuned on TPU (32-bit words only — the
-    # kernel's VMEM blocks are sized for 4-byte lanes)
-    if jax.default_backend() != "tpu" or not serviceable or jnp.dtype(dtype_name).itemsize != 4:
-        return "xla"
-    key = (op, rows, c_in, c_out, p, dtype_name)
-    if key in _DECISIONS and _DECISIONS[key].get("autotuned"):
-        return _DECISIONS[key]["impl"]
-    if not concrete:
-        return "xla"  # tracing: no autotune possible, stay on the floor
-    return _autotune(op, rows, c_in, c_out, p, dtype_name)
+        impl, why = "xla", "gate=0"
+    elif mode == "1" and pallas_serviceable(rows, c_in, c_out, p):
+        impl, why = "pallas", "gate=1"
+    elif mode == "1":
+        _inc("relayout.kernel.fallback")
+        impl, why = "xla", "gate=1: shape not serviceable"
+    else:
+        impl, why = "xla", f"auto: kernel off auto — {AUTO_REFUSAL}"
+    _DECISIONS[(op, rows, c_in, c_out, p, dtype_name)] = {"impl": impl, "why": why}
+    return impl
 
 
 def pack_rows(x: jax.Array, rows: int, c_in: int, c_out: int, p: int, impl: str | None = None) -> jax.Array:
@@ -322,8 +281,7 @@ def pack_rows(x: jax.Array, rows: int, c_in: int, c_out: int, p: int, impl: str 
     if c_out % p or c_out < c_in:
         raise ValueError(f"pack_rows: need p | c_out and c_out >= c_in, got {c_in}->{c_out} over p={p}")
     if impl is None:
-        impl = decide("pack", rows, c_in, c_out, p, jnp.dtype(x.dtype).name,
-                      concrete=not isinstance(x, jax.core.Tracer))
+        impl = decide("pack", rows, c_in, c_out, p, jnp.dtype(x.dtype).name)
     if impl == "pallas":
         _inc("relayout.kernel.hit")
         return _pack_rows_pallas(x, rows, c_in, c_out, p)
@@ -337,8 +295,7 @@ def unpack_rows(x: jax.Array, rows: int, c_in: int, c_out: int, p: int, impl: st
     if c_in % p or c_out > c_in:
         raise ValueError(f"unpack_rows: need p | c_in and c_out <= c_in, got {c_in}->{c_out} over p={p}")
     if impl is None:
-        impl = decide("unpack", rows, c_in, c_out, p, jnp.dtype(x.dtype).name,
-                      concrete=not isinstance(x, jax.core.Tracer))
+        impl = decide("unpack", rows, c_in, c_out, p, jnp.dtype(x.dtype).name)
     if impl == "pallas":
         _inc("relayout.kernel.hit")
         return _unpack_rows_pallas(x, rows, c_in, c_out, p)

@@ -32,8 +32,8 @@ Two implementations per family, dispatched by ``HEAT_TPU_SPMM_KERNEL``
   oracle, which is what makes kernel-on == kernel-off bit-identical.
 
 ``auto`` resolves to the oracle off-TPU and to a per-signature
-autotune on TPU (the PR 4/5 pattern: eager, timed with a scalar
-read-back, cached per (family, B, k, dtype) signature). Telemetry:
+autotune on TPU (eager, timed to ``block_until_ready``, cached per
+(family, B, k, dtype) signature). Telemetry:
 ``sparse.kernel.hit`` counts brick-kernel dispatches,
 ``sparse.kernel.fallback`` oracle dispatches.
 
@@ -58,18 +58,14 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import gates as _gates
 from ..core import _padding
 
-try:  # Pallas is optional at import time (CPU-only wheels)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - toolchain without pallas
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "spmm_kernel_mode",
@@ -127,17 +123,13 @@ def _acc_dtype(jt: jnp.dtype) -> jnp.dtype:
     return jnp.dtype(jt)
 
 
-def _pallas_available() -> bool:
-    return pl is not None and pltpu is not None
-
-
 def decide(family: str, B: int, k: int, jdtype: str) -> str:
     """Resolve the implementation path (``"xla"``/``"pallas"``) for one
     (family, bricks, dense-cols, dtype) signature under the gate."""
     mode = _mode()
     sig = (family, int(B), int(k), str(jdtype))
-    if mode == "0" or not _pallas_available():
-        d = {"path": "xla", "why": "gate=0" if mode == "0" else "no-pallas"}
+    if mode == "0":
+        d = {"path": "xla", "why": "gate=0"}
     elif mode == "1":
         d = {"path": "pallas", "why": "gate=1"}
     elif jax.default_backend() != "tpu":
@@ -155,8 +147,10 @@ def decide(family: str, B: int, k: int, jdtype: str) -> str:
 
 def _autotune(sig) -> dict:
     """Time both paths on synthetic operands of this signature (TPU
-    only, eager — never under a trace) and cache the winner. The PR 4/5
-    autotune shape: scalar read-back forces completion, median of 3."""
+    only, eager — never under a trace) and cache the winner: median of
+    3. A kernel the backend refuses is recorded with the compiler's
+    message in ``why`` (visible in :func:`last_decisions`); the oracle
+    then serves, and a forced gate (``=1``) raises instead."""
     family, B, k, jdtype = sig
     jt = jnp.dtype(jdtype)
     nb = max(2, min(B, 64))
@@ -186,26 +180,26 @@ def _autotune(sig) -> dict:
             )
 
     def _time(fn) -> float:
-        fn()  # compile + warm
+        jax.block_until_ready(fn())  # compile + warm
         ts = []
         for _ in range(3):
             t0 = time.perf_counter()
-            out = fn()
-            float(jnp.asarray(out).ravel()[0])  # sync read-back
+            jax.block_until_ready(fn())
             ts.append(time.perf_counter() - t0)
         ts.sort()
         return ts[1]
 
+    t_o = _time(run_xla)
     try:
         t_k = _time(run_pallas)
-        t_o = _time(run_xla)
+    except Exception as e:  # the backend refused the brick kernel
+        d = {"path": "xla", "why": f"autotune: pallas refused: {type(e).__name__}: {e}"}
+    else:
         d = {
             "path": "pallas" if t_k < t_o else "xla",
             "why": f"autotune:{t_k * 1e6:.0f}us-vs-{t_o * 1e6:.0f}us",
             "autotuned": True,
         }
-    except Exception as e:  # pragma: no cover - TPU-side failure
-        d = {"path": "xla", "why": f"autotune-error:{type(e).__name__}"}
     _AUTOTUNE[sig] = d
     return d
 
@@ -341,8 +335,6 @@ def spmm_bcsr_program(comm, m: int, nb: int, B: int, split, out_ndim: int,
     kw = dict(nb=nb, B=B, c=c, jt=jt, acc=acc, path=path)
 
     if split == 0 and p > 1:
-        from ..core._jax_compat import shard_map
-
         ax = comm.axis_name
 
         def local(bdata, bcol, brow, bmask, x):
@@ -354,6 +346,7 @@ def spmm_bcsr_program(comm, m: int, nb: int, B: int, split, out_ndim: int,
             mesh=comm.mesh,
             in_specs=(P(ax, None, None), P(ax), P(ax), P(ax, None), P(None, None)),
             out_specs=P(ax, None),
+            check_vma=False,  # pallas_call outputs carry no vma annotation
         )
 
         def run(bdata, bcol, brow, bmask, x):
@@ -406,8 +399,6 @@ def sddmm_bcsr_program(comm, mb: int, nb: int, B: int, split, jdtype: str,
     kw = dict(mb=mb, nb=nb, B=B, jt=jt, acc=acc, path=path)
 
     if split == 0 and p > 1:
-        from ..core._jax_compat import shard_map
-
         ax = comm.axis_name
 
         def local(sdata, bcol, brow, u, v):
@@ -418,6 +409,7 @@ def sddmm_bcsr_program(comm, mb: int, nb: int, B: int, split, jdtype: str,
             mesh=comm.mesh,
             in_specs=(P(ax, None, None), P(ax), P(ax), P(None, None), P(None, None)),
             out_specs=P(ax, None, None),
+            check_vma=False,  # pallas_call outputs carry no vma annotation
         )
         return jax.jit(fn)  # shardlint: ignore[SL202] -- lru-cached brick program (see spmm_bcsr_program); sharded path routes through comm.jit_sharded
 

@@ -28,7 +28,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..core._jax_compat import pcast, shard_map
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from typing import Optional
@@ -37,7 +38,7 @@ from ..core.dndarray import DNDarray
 from ..core.communication import register_mesh_cache
 from ..core import types
 
-__all__ = ["ring_attention", "ring_self_attention"]
+__all__ = ["last_decisions", "ring_attention", "ring_self_attention"]
 
 
 def _online_softmax_update(q, k_c, v_c, o, m, l, valid, scale, neg):
@@ -225,10 +226,21 @@ def _blocked_attention_program(
     return jax.jit(run)
 
 
-# set only on import-level failure (kernel module unavailable); a shape
-# whose kernel cannot compile is cached as None per-signature instead
-_PALLAS_ATTENTION_UNAVAILABLE = False
-_SPLASH_ATTENTION_UNAVAILABLE = False
+# which program served each dispatched signature, and why a fused kernel
+# did not where it did not: {signature: {"path": ..., "why": ...}}. Only
+# gates (backend, x64, tracers, shapes) route to the blocked XLA
+# programs; a kernel that fails to BUILD or COMPILE on the TPU backend
+# raises to the caller — it is never cached as a silent fallback.
+_DECISIONS: dict = {}
+
+
+def last_decisions() -> dict:
+    """Copy of the attention dispatcher's decisions: the ``path``
+    (``splash``/``flash``/``blocked`` on one device, ``ring-splash``/
+    ``ring-flash``/``blocked-ring`` over a mesh) that served each
+    signature, with the gate's reason where a kernel was not used."""
+    return {k: dict(v) for k, v in _DECISIONS.items()}
+
 
 # tests force Mosaic interpret mode so the kernel ring path runs (slowly)
 # on CPU meshes; production leaves this False and the path is TPU-gated
@@ -262,43 +274,40 @@ def _ring_step_kernels(
     for the block on the ring diagonal (src == r, requires bq == bk);
     ``full_fn`` is unmasked for blocks strictly behind the query block.
 
-    bf16 → splash kernel (the 0.684-MFU single-device carrier, which
-    computes in bf16 anyway); f32 → the flash kernel via its residual
-    form (keeps f32 exactness, no interpret mode). Build failures are
-    cached as None and the blocked XLA ring stays the fallback/oracle.
+    bf16 → splash kernel (which computes in bf16 anyway); f32 → the
+    flash kernel via its residual form (keeps f32 exactness, no
+    interpret mode). Only the shape gates return None; a build failure
+    raises.
     """
     jt = jnp.dtype(jdtype)
     if jt == jnp.bfloat16 or (interpret and jt == jnp.float32):
-        if _SPLASH_ATTENTION_UNAVAILABLE:
-            return None
         bq_blk = _pick_block(bq, (1024, 512, 256, 128))
         bkv_blk = _pick_block(bk, (2048, 1024, 512, 256, 128))
         if bq_blk is None or bkv_blk is None or d % 64 != 0:
             return None
-        try:
-            full_fn = _build_splash_mha(
-                h, bq, bk, False, scale, bq_blk, bkv_blk, True, interpret
+        full_fn = _build_splash_mha(
+            h, bq, bk, False, scale, bq_blk, bkv_blk, True, interpret
+        )
+        diag_fn = (
+            _build_splash_mha(
+                h, bq, bq, True, scale, bq_blk, bq_blk, True, interpret
             )
-            diag_fn = (
-                _build_splash_mha(
-                    h, bq, bq, True, scale, bq_blk, bq_blk, True, interpret
-                )
-                if bq == bk
-                else None
-            )
-        except Exception:
-            return None
+            if bq == bk
+            else None
+        )
         return (full_fn, diag_fn)
 
     if jt == jnp.float32 and not interpret:
-        if _PALLAS_ATTENTION_UNAVAILABLE:
-            return None
-        try:
-            import jax.experimental.pallas.ops.tpu.flash_attention as _fa
-        except Exception:
-            return None
+        import jax.experimental.pallas.ops.tpu.flash_attention as _fa
+
         bq_blk = _pick_block(bq, (1024, 512, 256, 128))
-        bkm = _pick_block(bk, (2048, 1024, 512, 256, 128))
+        # the residual form's (1024, 2048, 1024) working set grows with
+        # the K/V block and passes the 16 MB scoped-VMEM limit from
+        # bk = 16384 on (16.41 MB there, refused by the v5e compiler):
+        # long blocks take a 1024 k-major block
+        bkm = _pick_block(
+            bk, (1024, 512, 256, 128) if bk >= 16384 else (2048, 1024, 512, 256, 128)
+        )
         bk_blk = _pick_block(bk, (1024, 512, 256, 128))
         if None in (bq_blk, bkm, bk_blk) or d % 64 != 0:
             return None
@@ -307,8 +316,8 @@ def _ring_step_kernels(
             def run(qa, ka, va):
                 # keyword-bind everything after the arrays: the impl is
                 # underscore-private, and a signature drift must fail
-                # loudly (TypeError → cached None) rather than bind
-                # positionally and compute wrong residuals
+                # loudly (TypeError) rather than bind positionally and
+                # compute wrong residuals
                 o, l, m = _fa._flash_attention_impl(
                     qa, ka, va, None, None,
                     save_residuals=True, causal=causal_blk,
@@ -350,7 +359,7 @@ def _ring_attention_kernel_callable(
     diagonal (causal-masked kernel), or full (unmasked).
 
     Returns None when the signature has no serving kernel (odd blocks,
-    non-divisible shards, unavailable kernel module). Dispatch goes
+    non-divisible shards). Dispatch goes
     through the AOT ``_ring_attention_kernel_program``; bench loops this
     traceable form inside a fori_loop for the device-rate ring row.
     """
@@ -490,9 +499,9 @@ def _ring_attention_kernel_program(
 ):
     """AOT-compiled executable of ``_ring_attention_kernel_callable``,
     lowered against the exact shardings dispatch guarantees (the DNDarray
-    physical layout) — same rationale as ``_pallas_attention_program``: a
-    per-signature Mosaic failure surfaces here, once, and is cached as
-    None; it can never be re-paid at every ring_attention call."""
+    physical layout). None when a shape gate of the callable refuses
+    the signature; a Mosaic compile failure raises here, once, to the
+    caller."""
     fn = _ring_attention_kernel_callable(
         mesh, axis_name, n_q, n_kv, b, h, d, causal, scale, jdtype, interpret
     )
@@ -502,36 +511,34 @@ def _ring_attention_kernel_program(
     spec = P(*(axis_name if i == seq_axis else None for i in range(4)))
     jt = jnp.dtype(jdtype)
     sh = NamedSharding(mesh, spec)
-    try:
-        return jax.jit(fn).lower(
-            jax.ShapeDtypeStruct((b, h, n_q, d), jt, sharding=sh),
-            jax.ShapeDtypeStruct((b, h, n_kv, d), jt, sharding=sh),
-            jax.ShapeDtypeStruct((b, h, n_kv, d), jt, sharding=sh),
-        ).compile()
-    except Exception:
-        return None
+    return jax.jit(fn).lower(
+        jax.ShapeDtypeStruct((b, h, n_q, d), jt, sharding=sh),
+        jax.ShapeDtypeStruct((b, h, n_kv, d), jt, sharding=sh),
+        jax.ShapeDtypeStruct((b, h, n_kv, d), jt, sharding=sh),
+    ).compile()
 
 
-def _ring_kernel_eligible(qp, kp, vp, ndim: int, seq_axis: int, jt) -> bool:
-    """Dispatch gate for the kernel ring: concrete 4-D (B, H, S, D)
+def _ring_kernel_refusal(qp, kp, vp, ndim: int, seq_axis: int, jt) -> Optional[str]:
+    """Dispatch gate for the kernel ring — why it cannot serve these
+    operands, or None when it can: concrete 4-D (B, H, S, D)
     self-attention-shaped operands on the TPU backend (or interpret mode
     for tests), matching head dims, x64 off. Shape/divisibility gates
-    live in the program builder, which caches None per signature."""
+    live in the program builder, which returns None per signature."""
     if not (_RING_KERNEL_INTERPRET or jax.default_backend() == "tpu"):
-        return False
+        return "backend is not tpu"
     if jax.config.jax_enable_x64 and not _RING_KERNEL_INTERPRET:
         # hardware kernels mis-trace under forced x64 (same gate as
         # _pallas_attention); interpret mode traces cleanly regardless
-        return False
+        return "x64 is forced on"
     if any(isinstance(t, jax.core.Tracer) for t in (qp, kp, vp)):
         # user jit/grad trace: only the blocked ring is guaranteed
         # differentiable (the save-residuals combine is forward-only)
-        return False
-    if ndim != 4 or seq_axis != 2:
-        return False
-    if qp.shape[-1] != vp.shape[-1]:
-        return False
-    return jnp.dtype(jt) in (jnp.bfloat16, jnp.float32)
+        return "traced operands: the kernel ring is forward-only"
+    if ndim != 4 or seq_axis != 2 or qp.shape[-1] != vp.shape[-1]:
+        return "shape gate: needs (B, H, S, D) operands with equal q/v head dims"
+    if jnp.dtype(jt) not in (jnp.bfloat16, jnp.float32):
+        return f"dtype gate: {jnp.dtype(jt).name} is neither bf16 nor f32"
+    return None
 
 
 def _build_splash_mha(
@@ -541,8 +548,8 @@ def _build_splash_mha(
     """Shared splash-kernel assembly (mask, BlockSizes, pre-scaled-q vmap
     wrapper) behind both the single-device callable and the ring step
     kernels — the splash configuration lives in exactly one place.
-    Splash takes a PRE-SCALED q (no sm_scale parameter). Raises on
-    import/shape failure; callers cache None."""
+    Splash takes a PRE-SCALED q (no sm_scale parameter). Raises on a
+    shape the kernel refuses; the shape gates live with the callers."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
         splash_attention_mask as _sm,
@@ -589,10 +596,6 @@ def _splash_callable(q_shape, kv_shape, causal: bool, scale: float, jdtype: str)
     parameter), applied inside the compiled program. bench.py loops this
     callable inside a fori_loop for the stable device-rate row; dispatch
     uses the AOT ``_splash_attention_program``."""
-    global _SPLASH_ATTENTION_UNAVAILABLE
-    if _SPLASH_ATTENTION_UNAVAILABLE:
-        return None
-
     if jnp.dtype(jdtype) != jnp.bfloat16:
         # splash runs its matmuls in bf16 regardless of input dtype
         # (measured f32 rel-err ~3e-3 vs the blocked oracle, where the
@@ -605,53 +608,33 @@ def _splash_callable(q_shape, kv_shape, causal: bool, scale: float, jdtype: str)
     bkv = 2048 if skv % 2048 == 0 else 1024
     if skv % bkv != 0:
         return None
-    try:
-        return _build_splash_mha(h, sq, skv, causal, scale, 1024, bkv, False, False)
-    except ImportError:
-        _SPLASH_ATTENTION_UNAVAILABLE = True
-        return None
-    except Exception:
-        return None
+    return _build_splash_mha(h, sq, skv, causal, scale, 1024, bkv, False, False)
 
 
 @functools.lru_cache(maxsize=64)
 def _splash_attention_program(q_shape, kv_shape, causal: bool, scale: float, jdtype: str):
-    """AOT-compiled executable of ``_splash_callable`` (same rationale as
-    ``_pallas_attention_program``: per-shape Mosaic failures surface here,
-    once, never at dispatch)."""
+    """AOT-compiled executable of ``_splash_callable``, or None when its
+    shape gates refuse the signature. A Mosaic compile failure raises."""
     run = _splash_callable(q_shape, kv_shape, causal, scale, jdtype)
     if run is None:
         return None
-    try:
-        jt = jnp.dtype(jdtype)
-        return jax.jit(run).lower(
-            jax.ShapeDtypeStruct(q_shape, jt),
-            jax.ShapeDtypeStruct(kv_shape, jt),
-            jax.ShapeDtypeStruct(kv_shape, jt),
-        ).compile()
-    except Exception:
-        return None
+    jt = jnp.dtype(jdtype)
+    return jax.jit(run).lower(
+        jax.ShapeDtypeStruct(q_shape, jt),
+        jax.ShapeDtypeStruct(kv_shape, jt),
+        jax.ShapeDtypeStruct(kv_shape, jt),
+    ).compile()
 
 
 @functools.lru_cache(maxsize=64)
 def _pallas_attention_program(q_shape, kv_shape, causal: bool, scale: float, jdtype: str):
     """AOT-compiled Mosaic (Pallas) flash-attention executable for one
-    signature, or None if the kernel cannot compile for it (VMEM overflow
-    etc.) — the failure is cached so the signature is probed exactly once,
-    and other signatures keep the kernel. Compiling here means a per-shape
-    Mosaic error can never surface at dispatch time (dispatch only happens
-    on concrete arrays; traced calls are gated to the blocked program)."""
-    global _PALLAS_ATTENTION_UNAVAILABLE
-    if _PALLAS_ATTENTION_UNAVAILABLE:
-        return None
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes,
-            flash_attention,
-        )
-    except Exception:
-        _PALLAS_ATTENTION_UNAVAILABLE = True
-        return None
+    signature that ``_pallas_attention_fits`` admits. A Mosaic compile
+    failure (VMEM overflow etc.) raises to the caller."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        BlockSizes,
+        flash_attention,
+    )
 
     sq, skv = q_shape[-2], kv_shape[-2]
     # v5e-tuned tiles (interleaved sweep: ~1.4x over the blocked XLA
@@ -673,18 +656,15 @@ def _pallas_attention_program(q_shape, kv_shape, causal: bool, scale: float, jdt
             qa, ka, va, causal=causal, sm_scale=float(scale), block_sizes=bs
         )
 
-    try:
-        jt = jnp.dtype(jdtype)
-        # the AOT Compiled executable is what gets called — compiling once
-        # and dispatching through jit would compile the kernel a second
-        # time (AOT lowering does not populate jit's dispatch cache)
-        return jax.jit(run).lower(
-            jax.ShapeDtypeStruct(q_shape, jt),
-            jax.ShapeDtypeStruct(kv_shape, jt),
-            jax.ShapeDtypeStruct(kv_shape, jt),
-        ).compile()
-    except Exception:
-        return None
+    jt = jnp.dtype(jdtype)
+    # the AOT Compiled executable is what gets called — compiling once
+    # and dispatching through jit would compile the kernel a second
+    # time (AOT lowering does not populate jit's dispatch cache)
+    return jax.jit(run).lower(
+        jax.ShapeDtypeStruct(q_shape, jt),
+        jax.ShapeDtypeStruct(kv_shape, jt),
+        jax.ShapeDtypeStruct(kv_shape, jt),
+    ).compile()
 
 
 def _pallas_attention_fits(q_shape, k_shape, v_shape, dtype) -> bool:
@@ -704,51 +684,59 @@ def _pallas_attention_fits(q_shape, k_shape, v_shape, dtype) -> bool:
     )
 
 
-def _pallas_attention(qa, ka, va, causal: bool, scale: float):
-    """Mosaic (Pallas) fused flash-attention kernel for the single-device
-    path — the native-kernel realization of the same online-softmax
-    algorithm (one (Bq, Bk) tile in VMEM at a time). Returns None when the
-    workload does not fit the kernel's tiling constraints; the blocked
-    XLA program is the fallback and the numerical oracle."""
+def _kernel_refusal(qa, ka, va) -> Optional[str]:
+    """Why the fused single-device kernels cannot serve these operands,
+    or None when they can."""
     if jax.default_backend() != "tpu":
-        return None
+        return "backend is not tpu"
     if jax.config.jax_enable_x64:
         # explicitly-forced x64 on TPU: the kernel's block-index maps mix
         # int32 iotas with Python ints and mis-trace in x64 mode — the
         # blocked XLA program serves this configuration
-        return None
+        return "x64 is forced on"
     if any(isinstance(t, jax.core.Tracer) for t in (qa, ka, va)):
         # inside a user jit/grad trace: only the blocked program is
         # guaranteed differentiable and compilable — the flash kernel's
         # dkv/dq backward kernels are never AOT-probed here
-        return None
+        return "traced operands: the kernels are forward-only here"
     if not _pallas_attention_fits(qa.shape, ka.shape, va.shape, qa.dtype):
-        return None
+        return (
+            "shape gate: needs 4-D f32/bf16 self-attention with S % 512 == 0 "
+            "and D % 64 == 0"
+        )
     # the Compiled executable is lowered for default-device placement;
     # operands living elsewhere (explicit device_put, multi-chip sharding)
     # take the jitted blocked program, which places freely
-    try:
-        devs = {d for t in (qa, ka, va) for d in t.devices()}
-    except Exception:
+    if {d for t in (qa, ka, va) for d in t.devices()} != {jax.devices()[0]}:
+        return "operands are not on the default device"
+    return None
+
+
+def _pallas_attention(qa, ka, va, causal: bool, scale: float):
+    """Mosaic (Pallas) fused attention kernel for the single-device path
+    — the native-kernel realization of the same online-softmax algorithm
+    (one (Bq, Bk) tile in VMEM at a time): splash where its shape gates
+    admit the signature (bf16, 1024-multiple S), else the flash kernel.
+    Returns None when a gate refuses the operands (recorded with its
+    reason in ``last_decisions``); the blocked XLA program then serves
+    and is the numerical oracle."""
+    sig = (
+        "single", tuple(qa.shape), tuple(ka.shape), np.dtype(qa.dtype).name,
+        bool(causal),
+    )
+    why = _kernel_refusal(qa, ka, va)
+    if why is not None:
+        _DECISIONS[sig] = {"path": "blocked", "why": why}
         return None
-    if devs != {jax.devices()[0]}:
-        return None
-    # splash preferred (measured faster on v5e, see _splash_attention_program),
-    # flash kernel as fallback, blocked XLA program as the oracle
-    prog = _splash_attention_program(
-        tuple(qa.shape), tuple(ka.shape), bool(causal), float(scale),
-        np.dtype(qa.dtype).name,
-    ) or _pallas_attention_program(
+    args = (
         tuple(qa.shape), tuple(ka.shape), bool(causal), float(scale),
         np.dtype(qa.dtype).name,
     )
+    prog, path = _splash_attention_program(*args), "splash"
     if prog is None:
-        return None
-    try:
-        return prog(qa, ka, va)
-    except Exception:
-        # placement/runtime mismatch the gates missed — blocked fallback
-        return None
+        prog, path = _pallas_attention_program(*args), "flash"
+    _DECISIONS[sig] = {"path": path, "why": "compiled kernel"}
+    return prog(qa, ka, va)
 
 
 def _single_device_attention(qa, ka, va, causal: bool, scale):
@@ -828,19 +816,26 @@ def ring_attention(
     qp = q._phys.astype(jt) if q.split == seq_axis else comm.shard(q.larray.astype(jt), seq_axis)
     kp = k._phys.astype(jt) if k.split == seq_axis else comm.shard(k.larray.astype(jt), seq_axis)
     vp = v._phys.astype(jt) if v.split == seq_axis else comm.shard(v.larray.astype(jt), seq_axis)
-    if _ring_kernel_eligible(qp, kp, vp, q.ndim, seq_axis, jt):
+    sig = (
+        "ring", comm.size, tuple(q.shape), tuple(k.shape), np.dtype(jt).name,
+        bool(causal),
+    )
+    why = _ring_kernel_refusal(qp, kp, vp, q.ndim, seq_axis, jt)
+    if why is None:
         kprog = _ring_attention_kernel_program(
             comm.mesh, comm.axis_name, q.shape[seq_axis], k.shape[seq_axis],
             q.shape[0], q.shape[1], q.shape[-1], bool(causal), float(scale),
             np.dtype(jt).name, _RING_KERNEL_INTERPRET,
         )
         if kprog is not None:
-            try:
-                out_phys = kprog(qp, kp, vp)
-            except Exception:
-                out_phys = None  # Mosaic runtime miss the gates can't see
-            if out_phys is not None:
-                return DNDarray(out_phys, out_gshape, dtype, seq_axis, q.device, comm)
+            splash = jnp.dtype(jt) == jnp.bfloat16 or _RING_KERNEL_INTERPRET
+            _DECISIONS[sig] = {
+                "path": "ring-splash" if splash else "ring-flash",
+                "why": "interpret-mode kernel" if _RING_KERNEL_INTERPRET else "compiled kernel",
+            }
+            return DNDarray(kprog(qp, kp, vp), out_gshape, dtype, seq_axis, q.device, comm)
+        why = "shape gate: shards not divisible into kernel blocks, or D % 64 != 0"
+    _DECISIONS[sig] = {"path": "blocked-ring", "why": why}
     prog = _ring_attention_program(
         comm.mesh, comm.axis_name, q.ndim, seq_axis,
         q.shape[seq_axis], k.shape[seq_axis], bool(causal), float(scale),

@@ -400,7 +400,7 @@ def _all_gather_probe_fn(mesh):
 
     from jax.sharding import PartitionSpec as P
 
-    from ..core._jax_compat import shard_map
+    from jax import shard_map
 
     return jax.jit(
         shard_map(
@@ -408,6 +408,9 @@ def _all_gather_probe_fn(mesh):
             mesh=mesh,
             in_specs=P("probe"),
             out_specs=P(None),
+            # the installed vma checker cannot infer that a tiled
+            # all_gather's result is replicated
+            check_vma=False,
         )
     )
 

@@ -44,7 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..core._jax_compat import shard_map
+from jax import shard_map
 
 from typing import Optional
 

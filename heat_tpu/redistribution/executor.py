@@ -70,7 +70,7 @@ from jax.sharding import PartitionSpec as P
 
 from typing import Optional, Tuple
 
-from ..core._jax_compat import shard_map
+from jax import shard_map
 from ..observability import telemetry as _telemetry
 from ..observability import tracing as _tracing
 from . import planner as _planner
@@ -674,16 +674,12 @@ def _pivot_program(
 
 
 def _relayout_impls(
-    spec: RedistSpec, sched: Schedule, concrete: bool = True
+    spec: RedistSpec, sched: Schedule
 ) -> Tuple[Optional[str], Optional[str]]:
     """The (unpack-in, pack-out) kernel implementations serving a
     packed-pivot plan, decided EAGERLY at program-build time and baked
     into the program cache key: flipping ``HEAT_TPU_RELAYOUT_KERNEL``
-    rebuilds the program. ``concrete=False`` (the executor is itself
-    being traced, e.g. a reshape under ``ht.jit``) forbids the blocking
-    autotune — the decision falls back to a cached winner or the XLA
-    floor, honoring the ``relayout-autotune-sync`` boundary's
-    never-inside-a-trace contract."""
+    rebuilds the program."""
     from ..kernels import relayout as _relayout
 
     packed_in, packed_out = _packed_flags(sched)
@@ -691,12 +687,12 @@ def _relayout_impls(
     (r0, c0), (r1, c1) = spec.gshape, spec.out_shape
     c0p, c1p = _pad_extent(c0, p), _pad_extent(c1, p)
     impl_in = (
-        _relayout.decide("unpack", r0 // p, c0p, c0, p, spec.dtype, concrete=concrete)
+        _relayout.decide("unpack", r0 // p, c0p, c0, p, spec.dtype)
         if packed_in
         else None
     )
     impl_out = (
-        _relayout.decide("pack", r1 // p, c1, c1p, p, spec.dtype, concrete=concrete)
+        _relayout.decide("pack", r1 // p, c1, c1p, p, spec.dtype)
         if packed_out
         else None
     )
@@ -978,9 +974,7 @@ def execute(comm, phys, spec: RedistSpec, sched: Optional[Schedule] = None):
                 if any(st.kind in ("pack", "unpack") for st in sched.steps):
                     if _telemetry._ENABLED:
                         _telemetry.inc("redist.relayout.packed")
-                    impl_in, impl_out = _relayout_impls(
-                        spec, sched, concrete=not isinstance(phys, jax.core.Tracer)
-                    )
+                    impl_in, impl_out = _relayout_impls(spec, sched)
                     return _packed_pivot_program(
                         comm, spec, budget, impl_in, impl_out, pipelined, wire, topo
                     )(phys)
@@ -995,9 +989,7 @@ def execute(comm, phys, spec: RedistSpec, sched: Optional[Schedule] = None):
         if strategy == "packed-pivot":
             if _telemetry._ENABLED:
                 _telemetry.inc("redist.relayout.packed")
-            impl_in, impl_out = _relayout_impls(
-                spec, sched, concrete=not isinstance(phys, jax.core.Tracer)
-            )
+            impl_in, impl_out = _relayout_impls(spec, sched)
             return _packed_pivot_program(
                 comm, spec, budget, impl_in, impl_out, pipelined, wire, topo
             )(phys)

@@ -536,55 +536,48 @@ _XLA_CACHE_SAVED: Optional[tuple] = None
 
 def _reset_xla_cache_binding() -> None:
     """jax binds its persistent-cache object on first use; re-point it
-    after a config change (no-op on jax versions without the hook)."""
-    try:
-        from jax._src import compilation_cache as _cc
+    after a config change."""
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
+    _cc.reset_cache()
 
 
 def _wire_xla_cache(root: str) -> None:
     """Point jax's persistent compilation cache under the store root so
     XLA executables are reused across processes too (TPU/GPU; a no-op
-    store on CPU backends without executable-cache support). Respects a
-    user-set ``jax_compilation_cache_dir``; undone on disable."""
+    store on CPU backends without executable-cache support). Stands back
+    where a directory is already configured (the entry scripts'
+    ``place_compile_cache`` or ``JAX_COMPILATION_CACHE_DIR``); undone on
+    disable."""
     global _XLA_CACHE_WIRED, _XLA_CACHE_SAVED
-    try:
-        if jax.config.jax_compilation_cache_dir is None:
-            _XLA_CACHE_SAVED = (
-                jax.config.jax_persistent_cache_min_compile_time_secs,
-                jax.config.jax_persistent_cache_min_entry_size_bytes,
-            )
-            os.makedirs(os.path.join(root, "xla"), exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", os.path.join(root, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            _reset_xla_cache_binding()
-            _XLA_CACHE_WIRED = True
-    except Exception:
-        pass  # older jax without these knobs: export layer still works
+    if jax.config.jax_compilation_cache_dir is None:
+        _XLA_CACHE_SAVED = (
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            jax.config.jax_persistent_cache_min_entry_size_bytes,
+        )
+        os.makedirs(os.path.join(root, "xla"), exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", os.path.join(root, "xla"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        _reset_xla_cache_binding()
+        _XLA_CACHE_WIRED = True
 
 
 def _unwire_xla_cache() -> None:
     global _XLA_CACHE_WIRED, _XLA_CACHE_SAVED
     if not _XLA_CACHE_WIRED:
         return
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        if _XLA_CACHE_SAVED is not None:
-            # the floors are global knobs a user may rely on later —
-            # restore, don't leave every sub-second compile cacheable
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", _XLA_CACHE_SAVED[0]
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", _XLA_CACHE_SAVED[1]
-            )
-        _reset_xla_cache_binding()
-    except Exception:
-        pass
+    jax.config.update("jax_compilation_cache_dir", None)
+    if _XLA_CACHE_SAVED is not None:
+        # the floors are global knobs a user may rely on later —
+        # restore, don't leave every sub-second compile cacheable
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", _XLA_CACHE_SAVED[0]
+        )
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", _XLA_CACHE_SAVED[1]
+        )
+    _reset_xla_cache_binding()
     _XLA_CACHE_WIRED = False
     _XLA_CACHE_SAVED = None
 

@@ -23,7 +23,7 @@ Usage::
 
     python scripts/bench_compare.py                 # auto-pick files
     python scripts/bench_compare.py --strict        # gate (nonzero exit)
-    python scripts/bench_compare.py --baseline BENCH_r04.json --threshold 0.15
+    python scripts/bench_compare.py --baseline BENCH_r05.json --threshold 0.15
 """
 
 from __future__ import annotations
@@ -78,11 +78,9 @@ HIGHER_IS_BETTER = {
     # speedup of the `*_2x8_dcn` rows (tests pin >= 2x; dp_step_quant_2x8
     # reuses dp_model_speedup)
     "tier_model_speedup",
-    # serving acceptance fields (ISSUE 9): sustained micro-batched QPS
-    # (serving_qps row) and the fresh-process AOT-load-vs-compile ratio
-    # (serving_coldstart row, target >= 10x on TPU rounds)
+    # serving acceptance field (ISSUE 9): sustained micro-batched QPS
+    # (serving_qps row)
     "qps",
-    "coldstart_speedup",
     # out-of-core staging acceptance fields (ISSUE 11) on the
     # `*_hostram`/`kmeans_stream_2gb` rows: achieved fraction of the
     # depth-2 staging bound (tests pin >= 0.5; ~1.0 on real PCIe DMA),

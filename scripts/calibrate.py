@@ -71,7 +71,9 @@ def main() -> int:
     args = ap.parse_args()
 
     from heat_tpu.observability import calibration
+    from heat_tpu.utils import place_compile_cache
 
+    place_compile_cache()
     if args.workload:
         _span_workload()
 

@@ -61,6 +61,7 @@ SEED = 11
 
 
 def main() -> int:
+    ht.utils.place_compile_cache()
     n_dev = len(jax.devices())
     topology = "2x4" if n_dev == 8 else None  # odd meshes: flat, kill half
     rng = np.random.default_rng(0)

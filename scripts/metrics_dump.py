@@ -70,7 +70,9 @@ def main() -> int:
 
     from heat_tpu.observability import telemetry, tracing
     import heat_tpu.observability as obs
+    from heat_tpu.utils import place_compile_cache
 
+    place_compile_cache()
     if not args.no_workload:
         telemetry.enable()  # tracing follows at HEAT_TPU_TRACE=auto
         _workload()

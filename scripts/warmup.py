@@ -50,6 +50,7 @@ def main() -> int:
 
     import heat_tpu as ht
 
+    ht.utils.place_compile_cache()
     if args.list:
         print(json.dumps({"programs": sorted(ht.serving.WARMUP_PROGRAMS)}))
         return 0

@@ -70,7 +70,7 @@ def int8_wire_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -100,7 +100,7 @@ def flat_dcn_a2a_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -124,7 +124,7 @@ def ppermute_ring_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     p = comm.size
@@ -464,7 +464,7 @@ def divergent_cond_collective_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -493,7 +493,7 @@ def uniform_cond_collective_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -522,7 +522,7 @@ def divergent_while_collective_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -554,7 +554,7 @@ def open_ring_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     p = comm.size
@@ -581,7 +581,7 @@ def opposite_order_collectives_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     phys = x._phys
@@ -613,7 +613,7 @@ def overlapping_groups_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     p = comm.size
@@ -640,7 +640,7 @@ def aligned_groups_program(x):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from heat_tpu.core._jax_compat import shard_map
+    from jax import shard_map
 
     comm = x.comm
     p = comm.size

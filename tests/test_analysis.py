@@ -100,7 +100,7 @@ class TestIRCheckBadFixture(TestCase):
         from jax import lax
         from jax.sharding import PartitionSpec as PS
 
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
 
         enc = jax.jit(lambda g: g.astype(jnp.int8))  # shardlint: ignore[SL202] -- fixture
 
@@ -369,7 +369,7 @@ class TestMemCheckGoldenFixtures(TestCase):
         import jax as _jax
         from jax.sharding import PartitionSpec as PS
 
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
 
         mc = importlib.import_module("heat_tpu.analysis.memcheck")
         comm = ht.get_comm()
@@ -522,6 +522,8 @@ class TestLintCLI(TestCase):
             self.assertIn("SL201", r.stdout)
             self.assertIn("SL202", r.stdout)
 
+    # slow: ~17 s of CLI subprocesses; test_cli_exit_codes keeps the exit-code contract in tier-1
+    @pytest.mark.slow
     def test_sarif_format_exit_codes(self):
         """ISSUE 10 satellite: `--format sarif` emits one SARIF 2.1.0
         document with one run per pass and rule ids = SLxxx, while the

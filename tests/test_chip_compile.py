@@ -1,0 +1,204 @@
+"""Compile the main path's Pallas kernels for the real chip, without one.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (on-chip-measurement guide, section 2.3). A
+kernel that passes every interpret-mode test can still be refused here —
+for a block that breaks the (8, 128) tiling rule, a float iota, a reshape
+Mosaic cannot lay out — so these compiles guard each later PR at no chip
+time. ``interpret=False`` throughout; shapes are chip_smoke.py's.
+
+The topology is described inside a module-scoped fixture: only the xdist
+worker that is handed this file loads libtpu, and it keeps the lock until
+it exits — so every compile runs in this process, and all of them live in
+this one file.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable can be written to the persistent
+    # cache but not read back: keep the cache off around these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the chip's dtype policy: x64 off (Mosaic refuses 64-bit types). The
+    # CPU suite runs x64, switched on by whichever test file first touched
+    # the backend in this worker
+    with jax.enable_x64(False):
+        yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices), ("d",))
+
+
+def _compile(fn, *specs):
+    """The compiled module's text; raises what the chip's compiler raises."""
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _holds_kernel(fn, *specs) -> bool:
+    return "tpu_custom_call" in _compile(fn, *specs)
+
+
+def test_sketch_fused_and_dual(one_chip):
+    """hSVD's headline shard, 65536 x 8192 f32: the 2-pass sketch+norm
+    kernel and the one-view dual-sketch kernel."""
+    from heat_tpu.core.linalg import _pallas_sketch as ps
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    m, n = 65536, 8192
+    assert _holds_kernel(
+        ps._fused_call(m, n, 1024, 1024), s((ps._L_PAD, m), F32), s((m, n), F32)
+    )
+    assert _holds_kernel(
+        ps._dual_call(m, n, 512, 1024),
+        s((ps._L2_PAD, m), F32), s((n, ps._K_PAD), F32), s((m, n), F32),
+    )
+
+
+def test_sort_block(one_chip):
+    """The radix block kernel (integer iota; one-row blocks on a 3-D
+    array). 4 blocks: the (8, 128) rule bites only past one block. (One
+    case: this kernel alone compiles for ~12 s.)"""
+    from heat_tpu.kernels import sort as ks
+
+    n_blocks, t = 4, ks._PALLAS_BLOCK
+    blk = jax.ShapeDtypeStruct((n_blocks, 1, t), I32, sharding=one_chip)
+    assert _holds_kernel(ks._pallas_block_call(n_blocks, t, 2, 4, False), blk, blk)
+
+
+@pytest.mark.parametrize("op", ["pack", "unpack"])
+def test_relayout_refused_and_off_auto(one_chip, op, monkeypatch):
+    """Mosaic refuses the relayout kernels' in-register reshape, so
+    ``auto`` must not pick them (kernels/relayout.AUTO_REFUSAL). When a
+    toolchain accepts them this test fails: put them back on ``auto``."""
+    from heat_tpu.kernels import relayout as rl
+
+    rows, p = 65536, 4
+    b = rl._block_rows(rows, 128)
+    if op == "pack":
+        call = rl._pack_call(rows // b, b, 64, 128, p, "float32", False)
+        spec = jax.ShapeDtypeStruct((rows // b, 1, b * 64), F32, sharding=one_chip)
+        sig = ("pack", rows, 64, 128, p, "float32")
+    else:
+        call = rl._unpack_call(rows // b, b, 128, 64, p, "float32", False)
+        spec = jax.ShapeDtypeStruct((p, rows * 32), F32, sharding=one_chip)
+        sig = ("unpack", rows, 128, 64, p, "float32")
+    with pytest.raises(Exception, match="unsupported shape cast"):
+        _compile(call, spec)
+    monkeypatch.delenv("HEAT_TPU_RELAYOUT_KERNEL", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert rl.decide(*sig) == "xla"
+    assert "unsupported shape cast" in rl.last_decisions()[sig]["why"]
+
+
+@pytest.mark.parametrize("k", [4, 128])
+def test_spmm_brick(one_chip, k):
+    from heat_tpu.kernels import spmm
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    B, nb = 16384, 64
+    assert _holds_kernel(
+        spmm._brick_spmm_call(B, nb, k, "float32", False),
+        s((B,), I32), s((B, spmm.BR, spmm.BC), F32), s((nb, spmm.BC, k), F32),
+    )
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_sddmm_brick(one_chip, d):
+    from heat_tpu.kernels import spmm
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    B, mb, nb = 16384, 64, 64
+    assert _holds_kernel(
+        spmm._brick_sddmm_call(B, mb, nb, d, "float32", False),
+        s((B,), I32), s((B,), I32), s((B, spmm.BR, spmm.BC), F32),
+        s((mb, spmm.BR, d), F32), s((nb, spmm.BC, d), F32),
+    )
+
+
+@pytest.mark.parametrize("n", [4_194_304, 15_625_000])
+def test_kmeans_fused_assign(one_chip, n):
+    """The opt-in KMeans kernel, up to the north-star shard: its last
+    tile is masked in the kernel, so no padded copy of x is asked for
+    (with one, 15 625 000 x 64 needed 18.6 GB of a 15.75 GB chip)."""
+    from heat_tpu.cluster import _pallas as kp
+
+    d, k = 64, 8
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    prog = kp.fused_assign_program(n, d, k, "float32")
+    assert "tpu_custom_call" in prog.lower(s((n, d), F32), s((k, d), F32)).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype,family", [("bfloat16", "splash"), ("float32", "flash")])
+def test_attention_ring_step(one_chip, dtype, family):
+    """The per-ring-step kernels at the smoke's block, S=16384, D=128:
+    splash for bf16, the flash residual form for f32; full and causal
+    diagonal."""
+    from heat_tpu.nn import attention as att
+
+    b, h, s_len, d = 1, 8, 16384, 128
+    full, diag = att._ring_step_kernels(b, h, s_len, s_len, d, d ** -0.5, dtype, False)
+    q = jax.ShapeDtypeStruct((b, h, s_len, d), jnp.dtype(dtype), sharding=one_chip)
+    assert _holds_kernel(full, q, q, q), family
+    assert _holds_kernel(diag, q, q, q), family
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_attention_ring_program_four_chips(mesh4, dtype):
+    """The whole kernel ring over the 2x2 mesh: bf16 unrolled around
+    splash, f32 scan-with-carry around flash, K/V rotating by ppermute."""
+    from heat_tpu.nn import attention as att
+
+    b, h, s_len, d = 1, 8, 16384, 128
+    fn = att._ring_attention_kernel_callable(
+        mesh4, "d", s_len, s_len, b, h, d, True, d ** -0.5, dtype, False
+    )
+    q = jax.ShapeDtypeStruct(
+        (b, h, s_len, d), jnp.dtype(dtype),
+        sharding=NamedSharding(mesh4, P(None, None, "d", None)),
+    )
+    txt = _compile(fn, q, q, q)
+    assert "tpu_custom_call" in txt and "collective-permute" in txt
+
+
+def test_hsvd_level0_under_shard_map(mesh4, monkeypatch):
+    """hsvd_rank's level-0 program on four chips: the sketch kernel inside
+    shard_map, one (8192, 16384) column block per chip. The kernel's gate
+    reads the backend, which is the CPU here: steer it in the test."""
+    from heat_tpu.core.linalg import svdtools
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog = svdtools._local_svd_fn(mesh4, "d", 8192, 16384, 15, "float32", 25, None)
+    a = jax.ShapeDtypeStruct((8192, 65536), F32, sharding=NamedSharding(mesh4, P(None, "d")))
+    assert "tpu_custom_call" in prog.lower(a).compile().as_text()

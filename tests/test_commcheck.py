@@ -455,7 +455,7 @@ class TestSeededMutations(TestCase):
         congruence scan sees a device that receives without sending."""
         from jax.sharding import PartitionSpec as PS
 
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
 
         comm = self.comm
         full = cmatmul.grouped_ring_perm(1, P)
@@ -490,7 +490,7 @@ class TestSeededMutations(TestCase):
         from jax import lax
         from jax.sharding import PartitionSpec as PS
 
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
 
         comm = self.comm
 

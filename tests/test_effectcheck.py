@@ -257,6 +257,8 @@ class TestCacheKeyByteIdentity(TestCase):
         }
         self.assertEqual(got, _PR11_PLAN_IDS)
 
+    # slow: ~25 s subprocess dump; the plan-id pin above stays in tier-1
+    @pytest.mark.slow
     def test_golden_dump_bytes_unchanged_from_pr11(self):
         """The `scripts/redist_plans.py` dump — every canonical plan
         serialization, quant twins included — byte-identical to PR 11

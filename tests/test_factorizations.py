@@ -239,10 +239,13 @@ class TestEigh(TestCase):
     def test_eigh_distributed_recursion(self):
         """Force the divide-and-conquer to RECURSE distributed (not
         fall back to the local eigh of the sub-blocks) by lowering the
-        resplit threshold below the branch sizes."""
+        resplit threshold below the first level's branch sizes (~32):
+        one distributed recursion level, whose sub-branches solve
+        locally. (At 8 every level down to 8x8 compiled its own polar,
+        TSQR and ring programs: 91 s for the same code paths.)"""
         hn = _spd(64, seed=19) * 2
         old = F._EIGH_RESPLIT_MIN_N
-        F._EIGH_RESPLIT_MIN_N = 8
+        F._EIGH_RESPLIT_MIN_N = 24
         try:
             w, v = ht.linalg.eigh(ht.array(hn, split=0))
         finally:
@@ -426,6 +429,8 @@ class TestBitIdentity(TestCase):
 
         self._both_modes(run)
 
+    # slow: ~23 s of compiles; polar and cholesky/lu keep the seq-vs-pipelined pin in tier-1
+    @pytest.mark.slow
     def test_solve_eigh_bit_identical(self):
         hn = _spd(64, seed=34) * 2
         bn = _randn(64, 5, seed=35)

@@ -123,10 +123,14 @@ class TestDispatch(TestCase):
         with _env("HEAT_TPU_RELAYOUT_KERNEL", "1"):
             self.assertEqual(relayout.decide("pack", 8, 25, 32, 8, "float32"), "pallas")
 
-    def test_auto_off_tpu_is_xla(self):
+    def test_auto_is_xla_and_says_why(self):
+        """The Pallas kernels are off ``auto`` on every backend (Mosaic
+        refuses them, tests/test_chip_compile.py pins it): the decision
+        is XLA and carries the compiler's reason."""
+        sig = ("pack", 8, 25, 32, 8, "float32")
         with _env("HEAT_TPU_RELAYOUT_KERNEL", None):
-            if jax.default_backend() != "tpu":
-                self.assertEqual(relayout.decide("pack", 8, 25, 32, 8, "float32"), "xla")
+            self.assertEqual(relayout.decide(*sig), "xla")
+            self.assertIn("unsupported shape cast", relayout.last_decisions()[sig]["why"])
 
     def test_forced_mode_unserviceable_falls_back(self):
         from heat_tpu.observability import telemetry

@@ -356,6 +356,28 @@ class TestDispatch:
         finally:
             ksort._DECISIONS.pop(key, None)
 
+    def test_autotune_records_a_refused_candidate(self, monkeypatch):
+        """A candidate the backend refuses to lower is written into
+        last_decisions() with the compiler's words — never a bare inf
+        that reads like a slow timing — and the rest are still timed."""
+        n = 1 << 14  # past the radix gates: columnsort is the kernel path
+        real = ksort._run_pair_path
+
+        def refuse(u, idx, *, path, n):
+            if path == "columnsort":
+                raise NotImplementedError("Mosaic says no")
+            return real(u, idx, path=path, n=n)
+
+        monkeypatch.setattr(ksort, "_run_pair_path", refuse)
+        key = (n, "float32", "pairs")
+        try:
+            assert ksort._autotune(n, "float32") == "lax"
+            dec = ksort.last_decisions()[key]
+            assert dec["refused"] == {"columnsort": "NotImplementedError: Mosaic says no"}
+            assert list(dec["timings"]) == ["lax"] and dec["timings"]["lax"] < float("inf")
+        finally:
+            ksort._DECISIONS.pop(key, None)
+
     def test_sort_plan_models(self):
         lax_plan = ksort.sort_plan(1 << 27, "float32", path="lax")
         col_plan = ksort.sort_plan(1 << 27, "float32", path="columnsort")

@@ -547,22 +547,6 @@ class TestRingKernelAttention:
             comm.mesh, comm.axis_name, S, S, self.B, self.H,
             self.D, True, scale, "float32", True,
         )
-        if kprog is None:
-            # capability gate, not a regression: older splash kernels
-            # demand head_dim % 128 == 0 and refuse this D=64 signature
-            # (dispatch then falls back to the blocked XLA ring). Probe
-            # the kernel directly so a real program-build break on a
-            # capable runtime still fails loudly.
-            import jax.numpy as jnp
-
-            fns = att._build_splash_mha(
-                self.H, 128, 128, False, scale, 128, 128, True, True
-            )
-            shp = jax.ShapeDtypeStruct((self.B, self.H, 128, self.D), jnp.float32)
-            try:
-                jax.eval_shape(fns, shp, shp, shp)
-            except NotImplementedError as e:
-                pytest.skip(f"runtime splash kernel cannot serve D={self.D}: {e}")
         assert kprog is not None
         txt = kprog.as_text()
         n_pp = txt.count(" collective-permute(") + txt.count("collective-permute-start(")
@@ -603,6 +587,25 @@ class TestRingKernelAttention:
             att._ring_attention_kernel_callable.cache_clear()
             att._ring_attention_kernel_program.cache_clear()
 
+    def test_kernel_build_failure_raises(self, monkeypatch):
+        """Only shape gates may answer None: a kernel that fails to BUILD
+        is an error the caller sees, never a cached silent fallback to
+        the blocked ring."""
+        import heat_tpu.nn.attention as att
+
+        def broken(*a, **kw):
+            raise RuntimeError("Mosaic says no")
+
+        monkeypatch.setattr(att, "_build_splash_mha", broken)
+        att._ring_step_kernels.cache_clear()
+        try:
+            with pytest.raises(RuntimeError, match="Mosaic says no"):
+                att._ring_step_kernels(1, 2, 128, 128, 64, 0.125, "bfloat16", True)
+            # the shape gate still answers None without building anything
+            assert att._ring_step_kernels(1, 2, 100, 100, 64, 0.125, "bfloat16", True) is None
+        finally:
+            att._ring_step_kernels.cache_clear()
+
     def test_ineligible_signatures_fall_back(self):
         import heat_tpu.nn.attention as att
 
@@ -631,17 +634,17 @@ class TestRingKernelAttention:
 
         def probe(x):
             with mock.patch.object(att, "_RING_KERNEL_INTERPRET", True):
-                hit.append(att._ring_kernel_eligible(x, x, x, 4, 2, jnp.float32))
+                hit.append(att._ring_kernel_refusal(x, x, x, 4, 2, jnp.float32))
             return x
 
         jax.make_jaxpr(probe)(jnp.zeros((1, 2, 64, 64), jnp.float32))
-        assert hit == [False]
+        assert len(hit) == 1 and "traced" in hit[0]
 
 
 class TestPallasAttentionGating:
     """The Mosaic flash kernel is a TPU-only fast path: on any other
-    backend the gate must return None (blocked program serves), and a
-    per-signature compile failure must not disable other signatures."""
+    backend the gate must return None (blocked program serves) and say
+    why in ``last_decisions``."""
 
     def test_gate_off_on_non_tpu_backend(self):
         import jax
@@ -652,8 +655,8 @@ class TestPallasAttentionGating:
             pytest.skip("gate is open on a real TPU backend")
         x = jnp.zeros((1, 1, 512, 64), jnp.float32)
         assert att._pallas_attention(x, x, x, False, 0.125) is None
-        # gating must not have flipped the import-unavailable flag
-        assert not att._PALLAS_ATTENTION_UNAVAILABLE
+        dec = att.last_decisions()[("single", x.shape, x.shape, "float32", False)]
+        assert dec == {"path": "blocked", "why": "backend is not tpu"}
 
     def test_shape_gate_backend_independent(self):
         import jax.numpy as jnp

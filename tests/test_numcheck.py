@@ -294,7 +294,7 @@ class TestCleanPins(TestCase):
         f32 EF residual) — the shape SL601/SL603 exist to protect."""
         from jax.sharding import PartitionSpec as PS
 
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
 
         comm = self.comm
 

@@ -224,6 +224,8 @@ class TestVerifyPlansCLI(TestCase):
     """scripts/verify_plans.py: exit 0 over a fresh dump, exit 1 with
     the invariant named over a corrupted one — the ci.sh leg contract."""
 
+    # slow: ~22 s of CLI subprocesses; runs in scripts/ci.sh's full leg
+    @pytest.mark.slow
     def test_cli_ok_and_malformed(self):
         import tempfile
 

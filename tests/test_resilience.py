@@ -252,22 +252,28 @@ class TestCheckpointEnvelope(TestCase):
 
     def test_write_floor_vs_disk_edge(self):
         """Supporting evidence for the bench floor (``ckpt_write_2gb``
-        pins >= 0.5x at 2.1 GB): a 256 MiB durable commit must not fall
-        below a LOOSE 0.2x of the lattice's disk edge even on a noisy
-        CI box — the pipelined writer is disk-bound, not hash-bound."""
+        pins >= 0.5x at 2.1 GB): the pipelined writer is disk-bound, not
+        hash-bound — a 64 MiB durable commit must reach a LOOSE 0.2x of
+        what THIS disk gives a plain write + fsync of the same bytes,
+        measured beside it. (Against the lattice's constant disk edge the
+        test measured the sandbox: 0.09-0.10 GB/s here, under any code.)"""
         import time
 
         with tempfile.TemporaryDirectory() as d:
-            data = np.random.default_rng(0).standard_normal((64 << 20) // 8)
-            data = data.astype(np.float32)  # 32 MiB x 8 = 256 MiB? no: keep simple
-            data = np.tile(data, 8)  # 256 MiB
+            data = np.random.default_rng(0).standard_normal((32 << 20) // 4)
+            data = np.tile(data.astype(np.float32), 2)  # 64 MiB
+            t0 = time.perf_counter()
+            with open(os.path.join(d, "raw.bin"), "wb") as f:
+                f.write(data.tobytes())
+                f.flush()
+                os.fsync(f.fileno())
+            raw = time.perf_counter() - t0
             t0 = time.perf_counter()
             ck.save({"data": data}, tag="bw", step=1, directory=d)
             dt = time.perf_counter() - t0
-            gbps = data.nbytes / dt / 1e9
-            self.assertGreaterEqual(
-                gbps, 0.2 * tiers.bandwidth("disk") / 1e9,
-                f"durable commit at {gbps:.3f} GB/s",
+            self.assertLessEqual(
+                dt, raw / 0.2,
+                f"durable commit took {dt:.2f} s, a plain write+fsync {raw:.2f} s",
             )
 
     def test_failed_save_leaks_no_writer_threads(self):

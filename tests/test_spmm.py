@@ -210,6 +210,21 @@ class TestBrickSpMM:
         np.testing.assert_array_equal(y0.view(np.uint32), y1.view(np.uint32))
 
 
+    def test_autotune_records_a_refused_kernel(self):
+        """The TPU autotuner compiles the brick kernel out of interpret
+        mode; a backend that refuses it (here: the CPU has no Mosaic)
+        leaves the oracle serving and the refusal, in the compiler's
+        words, in the decision — not a silent fallback."""
+        sig = ("spmm", 16, 4, "float32")
+        try:
+            d = kspmm._autotune(sig)
+            assert d["path"] == "xla" and "autotuned" not in d
+            assert d["why"].startswith("autotune: pallas refused: ")
+            assert len(d["why"]) > len("autotune: pallas refused: X")
+        finally:
+            kspmm._AUTOTUNE.pop(sig, None)
+
+
 class TestSDDMM:
     def _setup(self, split, seed=15, dtype=np.float32):
         csr = _rand_csr(70, 260, 500, seed=seed, dtype=dtype)

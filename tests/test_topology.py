@@ -411,7 +411,7 @@ class TestTieredExecutor(TestCase):
         np.testing.assert_array_equal(outs["0"], outs["1"])
 
     def test_hierarchical_allreduce_sum_matches_psum(self):
-        from heat_tpu.core._jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PS
 
         rng = np.random.default_rng(2)
@@ -424,13 +424,13 @@ class TestTieredExecutor(TestCase):
             )
             return out[None], resid[None]
 
-        f = shard_map(
+        f = jax.jit(shard_map(  # jitted: eager shard_map runs op by op
             body,
             mesh=comm.mesh,
             in_specs=(PS(comm.axis_name, None),),
             out_specs=(PS(comm.axis_name, None), PS(comm.axis_name, None)),
             check_vma=False,
-        )
+        ))
         out, resid = f(comm.shard(jnp.asarray(h), 0))
         want = h.sum(axis=0)
         got = np.asarray(out)

@@ -13,6 +13,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 _WORKER = r"""
 import jax
 jax.config.update('jax_platforms', 'cpu')
@@ -79,6 +81,8 @@ print('TSQR_TWO_LEVEL_OK')
 """
 
 
+# slow: ~23 s in a subprocess; runs in scripts/ci.sh's full leg
+@pytest.mark.slow
 def test_two_level_tsqr_subprocess():
     env = {k: v for k, v in os.environ.items() if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     env["JAX_PLATFORMS"] = "cpu"
