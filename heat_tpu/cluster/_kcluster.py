@@ -26,6 +26,8 @@ from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..core.communication import place as _place
+from ..observability.instrument import observed_program_cache
+from ..observability.tracing import span as _span
 
 __all__ = ["_KCluster"]
 
@@ -76,7 +78,7 @@ def make_fit_loop(step, jdtype: str, tol: float, max_iter: int, returns_inertia:
     return jax.jit(run)
 
 
-@functools.lru_cache(maxsize=64)
+@observed_program_cache("kcluster.fused_fit", maxsize=64)
 def _fused_fit_program(step, k: int, shape, jdtype: str, tol: float, max_iter: int,
                        returns_inertia: bool, metric: str, seeded: bool):
     """The ENTIRE fit — ++-seeding (when ``seeded``), the convergence
@@ -105,7 +107,7 @@ def _fused_fit_program(step, k: int, shape, jdtype: str, tol: float, max_iter: i
     return run
 
 
-@functools.lru_cache(maxsize=64)
+@observed_program_cache("kcluster.predict", maxsize=64)
 def _predict_program(metric: str, eval_fv: bool):
     """The fused label-assignment program ``(arr, centers) -> labels[,
     functional value]`` — ONE dispatch for the whole predict path
@@ -136,7 +138,8 @@ def serving_spec(metric: str, centers: jax.Array, comm=None) -> dict:
     why the key lives here, next to the program)."""
     k, d = int(centers.shape[0]), int(centers.shape[1])
     return {
-        "build": lambda: _predict_program(metric, False),
+        # the jitted program itself: jax.export takes no proxy
+        "build": lambda: _predict_program(metric, False).program,
         "args": (centers,),
         "key": ("kcluster-predict", metric, k, d, str(np.dtype(centers.dtype))),
         "feature_shape": (d,),
@@ -393,54 +396,58 @@ class _KCluster(BaseEstimator, ClusteringMixin):
         seeded = isinstance(self.init, str) and self.init in (
             "probability_based", "kmeans++", "k-means++",
         )
-        if seeded:
-            # the SHARED derivation keeps seeded results identical between
-            # the fused fit and the composite _kmeanspp path
-            init_arg = self._with_stream(lambda: _seed_key(k))
-        else:
-            self._initialize_cluster_centers(x)
-            init_arg = self._cluster_centers.larray
+        with _span("ht.call.kmeans.init"):
+            if seeded:
+                # the SHARED derivation keeps seeded results identical between
+                # the fused fit and the composite _kmeanspp path
+                init_arg = self._with_stream(lambda: _seed_key(k))
+            else:
+                self._initialize_cluster_centers(x)
+                init_arg = self._cluster_centers.larray
 
-        step = step_factory(k, tuple(arr.shape), np.dtype(arr.dtype).name)
-        prog = _fused_fit_program(
-            step, k, tuple(arr.shape), np.dtype(arr.dtype).name,
-            float(self.tol), int(self.max_iter), returns_inertia,
-            self._assignment_metric, seeded,
-        )
-        centers, n_iter_dev, labels, inertia_dev = prog(arr, init_arg)
+        with _span("ht.call.kmeans.program"):
+            step = step_factory(k, tuple(arr.shape), np.dtype(arr.dtype).name)
+            prog = _fused_fit_program(
+                step, k, tuple(arr.shape), np.dtype(arr.dtype).name,
+                float(self.tol), int(self.max_iter), returns_inertia,
+                self._assignment_metric, seeded,
+            )
+            centers, n_iter_dev, labels, inertia_dev = prog(arr, init_arg)
 
-        self._n_iter = n_iter_dev  # lazy device scalars; properties read them
-        self._inertia = inertia_dev
-        self._cluster_centers = DNDarray(
-            _place(centers, x.comm.sharding(2, None)),
-            (k, x.shape[1]),
-            types.canonical_heat_type(centers.dtype),
-            None,
-            x.device,
-            x.comm,
-        )
-        gshape = (x.shape[0],)
-        split = 0 if x.split is not None else None
-        if split is not None:
-            labels = x.comm.shard(labels, split)
-        # index-output dtype convention (ADVICE r4): like sort/topk/unique
-        # indices, labels declare the PHYSICAL buffer's canonical type —
-        # int64 in x64 mode, int32 under the TPU degrade policy — so
-        # index-valued outputs expose one consistent logical dtype
-        self._labels = DNDarray(
-            labels, gshape, types.canonical_heat_type(labels.dtype), split,
-            x.device, x.comm,
-        )
+        with _span("ht.call.kmeans.wrap"):
+            self._n_iter = n_iter_dev  # lazy device scalars; properties read them
+            self._inertia = inertia_dev
+            self._cluster_centers = DNDarray(
+                _place(centers, x.comm.sharding(2, None)),
+                (k, x.shape[1]),
+                types.canonical_heat_type(centers.dtype),
+                None,
+                x.device,
+                x.comm,
+            )
+            gshape = (x.shape[0],)
+            split = 0 if x.split is not None else None
+            if split is not None:
+                labels = x.comm.shard(labels, split)
+            # index-output dtype convention (ADVICE r4): like sort/topk/unique
+            # indices, labels declare the PHYSICAL buffer's canonical type —
+            # int64 in x64 mode, int32 under the TPU degrade policy — so
+            # index-valued outputs expose one consistent logical dtype
+            self._labels = DNDarray(
+                labels, gshape, types.canonical_heat_type(labels.dtype), split,
+                x.device, x.comm,
+            )
         return self
 
     def predict(self, x: DNDarray) -> DNDarray:
         """Labels of the closest cluster center for new data (reference:
         _kcluster.py predict). One fused program dispatch (see
         ``_predict_program``)."""
-        sanitize_in(x)
-        if self._cluster_centers is None:
-            raise RuntimeError("fit needs to be called before predict")
-        return self._assign_to_cluster(x)
+        with _span("ht.call.kmeans.predict"):
+            sanitize_in(x)
+            if self._cluster_centers is None:
+                raise RuntimeError("fit needs to be called before predict")
+            return self._assign_to_cluster(x)
 
     def serving_program(self) -> dict:
         """The endpoint description ``ht.serving.estimator_endpoint``

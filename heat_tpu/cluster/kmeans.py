@@ -18,8 +18,6 @@ slab, each window one ``partial_fit`` batch.
 
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 import jax
@@ -32,11 +30,13 @@ from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ._kcluster import _KCluster
 from ..core.communication import place as _place
+from ..observability.instrument import observed_program_cache
+from ..observability.tracing import span as _span
 
 __all__ = ["KMeans"]
 
 
-@functools.lru_cache(maxsize=64)
+@observed_program_cache("kmeans.lloyd_step", maxsize=64)
 def _lloyd_step(k: int, shape, jdtype: str, use_pallas: Optional[bool] = None):
     """One Lloyd iteration as a pure jitted function: (x, centers) →
     (new_centers, shift², inertia).
@@ -88,7 +88,7 @@ def _lloyd_step(k: int, shape, jdtype: str, use_pallas: Optional[bool] = None):
     return step
 
 
-@functools.lru_cache(maxsize=64)
+@observed_program_cache("kmeans.partial_fit_step", maxsize=64)
 def _partial_fit_step(k: int, shape, jdtype: str):
     """One STREAMING minibatch update as a pure jitted function:
     ``(arr, centers, counts) -> (new_centers, new_counts, inertia)``.
@@ -223,11 +223,12 @@ class KMeans(_KCluster):
                         "streaming window path, which HEAT_TPU_OOC=0 "
                         "disables — unset the gate or drop ckpt="
                     )
-                return self._fit_fused(
-                    _staging.materialize(x, what="KMeans.fit"),
-                    _lloyd_step,
-                    returns_inertia=True,
-                )
+                with _span("ht.call.kmeans.fit"):
+                    return self._fit_fused(
+                        _staging.materialize(x, what="KMeans.fit"),
+                        _lloyd_step,
+                        returns_inertia=True,
+                    )
             return self._partial_fit_stream(
                 x, ckpt=ckpt, watcher=_watcher, chaos=_chaos, fresh=True
             )
@@ -238,7 +239,8 @@ class KMeans(_KCluster):
                 "stream a staging.HostArray (or drive partial_fit batches) "
                 "to checkpoint mid-fit"
             )
-        return self._fit_fused(x, _lloyd_step, returns_inertia=True)
+        with _span("ht.call.kmeans.fit"):
+            return self._fit_fused(x, _lloyd_step, returns_inertia=True)
 
     # ------------------------------------------------------------------ #
     # streaming / out-of-core (ISSUE 11)                                 #

@@ -37,6 +37,7 @@ from . import _padding
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shape, sanitize_axis
 from ..observability.instrument import observed_program_cache
+from ..observability.tracing import span as _span
 
 __all__ = []
 
@@ -151,8 +152,7 @@ def _resolve_neutral(tag, dtype):
 # --------------------------------------------------------------------- #
 # cached jitted executors                                               #
 # --------------------------------------------------------------------- #
-@observed_program_cache("op.binary")
-@functools.lru_cache(maxsize=4096)
+@observed_program_cache("op.binary", maxsize=4096)
 def _binary_callable(op, comm, out_ndim, split, n, pext, cast, scalar1, scalar2, kw):
     """One compiled program: cast → align pads → op → restore zero pad.
     ``scalar1/2`` record which operands arrived as Python scalars — those
@@ -175,8 +175,7 @@ def _binary_callable(op, comm, out_ndim, split, n, pext, cast, scalar1, scalar2,
     return comm.jit_sharded(fn, out_ndim, split)
 
 
-@observed_program_cache("op.unary")
-@functools.lru_cache(maxsize=4096)
+@observed_program_cache("op.unary", maxsize=4096)
 def _unary_callable(op, comm, ndim, split, n, pext, cast, static_kw, dyn_names):
     def fn(arr, *dyn):
         kwargs = dict(static_kw)
@@ -191,8 +190,7 @@ def _unary_callable(op, comm, ndim, split, n, pext, cast, static_kw, dyn_names):
     return comm.jit_sharded(fn, ndim, split)
 
 
-@observed_program_cache("op.reduce")
-@functools.lru_cache(maxsize=4096)
+@observed_program_cache("op.reduce", maxsize=4096)
 def _reduce_callable(op, comm, split, n, pext, axes, keepdims, neutral, out_ndim, out_split, out_n, out_pext, kw):
     def fn(arr):
         if split is not None and pext != n and neutral is not None:
@@ -207,8 +205,7 @@ def _reduce_callable(op, comm, split, n, pext, axes, keepdims, neutral, out_ndim
     return comm.jit_sharded(fn, out_ndim, out_split)
 
 
-@observed_program_cache("op.cum")
-@functools.lru_cache(maxsize=1024)
+@observed_program_cache("op.cum", maxsize=1024)
 def _cum_callable(op, comm, ndim, split, n, pext, axis, cast):
     def fn(arr):
         if cast is not None:
@@ -617,6 +614,23 @@ def __reduce_op(
             out.larray = _padding.unpad(result, output_shape, output_split).astype(out.dtype.jax_type())
         return out
     return DNDarray(result, output_shape, res_type, output_split, x.device, comm)
+
+
+def _spanned(name: str, op: Callable) -> Callable:
+    """``op`` under one dispatch span ``name``: lookup, call and wrapping."""
+
+    @functools.wraps(op)
+    def dispatch(*args, **kwargs):
+        with _span(name):
+            return op(*args, **kwargs)
+
+    return dispatch
+
+
+__binary_op = _spanned("ht.op.binary", __binary_op)
+__cum_op = _spanned("ht.op.cum", __cum_op)
+__local_op = _spanned("ht.op.unary", __local_op)
+__reduce_op = _spanned("ht.op.reduce", __reduce_op)
 
 from .communication import register_mesh_cache
 

@@ -43,6 +43,7 @@ from . import gates as _gates
 from ..observability import events as _obs_events
 from ..observability import telemetry as _telemetry
 from ..observability.instrument import nbytes_of as _nbytes_of
+from ..observability.tracing import span as _span
 
 __all__ = [
     "Communication",
@@ -268,9 +269,10 @@ def place(array: jax.Array, sharding) -> jax.Array:
     physical sharding. Under a trace this lowers to
     ``with_sharding_constraint`` (which IS binding); eagerly it is a plain
     ``device_put``."""
-    if isinstance(array, jax.core.Tracer):
-        return jax.lax.with_sharding_constraint(array, sharding)
-    return jax.device_put(array, sharding)
+    with _span("ht.comm.place"):
+        if isinstance(array, jax.core.Tracer):
+            return jax.lax.with_sharding_constraint(array, sharding)
+        return jax.device_put(array, sharding)
 
 
 def jit_sharded_mesh(fn, mesh, sharding_thunk):
@@ -487,13 +489,14 @@ class MeshCommunication(Communication):
                 bytes=nbytes,
                 traced=isinstance(array, jax.core.Tracer),
             )
-        if split is not None:
-            split = split % max(array.ndim, 1)
-            if array.shape[split] == 0:
-                # zero-extent split axis: nothing to distribute, store replicated
-                return place(array, self.sharding(array.ndim, None))
-            array = _padding.pad_logical(array, split, self.size)
-        return place(array, self.sharding(array.ndim, split))
+        with _span("ht.comm.shard"):
+            if split is not None:
+                split = split % max(array.ndim, 1)
+                if array.shape[split] == 0:
+                    # zero-extent split axis: nothing to distribute, store replicated
+                    return place(array, self.sharding(array.ndim, None))
+                array = _padding.pad_logical(array, split, self.size)
+            return place(array, self.sharding(array.ndim, split))
 
     def reshard_phys(
         self, phys: jax.Array, gshape, old_split: Optional[int], new_split: Optional[int]
@@ -522,7 +525,8 @@ class MeshCommunication(Communication):
             )
         from ..redistribution import executor as _redist_exec
 
-        return _redist_exec.resplit_phys(self, phys, gshape, old_split, new_split)
+        with _span("ht.comm.reshard"):
+            return _redist_exec.resplit_phys(self, phys, gshape, old_split, new_split)
 
     # ------------------------------------------------------------------ #
     # communicator management                                            #

@@ -55,6 +55,7 @@ from typing import Any, Callable, Dict, List, Optional
 from .dndarray import DNDarray
 from ..observability import events as _obs_events
 from ..observability import telemetry as _telemetry
+from ..observability.tracing import span as _span
 
 # __all__ stays ["jit"]: the executable_* introspection helpers below
 # are the analyzer's module-level readers (heat_tpu.analysis.memcheck),
@@ -376,100 +377,102 @@ def jit(fn: Optional[Callable] = None, **jit_kwargs) -> Callable:
         is_new_entry = entry is None
         from_aot = False
         donate_positions = ()
-        if entry is None:
-            if donate_user:
-                # map USER positional args to the flattened traced-leaf
-                # positions they contribute (statics carry no buffer and
-                # are skipped) — this is the alignment the r4 limitation
-                # note said was missing
-                if any(u < 0 or u >= len(args) for u in donate_user):
-                    raise ValueError(
-                        f"donate_argnums {donate_user} out of range for "
-                        f"{len(args)} positional arguments"
+        # the names observed_program_cache gives its lookups and launches
+        with _span("ht.program.miss" if is_new_entry else "ht.program.hit", cache="ht.jit"):
+            if entry is None:
+                if donate_user:
+                    # map USER positional args to the flattened traced-leaf
+                    # positions they contribute (statics carry no buffer and
+                    # are skipped) — this is the alignment the r4 limitation
+                    # note said was missing
+                    if any(u < 0 or u >= len(args) for u in donate_user):
+                        raise ValueError(
+                            f"donate_argnums {donate_user} out of range for "
+                            f"{len(args)} positional arguments"
+                        )
+                    spans, off = [], 0
+                    for a in args:
+                        n = len(jax.tree.flatten(a, is_leaf=_is_leaf)[0])
+                        spans.append(range(off, off + n))
+                        off += n
+                    traced_pos, t = {}, 0
+                    for i, (kind, _) in enumerate(specs):
+                        if kind != "static":
+                            traced_pos[i] = t
+                            t += 1
+                    donate_positions = tuple(
+                        traced_pos[i]
+                        for u in donate_user
+                        for i in spans[u]
+                        if i in traced_pos
                     )
-                spans, off = [], 0
-                for a in args:
-                    n = len(jax.tree.flatten(a, is_leaf=_is_leaf)[0])
-                    spans.append(range(off, off + n))
-                    off += n
-                traced_pos, t = {}, 0
-                for i, (kind, _) in enumerate(specs):
-                    if kind != "static":
-                        traced_pos[i] = t
-                        t += 1
-                donate_positions = tuple(
-                    traced_pos[i]
-                    for u in donate_user
-                    for i in spans[u]
-                    if i in traced_pos
-                )
-            aot = _AOT_HOOKS
-            if aot is not None:
-                entry = aot.load(fn, treedef, specs, donate_user, donate_positions, jit_kwargs)
-                from_aot = entry is not None
-                if from_aot:
-                    cache[key] = entry
-        if entry is None:
-            out_box = []
+                aot = _AOT_HOOKS
+                if aot is not None:
+                    entry = aot.load(fn, treedef, specs, donate_user, donate_positions, jit_kwargs)
+                    from_aot = entry is not None
+                    if from_aot:
+                        cache[key] = entry
+            if entry is None:
+                out_box = []
 
-            def inner(*traced):
-                # NOTE: closes over `specs` (metadata) only — never over
-                # `leaves`, which would pin the first call's device buffers
-                # in HBM for the lifetime of the cache entry
-                it = iter(traced)
-                rebuilt = []
-                for kind, spec in specs:
-                    if kind == "dnd":
-                        rebuilt.append(spec.rebuild(next(it)))
-                    elif kind in ("jax", "np"):
-                        rebuilt.append(next(it))
-                    else:
-                        rebuilt.append(spec)
-                a, kw = jax.tree.unflatten(treedef, rebuilt)
-                try:
-                    res = fn(*a, **kw)
-                except (
-                    jax.errors.ConcretizationTypeError,
-                    jax.errors.TracerArrayConversionError,
-                    jax.errors.TracerBoolConversionError,
-                    jax.errors.TracerIntegerConversionError,
-                ) as e:
-                    raise TypeError(
-                        "ht.jit: an op inside the traced function needs the array's "
-                        "VALUES on the host (data-dependent output shape — unique/"
-                        "nonzero/boolean-mask indexing — or a float()/int() read). "
-                        "Run that op eagerly, outside ht.jit. Original: " + str(e)
-                    ) from None
-                out_leaves, out_treedef = jax.tree.flatten(res, is_leaf=_is_leaf)
-                phys_out, out_meta = [], []
-                for o in out_leaves:
-                    if isinstance(o, DNDarray):
-                        out_meta.append(_DndSpec(o))
-                        phys_out.append(o._phys)
-                    else:
-                        out_meta.append(None)
-                        phys_out.append(o)
-                out_box.append((out_treedef, out_meta))
-                return tuple(phys_out)
+                def inner(*traced):
+                    # NOTE: closes over `specs` (metadata) only — never over
+                    # `leaves`, which would pin the first call's device buffers
+                    # in HBM for the lifetime of the cache entry
+                    it = iter(traced)
+                    rebuilt = []
+                    for kind, spec in specs:
+                        if kind == "dnd":
+                            rebuilt.append(spec.rebuild(next(it)))
+                        elif kind in ("jax", "np"):
+                            rebuilt.append(next(it))
+                        else:
+                            rebuilt.append(spec)
+                    a, kw = jax.tree.unflatten(treedef, rebuilt)
+                    try:
+                        res = fn(*a, **kw)
+                    except (
+                        jax.errors.ConcretizationTypeError,
+                        jax.errors.TracerArrayConversionError,
+                        jax.errors.TracerBoolConversionError,
+                        jax.errors.TracerIntegerConversionError,
+                    ) as e:
+                        raise TypeError(
+                            "ht.jit: an op inside the traced function needs the array's "
+                            "VALUES on the host (data-dependent output shape — unique/"
+                            "nonzero/boolean-mask indexing — or a float()/int() read). "
+                            "Run that op eagerly, outside ht.jit. Original: " + str(e)
+                        ) from None
+                    out_leaves, out_treedef = jax.tree.flatten(res, is_leaf=_is_leaf)
+                    phys_out, out_meta = [], []
+                    for o in out_leaves:
+                        if isinstance(o, DNDarray):
+                            out_meta.append(_DndSpec(o))
+                            phys_out.append(o._phys)
+                        else:
+                            out_meta.append(None)
+                            phys_out.append(o)
+                    out_box.append((out_treedef, out_meta))
+                    return tuple(phys_out)
 
-            if donate_user:
-                jitted_inner = jax.jit(
-                    inner, donate_argnums=donate_positions, **jit_kwargs
-                )
-                if _telemetry._ENABLED:
-                    # donation decision: how many traced buffers actually
-                    # get donated for this signature (statics drop out)
-                    _telemetry.inc("ht.jit.donated_buffers", len(donate_positions))
-                    _obs_events.emit(
-                        "ht.jit.donation", fn=getattr(fn, "__name__", "<fn>"),
-                        requested_args=len(donate_user),
-                        donated_buffers=len(donate_positions),
+                if donate_user:
+                    jitted_inner = jax.jit(
+                        inner, donate_argnums=donate_positions, **jit_kwargs
                     )
-            else:
-                jitted_inner = jax.jit(inner, **jit_kwargs)
-            _warn_closure_captures(fn)
-            entry = (jitted_inner, out_box)
-            cache[key] = entry
+                    if _telemetry._ENABLED:
+                        # donation decision: how many traced buffers actually
+                        # get donated for this signature (statics drop out)
+                        _telemetry.inc("ht.jit.donated_buffers", len(donate_positions))
+                        _obs_events.emit(
+                            "ht.jit.donation", fn=getattr(fn, "__name__", "<fn>"),
+                            requested_args=len(donate_user),
+                            donated_buffers=len(donate_positions),
+                        )
+                else:
+                    jitted_inner = jax.jit(inner, **jit_kwargs)
+                _warn_closure_captures(fn)
+                entry = (jitted_inner, out_box)
+                cache[key] = entry
 
         jitted, out_box = entry
         traced_in = [
@@ -477,34 +480,33 @@ def jit(fn: Optional[Callable] = None, **jit_kwargs) -> Callable:
             for leaf, (kind, _) in zip(leaves, specs)
             if kind != "static"
         ]
+        # first dispatch of a new signature = trace + XLA compile (+ one
+        # execution); later hits pay only program dispatch
+        with _span(
+            "ht.program.compile" if is_new_entry and not from_aot else "ht.program.launch",
+            cache="ht.jit",
+        ):
+            t0 = time.perf_counter()
+            phys_out = jitted(*traced_in)
+            dt = time.perf_counter() - t0
         if _telemetry._ENABLED:
             _telemetry.inc("ht.jit.cache.miss" if is_new_entry else "ht.jit.cache.hit")
-            if is_new_entry:
-                # first dispatch of a new signature = trace + XLA compile
-                # (+ one execution); later hits pay only program dispatch.
+            if is_new_entry and from_aot:
                 # An AOT-loaded entry never traces the user function —
                 # the census stays honest: ht.jit.compile counts FULL
                 # trace+compiles only, a served cold start records under
                 # serving.aot.first_dispatch instead
-                t0 = time.perf_counter()
-                phys_out = jitted(*traced_in)
-                dt = time.perf_counter() - t0
-                if from_aot:
-                    _telemetry.observe("serving.aot.first_dispatch", dt)
-                    _obs_events.emit(
-                        "serving.aot.dispatch", fn=getattr(fn, "__name__", "<fn>"),
-                        leaves=len(leaves), seconds=round(dt, 6),
-                    )
-                else:
-                    _telemetry.observe("ht.jit.compile", dt)
-                    _obs_events.emit(
-                        "ht.jit.trace", fn=getattr(fn, "__name__", "<fn>"),
-                        leaves=len(leaves), seconds=round(dt, 6),
-                    )
-            else:
-                phys_out = jitted(*traced_in)
-        else:
-            phys_out = jitted(*traced_in)
+                _telemetry.observe("serving.aot.first_dispatch", dt)
+                _obs_events.emit(
+                    "serving.aot.dispatch", fn=getattr(fn, "__name__", "<fn>"),
+                    leaves=len(leaves), seconds=round(dt, 6),
+                )
+            elif is_new_entry:
+                _telemetry.observe("ht.jit.compile", dt)
+                _obs_events.emit(
+                    "ht.jit.trace", fn=getattr(fn, "__name__", "<fn>"),
+                    leaves=len(leaves), seconds=round(dt, 6),
+                )
         if is_new_entry and not from_aot and _AOT_HOOKS is not None:
             # persist the freshly compiled program (serving AOT cache):
             # runs AFTER the first dispatch so the hooks can read concrete

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from typing import List, Optional, Tuple, Union
 
 from .. import types
-from .._operations import __binary_op as _binary_op
+from .._operations import __binary_op as _binary_op, _spanned
 from ..communication import sanitize_comm
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
@@ -526,6 +526,10 @@ def vector_norm(
                 split = split - sum(1 for a in axes if a < split)
     return _wrap(result, split, x)
 
+
+# neither goes through the generic op wrappers: a dispatch span of its own
+matmul = _spanned("ht.op.matmul", matmul)
+transpose = _spanned("ht.op.transpose", transpose)
 
 DNDarray.transpose = transpose
 DNDarray.__matmul__ = lambda self, other: matmul(self, other)

@@ -28,7 +28,6 @@ afterwards (see ``_padding``).
 from __future__ import annotations
 
 import collections
-import functools
 
 import numpy as np
 
@@ -44,6 +43,7 @@ from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
+from ...observability.instrument import observed_program_cache
 
 __all__ = ["qr"]
 
@@ -98,7 +98,7 @@ def _tsqr_ring_active() -> bool:
     return _cm.ring_enabled()
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("qr.tsqr")
 def _tsqr_fn(
     mesh, axis_name: str, lrows: int, cols: int, jdtype: str, calc_q: bool,
     ring: bool = False, topo=None,

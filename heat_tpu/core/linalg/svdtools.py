@@ -54,6 +54,8 @@ from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
+from ...observability.instrument import observed_program_cache
+from ...observability.tracing import span as _span
 from ._lapack import safe_svd, svd_x32_scope
 
 __all__ = ["hsvd", "hsvd_rank", "hsvd_rtol"]
@@ -501,7 +503,7 @@ def _truncate_with_err(res, r_final: int):
     )
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.one_view_rank")
 def _one_view_single_rank_fn(keep: int, k_hat: int, sketch_l: int, r_final: int, want: str = "left"):
     """Jitted one-view rank-budget program (the single_pass analog of
     ``_sketched_single_rank_fn``): truncation + approximate error fold
@@ -515,7 +517,7 @@ def _one_view_single_rank_fn(keep: int, k_hat: int, sketch_l: int, r_final: int,
     return jax.jit(run)
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.sketched")
 def _sketched_single_fn(keep: int, sketch_l: int, want: str = "left"):
     """Jitted single-device randomized truncated SVD returning the
     ``want``ed factor side(s) — both sides come from the same four
@@ -530,7 +532,7 @@ def _sketched_single_fn(keep: int, sketch_l: int, want: str = "left"):
     return jax.jit(run)
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.sketched_rank")
 def _sketched_single_rank_fn(keep: int, sketch_l: int, r_final: int, want: str = "left"):
     """Rank-budget variant: truncation and the a-posteriori error fold
     into the SAME compiled program, so one call is ONE dispatch and no
@@ -557,7 +559,7 @@ def _staged_stream_fns():
     )
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.staged_rank_tail")
 def _staged_rank_tail_fn(keep: int, r_final: int, want: str):
     """Jitted tail of the staged 2-pass rank-budget sketch: the exact
     ``_projection_tail`` + truncation + error arithmetic of
@@ -569,7 +571,7 @@ def _staged_rank_tail_fn(keep: int, r_final: int, want: str):
     return jax.jit(run)
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.staged_oneview_tail")
 def _staged_oneview_tail_fn(keep: int, sketch_l: int, r_final: int, want: str):
     """Jitted tail of the staged ONE-pass sketch: ``_one_view_tail`` +
     truncation + error, on the staged (w, y, norm)."""
@@ -719,27 +721,28 @@ def _hsvd_rank_host(host, maxrank: int, compute_sv: bool, safetyshift: int,
     r_final = max(1, min(maxrank, keep))
     want = "both" if compute_sv else "left"
     ov = _one_view_params(keep, full_rank_cap, m, n) if single_pass else None
-    with svd_x32_scope(jt):
+    with _span("ht.call.hsvd.level0"), svd_x32_scope(jt):
         u_t, v_t, s_t, err_dev = _staged_sketch_rank(
             host, keep, sketch_l=l, r_final=r_final, want=want, one_view=ov, jt=jt
         )
-    err = _err_scalar(err_dev, comm=comm, device=device)
-    U = DNDarray(u_t, (m, r_final), heat_dt, None, device, comm)
-    sigma = DNDarray(
-        _place(jnp.asarray(s_t), comm.sharding(1, None)),
-        (int(s_t.shape[0]),),
-        heat_dt,
-        None,
-        device,
-        comm,
-    )
-    if not compute_sv:
-        return U, err
-    V = DNDarray(v_t, (n, r_final), heat_dt, None, device, comm)
-    return U, sigma, V, err
+    with _span("ht.call.hsvd.wrap"):
+        err = _err_scalar(err_dev, comm=comm, device=device)
+        U = DNDarray(u_t, (m, r_final), heat_dt, None, device, comm)
+        sigma = DNDarray(
+            _place(jnp.asarray(s_t), comm.sharding(1, None)),
+            (int(s_t.shape[0]),),
+            heat_dt,
+            None,
+            device,
+            comm,
+        )
+        if not compute_sv:
+            return U, err
+        V = DNDarray(v_t, (n, r_final), heat_dt, None, device, comm)
+        return U, sigma, V, err
 
 
-@functools.lru_cache(maxsize=128)
+@observed_program_cache("hsvd.local_svd")
 def _local_svd_fn(
     mesh, axis_name: str, lrows: int, lcols: int, rloc: int, jdtype: str,
     sketch_l: Optional[int] = None, one_view: Optional[tuple] = None,
@@ -881,32 +884,34 @@ def hsvd_rank(
     """
     from ...redistribution import staging as _staging
 
-    if isinstance(A, _staging.HostArray):
-        if not isinstance(maxrank, (int, np.integer)) or maxrank < 1:
-            raise ValueError(f"maxrank must be a positive integer, got {maxrank}")
-        _warn_merge_knobs(maxmergedim, None)
-        return _hsvd_rank_host(
-            A, int(maxrank), compute_sv, int(safetyshift), bool(single_pass)
+    with _span("ht.call.hsvd_rank"):
+        if isinstance(A, _staging.HostArray):
+            if not isinstance(maxrank, (int, np.integer)) or maxrank < 1:
+                raise ValueError(f"maxrank must be a positive integer, got {maxrank}")
+            _warn_merge_knobs(maxmergedim, None)
+            return _hsvd_rank_host(
+                A, int(maxrank), compute_sv, int(safetyshift), bool(single_pass)
+            )
+        with _span("ht.call.hsvd.prepare"):
+            sanitize_in(A)
+            if A.ndim != 2:
+                raise ValueError(f"hsvd requires a 2-dimensional array, got {A.ndim}")
+            if not isinstance(maxrank, (int, np.integer)) or maxrank < 1:
+                raise ValueError(f"maxrank must be a positive integer, got {maxrank}")
+            if maxmergedim is not None and maxmergedim < 2 * (maxrank + safetyshift) + 1:
+                raise ValueError(
+                    "maxmergedim too small for maxrank+safetyshift (reference constraint, svdtools.py)"
+                )
+            _warn_merge_knobs(maxmergedim, None)
+        return _hsvd_impl(
+            A,
+            maxrank=int(maxrank),
+            rtol=None,
+            safetyshift=int(safetyshift),
+            compute_sv=compute_sv,
+            silent=silent,
+            single_pass=bool(single_pass),
         )
-    sanitize_in(A)
-    if A.ndim != 2:
-        raise ValueError(f"hsvd requires a 2-dimensional array, got {A.ndim}")
-    if not isinstance(maxrank, (int, np.integer)) or maxrank < 1:
-        raise ValueError(f"maxrank must be a positive integer, got {maxrank}")
-    if maxmergedim is not None and maxmergedim < 2 * (maxrank + safetyshift) + 1:
-        raise ValueError(
-            "maxmergedim too small for maxrank+safetyshift (reference constraint, svdtools.py)"
-        )
-    _warn_merge_knobs(maxmergedim, None)
-    return _hsvd_impl(
-        A,
-        maxrank=int(maxrank),
-        rtol=None,
-        safetyshift=int(safetyshift),
-        compute_sv=compute_sv,
-        silent=silent,
-        single_pass=bool(single_pass),
-    )
 
 
 def hsvd_rtol(
@@ -923,20 +928,22 @@ def hsvd_rtol(
     svdtools.py:124): the returned factorization satisfies
     ‖A − UΣVᵀ‖_F ≤ rtol·‖A‖_F (upper-bound estimate).
     """
-    sanitize_in(A)
-    if A.ndim != 2:
-        raise ValueError(f"hsvd requires a 2-dimensional array, got {A.ndim}")
-    if rtol <= 0:
-        raise ValueError(f"rtol must be positive, got {rtol}")
-    _warn_merge_knobs(maxmergedim, no_of_merges)
-    return _hsvd_impl(
-        A,
-        maxrank=int(maxrank) if maxrank is not None else None,
-        rtol=float(rtol),
-        safetyshift=int(safetyshift),
-        compute_sv=compute_sv,
-        silent=silent,
-    )
+    with _span("ht.call.hsvd_rtol"):
+        with _span("ht.call.hsvd.prepare"):
+            sanitize_in(A)
+            if A.ndim != 2:
+                raise ValueError(f"hsvd requires a 2-dimensional array, got {A.ndim}")
+            if rtol <= 0:
+                raise ValueError(f"rtol must be positive, got {rtol}")
+            _warn_merge_knobs(maxmergedim, no_of_merges)
+        return _hsvd_impl(
+            A,
+            maxrank=int(maxrank) if maxrank is not None else None,
+            rtol=float(rtol),
+            safetyshift=int(safetyshift),
+            compute_sv=compute_sv,
+            silent=silent,
+        )
 
 
 def hsvd(
@@ -951,18 +958,20 @@ def hsvd(
     warnings_off: bool = False,
 ):
     """General hierarchical SVD entry point (reference: svdtools.py:259)."""
-    sanitize_in(A)
-    if maxrank is None and rtol is None:
-        raise ValueError("at least one of maxrank and rtol must be given")
-    _warn_merge_knobs(maxmergedim, no_of_merges)
-    return _hsvd_impl(
-        A,
-        maxrank=int(maxrank) if maxrank is not None else None,
-        rtol=rtol,
-        safetyshift=int(safetyshift),
-        compute_sv=compute_sv,
-        silent=silent,
-    )
+    with _span("ht.call.hsvd"):
+        with _span("ht.call.hsvd.prepare"):
+            sanitize_in(A)
+            if maxrank is None and rtol is None:
+                raise ValueError("at least one of maxrank and rtol must be given")
+            _warn_merge_knobs(maxmergedim, no_of_merges)
+        return _hsvd_impl(
+            A,
+            maxrank=int(maxrank) if maxrank is not None else None,
+            rtol=rtol,
+            safetyshift=int(safetyshift),
+            compute_sv=compute_sv,
+            silent=silent,
+        )
 
 
 def _hsvd_impl(
@@ -974,6 +983,8 @@ def _hsvd_impl(
     silent: bool,
     single_pass: bool = False,
 ):
+    from ...redistribution import staging as _staging
+
     comm: MeshCommunication = A.comm
     dtype = A.dtype
     if types.heat_type_is_exact(dtype):
@@ -998,70 +1009,73 @@ def _hsvd_impl(
     u_direct = None
     v_direct = None
     if A.split is None or not comm.is_distributed():
-        arr = A.larray.astype(jt)
-        budget = (maxrank + safetyshift) if maxrank is not None else None
-        sketch_l = None
-        if budget is not None and not _needs_exact_spectrum(rtol):
-            l = min(budget + _SKETCH_OVERSAMPLE, full_rank_cap)
-            if 4 * l <= full_rank_cap:
-                sketch_l = l
-        if sketch_l is not None:
-            # small rank budget: randomized range finder, O(mnl) not O(mn²)
-            keep = min(budget, full_rank_cap)
-            want = "both" if compute_sv else "left"
+        with _span("ht.call.hsvd.prepare"):
+            arr = A.larray.astype(jt)
+            budget = (maxrank + safetyshift) if maxrank is not None else None
+            sketch_l = None
+            if budget is not None and not _needs_exact_spectrum(rtol):
+                l = min(budget + _SKETCH_OVERSAMPLE, full_rank_cap)
+                if 4 * l <= full_rank_cap:
+                    sketch_l = l
+            if sketch_l is not None:
+                # small rank budget: randomized range finder, O(mnl) not O(mn²)
+                keep = min(budget, full_rank_cap)
+                want = "both" if compute_sv else "left"
+                if rtol is None:
+                    r_final = max(1, min(maxrank, keep))
+                    ov = (
+                        _one_view_params(keep, full_rank_cap, A.shape[0], A.shape[1])
+                        if single_pass
+                        else None
+                    )
+        if sketch_l is not None and rtol is None:
             # rank-budget mode needs no spectrum on host (rank is static),
             # so truncation + error fold into the jitted program (one
             # dispatch) and err stays a lazy 0-d DNDarray
-            if rtol is None:
-                r_final = max(1, min(maxrank, keep))
-                ov = (
-                    _one_view_params(keep, full_rank_cap, A.shape[0], A.shape[1])
-                    if single_pass
-                    else None
-                )
-                from ...redistribution import staging as _staging
-
-                with svd_x32_scope(jt):
-                    if _staging.ooc_mode() == "1":
-                        # HEAT_TPU_OOC=1 (the forced CI leg): route the
-                        # in-HBM operand through the staged window
-                        # pipeline — the fixed-grain tile streams make
-                        # the result bit-identical by construction,
-                        # and the pinned sweep proves it
-                        host = _staging.HostArray(np.asarray(arr))
-                        u_t, v_t, s_t, err_dev = _staged_sketch_rank(
-                            host, keep, sketch_l=sketch_l, r_final=r_final,
-                            want=want, one_view=ov, jt=jt,
-                        )
-                    elif ov is not None:
-                        k_hat, l_row = ov
-                        u_t, v_t, s_t, err_dev = _one_view_single_rank_fn(
-                            keep, k_hat, l_row, r_final, want
-                        )(arr)
-                    else:
-                        u_t, v_t, s_t, err_dev = _sketched_single_rank_fn(
-                            keep, sketch_l, r_final, want
-                        )(arr)
+            with _span("ht.call.hsvd.level0"), svd_x32_scope(jt):
+                if _staging.ooc_mode() == "1":
+                    # HEAT_TPU_OOC=1 (the forced CI leg): route the
+                    # in-HBM operand through the staged window
+                    # pipeline — the fixed-grain tile streams make
+                    # the result bit-identical by construction,
+                    # and the pinned sweep proves it
+                    host = _staging.HostArray(np.asarray(arr))
+                    u_t, v_t, s_t, err_dev = _staged_sketch_rank(
+                        host, keep, sketch_l=sketch_l, r_final=r_final,
+                        want=want, one_view=ov, jt=jt,
+                    )
+                elif ov is not None:
+                    k_hat, l_row = ov
+                    u_t, v_t, s_t, err_dev = _one_view_single_rank_fn(
+                        keep, k_hat, l_row, r_final, want
+                    )(arr)
+                else:
+                    u_t, v_t, s_t, err_dev = _sketched_single_rank_fn(
+                        keep, sketch_l, r_final, want
+                    )(arr)
+            with _span("ht.call.hsvd.wrap"):
                 err = _err_scalar(err_dev, A)
                 u_direct = DNDarray(u_t, (A.shape[0], r_final), dtype, None, A.device, comm)
                 if v_t is not None:
                     v_direct = DNDarray(v_t, (A.shape[1], r_final), dtype, None, A.device, comm)
                 s_np = s_t
-            else:
-                with svd_x32_scope(jt):
-                    u_f, v_f, s_dev, err0_sq_dev, norm_sq_dev = _sketched_single_fn(
-                        keep, sketch_l, want
-                    )(arr)
+        elif sketch_l is not None:
+            with _span("ht.call.hsvd.level0"), svd_x32_scope(jt):
+                u_f, v_f, s_dev, err0_sq_dev, norm_sq_dev = _sketched_single_fn(
+                    keep, sketch_l, want
+                )(arr)
+            with _span("ht.call.hsvd.merge"):
                 s_host, err0_sq, norm_sq = jax.device_get((s_dev, err0_sq_dev, norm_sq_dev))
                 a_norm = float(np.sqrt(max(float(norm_sq), 0.0)))
                 r_final = _choose_rank(
                     np.asarray(s_host), maxrank, rtol, a_norm, float(err0_sq), full_rank_cap
                 )
-                err = _err_scalar(
+                err_val = (
                     float(np.sqrt(float(err0_sq) + np.sum(np.asarray(s_host)[r_final:] ** 2)))
-                    / max(a_norm, 1e-30),
-                    A,
+                    / max(a_norm, 1e-30)
                 )
+            with _span("ht.call.hsvd.wrap"):
+                err = _err_scalar(err_val, A)
                 u_direct = DNDarray(u_f[:, :r_final], (A.shape[0], r_final), dtype, None, A.device, comm)
                 if v_f is not None:
                     v_direct = DNDarray(v_f[:, :r_final], (A.shape[1], r_final), dtype, None, A.device, comm)
@@ -1069,83 +1083,88 @@ def _hsvd_impl(
         else:
             # full SVD dominates; BOTH sides fall out of the one call, so
             # no orientation transpose and no postprocessing pass
-            u, s, vt = safe_svd(arr, full_matrices=False)
-            # one combined transfer for norm + spectrum
-            s_host = np.asarray(jax.device_get(s))
-            a_norm = float(np.sqrt(np.sum(s_host.astype(np.float64) ** 2)))
-            err_sq = 0.0
-            r_final = _choose_rank(s_host, maxrank, rtol, a_norm, err_sq, full_rank_cap)
-            u_direct = DNDarray(u[:, :r_final], (A.shape[0], r_final), dtype, None, A.device, comm)
-            v_direct = DNDarray(vt[:r_final].T, (A.shape[1], r_final), dtype, None, A.device, comm)
-            s_np = s[:r_final]
-            err = _err_scalar(
-                float(np.sqrt(np.sum(s_host[r_final:] ** 2))) / max(a_norm, 1e-30), A
-            )
+            with _span("ht.call.hsvd.level0"):
+                u, s, vt = safe_svd(arr, full_matrices=False)
+            with _span("ht.call.hsvd.merge"):
+                # one combined transfer for norm + spectrum
+                s_host = np.asarray(jax.device_get(s))
+                a_norm = float(np.sqrt(np.sum(s_host.astype(np.float64) ** 2)))
+                err_sq = 0.0
+                r_final = _choose_rank(s_host, maxrank, rtol, a_norm, err_sq, full_rank_cap)
+            with _span("ht.call.hsvd.wrap"):
+                u_direct = DNDarray(u[:, :r_final], (A.shape[0], r_final), dtype, None, A.device, comm)
+                v_direct = DNDarray(vt[:r_final].T, (A.shape[1], r_final), dtype, None, A.device, comm)
+                s_np = s[:r_final]
+                err = _err_scalar(
+                    float(np.sqrt(np.sum(s_host[r_final:] ** 2))) / max(a_norm, 1e-30), A
+                )
     else:
-        p = comm.size
-        rloc = min(m, -(-n // p))
-        if maxrank is not None:
-            rloc = min(rloc, maxrank + safetyshift)
-        phys = A._phys.astype(jt)
-        if transposed:
-            # pad rows become zero pad columns: Frobenius/SVD-neutral
-            phys = phys.T
-        lcols = phys.shape[1] // p
-        sketch_l = None
-        if maxrank is not None and not _needs_exact_spectrum(rtol):
-            lmin = min(phys.shape[0], lcols)
-            l = min(rloc + _SKETCH_OVERSAMPLE, lmin)
-            if 4 * l <= lmin:
-                sketch_l = l
-        one_view = None
-        if single_pass and sketch_l is not None:
-            one_view = _one_view_params(
-                min(rloc, lcols), min(phys.shape[0], lcols), phys.shape[0], lcols
+        with _span("ht.call.hsvd.prepare"):
+            p = comm.size
+            rloc = min(m, -(-n // p))
+            if maxrank is not None:
+                rloc = min(rloc, maxrank + safetyshift)
+            phys = A._phys.astype(jt)
+            if transposed:
+                # pad rows become zero pad columns: Frobenius/SVD-neutral
+                phys = phys.T
+            lcols = phys.shape[1] // p
+            sketch_l = None
+            if maxrank is not None and not _needs_exact_spectrum(rtol):
+                lmin = min(phys.shape[0], lcols)
+                l = min(rloc + _SKETCH_OVERSAMPLE, lmin)
+                if 4 * l <= lmin:
+                    sketch_l = l
+            one_view = None
+            if single_pass and sketch_l is not None:
+                one_view = _one_view_params(
+                    min(rloc, lcols), min(phys.shape[0], lcols), phys.shape[0], lcols
+                )
+        with _span("ht.call.hsvd.level0"):
+            fn = _local_svd_fn(
+                comm.mesh, comm.axis_name, phys.shape[0], lcols, rloc, np.dtype(jt).name,
+                sketch_l, one_view,
             )
-        fn = _local_svd_fn(
-            comm.mesh, comm.axis_name, phys.shape[0], lcols, rloc, np.dtype(jt).name,
-            sketch_l, one_view,
-        )
-        with svd_x32_scope(jt):
-            b_phys, err_blocks, normsq_blocks = fn(phys)
-        B = DNDarray(
-            b_phys, (m, int(b_phys.shape[1])), dtype, 1, A.device, comm
-        )
-        U_merged, s_all = _merge_svd(B, calc_u=True)
-        if rtol is None:
-            # static rank: err computed on device, ONE scalar read-back
-            r_final = max(1, min(maxrank, min(int(s_all.shape[0]), full_rank_cap)))
-            err = _err_scalar(
-                jnp.sqrt(jnp.sum(err_blocks) + jnp.sum(s_all[r_final:] ** 2))
-                / jnp.maximum(jnp.sqrt(jnp.sum(normsq_blocks)), 1e-30),
-                A,
+            with svd_x32_scope(jt):
+                b_phys, err_blocks, normsq_blocks = fn(phys)
+            B = DNDarray(
+                b_phys, (m, int(b_phys.shape[1])), dtype, 1, A.device, comm
             )
-        else:
-            s_np_all, lvl_sq, nrm_sq = jax.device_get(
-                (s_all, jnp.sum(err_blocks), jnp.sum(normsq_blocks))
-            )
-            s_np_all = np.asarray(s_np_all)
-            a_norm = float(np.sqrt(max(float(nrm_sq), 0.0)))
-            level_err_sq = float(lvl_sq)
-            r_final = _choose_rank(s_np_all, maxrank, rtol, a_norm, level_err_sq, full_rank_cap)
-            merge_err_sq = float(np.sum(s_np_all[r_final:] ** 2))
-            err = _err_scalar(
-                float(np.sqrt(level_err_sq + merge_err_sq)) / max(a_norm, 1e-30), A
-            )
-        # truncate U to the final rank
-        u_trunc = U_merged.larray[:, :r_final]
-        U_arr = DNDarray(comm.shard(u_trunc, 0), (m, r_final), dtype, 0, A.device, comm)
-        s_np = s_all[:r_final]
+        with _span("ht.call.hsvd.merge"):
+            U_merged, s_all = _merge_svd(B, calc_u=True)
+            if rtol is None:
+                # static rank: err computed on device, ONE scalar read-back
+                r_final = max(1, min(maxrank, min(int(s_all.shape[0]), full_rank_cap)))
+                err_val = jnp.sqrt(
+                    jnp.sum(err_blocks) + jnp.sum(s_all[r_final:] ** 2)
+                ) / jnp.maximum(jnp.sqrt(jnp.sum(normsq_blocks)), 1e-30)
+            else:
+                s_np_all, lvl_sq, nrm_sq = jax.device_get(
+                    (s_all, jnp.sum(err_blocks), jnp.sum(normsq_blocks))
+                )
+                s_np_all = np.asarray(s_np_all)
+                a_norm = float(np.sqrt(max(float(nrm_sq), 0.0)))
+                level_err_sq = float(lvl_sq)
+                r_final = _choose_rank(s_np_all, maxrank, rtol, a_norm, level_err_sq, full_rank_cap)
+                merge_err_sq = float(np.sum(s_np_all[r_final:] ** 2))
+                err_val = float(np.sqrt(level_err_sq + merge_err_sq)) / max(a_norm, 1e-30)
+        with _span("ht.call.hsvd.wrap"):
+            err = _err_scalar(err_val, A)
+            # truncate U to the final rank
+            u_trunc = U_merged.larray[:, :r_final]
+            U_arr = DNDarray(comm.shard(u_trunc, 0), (m, r_final), dtype, 0, A.device, comm)
+            s_np = s_all[:r_final]
 
-    sigma_arr = jnp.asarray(s_np)
-    sigma = DNDarray(
-        _place(sigma_arr, comm.sharding(1, None)),
-        (int(sigma_arr.shape[0]),),
-        dtype,
-        None,
-        A.device,
-        comm,
-    )
+    with _span("ht.call.hsvd.wrap"):
+        sigma_arr = jnp.asarray(s_np)
+        sigma = DNDarray(
+            _place(sigma_arr, comm.sharding(1, None)),
+            (int(sigma_arr.shape[0]),),
+            dtype,
+            None,
+            A.device,
+            comm,
+        )
 
     if u_direct is not None or v_direct is not None:
         # single-device path: factors already in the input orientation
@@ -1188,34 +1207,35 @@ def _postprocess_v(A: DNDarray, factor: DNDarray, sigma: DNDarray, left: bool) -
     U = A V / σ (reference: svdtools.py:456-467)."""
     from . import basics
 
-    if left:
-        prod = basics.matmul(A, factor)  # (m, r)
-    else:
-        # V = A^H U / σ: the adjoint, not the transpose — native complex
-        # inputs conjugate (conj is the identity on reals)
-        At = basics.transpose(A, None)
-        if types.heat_type_is_complexfloating(A.dtype):
-            from .. import complex_math as _cmath
+    with _span("ht.call.hsvd.postprocess"):
+        if left:
+            prod = basics.matmul(A, factor)  # (m, r)
+        else:
+            # V = A^H U / σ: the adjoint, not the transpose — native complex
+            # inputs conjugate (conj is the identity on reals)
+            At = basics.transpose(A, None)
+            if types.heat_type_is_complexfloating(A.dtype):
+                from .. import complex_math as _cmath
 
-            At = _cmath.conj(At)
-        prod = basics.matmul(At, factor)  # (n, r)
-    inv_sigma = jnp.where(sigma.larray > 0, 1.0 / sigma.larray, 0.0)
-    scaled = prod.larray * inv_sigma
-    # A·V·Σ⁻¹ with TRUNCATED (σ, v) pairs is only approximately an
-    # isometry (deviation ~ discarded-energy/σ_r — ~1e-1 on flat spectra;
-    # the reference ships that deviation, svdtools.py:456-467). Two
-    # Cholesky-QR rounds on the skinny (·, r) result restore machine
-    # orthogonality without rotating columns; on a sharded operand the
-    # (r, r) Gram is XLA's psum, ~2 cheap passes.
-    scaled = _cholqr2_refine(scaled)
-    return DNDarray(
-        prod.comm.shard(scaled, prod.split) if prod.split is not None else scaled,
-        prod.shape,
-        prod.dtype,
-        prod.split,
-        prod.device,
-        prod.comm,
-    )
+                At = _cmath.conj(At)
+            prod = basics.matmul(At, factor)  # (n, r)
+        inv_sigma = jnp.where(sigma.larray > 0, 1.0 / sigma.larray, 0.0)
+        scaled = prod.larray * inv_sigma
+        # A·V·Σ⁻¹ with TRUNCATED (σ, v) pairs is only approximately an
+        # isometry (deviation ~ discarded-energy/σ_r — ~1e-1 on flat spectra;
+        # the reference ships that deviation, svdtools.py:456-467). Two
+        # Cholesky-QR rounds on the skinny (·, r) result restore machine
+        # orthogonality without rotating columns; on a sharded operand the
+        # (r, r) Gram is XLA's psum, ~2 cheap passes.
+        scaled = _cholqr2_refine(scaled)
+        return DNDarray(
+            prod.comm.shard(scaled, prod.split) if prod.split is not None else scaled,
+            prod.shape,
+            prod.dtype,
+            prod.split,
+            prod.device,
+            prod.comm,
+        )
 
 
 def _choose_rank(

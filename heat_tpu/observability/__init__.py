@@ -13,10 +13,16 @@ Five pieces, one import surface:
   fed by the hooks in ``core/`` (shard/reshard bytes, program-cache
   misses, ``ht.jit`` traces); overwrites counted, span-correlated.
 - :mod:`~heat_tpu.observability.tracing` — span tracing of the hot
-  layers (``ht.tracing.span``), the always-on flight recorder, and
-  Chrome-trace/Perfetto export (:func:`export_trace`); gated
-  ``HEAT_TPU_TRACE`` with ``affects_programs=False`` — plans, plan_ids,
-  programs, and AOT keys are byte-identical at every value.
+  layers (``ht.tracing.span``): every span is an
+  annotation of ``jax.profiler``'s trace, so under any profiler session
+  (``ht.utils.monitor.trace(path)``) the program's ``ht.call.*``,
+  ``ht.op.*``, ``ht.program.*`` and ``ht.comm.*`` spans sit beside the
+  device ops in the profiler's trace; with ``HEAT_TPU_TRACE`` on a span
+  is also a parented record in the module's ring (``attribution``,
+  Chrome-trace export :func:`export_trace`). The always-on flight
+  recorder lives there too. ``HEAT_TPU_TRACE`` is registered
+  ``affects_programs=False`` — plans, plan_ids, programs, and AOT keys
+  are byte-identical at every value, and with or without a session.
 - :mod:`~heat_tpu.observability.attribution` — the model-vs-measured
   join (:func:`attribution`): measured span time per step kind/tier
   against the plan's ``tier_time_model``/overlap/staging annotations,
@@ -31,7 +37,8 @@ Five pieces, one import surface:
   constants-vs-calibrated model-error proof the CI gate rides.
 
 Instrumentation glue for the core layers lives in
-:mod:`~heat_tpu.observability.instrument` (not re-exported).
+:mod:`~heat_tpu.observability.instrument` (not re-exported): the
+``observed_program_cache`` decorator of every program builder.
 """
 
 from . import events

@@ -6,14 +6,31 @@ nothing in the stack can answer "which lap, which tier, which window"
 1.54–1.60x, wire 0.251x, two-tier 7.5x, staging PCIe bounds) is a claim
 about exactly that per-step structure. This module is the instrument:
 
-- **spans** — a structured, parented trace of the hot layers at their
-  existing seams (``span()`` context manager + a low-overhead
-  ``start_span``/``end_span``/``add_span`` API). Spans carry HOST-SIDE
-  attrs only (plan_id, step kind, tier, lap/window index, bucket,
-  bytes, world epoch — never array values), so they are trace-safe:
-  a span inside a jitted program body fires once per compile and is
-  tagged ``traced=True`` (its duration is tracing time; attribution
-  uses it for census only).
+- **spans** — ``with span("ht.call.hsvd.level0"): ...`` at the seams of
+  the hot layers. A span is two things (PR 25):
+
+  1. always a ``jax.profiler.TraceAnnotation``: under any profiler
+     session (``ht.utils.monitor.trace(path)``,
+     ``jax.profiler.start_trace``, ``benchmarks/run.py --trace 1``) it
+     lands on the ``/host:CPU`` plane of the profiler's own trace, on the
+     clock of the device ops, where Perfetto shows it beside them and
+     ``benchmarks/spans.py`` reduces it. The session is the switch: no
+     environment variable, no call. With no session it costs the
+     constructor and one atomic read (about 1 us);
+  2. when collection is enabled (``HEAT_TPU_TRACE``, below) also a
+     structured, parented record in the ring of this module
+     (``start_span``/``end_span``/``add_span`` write to the ring only),
+     which ``attribution`` and :func:`export_trace` read.
+
+  What a reducer needs is in the span's NAME, a constant string at the
+  call site; attrs are for a person reading the trace and are values
+  already at hand, HOST-SIDE only (plan_id, step kind, tier, lap/window
+  index, bucket, bytes, world epoch — never array values), so spans are
+  trace-safe: inside a jitted program body one fires once per compile
+  and is tagged ``traced=True`` (its duration is tracing time;
+  attribution uses it for census only). The names of the ``ht.*`` spans
+  and the metric that reads each are listed in ``docs/API.md``
+  (observability) and root ``PERF.md`` section 3.
 - **flight recorder** — a small ALWAYS-ON fixed-field ring, independent
   of the trace gate and of telemetry: one bool check + one bounded
   append per record. Its tail is attached to ``WorldChangedError``,
@@ -40,8 +57,9 @@ long); the active-span stack and ambient-attribute context are
 per-thread (``threading.local``), so concurrent recorders never see
 each other's parents.
 
-Stdlib-only on purpose (like ``core/gates``): importable before jax
-loads, usable from the lightest CLI process.
+Stdlib-only at import on purpose (like ``core/gates``): importable
+before jax loads, usable from the lightest CLI process; the first
+``span`` imports ``jax.profiler``.
 """
 
 from __future__ import annotations
@@ -267,18 +285,51 @@ def end_span(sp: Optional[Span], **attrs) -> None:
         _spans.append(sp)
 
 
-@contextlib.contextmanager
-def span(name: str, parent_id: Optional[int] = None, **attrs) -> Iterator[Optional[Span]]:
-    """Context-manager form: a span around the enclosed block. A plain
-    passthrough (one module-bool read) when tracing is disabled."""
-    if not _ENABLED:
-        yield None
-        return
-    sp = start_span(name, parent_id=parent_id, **attrs)
-    try:
-        yield sp
-    finally:
-        end_span(sp)
+_annotation = None  # jax.profiler.TraceAnnotation, bound by the first span
+
+
+def _bind_annotation():
+    """``jax.profiler.TraceAnnotation``, imported by the first span and not
+    with this module, which stays importable before jax."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class span:
+    """A span around the enclosed block: ``with span("ht.call.x"): ...``.
+
+    Always a ``jax.profiler.TraceAnnotation``: under any profiler session
+    (``ht.utils.monitor.trace``, ``jax.profiler.start_trace``) the span
+    lands on the ``/host:CPU`` plane of the profiler's trace, on the clock
+    of the device ops, with ``attrs`` as the event's arguments; with no
+    session it costs the constructor and one atomic read. When collection
+    is enabled (``HEAT_TPU_TRACE``) it is also recorded in the ring, with
+    its parent, and ``__enter__`` returns the :class:`Span` (else
+    ``None``)."""
+
+    __slots__ = ("_name", "_parent_id", "_attrs", "_ann", "_sp")
+
+    def __init__(self, name: str, parent_id: Optional[int] = None, **attrs):
+        self._name = name
+        self._parent_id = parent_id
+        self._attrs = attrs
+
+    def __enter__(self) -> Optional[Span]:
+        ann = self._ann = (_annotation or _bind_annotation())(self._name, **self._attrs)
+        ann.__enter__()
+        if _ENABLED:
+            self._sp = sp = start_span(self._name, parent_id=self._parent_id, **self._attrs)
+            return sp
+        self._sp = None
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._sp is not None:
+            end_span(self._sp)
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 def add_span(

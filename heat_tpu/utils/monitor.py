@@ -124,7 +124,17 @@ def reset() -> None:
 def trace(path: str):
     """Capture a jax.profiler trace (Perfetto/XPlane) of the enclosed
     block to ``path`` — view in TensorBoard or ui.perfetto.dev. The
-    TPU-side story the reference delegates to perun's energy counters."""
+    TPU-side story the reference delegates to perun's energy counters.
+
+    The trace holds heat_tpu's own spans beside the device ops, on one
+    clock, with nothing to enable: ``ht.call.*`` (the phases of
+    ``hsvd_rank`` and ``KMeans.fit``), ``ht.op.*`` (one per eager op),
+    ``ht.program.hit``/``miss``/``launch``/``compile`` (every program
+    builder's cache and the host side of each jitted call, with the
+    builder as ``cache=``) and ``ht.comm.place``/``shard``/``reshard``.
+    They are on the thread lines of the ``/host:CPU`` plane; the device
+    ops are on ``/device:TPU:<i>``. ``docs/API.md`` (observability)
+    lists every name."""
     jax.profiler.start_trace(path)
     try:
         yield

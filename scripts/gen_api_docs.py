@@ -50,6 +50,44 @@ SECTIONS = [
 ]
 
 
+# hand-written notes under a section's heading: what no signature shows
+NOTES = {
+    "heat_tpu.observability (collective inspector)": """**Spans on the profiler's clock.** `with ht.utils.monitor.trace(path):` (or any
+`jax.profiler.start_trace`) captures heat_tpu's own spans beside the device ops, on one clock,
+with nothing to enable: every `ht.tracing.span` is a `jax.profiler.TraceAnnotation`. Open the
+trace in Perfetto or TensorBoard: the spans are on the thread lines of `/host:CPU`, the device
+ops on `/device:TPU:<i>`. With `HEAT_TPU_TRACE=1` (or telemetry on) the same spans are also
+kept, with parent ids, in the ring that `ht.observability.export_trace` writes. The readers in
+`benchmarks/layers/` are named in root `PERF.md` section 3.
+
+| span | around | read by |
+|---|---|---|
+| `ht.call.hsvd_rank`, `ht.call.hsvd_rtol`, `ht.call.hsvd` | the whole public call | `host_wrapper_ms_per_call` (self time) |
+| `ht.call.hsvd.prepare` | `sanitize_in`, checks, dtype, `astype`, the orientation (`phys.T` when split) and the sketch-size arithmetic | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.level0` | lookup and call of the level-0 program (the program spans nest in it) | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.merge` | `_merge_svd` and the rank/err arithmetic after it, any `device_get` | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.wrap` | `_err_scalar`, the `DNDarray` constructions, sigma's placement | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.postprocess` | `_postprocess_v` (the complementary factor across chips) | `host_wrapper_ms_per_call` |
+| `ht.call.kmeans.fit`, `ht.call.kmeans.predict` | `KMeans.fit` (the fused fit), `predict` | `host_wrapper_ms_per_call` |
+| `ht.call.kmeans.init`, `.program`, `.wrap` | initial centres or seed key; lookup of the step and the fused program and the call; placement and the two `DNDarray`s (shared by `KMedians`/`KMedoids.fit`) | `host_wrapper_ms_per_call` |
+| `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
+| `ht.program.hit` | entered right after a lookup that a builder's `lru_cache` served (`cache=` names the builder) | `host_launch_ms_per_call` |
+| `ht.program.miss` | a lookup that built; the builder's time | `program_cache_misses`, `host_launch_ms_per_call` |
+| `ht.program.launch` | every call of a built program: the host side of the jitted call, argument handling to enqueue | `launches_per_call`, `host_prelaunch_ms_per_call`, `host_launch_ms_per_call` |
+| `ht.program.compile` | the first call after a miss (trace + compile) | as `launch` |
+| `ht.comm.place`, `ht.comm.shard`, `ht.comm.reshard` | `communication.place`, `MeshCommunication.shard`, `reshard_phys` | `host_comm_ms_per_call` |
+
+Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`, `.miss`,
+`.build`, `.compile` for every observed builder (`op.binary`, `op.unary`, `op.reduce`, `op.cum`,
+`hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`,
+`hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `kmeans.lloyd_step`,
+`kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`), `ht.jit.cache.hit`/`.miss`,
+`comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`: for an operator's
+`ht.telemetry.report()`, read by no benchmark metric.
+""",
+}
+
+
 def first_line(obj) -> str:
     if isinstance(obj, (int, float, complex, str, tuple)):
         return "constant"  # builtins' type docstrings are noise
@@ -126,7 +164,10 @@ def render() -> str:
         "",
     ]
     for title, r in sections:
-        lines += [f"## {title}", "", "| export | kind | summary |", "|---|---|---|"]
+        lines += [f"## {title}", ""]
+        if title in NOTES:
+            lines += [NOTES[title]]
+        lines += ["| export | kind | summary |", "|---|---|---|"]
         lines += r
         lines.append("")
     return "\n".join(lines) + "\n"

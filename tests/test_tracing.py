@@ -738,3 +738,311 @@ if __name__ == "__main__":
     import unittest
 
     unittest.main()
+
+
+# --------------------------------------------------------------------- #
+# 8. spans on the profiler's clock (PR 25)                              #
+# --------------------------------------------------------------------- #
+# Every span is a jax.profiler.TraceAnnotation too: under a profiler
+# session the program's ht.call.* / ht.op.* / ht.program.* / ht.comm.*
+# spans sit on the /host:CPU plane of the trace, with no gate to set.
+import subprocess
+import sys
+import timeit
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` session; the ``ht.*`` events of
+    the host plane as (name, thread line, start_ns, end_ns), in order of
+    start, outermost first."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert found, "the profiler wrote no XPlane"
+    rows = []
+    for plane in ProfileData.from_file(str(found[-1])).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("ht."):
+                    rows.append((ev.name, line.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return sorted(rows, key=lambda r: (r[2], -r[3]))
+
+
+def _assert_properly_nested(rows):
+    """On one thread two spans are disjoint or one holds the other."""
+    for thread in {r[1] for r in rows}:
+        stack = []
+        for name, _, start, end in (r for r in rows if r[1] == thread):
+            while stack and stack[-1][1] <= start:
+                stack.pop()
+            if stack:
+                assert end <= stack[-1][1], f"{name} straddles the end of {stack[-1][0]}"
+            stack.append((name, end))
+
+
+def _inside(rows, inner, outer):
+    outers = [r for r in rows if r[0] == outer]
+    return all(any(o[2] <= r[2] and r[3] <= o[3] for o in outers) for r in rows if r[0] == inner)
+
+
+def _hsvd_one_device():
+    from heat_tpu.core.linalg import svdtools
+
+    svdtools._sketched_single_rank_fn.cache_clear()
+    a = ht.random.randn(331, 96, split=None)
+    return (
+        lambda: ht.linalg.hsvd_rank(a, 2, compute_sv=True),
+        {"ht.call.hsvd_rank", "ht.call.hsvd.prepare", "ht.call.hsvd.level0", "ht.call.hsvd.wrap", "ht.comm.place"},
+        "ht.call.hsvd_rank",
+    )
+
+
+def _hsvd_split():
+    from heat_tpu.core.linalg import svdtools
+
+    svdtools._local_svd_fn.cache_clear()
+    importlib.import_module("heat_tpu.core.linalg.qr")._tsqr_fn.cache_clear()
+    a = ht.random.randn(8 * 331, 96, split=0)
+    return (
+        lambda: ht.linalg.hsvd_rank(a, 2, compute_sv=True),
+        {"ht.call.hsvd_rank", "ht.call.hsvd.prepare", "ht.call.hsvd.level0", "ht.call.hsvd.merge",
+         "ht.call.hsvd.wrap", "ht.call.hsvd.postprocess", "ht.op.matmul", "ht.comm.reshard", "ht.comm.shard",
+         "ht.comm.place"},
+        "ht.call.hsvd_rank",
+    )
+
+
+def _kmeans_fit():
+    from heat_tpu.cluster import _kcluster, kmeans
+
+    kmeans._lloyd_step.cache_clear()
+    _kcluster._fused_fit_program.cache_clear()
+    x = ht.random.randn(8 * 53, 7, split=0)
+    init = ht.array(np.asarray(x.numpy()[:3]))
+    return (
+        lambda: ht.cluster.KMeans(3, init=init, max_iter=3, tol=0.0).fit(x),
+        {"ht.call.kmeans.fit", "ht.call.kmeans.init", "ht.call.kmeans.program", "ht.call.kmeans.wrap",
+         "ht.comm.place", "ht.comm.shard"},
+        "ht.call.kmeans.fit",
+    )
+
+
+@pytest.mark.skipif(P < 2, reason="the split path needs a real mesh")
+@pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _kmeans_fit], ids=lambda f: f.__name__.strip("_"))
+def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
+    """One call that misses and one that hits, under a profiler session and
+    nothing else: every span the path runs is on the host plane, nested,
+    with miss + compile in the first call and hit + launch in the second."""
+    assert not tracing.enabled()
+    call, names, root = case()
+    rows = _profiled(tmp_path, lambda: (call(), call()))
+    assert names <= {r[0] for r in rows}, sorted(names - {r[0] for r in rows})
+    _assert_properly_nested(rows)
+    first, second = [r for r in rows if r[0] == root]
+    for name in names - {root}:
+        assert _inside(rows, name, root), f"{name} outside {root}"
+    in_first = [r[0] for r in rows if first[2] <= r[2] and r[3] <= first[3]]
+    in_second = [r[0] for r in rows if second[2] <= r[2] and r[3] <= second[3]]
+    assert "ht.program.miss" in in_first and "ht.program.compile" in in_first
+    assert "ht.program.miss" not in in_second and "ht.program.compile" not in in_second
+    assert "ht.program.hit" in in_second and "ht.program.launch" in in_second
+    assert tracing.spans() == []  # a profiler session does not turn the ring on
+
+
+def test_profiler_trace_holds_predict_and_op_spans(tmp_path):
+    x = ht.random.randn(8 * 53, 7, split=0)
+    km = ht.cluster.KMeans(3, init=ht.array(np.asarray(x.numpy()[:3])), max_iter=2, tol=0.0).fit(x)
+
+    def work():
+        km.predict(x)
+        y = ht.exp(x) + 1.0
+        ht.cumsum(y, 0)
+        ht.sum(y, axis=0)
+        ht.transpose(y)
+        ht.jit(lambda v: v * 2.0)(x)
+
+    rows = _profiled(tmp_path, work)
+    names = {r[0] for r in rows}
+    assert {"ht.call.kmeans.predict", "ht.op.unary", "ht.op.binary", "ht.op.cum", "ht.op.reduce",
+            "ht.op.transpose", "ht.program.miss", "ht.program.compile"} <= names, sorted(names)
+    _assert_properly_nested(rows)
+    for name in ("ht.program.hit", "ht.program.launch"):
+        assert _inside(rows, name, "ht.call.kmeans.predict") or any(
+            _inside([r for r in rows if r[0] in (name, op)], name, op)
+            for op in ("ht.op.unary", "ht.op.binary", "ht.op.cum", "ht.op.reduce")
+        )
+
+
+def test_span_costs_little_with_no_session_and_leaves_the_ring_empty():
+    """No profiler session, ``HEAT_TPU_TRACE`` unset: a span is a small
+    object and a TraceMe that reads one atomic. 2 us is generous: it guards
+    against the generator's return, not the chip."""
+    assert not tracing.enabled()
+    tracing.clear()
+    span = tracing.span
+
+    def block():
+        with span("x"):
+            pass
+
+    n = 100_000
+    per_use = min(timeit.repeat(block, number=n, repeat=3)) / n
+    assert per_use < 2e-6, f"{per_use * 1e6:.2f} us a span"
+    assert tracing.spans() == []
+    with span("y") as sp:
+        assert sp is None
+
+
+def test_ring_still_gets_parented_spans_of_the_call():
+    """``HEAT_TPU_TRACE=1`` (here ``enable()``): the spans of a public call
+    land in the ring with their parents, as executor/serving spans do."""
+    a = ht.random.randn(331, 96, split=None)
+    ht.linalg.hsvd_rank(a, 2, compute_sv=True)  # the next call launches, it does not compile
+    tracing.clear()
+    tracing.enable()
+    try:
+        ht.linalg.hsvd_rank(a, 2, compute_sv=True)
+        rows = tracing.spans()
+    finally:
+        tracing.disable()
+        tracing.clear()
+    by_name = {r["name"]: r for r in rows}
+    root = by_name["ht.call.hsvd_rank"]
+    assert root["parent"] is None
+    assert by_name["ht.call.hsvd.level0"]["parent"] == root["id"]
+    assert by_name["ht.program.launch"]["parent"] == by_name["ht.call.hsvd.level0"]["id"]
+    assert by_name["ht.program.launch"]["attrs"] == {"cache": "hsvd.sketched_rank"}
+
+
+def _observed_builders():
+    from heat_tpu.cluster import _kcluster, kmeans
+    from heat_tpu.core import _operations as ops
+    from heat_tpu.core.linalg import svdtools
+
+    qr = importlib.import_module("heat_tpu.core.linalg.qr")  # the package exports the function under that name
+    return {
+        "op.binary": ops._binary_callable, "op.unary": ops._unary_callable,
+        "op.reduce": ops._reduce_callable, "op.cum": ops._cum_callable,
+        "hsvd.sketched_rank": svdtools._sketched_single_rank_fn,
+        "hsvd.one_view_rank": svdtools._one_view_single_rank_fn,
+        "hsvd.sketched": svdtools._sketched_single_fn,
+        "hsvd.local_svd": svdtools._local_svd_fn,
+        "hsvd.staged_rank_tail": svdtools._staged_rank_tail_fn,
+        "hsvd.staged_oneview_tail": svdtools._staged_oneview_tail_fn,
+        "qr.tsqr": qr._tsqr_fn,
+        "kmeans.lloyd_step": kmeans._lloyd_step,
+        "kmeans.partial_fit_step": kmeans._partial_fit_step,
+        "kcluster.fused_fit": _kcluster._fused_fit_program,
+        "kcluster.predict": _kcluster._predict_program,
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "op.binary", "op.unary", "op.reduce", "op.cum", "hsvd.sketched_rank", "hsvd.one_view_rank", "hsvd.sketched",
+    "hsvd.local_svd", "hsvd.staged_rank_tail", "hsvd.staged_oneview_tail", "qr.tsqr", "kmeans.lloyd_step",
+    "kmeans.partial_fit_step", "kcluster.fused_fit", "kcluster.predict",
+])
+def test_observed_builder_keeps_the_lru_cache_surface(name):
+    builder = _observed_builders()[name]
+    info = builder.cache_info()
+    assert {"hits", "misses", "maxsize", "currsize"} <= set(info._fields)
+    assert info.maxsize >= 64
+    assert callable(builder.cache_clear) and callable(builder.__wrapped__)
+
+
+def test_hit_hands_back_the_proxy_made_at_the_miss_and_mesh_caches_clear():
+    from heat_tpu.core import communication
+    from heat_tpu.core.linalg import svdtools
+
+    builder = svdtools._sketched_single_rank_fn
+    builder.cache_clear()
+    first = builder(7, 17, 2, "both")
+    assert builder(7, 17, 2, "both") is first  # nothing allocated on a hit
+    assert builder.cache_info().currsize == 1 and builder.cache_info().hits == 1
+    assert hasattr(first, "lower")  # the jitted program's own surface passes through
+    builder.cache_clear()
+    assert builder.cache_info().currsize == 0
+    # register_mesh_cache holds the wrapped object and still clears it
+    assert svdtools._local_svd_fn in communication._MESH_KEYED_CACHES
+    a = ht.random.randn(8 * 64, 40, split=0)
+    ht.linalg.hsvd_rank(a, 2)
+    if P > 1:
+        assert svdtools._local_svd_fn.cache_info().currsize >= 1
+    communication._clear_mesh_caches()
+    assert svdtools._local_svd_fn.cache_info().currsize == 0
+
+
+def test_telemetry_counts_the_cells_builders():
+    """The hit/miss/build/compile counters keep their names and now count
+    on the hSVD and KMeans paths too."""
+    from heat_tpu.cluster import _kcluster
+    from heat_tpu.core.linalg import svdtools
+
+    svdtools._sketched_single_rank_fn.cache_clear()
+    _kcluster._fused_fit_program.cache_clear()
+    a = ht.random.randn(331, 96, split=None)
+    x = ht.random.randn(8 * 53, 7, split=0)
+    init = ht.array(np.asarray(x.numpy()[:3]))
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        for _ in range(2):
+            ht.linalg.hsvd_rank(a, 2, compute_sv=True)
+            ht.cluster.KMeans(3, init=init, max_iter=2, tol=0.0).fit(x)
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.enable() if was else telemetry.disable()
+        tracing.disable()
+        tracing.clear()
+    for name in ("hsvd.sketched_rank", "kcluster.fused_fit"):
+        assert snap["counters"].get(f"{name}.miss") == 1, name
+        assert snap["counters"].get(f"{name}.hit", 0) >= 1, name
+        assert f"{name}.build" in snap["timers"] and f"{name}.compile" in snap["timers"], name
+
+
+def test_programs_plans_and_aot_keys_identical_with_and_without_a_session(tmp_path):
+    """A profiler session changes what is observed, never what runs: the
+    lowered program of a builder, a plan's id and bytes, and the AOT
+    stamps are the same inside a session and outside one."""
+    from heat_tpu.core.linalg import svdtools
+    from heat_tpu.serving import aot_cache
+
+    spec = RedistSpec.normalize((1000, 250000), "float32", 0, 1, 8)
+    shape = jax.ShapeDtypeStruct((331, 96), np.float32)
+
+    def stamps():
+        svdtools._sketched_single_rank_fn.cache_clear()
+        sched = planner.plan(spec, 256 << 20, topology="flat")
+        return (
+            svdtools._sketched_single_rank_fn(7, 17, 2, "both").lower(shape).as_text(),
+            sched.plan_id,
+            sched.canonical_json(),
+            gates.aot_fingerprint(),
+            aot_cache._envelope_stamps()["gate_roster"],
+        )
+
+    outside = stamps()
+    inside = []
+    _profiled(tmp_path, lambda: (ht.sum(ht.ones((8,), split=0)), inside.append(stamps())))
+    assert inside[0] == outside
+
+
+def test_selftest_of_the_span_readers():
+    """``benchmarks/selftest_spans.py``: hand-written events with known
+    answers through every reducer that reads the spans (no rehearsal here:
+    about 15 s a cell)."""
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "selftest_spans.py"), "--no-rehearse"],
+        capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-2000:]
+    assert "selftest_spans: all passed" in done.stdout
