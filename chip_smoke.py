@@ -188,7 +188,11 @@ def has_kernel(fn, *args) -> bool:
     """Whether the program ``fn(*args)`` compiles to holds a Mosaic
     kernel (compile only, nothing runs). Interpret mode lowers to plain HLO, so ``tpu_custom_call``
     means the kernel was compiled for the chip."""
-    return "tpu_custom_call" in ht.observability.collective_counts(fn, *args).hlo_text
+    return "tpu_custom_call" in compiled_text(fn, *args)
+
+
+def compiled_text(fn, *args) -> str:
+    return ht.observability.collective_counts(fn, *args).hlo_text
 
 
 # --------------------------------------------------------------------- #
@@ -336,7 +340,10 @@ def hsvd(rec: dict) -> None:
 
         (u, sig, v, err), first, warm = timed(lambda: call(a))
         rec[label] = {**times(first, warm), **svd_errors(aj, u, sig, v, err, lam, norm_sq, rank)}
-        rec[label]["sketch_kernel_in_hlo"] = has_kernel(call, a)
+        hlo = compiled_text(call, a)
+        rec[label]["sketch_kernel_in_hlo"] = "tpu_custom_call" in hlo
+        # the two passes read f32 A; a bf16 copy of it is a third stream
+        rec[label]["copy_of_a_in_hlo"] = f"bf16[{m},{n}]" in hlo
         del u, sig, v, err
     # how much of the one-view's sigma error is MXU input rounding: the
     # same call with every XLA matmul at full precision (diagnostic)
@@ -354,6 +361,8 @@ def hsvd(rec: dict) -> None:
         check_svd(label, rec[label], tol)
         need(rec[label]["sketch_kernel_in_hlo"] or not ON_CHIP,
              f"{label}: no tpu_custom_call in the compiled hsvd_rank program — the Pallas sketch kernel did not run")
+        need(not rec[label]["copy_of_a_in_hlo"],
+             f"{label}: the compiled hsvd_rank program casts all of A to bf16[{m},{n}] — a pass more than the schedule has")
 
 
 def blobs(n: int, d: int, k: int, kk):

@@ -54,6 +54,7 @@ from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
+from ...observability import telemetry as _telemetry
 from ...observability.instrument import observed_program_cache
 from ...observability.tracing import span as _span
 from ._lapack import safe_svd, svd_x32_scope
@@ -102,41 +103,30 @@ def _pass1_tiles(g, a):
 
 def _pass2_tiles(a, qw, norm_in):
     """Pass 2 — ``z = a @ qw`` in fixed ``_PASS_TILE``-row tiles, with
-    the Frobenius accumulation folded into the SAME stream when
-    ``norm_in`` (the running carry) is given: the XLA fallback now
-    reads A exactly twice, like the TPU fused-kernel schedule. The
-    carry is an explicit argument so staged windows thread it through
-    in tile order — the scalar addition sequence is identical to the
-    in-HBM fori_loop and the error estimate stays bit-identical too."""
+    the Frobenius accumulation folded into the SAME stream on top of
+    ``norm_in`` (the running carry): the XLA fallback reads A exactly
+    twice, like the TPU schedule. The carry is an explicit argument so
+    staged windows thread it through in tile order — the scalar addition
+    sequence is identical to the in-HBM fori_loop and the error estimate
+    stays bit-identical too."""
     m, n = a.shape
     T = _PASS_TILE
     nfull = m // T
-    want_norm = norm_in is not None
     if nfull == 0:
-        z = a @ qw
-        if want_norm:
-            return z, norm_in + jnp.sum(jnp.real(a * jnp.conj(a)))
-        return z, None
+        return a @ qw, norm_in + jnp.sum(jnp.real(a * jnp.conj(a)))
     z0 = jnp.zeros((nfull * T, qw.shape[1]), dtype=a.dtype)
-    if want_norm:
-        def body(k, carry):
-            z, acc = carry
-            blk = jax.lax.dynamic_slice(a, (k * T, 0), (T, n))
-            z = jax.lax.dynamic_update_slice(z, blk @ qw, (k * T, 0))
-            return z, acc + jnp.sum(jnp.real(blk * jnp.conj(blk)))
 
-        z, acc = jax.lax.fori_loop(0, nfull, body, (z0, norm_in))
-    else:
-        def body(k, z):
-            blk = jax.lax.dynamic_slice(a, (k * T, 0), (T, n))
-            return jax.lax.dynamic_update_slice(z, blk @ qw, (k * T, 0))
+    def body(k, carry):
+        z, acc = carry
+        blk = jax.lax.dynamic_slice(a, (k * T, 0), (T, n))
+        z = jax.lax.dynamic_update_slice(z, blk @ qw, (k * T, 0))
+        return z, acc + jnp.sum(jnp.real(blk * jnp.conj(blk)))
 
-        z, acc = jax.lax.fori_loop(0, nfull, body, z0), None
+    z, acc = jax.lax.fori_loop(0, nfull, body, (z0, norm_in))
     if m % T:
         tail = a[nfull * T :]
         z = jnp.concatenate([z, tail @ qw], axis=0)
-        if want_norm:
-            acc = acc + jnp.sum(jnp.real(tail * jnp.conj(tail)))
+        acc = acc + jnp.sum(jnp.real(tail * jnp.conj(tail)))
     return z, acc
 
 
@@ -295,15 +285,16 @@ def _sketched_uds_both(a_blk, keep: int, sketch_l: int, want: str = "left"):
     a-posteriori error estimate below stays EXACT for the returned
     factorization either way (orthonormal Q ⇒ ‖A − AQQᵀ‖² = ‖A‖² − ‖z‖²).
 
-    Passes over A: 2 — the fused Pallas sketch+norm kernel folds the
-    Frobenius pass into pass 1 on TPU, and the XLA fallback folds it
-    into pass 2's tiled stream (``_pass2_tiles``; ISSUE 11 — the old
-    fallback paid a third read). Bound 819/2 ≈ 410 GB/s either way.
-
-    Both passes run the fixed-grain tiled streams (``_pass1_tiles``/
-    ``_pass2_tiles``) so the out-of-core staged windows of
-    ``redistribution.staging`` replay the exact same tile sequence —
-    staged factors are bit-identical to in-HBM by construction.
+    Passes over A: 2, and nothing else streams it. On TPU the fused
+    Pallas sketch+norm kernel folds the Frobenius pass into pass 1 and
+    pass 2 is ONE dot, whose bf16 cast of A the compiler fuses into the
+    dot's operand read. Everywhere else (and where the kernel's gates do
+    not hold) both passes run the fixed-grain tiled XLA streams
+    ``_pass1_tiles``/``_pass2_tiles``, the norm folded into pass 2's
+    stream (ISSUE 11), so the out-of-core staged windows of
+    ``redistribution.staging`` replay the exact same tile sequence and
+    staged factors are bit-identical to in-HBM ones there. Bound
+    819/2 ≈ 410 GB/s either way.
 
     Returns (u|None, v|None, s, err_sq, norm_sq)."""
     m, n = a_blk.shape
@@ -327,8 +318,17 @@ def _sketched_uds_both(a_blk, keep: int, sketch_l: int, want: str = "left"):
         # pass 2 with the Frobenius accumulation folded into the stream
         zero = jnp.zeros((), dtype=jnp.real(jnp.zeros((), a_blk.dtype)).dtype)
         z, norm_sq = _pass2_tiles(a_blk, qw, zero)
+        _telemetry.inc("hsvd.pass2.tiled")
     else:
-        z, _ = _pass2_tiles(a_blk, qw, None)  # pass 2: (m, l) projection
+        # pass 2: (m, l) projection, on the chip (pass 1 was the kernel).
+        # ONE dot, not the tiled loop: out of the loop the chip's compiler
+        # hoists the loop-invariant bf16 cast of A, a third stream that
+        # writes a copy of A (9.7 of 19.4 ms at the north-star shard); of
+        # one dot it fuses the cast into the operand read and streams f32
+        # A once, at pass 1's speed. Same arithmetic, to the bit: bf16 MXU
+        # inputs, f32 accumulation
+        z = a_blk @ qw
+        _telemetry.inc("hsvd.pass2.one_dot")
     return _projection_tail(z, qw, norm_sq, keep, want)
 
 
@@ -520,7 +520,7 @@ def _one_view_single_rank_fn(keep: int, k_hat: int, sketch_l: int, r_final: int,
 @observed_program_cache("hsvd.sketched")
 def _sketched_single_fn(keep: int, sketch_l: int, want: str = "left"):
     """Jitted single-device randomized truncated SVD returning the
-    ``want``ed factor side(s) — both sides come from the same four
+    ``want``ed factor side(s) — both sides come from the same two
     passes, so the transposed (split=0) orientation never materializes
     Aᵀ (an eager or even traced ``arr.T`` at the north-star size is a
     full strided read+write over A, ~5 ms profiled round 3) and never

@@ -82,7 +82,10 @@ Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`,
 `hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`,
 `hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `kmeans.lloyd_step`,
 `kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`), `ht.jit.cache.hit`/`.miss`,
-`comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`: for an operator's
+`comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`, and `hsvd.pass2.one_dot` /
+`hsvd.pass2.tiled` (which form of the two-pass sketch's second pass a program was built with: one
+dot that reads f32 `A` once, where pass 1 was the Pallas kernel, or the tiled loop `_pass2_tiles`
+everywhere else; counted once per built program, like a `.miss`): for an operator's
 `ht.telemetry.report()`, read by no benchmark metric.
 """,
 }

@@ -86,6 +86,30 @@ def test_sketch_fused_and_dual(one_chip):
     )
 
 
+@pytest.mark.parametrize("m, n", [(131072, 8192), (8192, 131072)], ids=["tall", "wide"])
+def test_hsvd_rank_program_reads_a_twice(one_chip, monkeypatch, m, n):
+    """The whole one-chip ``hsvd_rank`` program (rank 10 + 5, sketch width
+    25) at the benchmark cell's shape, and at the shape of the four-chip
+    level 0's shard of A.T: pass 1 is the kernel, pass 2 one dot that
+    reads f32 A, and no bf16 copy of A is made. As a tiled loop pass 2
+    had its cast of A hoisted out: a third stream and 2.15 GB of
+    temporaries (PERF.md, PR 26). The gate reads the backend: steer it."""
+    from heat_tpu.core.linalg import svdtools
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    builder = svdtools._sketched_single_rank_fn
+    builder.cache_clear()
+    try:
+        a = jax.ShapeDtypeStruct((m, n), F32, sharding=one_chip)
+        compiled = builder(15, 25, 10, "both").lower(a).compile()
+    finally:
+        builder.cache_clear()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    assert f"bf16[{m},{n}]" not in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < m * n  # was half of A's bytes: the copy
+
+
 def test_sort_block(one_chip):
     """The radix block kernel (integer iota; one-row blocks on a 3-D
     array). 4 blocks: the (8, 128) rule bites only past one block. (One
@@ -201,4 +225,6 @@ def test_hsvd_level0_under_shard_map(mesh4, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     prog = svdtools._local_svd_fn(mesh4, "d", 8192, 16384, 15, "float32", 25, None)
     a = jax.ShapeDtypeStruct((8192, 65536), F32, sharding=NamedSharding(mesh4, P(None, "d")))
-    assert "tpu_custom_call" in prog.lower(a).compile().as_text()
+    txt = prog.lower(a).compile().as_text()
+    assert "tpu_custom_call" in txt
+    assert "bf16[8192,16384]" not in txt  # pass 2 reads the f32 shard: no copy
