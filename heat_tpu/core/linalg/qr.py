@@ -98,12 +98,11 @@ def _tsqr_ring_active() -> bool:
     return _cm.ring_enabled()
 
 
-@observed_program_cache("qr.tsqr")
-def _tsqr_fn(
-    mesh, axis_name: str, lrows: int, cols: int, jdtype: str, calc_q: bool,
-    ring: bool = False, topo=None,
-):
-    """Compiled TSQR over the mesh for physical shard shape (lrows, cols).
+def _tsqr_kernel(p: int, axis_name: str, calc_q: bool, ring: bool = False, topo=None):
+    """The per-device body of TSQR over a ``p``-wide mesh axis, to be run
+    under ``shard_map`` on a row block: ``_tsqr_fn`` wraps it into a
+    program of its own, the distributed hSVD's merge calls it inside its
+    one program (``svdtools._dist_rank_fn``).
 
     p < 16 (or prime p): the flat schedule — ONE all-gather of the p R
     factors, one stacked merge QR. p ≥ 16 with a divisor s ≤ √p: the
@@ -126,8 +125,11 @@ def _tsqr_fn(
     ``topo=(S, C)`` (ISSUE 8): slice-major grouping — level-1 groups
     are exactly the slices (``s = C``), so the heavy gathers never
     cross DCN and only the tiny cross-group gather (G = n_slices
-    group-Rs) rides the expensive tier."""
-    p = mesh.devices.size
+    group-Rs) rides the expensive tier.
+
+    The Q updates (``q1 @ q2_i``) run at ``precision="highest"``: their
+    contraction is ``cols`` wide, and at the MXU's default precision (one
+    bf16 pass) Q came back orthonormal to 3e-3 only (PERF.md, PR 25)."""
     s = _tsqr_grouping(p, topo)
     two_level = s > 1
     from ...kernels import cmatmul as _cm
@@ -153,7 +155,7 @@ def _tsqr_fn(
             if not calc_q:
                 return r
             q2_i = jax.lax.dynamic_slice_in_dim(q2, i * k, k)
-            return q1 @ q2_i, r
+            return jnp.matmul(q1, q2_i, precision="highest"), r
 
         G = p // s
         i = jax.lax.axis_index(axis_name)
@@ -179,8 +181,20 @@ def _tsqr_fn(
             return r
         q2_j = jax.lax.dynamic_slice_in_dim(q2, j * k, k)
         q3_g = jax.lax.dynamic_slice_in_dim(q3, g * k2, k2)
-        return q1 @ (q2_j @ q3_g), r
+        q23 = jnp.matmul(q2_j, q3_g, precision="highest")
+        return jnp.matmul(q1, q23, precision="highest"), r
 
+    return kernel
+
+
+@observed_program_cache("qr.tsqr")
+def _tsqr_fn(
+    mesh, axis_name: str, lrows: int, cols: int, jdtype: str, calc_q: bool,
+    ring: bool = False, topo=None,
+):
+    """Compiled TSQR over the mesh for physical shard shape (lrows, cols):
+    ``_tsqr_kernel`` as one ``shard_map`` program."""
+    kernel = _tsqr_kernel(mesh.devices.size, axis_name, calc_q, ring, topo)
     in_specs = PartitionSpec(axis_name, None)
     if calc_q:
         out_specs = (PartitionSpec(axis_name, None), PartitionSpec(None, None))

@@ -31,6 +31,14 @@ TPU-native redesign (same math, different schedule):
    (``hsvd_rtol``) picks the final rank from the merged spectrum on host
    (a scalar-sized transfer), keeping all array shapes static under jit.
 
+4. Since PR 27 the rank-budget call on a split array (the north star's
+   form) is ONE program, ``_dist_rank_fn``: level 0 on each device's
+   block as it lies (no ``Aᵀ``), the merge by an in-program gather of
+   ``B`` (TSQR where p·r is wide), and the split-side factor from the
+   devices' own level-0 factors (``U_i = u_i Z_i``) instead of a third
+   pass over ``A``. Steps 1-3 describe the staged path the other modes
+   keep.
+
 ``maxmergedim``/``no_of_merges`` tuned the reference's tree arity against
 MPI message sizes; the TSQR merge has no such knob — they are accepted and
 validated for API parity.
@@ -747,11 +755,15 @@ def _local_svd_fn(
     mesh, axis_name: str, lrows: int, lcols: int, rloc: int, jdtype: str,
     sketch_l: Optional[int] = None, one_view: Optional[tuple] = None,
 ):
-    """Compiled level-0 kernel: per-shard truncated SVD → U·Σ block plus
+    """Compiled level-0 kernel of the STAGED distributed path (tolerance
+    mode, full local SVDs, the one-view sketch; the rank-budget two-pass
+    call is ``_dist_rank_fn``): per-shard truncated SVD → U·Σ block plus
     discarded-energy scalar (the analog of reference
     ``compute_local_truncated_svd``, svdtools.py:477). With ``sketch_l``
     the block SVD is the randomized range-finder variant; ``one_view``
-    = (k̂, ℓ) selects the single-pass sketch per shard (r5)."""
+    = (k̂, ℓ) selects the single-pass sketch per shard (r5). It takes the
+    reference's orientation, column blocks (``P(None, axis)``): a split-0
+    caller hands it ``phys.T``, a copy of ``A``."""
 
     def kernel(a_blk):
         # a_blk: (lrows, lcols) local column block of A (split=1 layout)
@@ -795,6 +807,98 @@ def _local_svd_fn(
     )
 
 
+#: widest stacked factor ``B`` (p·rloc columns) that the distributed
+#: rank-budget program merges by gathering it whole onto every device:
+#: up to one 128-lane tile the Gram of ``B`` and its eigh are one tile's
+#: work, replicated. Wider stacks (the north star's 64 x 15 = 960) keep
+#: the row-split TSQR merge, whose work and bytes divide by p.
+_MERGE_GATHER_MAX_COLS = 128
+
+
+@observed_program_cache("hsvd.dist_rank")
+def _dist_rank_fn(
+    mesh, axis_name: str, split: int, blk_shape: tuple, jdtype: str,
+    rloc: int, sketch_l: int, r_final: int, tsqr: bool, ring: bool = False, topo=None,
+):
+    """The whole distributed rank-budget call as ONE ``shard_map`` program
+    over a split-``split`` 2-D array whose device block is ``blk_shape``
+    (level 0, merge, both factors, the error estimate; no host read).
+
+    **Level 0** (``jax.named_scope("hsvd.level0")``): every device runs
+    ``_sketched_uds_both`` on its block AS IT LIES, ``A_i ≈ u_i s_i v_iᴴ``:
+    the two streams of the one-device call (the Pallas sketch+norm pass
+    and one dot, at the MXU's default precision), no transposed or cast
+    copy of the block. Of the two local factors one lives on the split
+    axis (``own``: ``u_i`` for split 0, ``v_i`` for split 1), the other
+    spans the axis every device shares.
+
+    **Merge** (``"hsvd.merge"``): the stacked ``B = [shared_1 s_1 ∥ … ∥
+    shared_p s_p] = W S Zᴴ``. Up to ``_MERGE_GATHER_MAX_COLS`` columns
+    ``B`` is all-gathered (8192 x 60 f32 = 2 MB on four chips) and every
+    device takes the eigh of its Gram; wider, ``B`` goes row-split by one
+    all-to-all and through TSQR (``qr._tsqr_kernel``) and the SVD of R.
+    ``W`` (rows of it, out split 0) is the shared-side factor. The
+    own-side factor needs NO third pass ``A W Σ⁻¹``: device i's rows of it
+    are ``own_i Z_i`` (``Z_i``: the device's ``rloc`` rows of ``Z``), a
+    (rows x rloc)(rloc x r) product, orthonormal by construction
+    (``Σ Z_iᴴ Z_i = I``). Every product here is skinny and runs at
+    ``precision="highest"``.
+
+    Returns ``(own (split 0), sigma, shared rows (split 0), err)``."""
+    from .qr import _tsqr_kernel
+
+    p = mesh.devices.size
+    shared_n = blk_shape[1 - split]
+    chunk = -(-shared_n // p)  # rows of the shared-side factor a device returns
+    merge_rows = _tsqr_kernel(p, axis_name, True, ring, topo) if tsqr else None
+
+    def kernel(a_blk):
+        i = jax.lax.axis_index(axis_name)
+        with jax.named_scope("hsvd.level0"):
+            # rloc <= both extents of the block (the caller's min), so it is the rank kept
+            u, v, s, err_sq, norm_sq = _sketched_uds_both(a_blk, rloc, sketch_l, "both")
+            own, shared = (u, v) if split == 0 else (v, u)
+            b = shared * s
+        with jax.named_scope("hsvd.merge"):
+            b = jnp.pad(b, ((0, chunk * p - shared_n), (0, 0)))
+            if tsqr:
+                rows = jax.lax.all_to_all(b, axis_name, 0, 1, tiled=True)  # (chunk, p·rloc)
+                q_rows, r = merge_rows(rows)
+                u_r, s_all, zh = safe_svd(r, full_matrices=False)
+                w_rows = jnp.matmul(q_rows, u_r[:, :r_final], precision="highest")
+                z = jnp.conj(zh).T[:, :r_final]
+                # Q of an all-zero pad row is not zero by construction
+                live = (i * chunk + jnp.arange(chunk) < shared_n)[:, None]
+                w_rows = jnp.where(live, w_rows, 0)
+            else:
+                bs = jax.lax.all_gather(b, axis_name, axis=1, tiled=True)  # (·, p·rloc)
+                gram = jnp.matmul(jnp.conj(bs).T, bs, precision="highest")
+                lam, z = jnp.linalg.eigh(gram)  # ascending
+                s_all = jnp.sqrt(jnp.maximum(lam[::-1], 0.0))
+                z = z[:, ::-1][:, :r_final]
+                inv_s = jnp.where(s_all[:r_final] > 0, 1.0 / s_all[:r_final], 0.0)
+                w = _cholqr2_refine(jnp.matmul(bs, z, precision="highest") * inv_s)
+                w_rows = jax.lax.dynamic_slice_in_dim(w, i * chunk, chunk)
+            z_i = jax.lax.dynamic_slice_in_dim(z, i * rloc, rloc)
+            own_out = jnp.matmul(own, z_i, precision="highest")
+            err = jnp.sqrt(
+                jax.lax.psum(err_sq, axis_name) + jnp.sum(s_all[r_final:] ** 2)
+            ) / jnp.maximum(jnp.sqrt(jax.lax.psum(norm_sq, axis_name)), 1e-30)
+        return own_out, s_all[:r_final], w_rows, err
+
+    in_spec = [None, None]
+    in_spec[split] = axis_name
+    rows_spec = PartitionSpec(axis_name, None)
+    return jax.jit(
+        _shard_map(
+            kernel,
+            mesh=mesh,
+            in_specs=PartitionSpec(*in_spec),
+            out_specs=(rows_spec, PartitionSpec(), rows_spec, PartitionSpec()),
+            check_vma=False,
+        )
+    )
+
 
 def _err_scalar(val, A=None, comm=None, device=None) -> DNDarray:
     """Wrap the relative-error estimate as a 0-d replicated DNDarray — the
@@ -821,12 +925,18 @@ def _merge_svd(B: DNDarray, calc_u: bool = True):
     """SVD of the stacked factor matrix via TSQR + small-R SVD.
 
     B (m × K) with K = p·r small: resplit to rows, TSQR, then SVD of the
-    K×K R on-device (replicated — it is tiny).
+    K×K R on-device (replicated — it is tiny). The merge of the staged
+    distributed path (``hsvd_rtol``, full local SVDs, the one-view
+    sketch); the rank-budget two-pass call merges inside its one program
+    (``_dist_rank_fn``). ``Q·U_R`` is K wide and runs at
+    ``precision="highest"`` (at the MXU's default it cost U three digits
+    of orthonormality).
     Returns (U as DNDarray split=0 | None, s, total extra err 0.0).
     """
     from .qr import qr as _qr
 
     m, K = B.shape
+    _telemetry.inc("hsvd.dist.merge.tsqr" if m >= K else "hsvd.dist.merge.gather")
     if m >= K:
         Brow = B.resplit(0)
         q, r = _qr(Brow, calc_q=calc_u)
@@ -834,7 +944,9 @@ def _merge_svd(B: DNDarray, calc_u: bool = True):
         if not calc_u:
             return None, s
         U = DNDarray(
-            _padding.mask_phys(q._phys @ u_r, (m, int(u_r.shape[1])), 0),
+            _padding.mask_phys(
+                jnp.matmul(q._phys, u_r, precision="highest"), (m, int(u_r.shape[1])), 0
+            ),
             (m, int(u_r.shape[1])),
             q.dtype,
             0,
@@ -983,6 +1095,23 @@ def _hsvd_impl(
     silent: bool,
     single_pass: bool = False,
 ):
+    """The three public calls' shared body. Not distributed (or
+    ``split=None``): one jitted sketch program, or a full SVD. Distributed:
+
+    - rank budget, two-pass sketch (``rtol is None``, the sketch gates
+      holding, real dtype): ``_hsvd_dist_rank``: ONE observed program on
+      the physical array as it lies. No transpose of ``A`` (each device
+      asks ``_sketched_uds_both`` for both sides of its block), the merged
+      factor from a gather (or TSQR, where p·r is wide) inside the program,
+      the split-side factor from the devices' own level-0 factors times
+      their rows of the merge's ``Z`` (no third pass over ``A``), every
+      product but the two streams over ``A`` at ``precision="highest"``,
+      the estimate a lazy 0-d array.
+    - everything else (tolerance mode, which reads the merged spectrum on
+      the host; full local SVDs; the one-view sketch; complex): the staged
+      path: ``_local_svd_fn`` on the reference's column orientation (an
+      eager ``phys.T`` for split 0), ``_merge_svd`` (resplit + TSQR), the
+      complementary factor by ``_postprocess_v``'s pass over ``A``."""
     from ...redistribution import staging as _staging
 
     comm: MeshCommunication = A.comm
@@ -1104,22 +1233,33 @@ def _hsvd_impl(
             rloc = min(m, -(-n // p))
             if maxrank is not None:
                 rloc = min(rloc, maxrank + safetyshift)
-            phys = A._phys.astype(jt)
-            if transposed:
-                # pad rows become zero pad columns: Frobenius/SVD-neutral
-                phys = phys.T
-            lcols = phys.shape[1] // p
+            # a device's block as it lies; its transpose is level 0's
+            # (m, lcols) column block of the reference orientation
+            blk = list(A._phys.shape)
+            blk[A.split] //= p
+            lcols = blk[A.split]
             sketch_l = None
             if maxrank is not None and not _needs_exact_spectrum(rtol):
-                lmin = min(phys.shape[0], lcols)
+                lmin = min(blk)
                 l = min(rloc + _SKETCH_OVERSAMPLE, lmin)
                 if 4 * l <= lmin:
                     sketch_l = l
             one_view = None
             if single_pass and sketch_l is not None:
-                one_view = _one_view_params(
-                    min(rloc, lcols), min(phys.shape[0], lcols), phys.shape[0], lcols
-                )
+                one_view = _one_view_params(min(rloc, lcols), min(m, lcols), m, lcols)
+        if (
+            sketch_l is not None
+            and rtol is None
+            and one_view is None
+            and not types.heat_type_is_complexfloating(dtype)
+        ):
+            # rank budget, two-pass sketch: the whole call is one program
+            return _hsvd_dist_rank(A, dtype, tuple(blk), rloc, sketch_l, maxrank, compute_sv)
+        with _span("ht.call.hsvd.prepare"):
+            phys = A._phys.astype(jt)
+            if transposed:
+                # pad rows become zero pad columns: Frobenius/SVD-neutral
+                phys = phys.T
         with _span("ht.call.hsvd.level0"):
             fn = _local_svd_fn(
                 comm.mesh, comm.axis_name, phys.shape[0], lcols, rloc, np.dtype(jt).name,
@@ -1202,11 +1342,50 @@ def _hsvd_impl(
     return U, sigma, V_of_A, err
 
 
+def _hsvd_dist_rank(
+    A: DNDarray, dtype, blk: tuple, rloc: int, sketch_l: int, maxrank: int, compute_sv: bool
+):
+    """``hsvd_rank`` on a distributed split-0 or split-1 array whose
+    device block is ``blk``: ONE launch of ``_dist_rank_fn`` on the
+    physical array as it lies, then the ``DNDarray`` wrappers. Both factors come back split 0, sigma
+    replicated, the error estimate a lazy 0-d array, as the staged
+    distributed path returns them."""
+    from .qr import _tsqr_ring_active
+
+    comm: MeshCommunication = A.comm
+    jt = dtype.jax_type()
+    r_final = max(1, min(maxrank, comm.size * rloc, min(A.shape)))
+    tsqr = comm.size * rloc > _MERGE_GATHER_MAX_COLS
+    _telemetry.inc("hsvd.dist.merge.tsqr" if tsqr else "hsvd.dist.merge.gather")
+    _telemetry.inc("hsvd.dist.u.local")
+    topo_t = comm.topology
+    with _span("ht.call.hsvd.level0"), svd_x32_scope(jt):
+        fn = _dist_rank_fn(
+            comm.mesh, comm.axis_name, A.split, blk, np.dtype(jt).name,
+            rloc, sketch_l, r_final, tsqr,
+            ring=tsqr and _tsqr_ring_active(),
+            topo=(topo_t.n_slices, topo_t.chips_per_slice) if tsqr and topo_t.tiered else None,
+        )
+        own, s_dev, shared, err_dev = fn(A._phys.astype(jt))
+    with _span("ht.call.hsvd.wrap"):
+        err = _err_scalar(err_dev, A)
+        own = DNDarray(own, (A.shape[A.split], r_final), dtype, 0, A.device, comm)
+        shared = DNDarray(shared, (A.shape[1 - A.split], r_final), dtype, 0, A.device, comm)
+        U, V = (own, shared) if A.split == 0 else (shared, own)
+        if not compute_sv:
+            return U, err
+        sigma = DNDarray(
+            _place(s_dev, comm.sharding(1, None)), (r_final,), dtype, None, A.device, comm
+        )
+        return U, sigma, V, err
+
+
 def _postprocess_v(A: DNDarray, factor: DNDarray, sigma: DNDarray, left: bool) -> DNDarray:
     """Compute the complementary singular factor: V = Aᵀ U / σ or
     U = A V / σ (reference: svdtools.py:456-467)."""
     from . import basics
 
+    _telemetry.inc("hsvd.dist.u.postprocess")
     with _span("ht.call.hsvd.postprocess"):
         if left:
             prod = basics.matmul(A, factor)  # (m, r)

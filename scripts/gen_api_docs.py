@@ -63,11 +63,19 @@ kept, with parent ids, in the ring that `ht.observability.export_trace` writes. 
 | span | around | read by |
 |---|---|---|
 | `ht.call.hsvd_rank`, `ht.call.hsvd_rtol`, `ht.call.hsvd` | the whole public call | `host_wrapper_ms_per_call` (self time) |
-| `ht.call.hsvd.prepare` | `sanitize_in`, checks, dtype, `astype`, the orientation (`phys.T` when split) and the sketch-size arithmetic | `host_wrapper_ms_per_call` |
-| `ht.call.hsvd.level0` | lookup and call of the level-0 program (the program spans nest in it) | `host_wrapper_ms_per_call` |
-| `ht.call.hsvd.merge` | `_merge_svd` and the rank/err arithmetic after it, any `device_get` | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.prepare` | `sanitize_in`, checks, dtype, `astype`, the sketch-size arithmetic; on the staged split path also the orientation (`phys.T`) | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.level0` | lookup and call of the level-0 program (the program spans nest in it). The rank-budget call on a split array is ONE program (`hsvd.dist_rank`: level 0, merge, both factors, the estimate), launched here | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.merge` | staged split path and tolerance mode: `_merge_svd` and the rank/err arithmetic after it, any `device_get` | `host_wrapper_ms_per_call` |
 | `ht.call.hsvd.wrap` | `_err_scalar`, the `DNDarray` constructions, sigma's placement | `host_wrapper_ms_per_call` |
-| `ht.call.hsvd.postprocess` | `_postprocess_v` (the complementary factor across chips) | `host_wrapper_ms_per_call` |
+| `ht.call.hsvd.postprocess` | `_postprocess_v` (the complementary factor by a third pass over `A`; staged split path only) | `host_wrapper_ms_per_call` |
+
+Inside the `hsvd.dist_rank` program the device ops carry `jax.named_scope`s, so a device trace
+tells the phases of the one program apart: `hsvd.level0` (each device's two streams over its block
+and the local factors) and `hsvd.merge` (the exchange of the stacked factor, its factorization, `U`
+from the local factors, the estimate). They are in every op's `op_name` metadata.
+
+| span | around | read by |
+|---|---|---|
 | `ht.call.kmeans.fit`, `ht.call.kmeans.predict` | `KMeans.fit` (the fused fit), `predict` | `host_wrapper_ms_per_call` |
 | `ht.call.kmeans.init`, `.program`, `.wrap` | initial centres or seed key; lookup of the step and the fused program and the call; placement and the two `DNDarray`s (shared by `KMedians`/`KMedoids.fit`) | `host_wrapper_ms_per_call` |
 | `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
@@ -79,14 +87,18 @@ kept, with parent ids, in the ring that `ht.observability.export_trace` writes. 
 
 Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`, `.miss`,
 `.build`, `.compile` for every observed builder (`op.binary`, `op.unary`, `op.reduce`, `op.cum`,
-`hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`,
+`hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`, `hsvd.dist_rank`,
 `hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `kmeans.lloyd_step`,
 `kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`), `ht.jit.cache.hit`/`.miss`,
 `comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`, and `hsvd.pass2.one_dot` /
 `hsvd.pass2.tiled` (which form of the two-pass sketch's second pass a program was built with: one
 dot that reads f32 `A` once, where pass 1 was the Pallas kernel, or the tiled loop `_pass2_tiles`
-everywhere else; counted once per built program, like a `.miss`): for an operator's
-`ht.telemetry.report()`, read by no benchmark metric.
+everywhere else; counted once per built program, like a `.miss`), and, once per call of an hSVD on a
+split array, `hsvd.dist.merge.gather` / `hsvd.dist.merge.tsqr` (which merge ran: the stacked factor
+gathered whole and factored on every device, up to 128 columns, or TSQR over its rows) and
+`hsvd.dist.u.local` / `hsvd.dist.u.postprocess` (where the split-side factor came from: the devices'
+own level-0 factors times their rows of the merge's `Z`, or `_postprocess_v`'s third pass over `A`):
+for an operator's `ht.telemetry.report()`, read by no benchmark metric.
 """,
 }
 

@@ -14,6 +14,7 @@ this one file.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -217,9 +218,10 @@ def test_attention_ring_program_four_chips(mesh4, dtype):
 
 
 def test_hsvd_level0_under_shard_map(mesh4, monkeypatch):
-    """hsvd_rank's level-0 program on four chips: the sketch kernel inside
-    shard_map, one (8192, 16384) column block per chip. The kernel's gate
-    reads the backend, which is the CPU here: steer it in the test."""
+    """The staged distributed path's level-0 program on four chips (rtol
+    mode, the one-view sketch): the sketch kernel inside shard_map, one
+    (8192, 16384) column block per chip. The kernel's gate reads the
+    backend, which is the CPU here: steer it in the test."""
     from heat_tpu.core.linalg import svdtools
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -228,3 +230,63 @@ def test_hsvd_level0_under_shard_map(mesh4, monkeypatch):
     txt = prog.lower(a).compile().as_text()
     assert "tpu_custom_call" in txt
     assert "bf16[8192,16384]" not in txt  # pass 2 reads the f32 shard: no copy
+
+
+_DOT = re.compile(r"^\s*(?:ROOT )?%\S+ = (\S+?)\{[^ ]* (?:dot|convolution)\(", re.M)
+
+
+def _dots_below_highest(hlo: str) -> list:
+    """Result shapes of the compiled module's dots (the chip's compiler
+    writes most of them as convolutions) whose operands are not both at
+    ``highest`` precision."""
+    return [
+        m.group(1) for m in _DOT.finditer(hlo)
+        if "operand_precision={highest,highest}" not in hlo[m.start(): hlo.index("\n", m.end())]
+    ]
+
+
+@pytest.mark.parametrize("tsqr", [False, True], ids=["gather_merge", "tsqr_merge"])
+def test_hsvd_dist_rank_program_four_chips(mesh4, monkeypatch, tsqr):
+    """The one program of ``hsvd_rank`` on a split-0 array over four chips,
+    at the cell's size (131072 x 8192 f32 a chip, rank 10 + 5, sketch width
+    25), with either merge: each chip's rows go through the sketch kernel
+    as they lie (no transposed, f32 or bf16 copy of the block; the program
+    needs no temporaries of its size), and the ONLY product below
+    ``highest`` precision is pass 2's stream over A (pass 1 is inside the
+    kernel). At the parent the four-chip call was ~60 programs, level 0
+    read an eager ``A.T`` and Q's updates ran at the MXU's default."""
+    from heat_tpu.core.linalg import svdtools
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, n, l = 131072, 8192, 25
+    builder = svdtools._dist_rank_fn
+    builder.cache_clear()
+    try:
+        a = jax.ShapeDtypeStruct((4 * rows, n), F32, sharding=NamedSharding(mesh4, P("d", None)))
+        compiled = builder(mesh4, "d", 0, (rows, n), "float32", 15, l, 10, tsqr).lower(a).compile()
+    finally:
+        builder.cache_clear()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    assert ("all-to-all" in txt) == tsqr and "all-gather" in txt
+    for copy in (f"bf16[{rows},{n}]", f"f32[{n},{rows}]", f"bf16[{n},{rows}]"):
+        assert copy not in txt
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * n  # a quarter of the block's bytes
+    assert _dots_below_highest(txt) == [f"f32[{rows},{l}]"]
+
+
+def test_tsqr_q_updates_at_highest(mesh4):
+    """``ht.linalg.qr``'s TSQR program (and the merge of the staged
+    distributed hSVD): no product at the MXU's default precision, which
+    left Q orthonormal to 3e-3 on the chip (PERF.md, PR 25)."""
+    import importlib
+
+    qr = importlib.import_module("heat_tpu.core.linalg.qr")  # the package exports the function under this name
+    qr._tsqr_fn.cache_clear()
+    try:
+        a = jax.ShapeDtypeStruct((4 * 2048, 60), F32, sharding=NamedSharding(mesh4, P("d", None)))
+        txt = qr._tsqr_fn(mesh4, "d", 2048, 60, "float32", True).lower(a).compile().as_text()
+    finally:
+        qr._tsqr_fn.cache_clear()
+    assert "all-gather" in txt
+    assert _dots_below_highest(txt) == []

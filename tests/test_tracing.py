@@ -807,17 +807,32 @@ def _hsvd_one_device():
 
 
 def _hsvd_split():
+    """The rank-budget call on a split array: one observed program."""
+    from heat_tpu.core.linalg import svdtools
+
+    svdtools._dist_rank_fn.cache_clear()
+    a = ht.random.randn(8 * 331, 96, split=0)
+    return (
+        lambda: ht.linalg.hsvd_rank(a, 2, compute_sv=True),
+        {"ht.call.hsvd_rank", "ht.call.hsvd.prepare", "ht.call.hsvd.level0", "ht.call.hsvd.wrap", "ht.comm.place"},
+        "ht.call.hsvd_rank",
+    )
+
+
+def _hsvd_split_staged():
+    """Tolerance mode on a split array keeps the staged path: level-0
+    program, resplit and TSQR merge, the other factor by a matmul over A."""
     from heat_tpu.core.linalg import svdtools
 
     svdtools._local_svd_fn.cache_clear()
     importlib.import_module("heat_tpu.core.linalg.qr")._tsqr_fn.cache_clear()
     a = ht.random.randn(8 * 331, 96, split=0)
     return (
-        lambda: ht.linalg.hsvd_rank(a, 2, compute_sv=True),
-        {"ht.call.hsvd_rank", "ht.call.hsvd.prepare", "ht.call.hsvd.level0", "ht.call.hsvd.merge",
+        lambda: ht.linalg.hsvd_rtol(a, 0.5, compute_sv=True, maxrank=2),
+        {"ht.call.hsvd_rtol", "ht.call.hsvd.prepare", "ht.call.hsvd.level0", "ht.call.hsvd.merge",
          "ht.call.hsvd.wrap", "ht.call.hsvd.postprocess", "ht.op.matmul", "ht.comm.reshard", "ht.comm.shard",
          "ht.comm.place"},
-        "ht.call.hsvd_rank",
+        "ht.call.hsvd_rtol",
     )
 
 
@@ -837,7 +852,8 @@ def _kmeans_fit():
 
 
 @pytest.mark.skipif(P < 2, reason="the split path needs a real mesh")
-@pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _kmeans_fit], ids=lambda f: f.__name__.strip("_"))
+@pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _hsvd_split_staged, _kmeans_fit],
+                         ids=lambda f: f.__name__.strip("_"))
 def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     """One call that misses and one that hits, under a profiler session and
     nothing else: every span the path runs is on the host plane, nested,
@@ -855,6 +871,10 @@ def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     assert "ht.program.miss" in in_first and "ht.program.compile" in in_first
     assert "ht.program.miss" not in in_second and "ht.program.compile" not in in_second
     assert "ht.program.hit" in in_second and "ht.program.launch" in in_second
+    if case is _hsvd_split:
+        # the whole split call is ONE launch, with no op, shard or reshard beside it
+        assert in_second.count("ht.program.launch") == 1
+        assert not [n for n in in_second if n.startswith("ht.op.") or n in ("ht.comm.shard", "ht.comm.reshard")]
     assert tracing.spans() == []  # a profiler session does not turn the ring on
 
 
