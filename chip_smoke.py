@@ -388,11 +388,15 @@ def blobs(n: int, d: int, k: int, kk):
 
 def kmeans(rec: dict) -> None:
     (n, d), k, iters = SZ["km"], SZ["km_k"], 5
-    rec.update(path="ht.cluster.KMeans.fit -> one fused XLA program (Lloyd while_loop)",
-               rows=n, rows_north_star=REAL["km"][0], features=d)
     xj, centers = blobs(n, d, k, key(40))
     init = centers + 0.5 * jax.random.normal(key(41), (k, d), jnp.float32)
     x = ht.array(xj, split=0)
+    from heat_tpu.cluster._pallas import lloyd_pass_serves
+
+    fused = lloyd_pass_serves(jax.default_backend(), "float32", (n, d), k, x.split, x.comm.size)
+    rec.update(path="ht.cluster.KMeans.fit -> one program (Lloyd while_loop + label pass), the step "
+               + ("the Pallas pass over f32 X" if fused else "XLA's two streams"),
+               rows=n, rows_north_star=REAL["km"][0], features=d)
 
     def fit():
         km = ht.cluster.KMeans(n_clusters=k, init=ht.array(init), max_iter=iters, tol=0.0).fit(x)

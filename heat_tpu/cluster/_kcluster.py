@@ -5,8 +5,11 @@ init strategies ``random``/``probability_based`` (k-means++) with
 per-centroid Bcast from the owning rank at _kcluster.py:100-187; assignment
 = cdist + argmin at :196-209). Here initialization samples/percolates on
 the sharded global array (no rank-owned rows — the controller indexes the
-global array and XLA fetches the row), and each Lloyd-style iteration is a
-single jit: distances on the MXU via the quadratic expansion, masked
+global array and XLA fetches the row), and the whole fit — seeding, the
+Lloyd-style ``while_loop``, the label pass — is one jitted program. The
+iteration is the subclass's step: for KMeans on a TPU one fused pass over
+f32 ``X`` that also serves the label pass (``_pallas``; PERF.md section
+6, PR 28), otherwise XLA: distances by the quadratic expansion, masked
 per-cluster reductions lowering to one all-reduce over the mesh.
 """
 
@@ -86,15 +89,22 @@ def _fused_fit_program(step, k: int, shape, jdtype: str, tol: float, max_iter: i
     a single dispatch per fit. The eager composite paid 3-4 dispatches
     (seeding, loop, assignment, functional value), which dominated fit
     time for cb-scale inputs. ``init_arg`` is a PRNG
-    key when ``seeded`` else the (k, d) initial centers."""
+    key when ``seeded`` else the (k, d) initial centers.
+
+    A step that offers ``step.assign(arr, centers) -> labels`` (KMeans'
+    fused pass, which then also ``returns_inertia``) gives the final
+    assignment itself; for every other step it is ``_pairwise`` + argmin."""
     loop = make_fit_loop(step, jdtype, tol, max_iter, returns_inertia)
     seed_prog = _kmeanspp_program(k, shape, jdtype) if seeded else None
+    assign = getattr(step, "assign", None) if returns_inertia else None
 
     @jax.jit
     def run(arr, init_arg):
         centers0 = seed_prog(arr, init_arg) if seeded else init_arg.astype(arr.dtype)
         res = loop(arr, centers0)
         centers, n_iter = res[0], res[1]
+        if assign is not None:
+            return centers, n_iter, assign(arr, centers).astype(types.index_jax_type()), res[2]
         d = _KCluster._pairwise(arr, centers, metric)
         labels = jnp.argmin(d, axis=1).astype(types.index_jax_type())
         if metric == "manhattan":

@@ -1,28 +1,40 @@
-"""Pallas TPU kernel: fused distance + argmin + accumulate for KMeans.
+"""Pallas TPU kernel: one Lloyd iteration of KMeans in ONE read of f32 ``X``.
 
-The native-kernel layer SURVEY §7 plans ("custom Pallas kernels for hot
-spots — fused distance+argmin for KMeans"). The reference's Lloyd update
-(kmeans.py:74-100) materializes the (n × k) distance matrix and a one-hot
-assignment matrix; the fused jnp step (`kmeans._lloyd_step`) still writes
-both through HBM. This kernel streams row tiles of X through VMEM once per
-iteration and never materializes either:
+The XLA form of the step (``kmeans._lloyd_step``) is two streams an
+iteration over a bf16 copy of ``X`` that the compiler hoists out of the
+loop, an ``x2`` pass and two small reductions. This pass reads the f32
+rows once, as they lie, and returns everything an iteration needs.
 
-    per (TM × d) tile:  d² = ‖x‖² + ‖c‖² − 2 x·cᵀ   (MXU)
-                        labels = argmin d²            (VPU)
-                        acc   += onehotᵀ · [x | 1 | min d²]  (MXU)
+The chip keeps a tall ``f32[n, d]`` with ``d < 128`` FEATURE-MAJOR
+(``{0,1:T(8,128)}``: rows on the lanes, the only compact form of a narrow
+f32 array under (8, 128) tiles), so ``x.T`` is a bitcast and the kernel
+works on blocks ``(d, tn)`` of it — rows on all 128 lanes, the k centres
+on the sublanes:
 
-The single (k × d+2) accumulator carries cluster sums, counts and
-per-cluster inertia; HBM traffic is exactly one read of X per iteration —
-the bandwidth lower bound.
+    per (d x tn) tile:  xb  = bf16(xt)                          cast in VMEM
+                        g   = C_bf16 (k x d) . xb               MXU, f32 acc
+                        d2  = max(x2 + c2 - 2 g, 0)             x2 from the f32 tile
+                        lab = first argmin over the k sublanes  VPU
+                        sums  += onehot (k x tn) . xb^T         MXU, f32 acc
+                        counts, inertia += lane partials        VPU
 
-MEASURED OUTCOME (TPU v5e, n=1M d=64 k=8): the XLA-fused jnp Lloyd step
-runs at 1.14 ms/iter ≈ 225 GB/s — already at the HBM bandwidth bound —
-while this kernel reaches 6.8 ms (k=8 lanes waste 15/16 of the VPU; the
-(k × d+2) matmul underfills the MXU). Exactly the guide's rule: don't
-hand-schedule what the compiler already fuses. The kernel is therefore
-OPT-IN (``use_pallas=True``), kept as the validated native-kernel path
-(numerics match the jnp step to 2e-6) and as the scaffold for shapes
-where XLA's fusion does fall short (very large k, fused multi-metric).
+The operands and accumulation are those of the default-precision
+``arr @ centers.T`` and ``onehot.T @ arr`` of the XLA step. The ``(k, d)``
+sums and two ``(·, 128)`` lane-partial blocks stay in VMEM over the grid;
+only the last tile is masked (``n`` need not divide by anything). With
+``labels=True`` the same pass also writes the assignment: the label pass
+of a fit.
+
+A row-major ``(tm, d)`` kernel stood here until PR 28. It was opt-in and
+never ran: the compiler put a 128-lane-padded row-major copy of ``X`` in
+front of the loop for it (9.6 GB at the north-star shard).
+
+MEASURED (TPU v5e, 18 750 000 x 64 f32, k 8; builder's chip runs, PR 28,
+PERF.md section 6): the pass 6.34 ms an iteration inside the fit (757 GB/s,
+92 % of the HBM peak, what a kernel that only adds the tile up reads too),
+the XLA step's loop 9.02; a whole ``fit`` of ten iterations 72.0 ms
+against 98.0. Not the MXU but the read bounds it, from 4096 to 32768 rows
+a step and up to k = 64; at k = 128 it is 8.5 ms (XLA: 47).
 """
 
 from __future__ import annotations
@@ -33,96 +45,213 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
+from jax.sharding import PartitionSpec as P
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
 
-__all__ = ["fused_assign_program", "pallas_available"]
+__all__ = ["fused_lloyd_step", "lloyd_pass_program", "lloyd_pass_serves"]
+
+# k up to the widest tile of X (d < 128): the (k, tn) distance block is then no
+# taller than the (d, tn) tile it is made from. A grid step takes 2 MiB of f32 X
+# (8192 rows at d 64: 4096 to 32768 read alike on the chip, 2048 6 % slower;
+# PERF.md, PR 28), fewer where the tile twice (two pipeline buffers), its bf16
+# copy, its square and five (k, tn) f32 temporaries would pass _TILE_BYTES of
+# the _VMEM_LIMIT asked for (k 128 at d 120: 4.2 KB a row, 4096 rows)
+_K_MAX = 128
+_VMEM_LIMIT = 32 * 1024 * 1024
+_TILE_BYTES = 24 * 1024 * 1024
+_TILE_X_BYTES = 2 * 1024 * 1024
 
 
-def pallas_available() -> bool:
-    """True when the backend can execute the compiled kernel (gate for the
-    opt-in path; auto-selection stays on the XLA-fused formulation, which
-    measures at the bandwidth bound — see module docstring)."""
-    return (
-        jax.default_backend() == "tpu"
-        and jax.device_count() == 1
-        and not jax.config.jax_enable_x64  # Mosaic rejects x64-mode traces
-    )
+def lloyd_pass_serves(backend: str, dtype, shape, k: int, split, devices: int = 1) -> bool:
+    """The gate: does one fit's Lloyd step run the fused pass? A pure
+    function of what the code sees in its input.
+
+    Yes where the backend is a TPU (x64 off, its platform default: Mosaic
+    refuses 64-bit traces), the data f32 and 2-D, ``d`` a multiple of 8
+    under 128 (there the chip keeps the array feature-major and ``x.T`` is
+    free; at ``d >= 128`` it is row-major and the XLA step stays until a
+    cell asks), ``k <= 128`` (``_K_MAX``: the distance block no taller
+    than the widest tile of ``X``, so the VMEM budget holds at ``tn >=
+    4096``), and ``X`` lies on one device or is split 0 over ``devices``
+    with equal shards (``n`` a multiple of them: the array is then its
+    physical self, no pad rows). Everything else runs the XLA step."""
+    if backend != "tpu" or jax.config.jax_enable_x64:
+        return False
+    if np.dtype(dtype) != np.float32 or len(shape) != 2:
+        return False
+    n, d = int(shape[0]), int(shape[1])
+    if d % 8 or not 8 <= d < 128 or not 1 <= k <= _K_MAX or n < 1:
+        return False
+    if split is None or devices == 1:
+        return True
+    return split == 0 and n % devices == 0
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _make_kernel(tm: int, n: int, k: int):
-    def kernel(x_ref, c_ref, acc_ref):
-        # every scalar is pinned to a ≤32-bit dtype: x64 mode would
-        # otherwise leak int64/float64 into the kernel, which Mosaic rejects
-        f1 = jnp.float32(1.0)
-        f0 = jnp.float32(0.0)
+def _pick_tn(n: int, d: int, k8: int) -> int:
+    """Rows a grid step: a multiple of 1024, the tiling of the 1-D label
+    output, that keeps the step's VMEM inside ``_TILE_BYTES``. Up to 1024
+    rows the chip tiles that output by the power of two that holds it
+    (128 at least), and the one block has to be just that."""
+    if n <= 1024:
+        return max(128, 1 << (n - 1).bit_length())
+    per_row = d * (4 * 2 + 2 + 4) + k8 * 4 * 5
+    tn = max(1024, min(_TILE_X_BYTES // (4 * d), _TILE_BYTES // per_row) // 1024 * 1024)
+    return min(tn, _round_up(n, 1024))
+
+
+def _lane_partials(v, tn: int):
+    """(r, tn) -> (r, 128): the tile's 128-lane groups added up, the
+    cross-lane sum left to the caller (once a pass, not once a tile)."""
+    acc = v[:, :128]
+    for j in range(1, tn // 128):
+        acc = acc + v[:, j * 128:(j + 1) * 128]
+    return acc
+
+
+def _make_kernel(n: int, k: int, k8: int, tn: int, labels: bool):
+    steps = pl.cdiv(n, tn)
+    tail = n - (steps - 1) * tn  # rows of the last tile that exist
+
+    def tile(xt_ref, c_ref, c2_ref, out_refs, masked: bool):
+        sums_ref, cnt_ref, ine_ref = out_refs[:3]
+        x = xt_ref[...]  # (d, tn) f32
+        if masked:
+            # what the last block holds past row n is unspecified: zero it
+            # before any arithmetic, drop it from every accumulator below
+            valid = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1) < tail
+            x = jnp.where(valid, x, 0.0)
+        xb = x.astype(jnp.bfloat16)
+        g = jnp.dot(c_ref[...], xb, preferred_element_type=jnp.float32)  # (k8, tn)
+        x2 = jnp.sum(x * x, axis=0, keepdims=True)  # (1, tn)
+        d2 = jnp.maximum(x2 + c2_ref[...] - 2.0 * g, 0.0)
+        dmin = jnp.min(d2, axis=0, keepdims=True)
+        # first index of the minimum, as argmin has it
+        row = jax.lax.broadcasted_iota(jnp.int32, (k8, tn), 0)
+        lab = jnp.min(jnp.where(d2 == dmin, row, k - 1), axis=0, keepdims=True)
+        onehot = row == lab
+        if masked:
+            onehot = onehot & valid
+            dmin = jnp.where(valid, dmin, 0.0)
+        onehot = jnp.where(onehot, 1.0, 0.0)  # (k8, tn) f32
+        sums_ref[...] += jax.lax.dot_general(
+            onehot.astype(jnp.bfloat16), xb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (k8, d)
+        cnt_ref[...] += _lane_partials(onehot, tn)
+        ine_ref[...] += _lane_partials(dmin, tn)
+        if labels:
+            out_refs[3][...] = lab.reshape(tn)
+
+    def kernel(xt_ref, c_ref, c2_ref, *out_refs):
         i = pl.program_id(0)
-        # the grid's last tile may reach past row n: what it reads there is
-        # unspecified, so those rows are zeroed before any arithmetic
-        # (0 * NaN would poison the accumulator matmul) and masked below
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
-        valid = (i.astype(jnp.int32) * jnp.int32(tm) + row_ids) < jnp.int32(n)
-        x = jnp.where(valid, x_ref[:].astype(jnp.float32), f0)  # (TM, d)
-        c = c_ref[:].astype(jnp.float32)          # (k, d)
-        x2 = jnp.sum(x * x, axis=1, keepdims=True)
-        c2 = jnp.sum(c * c, axis=1, keepdims=True).T
-        d2 = x2 + c2 - jnp.float32(2.0) * jnp.dot(x, c.T, preferred_element_type=jnp.float32)
-        d2 = jnp.maximum(d2, f0)                  # (TM, k)
-        dmin = jnp.min(d2, axis=1, keepdims=True)
-        # first-argmin via min-reduction over indices (Mosaic's argmin
-        # primitive rejects the int64 index dtype x64 mode implies)
-        col_ids = jax.lax.broadcasted_iota(jnp.int32, (tm, k), 1)
-        labels = jnp.min(
-            jnp.where(d2 == dmin, col_ids, jnp.int32(k)), axis=1, keepdims=True
-        )
-        onehot = col_ids == labels
-        onehot = jnp.where(valid & onehot, f1, f0)
-        ones = jnp.where(valid, f1, f0)
-        # [x | 1 | min d²]: one MXU matmul yields sums, counts AND
-        # per-cluster inertia in a single (k, d+2) accumulator
-        xe = jnp.concatenate([x, ones, jnp.where(valid, dmin, f0)], axis=1)
-        part = jnp.dot(onehot.T, xe, preferred_element_type=jnp.float32)
 
         @pl.when(i == 0)
         def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+            for ref in out_refs[:3]:
+                ref[...] = jnp.zeros_like(ref)
 
-        acc_ref[:] += part
+        if tail == tn:
+            tile(xt_ref, c_ref, c2_ref, out_refs, False)
+            return
+
+        @pl.when(i < steps - 1)
+        def _whole():
+            tile(xt_ref, c_ref, c2_ref, out_refs, False)
+
+        @pl.when(i == steps - 1)
+        def _last():
+            tile(xt_ref, c_ref, c2_ref, out_refs, True)
 
     return kernel
 
 
 @functools.lru_cache(maxsize=64)
-def fused_assign_program(n: int, d: int, k: int, jdtype: str, interpret: bool = False):
-    """Compiled fused-assignment pass: (x (n,d), centers (k,d)) →
-    (sums (k,d) f32, counts (k,) f32, inertia () f32)."""
-    tm = max(8, min(1024, _round_up(min(n, 1024), 8)))
-    kernel = _make_kernel(tm, n, k)
+def lloyd_pass_program(n: int, d: int, k: int, labels: bool = False, interpret: bool = False,
+                       tn: int = 0):
+    """The pass over ONE device's rows: ``(x (n, d) f32, centers (k, d))
+    -> (sums (k, d), counts (k,), inertia ()[, labels (n,) int32])``, all
+    f32. Traceable; ``interpret=True`` runs it on the CPU for the tests."""
+    k8 = _round_up(k, 8)
+    tn = tn or _pick_tn(n, d, k8)
+    const = lambda i: (0, 0)
+    out_shape = [
+        jax.ShapeDtypeStruct((k8, d), jnp.float32),
+        jax.ShapeDtypeStruct((k8, 128), jnp.float32),
+        jax.ShapeDtypeStruct((1, 128), jnp.float32),
+    ]
+    out_specs = [pl.BlockSpec(s.shape, const, memory_space=_VMEM) for s in out_shape]
+    if labels:
+        out_shape.append(jax.ShapeDtypeStruct((n,), jnp.int32))
+        out_specs.append(pl.BlockSpec((tn,), lambda i: (i,), memory_space=_VMEM))
     call = pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(n, tm),),
+        _make_kernel(n, k, k8, tn, labels),
+        grid=(pl.cdiv(n, tn),),
         in_specs=[
-            pl.BlockSpec((tm, d), lambda i: (i, 0), memory_space=_VMEM),
-            pl.BlockSpec((k, d), lambda i: (0, 0), memory_space=_VMEM),
+            pl.BlockSpec((d, tn), lambda i: (0, i), memory_space=_VMEM),
+            pl.BlockSpec((k8, d), const, memory_space=_VMEM),
+            pl.BlockSpec((k8, 1), const, memory_space=_VMEM),
         ],
-        out_specs=pl.BlockSpec((k, d + 2), lambda i: (0, 0), memory_space=_VMEM),
-        out_shape=jax.ShapeDtypeStruct((k, d + 2), jnp.float32),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT
+        ),
+        name="kmeans_lloyd_pass",
         interpret=interpret,
     )
 
     def run(x, centers):
-        # x64 is off on TPU by platform policy, so Mosaic's grid/index
-        # machinery traces with 32-bit scalars; the forced-x64
-        # configuration is gated out in pallas_available. The last tile
-        # is masked in the kernel: no padded copy of x is made
-        acc = call(x.astype(jnp.dtype(jdtype)), centers.astype(jnp.dtype(jdtype)))
-        return acc[:, :d], acc[:, d], jnp.sum(acc[:, d + 1])
+        with jax.named_scope("kmeans.lloyd_pass"):
+            c = centers.astype(jnp.float32)
+            # pad centres sit at "infinity": never the nearest
+            c2 = jnp.pad(jnp.sum(c * c, axis=1), (0, k8 - k), constant_values=np.finfo(np.float32).max)
+            cb = jnp.pad(c, ((0, k8 - k), (0, 0))).astype(jnp.bfloat16)
+            out = call(x.T, cb, c2[:, None])  # x.T: a bitcast of the feature-major array
+            res = (out[0][:k], jnp.sum(out[1][:k], axis=1), jnp.sum(out[2]))
+            return res + (out[3],) if labels else res
 
-    return jax.jit(run)
+    return run
+
+
+@functools.lru_cache(maxsize=64)
+def fused_lloyd_step(k: int, shape, mesh=None, axis_name=None, interpret: bool = False):
+    """The Lloyd step on the fused pass: ``step(arr, centers) ->
+    (new_centers, shift, inertia)``, and ``step.assign(arr, centers) ->
+    labels`` (int32), the same pass with its label output, for the fit's
+    final assignment. On one device the pass is called bare. With a
+    ``mesh`` it runs under ``shard_map`` (Mosaic kernels are not
+    partitioned automatically): with an ``axis_name``, ``arr`` is split 0
+    over it in equal shards, each device passes over its rows and the
+    ``(k, d)`` sums, counts and inertia are ``psum``med; without one
+    ``arr`` is replicated and every device runs the whole pass."""
+    n, d = int(shape[0]), int(shape[1])
+    p = mesh.devices.size if axis_name is not None else 1
+    stats = lloyd_pass_program(n // p, d, k, False, interpret)
+    with_labels = lloyd_pass_program(n // p, d, k, True, interpret)
+    assign = lambda arr, centers: with_labels(arr, centers)[3]
+    if mesh is not None:
+        if axis_name is not None:
+            local = stats
+            stats = lambda arr, centers: jax.lax.psum(local(arr, centers), axis_name)
+        specs = dict(mesh=mesh, in_specs=(P(axis_name, None), P()), check_vma=False)
+        stats = _shard_map(stats, out_specs=P(), **specs)
+        assign = _shard_map(assign, out_specs=P(axis_name), **specs)
+
+    def step(arr, centers):
+        sums, counts, inertia = stats(arr, centers)
+        counts = counts[:, None]
+        new_centers = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), centers)
+        shift = jnp.sum((new_centers - centers) ** 2)
+        return new_centers, shift, inertia
+
+    step.assign = assign
+    return step

@@ -2,11 +2,16 @@
 
 API parity with /root/reference/heat/cluster/kmeans.py (``KMeans``; Lloyd
 update via masked mean at kmeans.py:74-100, issuing k Allreduces per
-iteration — reference call stack SURVEY §3.4). Here one Lloyd iteration is
-ONE jit-compiled program: the distance matrix rides the MXU (quadratic
-expansion), the per-cluster sums are a single one-hot matmul whose
-reduction over the sharded sample axis lowers to ONE all-reduce of a
-(k × d+1) buffer — independent of k — and convergence is a scalar.
+iteration — reference call stack SURVEY §3.4). Here one Lloyd fit is
+ONE jit-compiled program. On a TPU, for tall narrow f32 data, a Lloyd
+iteration is one Pallas pass that reads the f32 rows once
+(``_pallas``: distances on the MXU, argmin, one-hot sums, counts and
+inertia from the same tile; 6.5 ms at 18.75M x 64 on a v5e, the rate a
+bare read of the array gets — PERF.md section 6, PR 28). Elsewhere it is
+the XLA formulation: the distance matrix by the quadratic expansion, the
+per-cluster sums a one-hot matmul whose reduction over the sharded
+sample axis lowers to ONE all-reduce of a (k × d) buffer, convergence a
+scalar.
 
 ISSUE 11 adds the STREAMING form: ``partial_fit`` (sklearn
 MiniBatchKMeans-style running-mean updates, one fused program per
@@ -30,44 +35,35 @@ from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ._kcluster import _KCluster
 from ..core.communication import place as _place
+from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import span as _span
+from . import _pallas
 
 __all__ = ["KMeans"]
 
 
 @observed_program_cache("kmeans.lloyd_step", maxsize=64)
-def _lloyd_step(k: int, shape, jdtype: str, use_pallas: Optional[bool] = None):
-    """One Lloyd iteration as a pure jitted function: (x, centers) →
+def _lloyd_step(k: int, shape, jdtype: str, split=None, mesh=None, axis_name=None):
+    """One Lloyd iteration as a pure function: (x, centers) →
     (new_centers, shift², inertia).
 
-    The default is the XLA-fused jnp formulation: measured on TPU v5e it
-    runs at the HBM bandwidth bound (1.14 ms/iter at n=1M, d=64, k=8 ≈
-    225 GB/s), which no hand-scheduled kernel can beat. ``use_pallas=True``
-    opts into the fused Pallas assignment kernel
-    (``_pallas.fused_assign_program``) — numerically equivalent (≤2e-6),
-    kept for shapes where XLA's fusion falls short; see ``_pallas``.
+    Which form runs is decided by ``_pallas.lloyd_pass_serves`` from the
+    backend, dtype, shape and split, nothing else. Where it says yes (a
+    TPU, f32, ``d`` a multiple of 8 under 128, ``k`` ≤ 128, ``x`` on one
+    device or split 0 in equal shards over ``mesh``) the step is ONE read
+    of the f32 rows (``_pallas.fused_lloyd_step``; under ``shard_map``
+    with one ``psum`` of the (k, d) sums, counts and inertia across
+    chips), and it offers ``step.assign`` for the fit's label pass.
+    Everywhere else it is the XLA formulation below: two streams an
+    iteration over a bf16 copy of ``x`` that the chip's compiler hoists
+    out of the loop, 9.0 ms an iteration at 18.75M x 64 on a v5e against
+    the pass's 6.5 (PERF.md section 6, PR 28).
     """
-    from . import _pallas
-
-    if use_pallas is None:
-        use_pallas = False
-
-    if use_pallas:
-        assign = _pallas.fused_assign_program(int(shape[0]), int(shape[1]), k, jdtype)
-
-        @jax.jit
-        def step(arr, centers):
-            sums, counts, inertia = assign(arr, centers)
-            sums = sums.astype(arr.dtype)
-            counts = counts.astype(arr.dtype)
-            new_centers = jnp.where(
-                counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1), centers
-            )
-            shift = jnp.sum((new_centers - centers) ** 2)
-            return new_centers, shift, inertia.astype(arr.dtype)
-
-        return step
+    devices = 1 if mesh is None else mesh.devices.size
+    if _pallas.lloyd_pass_serves(jax.default_backend(), jdtype, shape, k, split, devices):
+        where = (mesh, axis_name if split == 0 else None) if devices > 1 else ()
+        return _pallas.fused_lloyd_step(k, tuple(shape), *where)
 
     @jax.jit
     def step(arr, centers):
@@ -86,6 +82,20 @@ def _lloyd_step(k: int, shape, jdtype: str, use_pallas: Optional[bool] = None):
         return new_centers, shift, inertia
 
     return step
+
+
+def _lloyd_step_for(x: DNDarray):
+    """``_lloyd_step`` bound to where ``x`` lies, as the ``step_factory``
+    ``_fit_fused`` asks for; counts which form the fit got
+    (``kmeans.step.fused`` / ``kmeans.step.xla``, once a fit)."""
+
+    def factory(k: int, shape, jdtype: str):
+        step = _lloyd_step(k, shape, jdtype, x.split, x.comm.mesh, x.comm.axis_name)
+        fused = getattr(step, "assign", None) is not None
+        _telemetry.inc("kmeans.step.fused" if fused else "kmeans.step.xla")
+        return step
+
+    return factory
 
 
 @observed_program_cache("kmeans.partial_fit_step", maxsize=64)
@@ -224,11 +234,8 @@ class KMeans(_KCluster):
                         "disables — unset the gate or drop ckpt="
                     )
                 with _span("ht.call.kmeans.fit"):
-                    return self._fit_fused(
-                        _staging.materialize(x, what="KMeans.fit"),
-                        _lloyd_step,
-                        returns_inertia=True,
-                    )
+                    x = _staging.materialize(x, what="KMeans.fit")
+                    return self._fit_fused(x, _lloyd_step_for(x), returns_inertia=True)
             return self._partial_fit_stream(
                 x, ckpt=ckpt, watcher=_watcher, chaos=_chaos, fresh=True
             )
@@ -240,7 +247,7 @@ class KMeans(_KCluster):
                 "to checkpoint mid-fit"
             )
         with _span("ht.call.kmeans.fit"):
-            return self._fit_fused(x, _lloyd_step, returns_inertia=True)
+            return self._fit_fused(x, _lloyd_step_for(x), returns_inertia=True)
 
     # ------------------------------------------------------------------ #
     # streaming / out-of-core (ISSUE 11)                                 #

@@ -97,8 +97,13 @@ everywhere else; counted once per built program, like a `.miss`), and, once per 
 split array, `hsvd.dist.merge.gather` / `hsvd.dist.merge.tsqr` (which merge ran: the stacked factor
 gathered whole and factored on every device, up to 128 columns, or TSQR over its rows) and
 `hsvd.dist.u.local` / `hsvd.dist.u.postprocess` (where the split-side factor came from: the devices'
-own level-0 factors times their rows of the merge's `Z`, or `_postprocess_v`'s third pass over `A`):
-for an operator's `ht.telemetry.report()`, read by no benchmark metric.
+own level-0 factors times their rows of the merge's `Z`, or `_postprocess_v`'s third pass over `A`),
+and, once per `KMeans.fit` (since PR 28), `kmeans.step.fused` / `kmeans.step.xla` (which Lloyd step
+the fit's program runs, as `cluster._pallas.lloyd_pass_serves` decided from backend, dtype, shape
+and split: the Pallas pass that reads f32 `X` once an iteration, or XLA's two streams):
+for an operator's `ht.telemetry.report()`, read by no benchmark metric. On the device the pass is
+named `kmeans_lloyd_pass` (the kernel) under `jax.named_scope("kmeans.lloyd_pass")`: a trace's op
+line and the ledger's `breakdown.device_ops` show it by that name.
 """,
 }
 

@@ -172,17 +172,72 @@ def test_sddmm_brick(one_chip, d):
     )
 
 
-@pytest.mark.parametrize("n", [4_194_304, 15_625_000])
-def test_kmeans_fused_assign(one_chip, n):
-    """The opt-in KMeans kernel, up to the north-star shard: its last
-    tile is masked in the kernel, so no padded copy of x is asked for
-    (with one, 15 625 000 x 64 needed 18.6 GB of a 15.75 GB chip)."""
-    from heat_tpu.cluster import _pallas as kp
+_X_SIZED_OP = re.compile(r"= (?:f32|bf16)\[(\d+),(\d+)\]\S* (copy|transpose|convert)\(")
 
-    d, k = 64, 8
-    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    prog = kp.fused_assign_program(n, d, k, "float32")
-    assert "tpu_custom_call" in prog.lower(s((n, d), F32), s((k, d), F32)).compile().as_text()
+
+def _kmeans_fit_compiled(n, d, k, x_sharding, mesh=None, axis_name=None):
+    """The whole fused KMeans fit (Lloyd ``while_loop`` + label pass, init
+    given) on the fused pass, compiled for the described chip(s)."""
+    from heat_tpu.cluster import _kcluster, _pallas as kp
+
+    step = kp.fused_lloyd_step(k, (n, d), mesh, axis_name)
+    builder = _kcluster._fused_fit_program
+    builder.cache_clear()
+    try:
+        prog = builder(step, k, (n, d), "float32", 0.0, 10, True, "euclidean", False)
+        x = jax.ShapeDtypeStruct((n, d), F32, sharding=x_sharding)
+        c = jax.ShapeDtypeStruct((k, d), F32)
+        return prog.program.lower(x, c).compile()
+    finally:
+        builder.cache_clear()
+
+
+def _assert_x_is_read_as_it_lies(compiled, rows, d):
+    txt = compiled.as_text()
+    assert txt.count("tpu_custom_call") >= 2  # the loop's pass and the label pass
+    sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
+    assert sized == []  # no copy, transpose or cast of X: x.T is a bitcast
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * d * 4 // 100
+
+
+@pytest.mark.parametrize("n", [4_194_304, 15_625_000, 18_750_000])
+def test_kmeans_fused_assign(one_chip, n):
+    """KMeans' fused fit program up to the benchmark cell's shard
+    (18 750 000 x 64, no multiple of 128: the last tile is masked in the
+    kernel): the chip holds X feature-major, the pass reads blocks of
+    ``x.T``, and nothing of X's size is copied, transposed or cast (the XLA
+    step's program: a 2.4 GB bf16 copy; the row-major kernel that stood
+    here until PR 28: a 9.6 GB relayout)."""
+    _assert_x_is_read_as_it_lies(_kmeans_fit_compiled(n, 64, 8, one_chip), n, 64)
+
+
+@pytest.mark.parametrize("d,k", [(8, 3), (120, 128)], ids=["d8_k3", "d120_k128"])
+def test_kmeans_fused_assign_gate_corners(one_chip, d, k):
+    """The corners of ``lloyd_pass_serves``: the narrowest and the widest
+    feature-major ``d``, the largest ``k`` (VMEM: 4096 rows a step)."""
+    _assert_x_is_read_as_it_lies(_kmeans_fit_compiled(1_000_003, d, k, one_chip), 1_000_003, d)
+
+
+@pytest.mark.parametrize("n", [1, 300, 1024, 1025])
+def test_kmeans_fused_assign_short_arrays(one_chip, n):
+    """Fewer rows than one block: the chip tiles the 1-D label output by
+    the power of two that holds it (128 to 1024) and refuses any other
+    block (a 384-row block of 300 labels; a 1024-row block of one)."""
+    assert _kmeans_fit_compiled(n, 16, 4, one_chip).as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("split", [0, None], ids=["split0", "replicated"])
+def test_kmeans_fused_assign_four_chips(mesh4, split):
+    """The same fit over the 2 x 2, under ``shard_map`` (a bare Mosaic call
+    is refused on a mesh). ``X`` split 0: the pass on each chip's rows, the
+    sums, counts and inertia ``psum``med (one all-reduce an iteration),
+    labels split 0. ``X`` replicated: every chip the whole pass, nothing
+    crosses."""
+    rows = 4_687_500  # the cell's 18.75M over four chips
+    n, spec, axis = (4 * rows, P("d", None), "d") if split == 0 else (rows, P(), None)
+    compiled = _kmeans_fit_compiled(n, 64, 8, NamedSharding(mesh4, spec), mesh4, axis)
+    _assert_x_is_read_as_it_lies(compiled, rows, 64)
+    assert ("all-reduce" in compiled.as_text()) == (split == 0)
 
 
 @pytest.mark.parametrize("dtype,family", [("bfloat16", "splash"), ("float32", "flash")])

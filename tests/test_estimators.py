@@ -266,56 +266,6 @@ if __name__ == "__main__":
     unittest.main()
 
 
-class TestPallasFusedAssign(TestCase):
-    """Fused distance+argmin Pallas kernel (interpreter mode on CPU) vs the
-    jnp Lloyd formulation — same sums/counts/inertia."""
-
-    def test_matches_jnp_step(self):
-        import jax.numpy as jnp
-        from heat_tpu.cluster import _pallas
-
-        rng = np.random.default_rng(0)
-        for n, d, k in ((1000, 64, 8), (1003, 16, 4), (64, 8, 3)):
-            x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
-            c = x[:k]
-            prog = _pallas.fused_assign_program(n, d, k, "float32", interpret=True)
-            sums, counts, inertia = prog(x, c)
-            # oracle
-            d2 = np.maximum(
-                (np.asarray(x) ** 2).sum(1)[:, None]
-                + (np.asarray(c) ** 2).sum(1)[None, :]
-                - 2 * np.asarray(x) @ np.asarray(c).T,
-                0.0,
-            )
-            labels = d2.argmin(1)
-            oh = np.eye(k, dtype=np.float32)[labels]
-            np.testing.assert_allclose(np.asarray(sums), oh.T @ np.asarray(x), rtol=2e-4, atol=2e-4)
-            np.testing.assert_allclose(np.asarray(counts), oh.sum(0), rtol=1e-6)
-            np.testing.assert_allclose(float(inertia), d2.min(1).sum(), rtol=2e-4)
-
-    def test_lloyd_step_pallas_flag(self):
-        import jax.numpy as jnp
-        from heat_tpu.cluster.kmeans import _lloyd_step
-        from heat_tpu.cluster import _pallas
-
-        rng = np.random.default_rng(1)
-        n, d, k = 500, 8, 4
-        x = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
-        c0 = x[:k]
-        ref_step = _lloyd_step(k, (n, d), "float32", use_pallas=False)
-        ref = ref_step(x, c0)
-        # interpret-mode pallas variant: patch availability then compare
-        prog = _pallas.fused_assign_program(n, d, k, "float32", interpret=True)
-        sums, counts, inertia = prog(x, c0)
-        new_centers = np.where(
-            np.asarray(counts)[:, None] > 0,
-            np.asarray(sums) / np.maximum(np.asarray(counts)[:, None], 1),
-            np.asarray(c0),
-        )
-        np.testing.assert_allclose(np.asarray(ref[0]), new_centers, rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(float(ref[2]), float(inertia), rtol=2e-4)
-
-
 class TestSparseEncoders(TestCase):
     """ISSUE 18: transforms that EMIT sparse outputs — one-hot and
     TF-IDF return DCSR matrices, register as serving ``transform``
