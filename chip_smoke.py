@@ -38,7 +38,7 @@ import time
 import traceback
 
 # --------------------------------------------------------------------- #
-# sizes: the per-chip north-star shards (bench.py) and their toy twins   #
+# sizes: the per-chip north-star shards and their toy twins              #
 # --------------------------------------------------------------------- #
 REAL = dict(
     chain_n=67_108_864,            # 256 MB f32 per elementwise pass

@@ -47,7 +47,7 @@ def main() -> None:
 
     # eager comparison: the same chain, one program PER op. Warm the
     # per-op programs first — timing the cold pass would charge one-time
-    # compiles to the eager side (bench.py's methodology: warm, THEN time)
+    # compiles to the eager side (warm, THEN time)
     def eager_chain(a):
         ae = (a - ht.mean(a, axis=0)) / (ht.std(a, axis=0) + 1e-6)
         g = ht.matmul(ht.transpose(ae), ae)

@@ -67,15 +67,6 @@ well-formed, invariant by invariant:
     reordered lap makes the consume slot read an unissued buffer).
     Available standalone as :func:`check_progress` — what the MPMD
     stage-graph verifier will consume per stage.
-``calibration``
-    the stamped lattice profile (ISSUE 16): a plan priced under
-    ``HEAT_TPU_LATTICE_PROFILE`` carries ``{profile_id, edges}`` —
-    the stamp must be well-formed (non-empty id, known edges, positive
-    prices) and the numbers DERIVED from the prices elsewhere in the
-    plan must agree (the topology annotation's ``dcn_penalty`` is the
-    recorded ici/dcn ratio; the staging model recompute above uses the
-    recorded pcie/hbm prices). Environment-independent: a dumped
-    calibrated plan verifies on a container with no profile.
 ``tolerance``
     the error-bound recomputation (ISSUE 17, pass 6's dynamic half):
     the end-to-end error bound recomputed from the recorded per-step
@@ -974,17 +965,8 @@ def verify_plan(
                 "it was sized for",
             )
         model = staging.get("model") or {}
-        # ISSUE 16: a calibrated plan's model was priced at its RECORDED
-        # edge prices, not the module constants — recompute from the
-        # annotation so verification stays environment-independent (a
-        # dumped calibrated plan verifies on a container with no profile)
-        _cal_prices = (d.get("calibration") or {}).get("edges") or {}
-        want_pcie_s = round(
-            pcie_total / float(_cal_prices.get("pcie") or _tiers_mod.PCIE_BPS), 9
-        )
-        want_hbm_s = round(
-            pcie_total / float(_cal_prices.get("hbm") or _tiers_mod.HBM_BPS), 9
-        )
+        want_pcie_s = round(pcie_total / _tiers_mod.PCIE_BPS, 9)
+        want_hbm_s = round(pcie_total / _tiers_mod.HBM_BPS, 9)
         n_total = sum(int(pm.get("n_windows", 0)) for pm in passes)
         seq_s = want_pcie_s + want_hbm_s
         cp_s = max(want_pcie_s, want_hbm_s) + min(want_pcie_s, want_hbm_s) / max(
@@ -1004,61 +986,6 @@ def verify_plan(
                     f"model {field}={model.get(field)} != the lattice "
                     f"recompute {want} (tiers.transfer_time arithmetic)",
                 )
-
-    # ---- calibration: the stamped lattice profile (ISSUE 16) ----------
-    # A plan priced under HEAT_TPU_LATTICE_PROFILE carries {profile_id,
-    # edges}; the invariant checks the stamp is well-formed and that the
-    # derived numbers ELSEWHERE in the plan agree with the recorded
-    # prices (the topology annotation's dcn_penalty is the measured
-    # ici/dcn ratio). Environment-independent: the plan's own recorded
-    # prices are the ground truth, never the ambient gate.
-    cal = d.get("calibration")
-    if cal is not None:
-        pid_c = cal.get("profile_id")
-        if not isinstance(pid_c, str) or not pid_c.strip():
-            fail(
-                "calibration",
-                f"calibration annotation without a profile_id stamp ({pid_c!r})",
-            )
-        cal_edges = cal.get("edges")
-        if not isinstance(cal_edges, dict) or not cal_edges:
-            fail("calibration", "calibration annotation records no edge prices")
-        else:
-            from ..core import tiers as _cal_tiers
-
-            for name in sorted(cal_edges):
-                if name not in _cal_tiers.EDGES:
-                    fail(
-                        "calibration",
-                        f"calibration price for unknown lattice edge {name!r}",
-                    )
-                    continue
-                try:
-                    bps_ok = float(cal_edges[name]) > 0
-                except (TypeError, ValueError):
-                    bps_ok = False
-                if not bps_ok:
-                    fail(
-                        "calibration",
-                        f"calibration edge {name!r} price {cal_edges[name]!r} "
-                        "is not a positive bytes/s",
-                    )
-            if (
-                topo is not None
-                and cal_edges.get("ici")
-                and cal_edges.get("dcn")
-            ):
-                want_pen = max(
-                    1, int(float(cal_edges["ici"]) / float(cal_edges["dcn"]))
-                )
-                if int(topo.get("dcn_penalty", 0)) != want_pen:
-                    fail(
-                        "calibration",
-                        f"topology dcn_penalty={topo.get('dcn_penalty')} != "
-                        f"{want_pen}, the recorded ici/dcn price ratio — the "
-                        "plan was priced under a different profile than it "
-                        "is stamped with",
-                    )
 
     # ---- progress: the collective-congruence replay (ISSUE 14) --------
     for _rule, defect in _progress_defects(d, steps, coll, p, strategy, topo):
@@ -1083,7 +1010,7 @@ def verify_plan(
     checks = [
         "step-kinds", "accounting", "quant-pairing", "tier-labels",
         "composition", "conservation", "overlap-structure", "staging",
-        "calibration", "progress", "tolerance", "plan-id",
+        "progress", "tolerance", "plan-id",
     ]
     return {
         "ok": not violations,

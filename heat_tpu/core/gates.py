@@ -370,25 +370,6 @@ declare(GateSpec(
          "deployment's code. A path, never program-bytes key material",
 ))
 declare(GateSpec(
-    "HEAT_TPU_LATTICE_PROFILE", default="", kind="path",
-    affects_programs=True, scopes=("plan", "aot"),
-    key_params=("profile_id", "calibration"),
-    accessors=("active_profile", "profile_id"),
-    help="measured lattice-profile JSON path (ISSUE 16, "
-         "observability.calibration): unset/empty = the hard-coded "
-         "core.tiers constants, byte-identical plans/plan_ids/programs "
-         "to the pre-calibration era (diffed in CI). Set = bandwidth()/"
-         "transfer_time()/penalty() consult the profile's measured "
-         "per-edge prices, the planner re-prices candidate selection, "
-         "and the profile_id is stamped into plan canonical "
-         "serialization — recalibration is a VISIBLE plan_id "
-         "invalidation, never silent drift. Unlike the other path "
-         "gates this one IS program-affecting: measured prices change "
-         "which plan the planner picks. A tampered or "
-         "version-mismatched profile is evicted and the constants are "
-         "used (never an error)",
-))
-declare(GateSpec(
     "HEAT_TPU_NUMCHECK_ACC_DIM", default=str(1024), kind="int",
     affects_programs=False, scopes=(),
     key_params=(),

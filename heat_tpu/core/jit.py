@@ -3,10 +3,9 @@
 The reference framework is eager: every ``heat.*`` call runs its own
 kernels (torch eager + MPI). This repo's eager path is already compiled
 per op, but a CHAIN of public ops still pays one XLA program dispatch per
-op — measured at ~3.4x the cost of the equivalent single fused program
-for a 6-op elementwise chain (bench.py ``op_chain``). The reference has
-no answer to this; on TPU the answer is the same one JAX gives:
-trace the whole user function into ONE XLA program.
+op (root ``PERF.md``, section 7, row 4 is the cell that would measure
+it). The reference has no answer to this; on TPU the answer is the same
+one JAX gives: trace the whole user function into ONE XLA program.
 
 ``ht.jit(fn)`` wraps a function of DNDarrays (any pytree of DNDarrays,
 jax arrays and static Python values) so that every ``heat_tpu`` op inside

@@ -234,16 +234,13 @@ def golden_factorization_plans():
 
 
 def _runtime_plan(kind, gshape, jt, comm):
-    """Build + register the plan a public solver is about to execute."""
-    from ...observability.attribution import register_plan
+    """Build the plan a public solver is about to execute."""
     from ...redistribution import planner as _planner
 
-    sched = _factorization_plan(
+    return _factorization_plan(
         kind, tuple(int(s) for s in gshape), np.dtype(jt).name, comm.size,
         budget=_planner.budget_bytes(),
     )
-    register_plan(sched)
-    return sched
 
 
 # ---------------------------------------------------------------------- #
@@ -1032,7 +1029,6 @@ def _solve_host_rhs(a: DNDarray, b, assume_a: str = "gen"):
     :class:`HostArray` of solutions. When the RHS fits HBM comfortably
     (``ooc_engaged`` false) the escape hatch materializes and takes the
     ordinary :func:`solve` path."""
-    from ...observability.attribution import register_plan
     from ...redistribution import staging as _staging
 
     sanitize_in(a)
@@ -1076,7 +1072,6 @@ def _solve_host_rhs(a: DNDarray, b, assume_a: str = "gen"):
         (n, nrhs), jt, [{"tag": "solve", "axis": 1, "writeback": True}],
         out_bytes=0, mesh_size=comm.size,
     )
-    register_plan(sched)
     wins = _staging.window_extents((n, nrhs), itemsize, 1, _staging.slab_bytes())
     out = np.empty((n, nrhs), np.dtype(jt))
 

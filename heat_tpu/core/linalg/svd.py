@@ -96,7 +96,6 @@ def _host_svdvals(host, jt):
     row windows accumulating the Gram matrix on device (the window
     stream is the hsvd "sketch" pass shape with a rank-n resident), no
     device materialization of the operand. Descending values, local."""
-    from ...observability.attribution import register_plan
     from ...redistribution import staging as _staging
 
     m, n = (int(s) for s in host.shape)
@@ -105,7 +104,6 @@ def _host_svdvals(host, jt):
         (m, n), jt, [{"tag": "gram", "axis": 0}],
         out_bytes=n * n * itemsize,
     )
-    register_plan(sched)
     wins = _staging.window_extents((m, n), itemsize, 0, _staging.slab_bytes())
     acc = jnp.zeros((n, n), jt)
 

@@ -61,15 +61,11 @@ __all__ = [
     "PCIE_BPS",
     "TIERS",
     "VMEM_ENV",
-    "active_profile",
     "bandwidth",
     "capacity",
     "describe",
     "edge_between",
     "penalty",
-    "profile_annotation",
-    "profile_id",
-    "reload_profile",
     "transfer_time",
 ]
 
@@ -181,99 +177,20 @@ def capacity(tier: str) -> int:
 
 
 # --------------------------------------------------------------------- #
-# measured lattice profiles (ISSUE 16)                                  #
-# --------------------------------------------------------------------- #
-# ``HEAT_TPU_LATTICE_PROFILE`` names a calibration profile recorded by
-# ``observability.calibration`` (probe suite or span ingestion). Unset
-# (the default) short-circuits to the constants above WITHOUT importing
-# the calibration module, so the dependency-free contract of this
-# module — and byte-identity of every plan/plan_id — holds exactly.
-# The cache keys on the raw gate value: flipping the gate mid-process
-# takes effect on the next pricing call, and a repeated read of the
-# same path costs one string compare.
-_profile_cache: Tuple[Optional[str], Optional[dict]] = (None, None)
-
-
-def active_profile() -> Optional[dict]:
-    """The loaded lattice-profile envelope named by
-    ``HEAT_TPU_LATTICE_PROFILE``, or ``None`` when the gate is unset or
-    the file is missing/tampered/version-mismatched (the loader evicts
-    and falls back — a bad profile is NEVER an error, it is the
-    constants)."""
-    global _profile_cache
-    raw = _gates.get("HEAT_TPU_LATTICE_PROFILE", "") or ""
-    cached_raw, cached_profile = _profile_cache
-    if raw == cached_raw:
-        return cached_profile
-    if not raw.strip():
-        _profile_cache = (raw, None)
-        return None
-    from ..observability import calibration as _calibration
-
-    profile = _calibration.load_profile(raw.strip())
-    _profile_cache = (raw, profile)
-    return profile
-
-
-def reload_profile() -> Optional[dict]:
-    """Drop the one-entry profile cache and re-resolve the gate — the
-    in-process recalibration hook (``calibrate`` re-saving to the SAME
-    path would otherwise keep serving the old prices until the process
-    restarts; the cache is keyed on the gate's raw value, not the file
-    content). Returns what :func:`active_profile` now sees."""
-    global _profile_cache
-    _profile_cache = (None, None)
-    return active_profile()
-
-
-def profile_id() -> Optional[str]:
-    """The active profile's stamped id (sha256 prefix of its canonical
-    measurement content), or ``None`` under the constants — the token
-    the planner folds into plan canonical serialization so a
-    recalibration is a visible plan_id invalidation."""
-    profile = active_profile()
-    return profile["profile_id"] if profile else None
-
-
-def profile_annotation() -> Optional[dict]:
-    """The ``calibration`` annotation a plan priced under the active
-    profile must carry (``{"profile_id", "edges": {edge -> bytes/s}}``
-    — the FULL resolved price map, measured edges and constant
-    fallbacks alike, so ``verify_plan`` can recompute every derived
-    number from the recorded prices alone), or ``None`` under the
-    constants — the conditional-key contract of the Schedule IR."""
-    pid = profile_id()
-    if pid is None:
-        return None
-    return {
-        "profile_id": pid,
-        "edges": {e: bandwidth(e) for e in sorted(EDGES)},
-    }
-
-
-# --------------------------------------------------------------------- #
 # edge pricing                                                          #
 # --------------------------------------------------------------------- #
-def bandwidth(edge: str) -> float:  # shardlint: ignore[SL402] -- no program cache here: the profile dict IS the gate-resolved value, re-resolved on every call
+def bandwidth(edge: str) -> float:
     """Bytes/s of a lattice edge (``hbm``/``pcie``/``ici``/``dcn``/
-    ``disk``) — the measured per-edge price when a lattice profile is
-    active (``HEAT_TPU_LATTICE_PROFILE``), the hard-coded constant
-    otherwise."""
+    ``disk``)."""
     if edge not in EDGES:
         raise ValueError(f"bandwidth: unknown lattice edge {edge!r} (one of {tuple(EDGES)})")
-    profile = active_profile()
-    if profile is not None:
-        rec = profile["edges"].get(edge)
-        if rec is not None and rec.get("bps"):
-            return float(rec["bps"])
     return EDGES[edge][2]
 
 
 def transfer_time(nbytes: int, edge: str) -> float:
     """Seconds to move ``nbytes`` across ``edge`` at the lattice
     bandwidth — THE pricing function every analytic model routes
-    through (``planner.tier_time_model``, the staging window model, the
-    ``*_hostram`` bench rows)."""
+    through (``planner.tier_time_model``, the staging window model)."""
     return max(int(nbytes), 0) / bandwidth(edge)
 
 
@@ -296,12 +213,8 @@ def penalty(edge: str) -> int:
     multiplier that lets the planner's byte-equivalent cost scalar keep
     ONE unit across tiers. ``penalty("dcn")`` == the former
     ``communication.DCN_PENALTY`` == 8 exactly; ``penalty("pcie")`` ==
-    12 prices a staging window's wire in the same scalar. Under a
-    lattice profile BOTH sides of the ratio are measured (the numerator
-    is ``bandwidth("ici")``, not the constant), so the scalar keeps
-    meaning "one edge byte in ici bytes" on calibrated meshes too —
-    identical to the constant arithmetic when no profile is active."""
-    return max(1, int(bandwidth("ici") / bandwidth(edge)))
+    12 prices a staging window's wire in the same scalar."""
+    return max(1, int(ICI_BPS / bandwidth(edge)))
 
 
 def edge_between(a: str, b: str) -> Optional[str]:
@@ -318,22 +231,17 @@ def edge_between(a: str, b: str) -> Optional[str]:
     return None
 
 
-def describe() -> str:  # shardlint: ignore[SL402] -- renders a report; nothing cached under a key
+def describe() -> str:
     """Human-readable lattice table: tiers, capacities, edges,
     bandwidths, penalties — what ``ht.core.tiers`` looks like to a
     placement decision."""
-    pid = profile_id()
-    head = "memory-tier lattice (vmem -> hbm -> host; ici/dcn off hbm"
-    head += f"; profile {pid}):" if pid else "; constants):"
-    lines = [head]
+    lines = ["memory-tier lattice (vmem -> hbm -> host; ici/dcn off hbm):"]
     for tier in MEMORY_TIERS:
         env, _ = _CAPACITY[tier]
         lines.append(f"  {tier:>5}: capacity {capacity(tier)} B  ({env})")
-    for name, (near, far, default_bps) in EDGES.items():
-        bps = bandwidth(name)
-        mark = "" if bps == default_bps else f"  [measured; constant {default_bps / 1e9:.1f}]"
+    for name, (near, far, bps) in EDGES.items():
         lines.append(
             f"  edge {name:>4}: {near}<->{far}  {bps / 1e9:.1f} GB/s  "
-            f"(penalty {penalty(name)}x vs ici){mark}"
+            f"(penalty {penalty(name)}x vs ici)"
         )
     return "\n".join(lines)

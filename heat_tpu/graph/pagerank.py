@@ -150,7 +150,7 @@ def pagerank_stream(
     the edges through the PR 11 depth-2 windows, accumulating
     ``segment_sum(r[src] / outdeg[src], dst)`` per window. The staged
     plan is stamped (``plan_staged_passes`` + ``prove_fits``), so the
-    stream shows up in attribution like every other staged workload.
+    stream's spans carry its ``plan_id`` like every other staged workload.
     """
     if not 0.0 < alpha < 1.0:
         raise ValueError(f"alpha must be in (0, 1), got {alpha}")

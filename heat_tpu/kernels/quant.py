@@ -411,8 +411,7 @@ def dp_step_model_2tier(
     DCN traffic is the encoded slice-reduced shard —
     ``(S-1)·wire_bytes(B/C)`` per chip. The step costs
     ``max(compute, wire)``; ``model_speedup`` is the flat/hierarchical
-    step-time ratio (the ``dp_step_quant_2x8`` bench row pins ≥ 2× on
-    DCN-bound layers)."""
+    step-time ratio."""
     _check_mode(mode)
     S, C = int(n_slices), int(chips_per_slice)
     p = S * C

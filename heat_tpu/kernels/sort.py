@@ -739,7 +739,7 @@ def block_sort(operands, dimension: int = 0, num_keys: int = 1, is_stable: bool 
 
 
 # ---------------------------------------------------------------------- #
-# pass-count model (bench sort_frac / PERF.md arithmetic)                #
+# pass-count model (docs/PERF.md "Sort" arithmetic)                      #
 # ---------------------------------------------------------------------- #
 def sort_plan(n: int, dtype: str = "float32", with_indices: bool = True, path: str | None = None) -> dict:
     """Pass-count and HBM-byte model of an n-element local sort on the
@@ -752,9 +752,9 @@ def sort_plan(n: int, dtype: str = "float32", with_indices: bool = True, path: s
     passes — so passes = 1 + Σ_{k>s}(k − s). ``columnsort`` replaces
     one depth-L network with 4 batched depth-log₂(B) sorts (each fully
     VMEM-fusable when B ≤ 2^s) + 3 relayout passes. ``radix`` is
-    ⌈bits/8⌉ histogram+scatter pass pairs. The bench row's
-    ``sort_frac`` = model_bytes / t / HBM_peak — achieved fraction of
-    stream peak AT the model's pass count (docs/PERF.md "Sort").
+    ⌈bits/8⌉ histogram+scatter pass pairs. ``model_bytes`` / t /
+    HBM_peak is the achieved fraction of stream peak AT the model's
+    pass count (docs/PERF.md "Sort").
     """
     itemsize = jnp.dtype(dtype).itemsize
     ops_bytes = n * itemsize * (2 if with_indices else 1)

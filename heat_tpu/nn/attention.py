@@ -360,8 +360,7 @@ def _ring_attention_kernel_callable(
 
     Returns None when the signature has no serving kernel (odd blocks,
     non-divisible shards). Dispatch goes
-    through the AOT ``_ring_attention_kernel_program``; bench loops this
-    traceable form inside a fori_loop for the device-rate ring row.
+    through the AOT ``_ring_attention_kernel_program``.
     """
     p = mesh.devices.size
     if n_q % p or n_kv % p:
@@ -593,9 +592,8 @@ def _splash_callable(q_shape, kv_shape, causal: bool, scale: float, jdtype: str)
     kernel's ~0.60-0.67 across a block sweep (docs/PERF.md records the
     sweep) — splash is preferred, flash is the fallback, the blocked XLA
     program stays the oracle. Splash takes a PRE-SCALED q (no sm_scale
-    parameter), applied inside the compiled program. bench.py loops this
-    callable inside a fori_loop for the stable device-rate row; dispatch
-    uses the AOT ``_splash_attention_program``."""
+    parameter), applied inside the compiled program. Dispatch uses the
+    AOT ``_splash_attention_program``."""
     if jnp.dtype(jdtype) != jnp.bfloat16:
         # splash runs its matmuls in bf16 regardless of input dtype
         # (measured f32 rel-err ~3e-3 vs the blocked oracle, where the

@@ -2,7 +2,7 @@
 delegates ALL instrumentation to external tools — perun around its
 benchmark scripts, nothing inside the library).
 
-Five pieces, one import surface:
+Four pieces, one import surface:
 
 - :mod:`~heat_tpu.observability.telemetry` — process-wide counters,
   timers (p50/p95/p99), the ``record()`` context manager, and
@@ -18,23 +18,14 @@ Five pieces, one import surface:
   (``ht.utils.monitor.trace(path)``) the program's ``ht.call.*``,
   ``ht.op.*``, ``ht.program.*`` and ``ht.comm.*`` spans sit beside the
   device ops in the profiler's trace; with ``HEAT_TPU_TRACE`` on a span
-  is also a parented record in the module's ring (``attribution``,
-  Chrome-trace export :func:`export_trace`). The always-on flight
+  is also a parented record in the module's ring (Chrome-trace
+  export :func:`export_trace`). The always-on flight
   recorder lives there too. ``HEAT_TPU_TRACE`` is registered
   ``affects_programs=False`` — plans, plan_ids, programs, and AOT keys
   are byte-identical at every value, and with or without a session.
-- :mod:`~heat_tpu.observability.attribution` — the model-vs-measured
-  join (:func:`attribution`): measured span time per step kind/tier
-  against the plan's ``tier_time_model``/overlap/staging annotations,
-  reported as per-leg ``model_error``.
 - :mod:`~heat_tpu.observability.hlo` — :func:`collective_counts`, the
   compile-only HLO inspector pinning each op's collective structure
   (the public form of the MULTICHIP dryrun asserts).
-- :mod:`~heat_tpu.observability.calibration` — the self-calibrating
-  cost lattice (ISSUE 16): per-edge probe suite + span ingestion,
-  persisted as stamped per-deployment lattice profiles
-  (``HEAT_TPU_LATTICE_PROFILE``), and :func:`calibration_report` — the
-  constants-vs-calibrated model-error proof the CI gate rides.
 
 Instrumentation glue for the core layers lives in
 :mod:`~heat_tpu.observability.instrument` (not re-exported): the
@@ -46,10 +37,6 @@ from . import hlo
 from . import instrument
 from . import telemetry
 from . import tracing
-from . import attribution
-from . import calibration
-
-from .calibration import calibration_report
 
 from .hlo import COLLECTIVE_OPS, CollectiveReport, collective_counts
 from .telemetry import (
@@ -67,16 +54,9 @@ from .telemetry import (
 )
 from .tracing import export_trace, flight_tail, span
 
-# `ht.observability.attribution(plan_id)` is the documented call shape:
-# the FUNCTION takes the package-attr slot, the module stays reachable
-# as `heat_tpu.observability.attribution` via sys.modules/importlib
-attribution = attribution.attribution
-
 __all__ = [
     "COLLECTIVE_OPS",
     "CollectiveReport",
-    "attribution",
-    "calibration_report",
     "collective_counts",
     "disable",
     "enable",

@@ -415,7 +415,7 @@ def prometheus_text() -> str:
     lines.append(f"heat_tpu_events_dropped_total {emeta['dropped']}")
     lines.append("# TYPE heat_tpu_events_buffered gauge")
     lines.append(f"heat_tpu_events_buffered {emeta['buffered']}")
-    # flight-recorder health (ISSUE 16 satellite): spans already export
+    # flight-recorder health: spans already export
     # their drop count via events; the always-on flight ring gets the
     # same treatment so a scraped process shows when its post-mortem
     # tail stopped being complete
@@ -423,42 +423,6 @@ def prometheus_text() -> str:
 
     lines.append("# TYPE heat_tpu_flight_dropped_total counter")
     lines.append(f"heat_tpu_flight_dropped_total {_tracing.flight_dropped()}")
-    # per-leg model_error gauges (ISSUE 16 satellite): the latest
-    # attribution diagnosis per plan, labeled by plan/step/tier —
-    # signed relative error, so a fleet dashboard can watch the cost
-    # model drift per deployment (the calibration loop's live signal).
-    # The package attr `attribution` is the FUNCTION; the module comes
-    # via importlib (same convention as bench.py)
-    import importlib
-
-    _attribution = importlib.import_module("heat_tpu.observability.attribution")
-    reports = _attribution.last_reports()
-    if reports:
-        err_rows = []
-        for pid, legs in sorted(reports.items()):
-            for leg in legs:
-                if "model_error" not in leg:
-                    continue
-                err_rows.append(
-                    (pid, leg["step"], leg.get("tier") or "", leg["model_error"],
-                     leg.get("calibrated_error"))
-                )
-        if err_rows:
-            lines.append("# TYPE heat_tpu_attribution_model_error gauge")
-            for pid, step, tier, err, _cal in err_rows:
-                lines.append(
-                    'heat_tpu_attribution_model_error{plan_id="%s",step="%s",tier="%s"} %s'
-                    % (pid, step, tier, _prom_num(err))
-                )
-            if any(c is not None for *_x, c in err_rows):
-                lines.append("# TYPE heat_tpu_attribution_calibrated_error gauge")
-                for pid, step, tier, _err, cal in err_rows:
-                    if cal is None:
-                        continue
-                    lines.append(
-                        'heat_tpu_attribution_calibrated_error{plan_id="%s",step="%s",tier="%s"} %s'
-                        % (pid, step, tier, _prom_num(cal))
-                    )
     # live dispatcher gauges — only when the serving layer is already
     # loaded (never import jax into a light metrics process)
     import sys

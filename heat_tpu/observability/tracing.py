@@ -20,15 +20,15 @@ about exactly that per-step structure. This module is the instrument:
   2. when collection is enabled (``HEAT_TPU_TRACE``, below) also a
      structured, parented record in the ring of this module
      (``start_span``/``end_span``/``add_span`` write to the ring only),
-     which ``attribution`` and :func:`export_trace` read.
+     which :func:`export_trace` reads.
 
   What a reducer needs is in the span's NAME, a constant string at the
   call site; attrs are for a person reading the trace and are values
   already at hand, HOST-SIDE only (plan_id, step kind, tier, lap/window
   index, bucket, bytes, world epoch — never array values), so spans are
   trace-safe: inside a jitted program body one fires once per compile
-  and is tagged ``traced=True`` (its duration is tracing time;
-  attribution uses it for census only). The names of the ``ht.*`` spans
+  and is tagged ``traced=True`` (its duration is tracing time: it
+  counts for the census only). The names of the ``ht.*`` spans
   and the metric that reads each are listed in ``docs/API.md``
   (observability) and root ``PERF.md`` section 3.
 - **flight recorder** — a small ALWAYS-ON fixed-field ring, independent
@@ -101,8 +101,8 @@ __all__ = [
 
 TRACE_ENV = "HEAT_TPU_TRACE"
 
-#: span ring capacity — big enough for a bench row's full lifecycle
-#: (every lap/window/batch span of a multi-GB plan execution), bounded
+#: span ring capacity — big enough for every lap/window/batch span
+#: of a multi-GB plan execution, bounded
 #: so instrumenting a serving hot loop cannot grow memory; overwrites
 #: are counted in :func:`dropped` (never silently).
 _SPAN_CAP = 16384
@@ -444,7 +444,7 @@ def window_probes(
 ) -> Tuple[Callable, Callable]:
     """Wrap ``staging.stream_windows``' ``(device_put, consume)`` pair:
     one ``staging.stage_in`` span per window transfer (REAL host wall
-    time — the PCIe leg attribution reads) and one ``staging.compute``
+    time of the PCIe leg) and one ``staging.compute``
     span per window's consume call."""
     state = {"k": 0}
 

@@ -1010,11 +1010,6 @@ def execute(comm, phys, spec: RedistSpec, sched: Optional[Schedule] = None):
     # inherit it. On a program-cache hit the body never re-traces, so
     # the lap spans fire once per compile — span census == plan
     # structure, pinned in tier-1.
-    # the MODULE, not the `attribution` function that shadows it in the
-    # observability package namespace (the core.jit gotcha)
-    from ..observability.attribution import register_plan as _register_plan
-
-    _register_plan(sched)
     with _tracing.span(
         "redist.execute",
         plan_id=sched.plan_id,

@@ -300,34 +300,17 @@ def _topo_annotation(topo: Tuple[int, int]) -> dict:
     }
 
 
-def tier_time_model(sched: Schedule, edges: Optional[dict] = None) -> dict:
+def tier_time_model(sched: Schedule) -> dict:
     """Analytic per-device wall-time split of a plan's payload over the
-    lattice edges it rides (``core.tiers.transfer_time``: the v5e
-    constants, or the measured profile when ``HEAT_TPU_LATTICE_PROFILE``
-    is active) — the checkable model the ``*_2x8_dcn`` and
-    ``*_hostram`` bench rows report (no DCN/PCIe hardware is driven on
-    the CPU container; this is the MULTICHIP methodology). Flat plans
-    price everything at ICI; staged plans (ISSUE 11) additionally carry
-    the ``pcie`` staging traffic.
-
-    ``edges`` (ISSUE 16) overrides the per-edge bytes/s explicitly —
-    ``{edge: bps}`` or profile-style ``{edge: {"bps": ...}}`` records;
-    missing edges fall through to the ambient price. Attribution uses
-    this to build the CALIBRATED model column from a plan's recorded
-    ``calibration`` annotation without touching the process gate."""
+    lattice edges it rides (``core.tiers.transfer_time`` at the v5e
+    constants; no DCN/PCIe hardware is driven on the CPU container).
+    Flat plans price everything at ICI; staged plans (ISSUE 11)
+    additionally carry the ``pcie`` staging traffic."""
     from ..core import tiers as _tiers
 
-    def _time(nbytes: int, edge: str) -> float:
-        if edges and edge in edges:
-            rec = edges[edge]
-            bps = float(rec["bps"] if isinstance(rec, dict) else rec)
-            if bps > 0:
-                return max(int(nbytes), 0) / bps
-        return _tiers.transfer_time(nbytes, edge)
-
     tb = sched.tier_bytes()
-    ici_s = _time(tb["ici"], "ici")
-    dcn_s = _time(tb["dcn"], "dcn")
+    ici_s = _tiers.transfer_time(tb["ici"], "ici")
+    dcn_s = _tiers.transfer_time(tb["dcn"], "dcn")
     out = {
         "ici_bytes": tb["ici"],
         "dcn_bytes": tb["dcn"],
@@ -336,7 +319,7 @@ def tier_time_model(sched: Schedule, edges: Optional[dict] = None) -> dict:
         "total_s": ici_s + dcn_s,
     }
     if tb.get("pcie"):
-        pcie_s = _time(tb["pcie"], "pcie")
+        pcie_s = _tiers.transfer_time(tb["pcie"], "pcie")
         out["pcie_bytes"] = tb["pcie"]
         out["pcie_s"] = pcie_s
         out["total_s"] = ici_s + dcn_s + pcie_s
@@ -1489,11 +1472,10 @@ def plan(
     forces one ICI domain (the pre-topology plans, byte-identical), an
     ``"SxC"`` string / ``(S, C)`` tuple forces a simulated
     factorization. Plans are cached per (spec, budget, resolved codec,
-    resolved topology, active lattice profile_id) — all five are part
-    of the canonical serialization and plan_id, so a gate flip (or a
-    recalibration, ISSUE 16) can never serve a stale plan. Cache
-    hits/misses and the planned byte/step/peak totals feed the
-    telemetry registry."""
+    resolved topology) — all four are part of the canonical
+    serialization and plan_id, so a gate flip can never serve a stale
+    plan. Cache hits/misses and the planned byte/step/peak totals feed
+    the telemetry registry."""
     b = budget_bytes() if budget is None else int(budget)
     if quant is None:
         qmode = wire_quant_gate()
@@ -1506,17 +1488,7 @@ def plan(
             raise ValueError(f"plan: unknown wire codec {quant!r}")
         qmode = quant
     topo = resolve_topology(spec.mesh_size, topology)
-    # ISSUE 16: the active lattice profile (HEAT_TPU_LATTICE_PROFILE)
-    # re-prices candidate selection (_cost's dcn penalty, the tier
-    # annotations' recorded prices), so it is plan-cache key material —
-    # and the chosen plan is rebuilt with the calibration annotation so
-    # the profile_id lands in the canonical serialization and plan_id
-    # (recalibration = visible invalidation). Unset resolves to None:
-    # key and plan bytes are identical to the pre-calibration era.
-    from ..core import tiers as _tiers
-
-    cal = _tiers.profile_annotation()
-    key = (spec, b, qmode or "0", topo, cal["profile_id"] if cal else None)
+    key = (spec, b, qmode or "0", topo)
     with _plan_lock:
         cached = _plan_cache.get(key)
     if cached is not None:
@@ -1524,12 +1496,6 @@ def plan(
             _telemetry.inc("redist.plan_cache.hit")
         return cached
     sched = _quantize_schedule(_build(spec, b, topo), qmode)
-    if cal is not None:
-        sched = Schedule(
-            sched.spec, sched.strategy, sched.steps, sched.budget_bytes,
-            notes=sched.notes, overlap=sched.overlap, quant=sched.quant,
-            topology=sched.topology, staging=sched.staging, calibration=cal,
-        )
     with _plan_lock:
         if len(_plan_cache) >= _PLAN_CACHE_MAX:
             _plan_cache.pop(next(iter(_plan_cache)))

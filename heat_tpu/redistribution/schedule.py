@@ -60,15 +60,6 @@ both annotations fold into the canonical serialization and
 CRITICALLY, both are *conditional* keys: a flat-topology plan
 serializes without them, byte-identical to the pre-ISSUE-8 plans — the
 ``HEAT_TPU_TOPOLOGY`` unset/1xN escape hatch is exact by construction.
-
-ISSUE 16 adds the **calibration annotation** under the same contract:
-a plan priced under a measured lattice profile
-(``HEAT_TPU_LATTICE_PROFILE``, ``observability.calibration``) carries
-``calibration`` = {profile_id, edges: {edge -> bytes/s}} in its
-canonical serialization, so recalibrating a deployment changes every
-plan_id it re-prices — a VISIBLE invalidation the program caches key
-on — while the unset default serializes without the key,
-byte-identical to the constants era.
 """
 
 from __future__ import annotations
@@ -237,8 +228,7 @@ class Schedule:
                       "critical_path_bytes": w + (C-1)*max(w, c) + c}, ...],
           "sequential_bytes":   sum of group sequential models,
           "critical_path_bytes": sum of group critical paths,
-          "model_speedup":      sequential / critical-path  (the bench
-                                ``critical_path_model`` field),
+          "model_speedup":      sequential / critical-path,
         }
 
     The annotation is cost MODEL, not movement: an overlapped program
@@ -257,7 +247,6 @@ class Schedule:
         quant: Optional[Dict[str, Any]] = None,
         topology: Optional[Dict[str, Any]] = None,
         staging: Optional[Dict[str, Any]] = None,
-        calibration: Optional[Dict[str, Any]] = None,
     ):
         self.spec = spec
         self.strategy = strategy
@@ -273,16 +262,6 @@ class Schedule:
         # Conditional like quant/topology: non-staged plans serialize
         # without the key, byte-identical to the pre-staging era.
         self.staging = staging
-        # ISSUE 16: the calibration annotation — {profile_id, edges:
-        # {edge -> measured bytes/s}} recorded when the plan was priced
-        # under a lattice profile (HEAT_TPU_LATTICE_PROFILE). Part of
-        # the canonical serialization, so a recalibration CHANGES the
-        # plan_id — a visible invalidation, never silent drift — and
-        # verify_plan can recompute the recorded prices. Conditional
-        # like the others: constants-priced plans (the default)
-        # serialize without the key, byte-identical to the
-        # pre-calibration era.
-        self.calibration = calibration
         self.plan_id = hashlib.sha1(
             self.canonical_json(with_plan_id=False).encode()
         ).hexdigest()[:12]
@@ -570,10 +549,6 @@ class Schedule:
         # annotation — non-staged plans stay byte-identical
         if self.staging is not None:
             d["staging"] = self.staging
-        # conditional (ISSUE 16): same contract for the calibration
-        # annotation — constants-priced plans stay byte-identical
-        if self.calibration is not None:
-            d["calibration"] = self.calibration
         if with_plan_id:
             d["plan_id"] = self.plan_id
         return d
@@ -671,14 +646,6 @@ class Schedule:
                 f"host-resident={sg['host_bytes']} B  "
                 f"model: pcie {model['pcie_s']}s / critical path "
                 f"{model['critical_path_s']}s ({model['bound_gbps']} GB/s)"
-            )
-        if self.calibration:
-            c = self.calibration
-            edges = "  ".join(
-                f"{e}={c['edges'][e] / 1e9:.2f}GB/s" for e in sorted(c["edges"])
-            )
-            lines.append(
-                f"  calibration: profile {c['profile_id']}  {edges}"
             )
         if self.notes:
             lines.append(f"  notes: {self.notes}")

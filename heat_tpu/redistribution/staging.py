@@ -383,21 +383,7 @@ def plan_staged_passes(
             f"double-buffered HBM slab (HEAT_TPU_OOC)"
         ),
         staging=annotation,
-        # ISSUE 16: the model above was priced through the (possibly
-        # profile-calibrated) tiers.transfer_time — record the prices +
-        # profile_id so the verifier recomputes from the plan's OWN
-        # numbers and a recalibration re-keys the staged plan_ids too.
-        # None under the constants: bytes identical to the pre-
-        # calibration golden dumps.
-        calibration=_tiers.profile_annotation(),
     )
-    # staged plans live outside the planner's schedule cache — register
-    # for ht.observability.attribution(plan_id) lookup (cheap bounded
-    # dict; the module is shadowed by the function in the package
-    # namespace, so import the name off the module path)
-    from ..observability.attribution import register_plan as _register_plan
-
-    _register_plan(sched)
     if _telemetry._ENABLED:
         _telemetry.inc("redist.staging.planned_windows", n_total)
         _telemetry.inc("redist.staging.planned_bytes", pcie_total)
@@ -477,8 +463,8 @@ def stream_windows(
     slab_array, (start, stop))`` runs the per-window compute.
 
     Under ``HEAT_TPU_TRACE`` each window gets a ``staging.stage_in``
-    span (real host wall around the ``device_put`` — the PCIe leg
-    attribution measures) and a ``staging.compute`` span around its
+    span (real host wall around the ``device_put``: the PCIe leg)
+    and a ``staging.compute`` span around its
     consume call, tagged with ``plan_id`` (the staged plan this stream
     executes) when the caller provides it. The probes wrap the
     callables, never the loop: issue order and numerics are identical
@@ -516,8 +502,8 @@ def golden_staged_plans() -> List[Tuple[str, Schedule]]:
     working-set bytes are pinned explicitly so an ambient
     ``HEAT_TPU_OOC_SLAB_MB``/``HEAT_TPU_HBM_BYTES`` cannot make two CI
     runs diverge. The 20 GB hsvd shape is the ROADMAP scenario (an
-    operand larger than one v5e chip's HBM); the 2 GB twins match the
-    measured bench rows."""
+    operand larger than one v5e chip's HBM); the 2 GB twins fit one.
+    """
     from ..core import tiers as _tiers
 
     slab = DEFAULT_SLAB_MB << 20

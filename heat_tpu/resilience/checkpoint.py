@@ -240,7 +240,7 @@ class _SlabWriter:
     whole flush serially after the whole write. Measured on the dev
     box: inline hashing + one trailing fsync commits a 2.1 GB entry at
     ~0.36 GB/s; pipelined it tracks the raw durable-write figure
-    (~0.47 GB/s) — the ``ckpt_write_2gb`` bench row pins the floor."""
+    (~0.47 GB/s)."""
 
     def __init__(self, path: str):
         import queue
@@ -463,7 +463,7 @@ def save(state: Dict[str, Any], *, tag: str, step: int,
                 continue
             # one span per entry around the slab write stream, one
             # around close() — the hasher join + trailing fsync, the
-            # durable edge the ckpt_write_2gb bench row prices
+            # durable edge.
             # detached: a mid-write failure (ENOSPC) must not strand an
             # open span on the thread's parent stack
             entry_sp = _tracing.start_span(
@@ -619,8 +619,7 @@ def _restore_flat_entry(path: str, name: str, desc: Dict[str, Any], verify: bool
     """One-pass restore of an ``np``/``jax`` entry: the bytes are read
     ONCE into the destination buffer and hashed from there — recovery
     reads each byte a single time (a second full read of a multi-GB
-    envelope at the disk edge would double exactly the ``recovery_s``
-    wall-clock the bench gates)."""
+    envelope at the disk edge would double the recovery wall-clock)."""
     import jax.numpy as jnp
 
     fp = os.path.join(path, f"{name}.bin")

@@ -2,9 +2,10 @@
 
 The directory is part of the cache's key, so it has to be the same on
 every run: a temporary name, a pid or a time in it never hits. Called by
-the entry scripts (``chip_smoke.py``, ``bench.py``, ``scripts/``,
-``examples/``) before their first compile — never by ``import heat_tpu``,
-which must not decide where a host application caches.
+the entry scripts (``chip_smoke.py``, ``benchmarks/run.py``,
+``scripts/``, ``examples/``) before their first compile — never by
+``import heat_tpu``, which must not decide where a host application
+caches.
 """
 
 from __future__ import annotations

@@ -20,15 +20,7 @@
 #     default is covered by every other leg running with them off;
 #  4. the multi-chip dryrun: the full training step jit-compiled and
 #     executed on an 8-device mesh (real dp/sp shardings);
-#  5. the bench regression gate, whenever bench artifacts exist:
-#     threshold regressions are report-only (BENCH_COMPARE.json + one
-#     verdict line; a bench-carrying change gates itself via --strict),
-#     but DETERMINISTIC analytic fields (model speedups, pass counts)
-#     HARD-gate via --unchanged-fields (ISSUE 12): they can only move
-#     when a PR intentionally changes pricing, and such a PR must
-#     regenerate its bench artifacts in the same change — the same
-#     update-the-pin rule the golden plan_ids follow;
-#  6. the shardlint legs: source lint over heat_tpu/ (undeclared host
+#  5. the shardlint legs: source lint over heat_tpu/ (undeclared host
 #     syncs, bare jax.jit, unsanitized public ops) and the IR check of
 #     the __graft_entry__ training step on the 8-device CPU mesh
 #     (ht.analysis.check: implicit reshards, replicated
@@ -336,76 +328,6 @@ print(f"check_tolerance: {n} plan(s) ({nq} quantized) compose to their "
 EOF
 rm -f "$tol_dump"
 
-# calibration legs (ISSUE 16): (30) the escape-hatch parity diff —
-# gate unset, gate EMPTY, and a measured profile sitting on disk but
-# NOT activated must dump byte-identical plans (the constants era);
-# (31) the measured-profile dump: scripts/calibrate.py probes this
-# container's real edges on the 8-device CPU mesh, the activated
-# profile stamps every plan (calibration annotation + re-keyed
-# plan_ids — recalibration is a VISIBLE invalidation), two fresh
-# processes agree byte-for-byte, and the verifier sweep accepts the
-# stamped dumps from a process WITHOUT the gate (the prices verify_plan
-# recomputes from are recorded in the plan, not read from the
-# environment); (32) the loop-closure gate: one traced staged run, a
-# profile built from that run's own effective bandwidths, and the
-# re-judged mean |model_error| must SHRINK vs the constants column —
-# the whole point of calibrating
-cal_dir="$(mktemp -d)"
-python scripts/redist_plans.py > "$cal_dir/unset.txt"
-HEAT_TPU_LATTICE_PROFILE= python scripts/redist_plans.py > "$cal_dir/empty.txt"
-diff "$cal_dir/unset.txt" "$cal_dir/empty.txt"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \
-  python scripts/calibrate.py --out "$cal_dir/profile.json" --bytes $((1<<22)) --repeats 2
-python scripts/redist_plans.py > "$cal_dir/inactive.txt"
-diff "$cal_dir/unset.txt" "$cal_dir/inactive.txt"
-echo "HEAT_TPU_LATTICE_PROFILE unset/empty/inactive: dumps byte-identical"
-
-HEAT_TPU_LATTICE_PROFILE="$cal_dir/profile.json" python scripts/redist_plans.py > "$cal_dir/cal_a.txt"
-HEAT_TPU_LATTICE_PROFILE="$cal_dir/profile.json" python scripts/redist_plans.py > "$cal_dir/cal_b.txt"
-diff "$cal_dir/cal_a.txt" "$cal_dir/cal_b.txt"
-if cmp -s "$cal_dir/unset.txt" "$cal_dir/cal_a.txt"; then
-  echo "activated profile did not re-key the golden plans" >&2; exit 1
-fi
-python scripts/verify_plans.py "$cal_dir/cal_a.txt"
-echo "measured-profile dumps: deterministic + re-keyed + well-formed (gate-free verify)"
-rm -rf "$cal_dir"
-
-HEAT_TPU_TRACE=1 HEAT_TPU_OOC_SLAB_MB=8 python - <<'EOF'
-import numpy as np
-import heat_tpu as ht
-from heat_tpu.observability import calibration, tracing
-from heat_tpu.redistribution import staging
-
-tracing.enable()
-host = staging.HostArray(
-    np.random.default_rng(0).standard_normal((4096, 4096)).astype(np.float32))
-u, _ = ht.linalg.hsvd_rank(host, 8)
-u.larray.block_until_ready()
-rows = tracing.spans()
-pids = [p for p in ((r.get("attrs") or {}).get("plan_id") for r in rows) if p]
-assert pids, "no staged plan traced"
-# this run's EFFECTIVE per-edge bandwidth (sum bytes / sum seconds)
-agg = {}
-for r in rows:
-    a = r.get("attrs") or {}
-    t, nb, d = a.get("tier"), a.get("bytes"), r.get("dur_s")
-    if a.get("traced") or t is None or not nb or not d:
-        continue
-    agg.setdefault(t, [0, 0.0])
-    agg[t][0] += nb
-    agg[t][1] += d
-edges = {t: {"bps": b / s, "method": "spans-effective"}
-         for t, (b, s) in agg.items() if s > 0}
-assert edges, "no tiered spans measured"
-prof = calibration.build_profile(edges, platform="cpu")
-rep = calibration.calibration_report(pids[-1], span_rows=rows, profile=prof)
-assert rep["n_legs"] > 0, rep
-assert rep["improved"], rep
-print(f"calibration loop closure: mean |model_error| "
-      f"{rep['mean_abs_error_constants']} -> {rep['mean_abs_error_calibrated']} "
-      f"over {rep['n_legs']} leg(s), profile {prof['profile_id']}")
-EOF
-
 # spmm-kernel legs (ISSUE 18), mirroring the sort/relayout legs: the
 # brick SpMM/SDDMM family FORCED onto the Pallas scalar-prefetch
 # kernels (interpret mode on CPU) over the sparse + graph suites —
@@ -420,10 +342,3 @@ HEAT_TPU_SPMM_KERNEL=1 python -m pytest tests/test_spmm.py tests/test_sparse.py 
 
 HEAT_TPU_SPMM_KERNEL=0 python -m pytest tests/test_spmm.py tests/test_sparse.py tests/test_graph.py -q "$@"
 
-if [ -f BENCH_DETAIL.json ] && ls BENCH_r*.json >/dev/null 2>&1; then
-  # the regex holds every DETERMINISTIC analytic field
-  # (model_speedup, tier_model_speedup, stage_model_gbps, ...) to exact
-  # equality: a pure refactor — the ISSUE 12 gate-registry move — must
-  # prove it shifted zero bench numbers, not just stayed in threshold
-  python scripts/bench_compare.py --unchanged-fields 'model|passes_over_A|_algorithmic'
-fi

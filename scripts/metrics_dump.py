@@ -8,10 +8,8 @@ already-live registry when ``--no-workload``):
 - default: Prometheus text format (``ht.observability.prometheus_text``)
   — registry counters as ``_total``, timers as summaries with
   p50/p95/p99 quantile labels, event-ring + flight-recorder health
-  (``heat_tpu_flight_dropped_total``), per-leg attribution
-  ``model_error`` gauges (ISSUE 16 — the built-in workload performs
-  one fenced attribution join so they render), and per-dispatcher
-  gauges when the serving layer is live;
+  (``heat_tpu_flight_dropped_total``), and per-dispatcher gauges when
+  the serving layer is live;
 - ``--json``: the raw ``telemetry.snapshot()`` (counters, timers, event
   ring metadata) as one JSON document;
 - ``--trace PATH``: additionally export the span buffer as Chrome
@@ -38,24 +36,12 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 def _workload() -> None:
     """One planned redistribution + a tiny reduction: enough to light up
-    op/program counters, redistribution spans, the event ring — and,
-    ISSUE 16, one fenced attribution join so the per-leg
-    ``model_error`` gauges render in the exposition below."""
-    import time
-
+    op/program counters, redistribution spans, and the event ring."""
     import heat_tpu as ht
-    from heat_tpu.observability import tracing
 
     x = ht.arange(4096, split=0).astype(ht.float32)
-    plan = ht.redistribution.explain(x.reshape((64, 64)), 1)
-    t0 = time.perf_counter()
     y = x.reshape((64, 64)).resplit(1)
     ht.sum(y).numpy()
-    tracing.add_span(
-        "metrics.execute", t0, time.perf_counter(),
-        plan_id=plan.plan_id, step="execute", fenced=True,
-    )
-    ht.observability.attribution(plan)  # populates last_reports()
 
 
 def main() -> int:

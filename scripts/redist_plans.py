@@ -38,30 +38,21 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
-    ap.add_argument(
-        "--topology",
-        default=None,
-        help="force a two-tier topology (e.g. 2x8) for every golden plan; "
-        "default: flat (pinned — NOT the ambient HEAT_TPU_TOPOLOGY)",
-    )
-    args = ap.parse_args()
-
+def rows(topology=None):
+    """``(name, Schedule)`` for every row of the dump, in dump order —
+    ``tests/test_plan_ids.py`` holds each row's ``plan_id`` to its table."""
     from heat_tpu.redistribution import planner
 
     # the default budget / codec / topology, pinned explicitly so an
     # ambient HEAT_TPU_REDIST_BUDGET_MB / HEAT_TPU_WIRE_QUANT /
     # HEAT_TPU_TOPOLOGY cannot make two CI runs diverge
     budget = planner.DEFAULT_BUDGET_MB << 20
-    topology = args.topology if args.topology else "flat"
-    suffix = f"@{args.topology}" if args.topology else ""
+    pinned = topology if topology else "flat"
+    suffix = f"@{topology}" if topology else ""
     for name, spec in planner.golden_specs():
-        sched = planner.plan(spec, budget, quant="0", topology=topology)
-        print(f"{name}{suffix}\t{sched.canonical_json()}")
+        yield f"{name}{suffix}", planner.plan(spec, budget, quant="0", topology=pinned)
     for name, spec in planner.golden_specs():
-        sched = planner.plan(spec, budget, quant="int8", topology=topology)
-        print(f"{name}.quant{suffix}\t{sched.canonical_json()}")
+        yield f"{name}.quant{suffix}", planner.plan(spec, budget, quant="int8", topology=pinned)
 
     # ISSUE 11: the out-of-core staged golden plans ride the same
     # determinism + verify_plan sweep. Slab/working-set bytes are pinned
@@ -73,7 +64,7 @@ def main() -> int:
     from heat_tpu.redistribution import staging
 
     for name, sched in staging.golden_staged_plans():
-        print(f"{name}{suffix}\t{sched.canonical_json()}")
+        yield f"{name}{suffix}", sched
 
     # ISSUE 19: the dense-factorization ring schedules ride the same
     # determinism + verify_plan sweep. Shapes/budget are pinned inside
@@ -85,7 +76,20 @@ def main() -> int:
     from heat_tpu.core.linalg.factorizations import golden_factorization_plans
 
     for name, sched in golden_factorization_plans():
-        print(f"{name}{suffix}\t{sched.canonical_json()}")
+        yield f"{name}{suffix}", sched
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n", 1)[0])
+    ap.add_argument(
+        "--topology",
+        default=None,
+        help="force a two-tier topology (e.g. 2x8) for every golden plan; "
+        "default: flat (pinned — NOT the ambient HEAT_TPU_TOPOLOGY)",
+    )
+    args = ap.parse_args()
+    for name, sched in rows(args.topology):
+        print(f"{name}\t{sched.canonical_json()}")
     return 0
 
 

@@ -640,72 +640,6 @@ class TestBoundaries(TestCase):
             self.assertTrue(os.path.exists(os.path.join(ROOT, "heat_tpu", mod)))
 
 
-class TestBenchCompareNewRows(TestCase):
-    def _mod(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_compare", os.path.join(ROOT, "scripts", "bench_compare.py")
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_new_and_missing_rows_never_gate(self):
-        bc = self._mod()
-        current = {"detail": {"old": {"gbps": 10.0}, "brand_new": {"gbps": 5.0}}}
-        baseline = {"key_rows": {"old": {"gbps": 10.0}, "dropped": {"gbps": 3.0}}}
-        res = bc.compare(current, baseline, 0.10)
-        self.assertEqual(res["verdict"], "ok")
-        self.assertEqual(res["new_rows"], ["brand_new"])
-        self.assertEqual(res["missing_rows"], ["dropped"])
-        self.assertEqual(res["regressions"], [])
-
-    def test_regression_still_gates_alongside_new_rows(self):
-        bc = self._mod()
-        current = {"detail": {"old": {"gbps": 5.0}, "brand_new": {"gbps": 5.0}}}
-        baseline = {"key_rows": {"old": {"gbps": 10.0}}}
-        res = bc.compare(current, baseline, 0.10)
-        self.assertEqual(res["verdict"], "regressed")
-        self.assertEqual(res["new_rows"], ["brand_new"])
-
-    def test_measurement_suspect_rows_waived_but_counted(self):
-        """ISSUE 17 satellite: a regression on a row either side flags
-        ``measurement_suspect`` never gates (the r5 attention-MFU
-        0.68->0.58 slip was exactly this shape) — but it stays in the
-        record, marked waived and counted in the summary."""
-        bc = self._mod()
-        current = {
-            "detail": {
-                "attn": {"mfu": 0.58, "measurement_suspect": True},
-                "solid": {"gbps": 10.0},
-            }
-        }
-        baseline = {"key_rows": {"attn": {"mfu": 0.68}, "solid": {"gbps": 10.0}}}
-        res = bc.compare(current, baseline, 0.10)
-        self.assertEqual(res["verdict"], "ok")
-        self.assertEqual(res["waived"], 1)
-        self.assertEqual(len(res["regressions"]), 1)
-        self.assertEqual(res["regressions"][0]["row"], "attn")
-        self.assertEqual(res["regressions"][0]["waived"], "measurement_suspect")
-        # the suspect flag on the BASELINE side waives too
-        res2 = bc.compare(
-            {"detail": {"attn": {"mfu": 0.58}}},
-            {"key_rows": {"attn": {"mfu": 0.68, "measurement_suspect": True}}},
-            0.10,
-        )
-        self.assertEqual(res2["verdict"], "ok")
-        self.assertEqual(res2["waived"], 1)
-        # an unflagged regression of the same size still gates
-        res3 = bc.compare(
-            {"detail": {"attn": {"mfu": 0.58}}},
-            {"key_rows": {"attn": {"mfu": 0.68}}},
-            0.10,
-        )
-        self.assertEqual(res3["verdict"], "regressed")
-        self.assertEqual(res3["waived"], 0)
-
-
 class TestSparseEngineFixtures(TestCase):
     """ISSUE 18: the sparse-engine golden fixtures — the gather-per-row
     SpMV anti-pattern trips SL101/SL103, and the engine's kernel SpMM

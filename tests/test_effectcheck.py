@@ -47,6 +47,7 @@ from heat_tpu.redistribution import planner, staging
 from heat_tpu.serving import aot_cache
 from heat_tpu.serving.dispatcher import Dispatcher, Endpoint
 
+from test_plan_ids import PLAN_IDS
 from test_suites.basic_test import TestCase, env_pin
 
 P = len(jax.devices())
@@ -160,9 +161,6 @@ class TestGateRegistry(TestCase):
         self.assertEqual(
             gates.program_gate_roster(), ",".join(sorted(affecting))
         )
-        # the lattice profile changes plan pricing AND (via the roster
-        # bump) AOT envelope identity — affecting, plan+aot scoped
-        self.assertIn("HEAT_TPU_LATTICE_PROFILE", affecting)
         # plan-scope gates are exactly the components of the planner key
         plan_scope = {s.name for s in gates.scope_gates("plan")}
         self.assertEqual(
@@ -170,7 +168,7 @@ class TestGateRegistry(TestCase):
             {
                 "HEAT_TPU_REDIST_BUDGET_MB", "HEAT_TPU_WIRE_QUANT",
                 "HEAT_TPU_TOPOLOGY", "HEAT_TPU_OOC", "HEAT_TPU_OOC_SLAB_MB",
-                "HEAT_TPU_HBM_BYTES", "HEAT_TPU_LATTICE_PROFILE",
+                "HEAT_TPU_HBM_BYTES",
             },
         )
         with self.assertRaises(ValueError):
@@ -211,29 +209,9 @@ class TestGateRegistry(TestCase):
 # ------------------------------------------------------------------ #
 # cache-key byte identity (the PR 11 artifacts)                      #
 # ------------------------------------------------------------------ #
-#: golden plan_ids captured at PR 11 HEAD (all gates at defaults) —
-#: the registry refactor must reproduce every one bit-for-bit.
-_PR11_PLAN_IDS = {
-    "noop_same_split": "a73577b2e204",
-    "resplit_0_to_1_p8": "3fa7e27aefe5",
-    "resplit_1_to_0_p8": "9dcceb241644",
-    "resplit_0_to_1_int32_p4": "7da388bc1f4e",
-    "resplit_uneven_p8": "785b5c64ef22",
-    "resplit_3d_1_to_2_p8": "a4312eca02cb",
-    "replicate_p8": "ba5015838a00",
-    "slice_from_replicated_p8": "fd958543fa59",
-    "mesh1_resplit": "ea8f4a542d36",
-    "resplit_chunked_2gb_p8": "ac7c3d3bd0e2",
-    "resplit_ring_8gb_p8": "9a9f6522afa0",
-    "reshape_pivot_p8": "7e55bd63cf2f",
-    "reshape_split0_local_p8": "06af6969c5a1",
-    "reshape_gather_fallback_p8": "7187d492c0d5",
-    "reshape_split1_1gb_p8": "e25264d7562c",
-    "reshape_packed_rev_p8": "1424eb21252e",
-    "reshape_lane_1gb_p8": "4f79dda1bad3",
-    "resplit_1gb_p16": "6c06e58a4b8e",
-    "reshape_split1_1gb_p16": "266f4c37f19f",
-}
+#: the golden specs' plan_ids (all gates at defaults), as PR 11 HEAD
+#: made them: rows of the one table, ``tests/test_plan_ids.py``
+_PR11_PLAN_IDS = {name: PLAN_IDS[name] for name, _ in planner.golden_specs()}
 
 
 def _pr9_hand_fingerprint():
@@ -534,11 +512,9 @@ class TestSeededBugMutations(TestCase):
         """Invariant: the resolved topology is a component of the
         planner's dict-cache key. Mutation: delete it from the tuple."""
         src = _read("heat_tpu/redistribution/planner.py")
-        anchor = 'key = (spec, b, qmode or "0", topo, cal["profile_id"] if cal else None)'
+        anchor = 'key = (spec, b, qmode or "0", topo)'
         self.assertIn(anchor, src)
-        mutated = src.replace(
-            anchor, 'key = (spec, b, qmode or "0", cal["profile_id"] if cal else None)'
-        )
+        mutated = src.replace(anchor, 'key = (spec, b, qmode or "0")')
         found = effectcheck.lint_source(mutated, "heat_tpu/redistribution/planner.py")
         hits = [f for f in found if f.rule == "SL402" and "HEAT_TPU_TOPOLOGY" in f.message]
         self.assertTrue(hits, [repr(f) for f in found])

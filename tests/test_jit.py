@@ -1,6 +1,6 @@
 """Tests for ``ht.jit`` — the fused-program surface (no reference analog;
-the reference is torch-eager throughout, bench.py ``op_chain`` measures
-the dispatch gap this closes)."""
+the reference is torch-eager throughout; one fused program closes the
+per-op dispatch gap)."""
 
 import numpy as np
 import pytest

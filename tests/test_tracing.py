@@ -1,4 +1,4 @@
-"""Span tracing, flight recorder & attribution (ISSUE 15).
+"""Span tracing & flight recorder (ISSUE 15).
 
 The contract pinned here, four ways:
 
@@ -27,6 +27,7 @@ determinism, the flight recorder's bound/tail/always-on contract,
 ``timer_table`` p99, and the Prometheus text exposition.
 """
 
+import importlib
 import json
 import os
 import threading
@@ -43,10 +44,6 @@ from heat_tpu.observability import events, telemetry, tracing
 from heat_tpu.redistribution import RedistSpec, executor, planner, staging
 
 from test_suites.basic_test import TestCase, env_pin
-
-import importlib
-
-attribution = importlib.import_module("heat_tpu.observability.attribution")
 
 P = len(jax.devices())
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -189,8 +186,8 @@ class TestCensusMatchesPlan(TracingCase):
 
     def test_staged_window_census(self):
         """One staged stream records exactly one stage_in + one compute
-        span per window, plan_id-tagged, and the attribution join sees
-        real (non-traced) wall time on the pcie leg."""
+        span per window, plan_id-tagged, with real (non-traced) wall
+        time and bytes on the pcie leg."""
         data = np.arange(4096 * 64, dtype=np.float32).reshape(4096, 64)
         host = staging.HostArray(data)
         slab = 256 << 10
@@ -367,19 +364,16 @@ class TestThreadedRecorders(TracingCase):
                 self.assertEqual(parent["thread"], r["thread"])
 
     def test_tracing_module_is_analyzer_clean(self):
-        """SL402–SL406 over the tracer and the attribution join: the
-        lock/ring/TLS discipline documented in the module must hold up
-        to the racecheck pass, not just the docstring."""
+        """SL402–SL406 over the tracer: the lock/ring/TLS discipline
+        documented in the module must hold up to the racecheck pass,
+        not just the docstring."""
         from heat_tpu.analysis import effectcheck
 
-        for rel in (
-            "heat_tpu/observability/tracing.py",
-            "heat_tpu/observability/attribution.py",
-        ):
-            with open(os.path.join(ROOT, rel), encoding="utf-8") as f:
-                src = f.read()
-            found = effectcheck.lint_source(src, rel)
-            self.assertEqual([repr(f) for f in found], [], rel)
+        rel = "heat_tpu/observability/tracing.py"
+        with open(os.path.join(ROOT, rel), encoding="utf-8") as f:
+            src = f.read()
+        found = effectcheck.lint_source(src, rel)
+        self.assertEqual([repr(f) for f in found], [], rel)
 
 
 # --------------------------------------------------------------------- #
@@ -639,99 +633,13 @@ class TestTelemetryExposition(TestCase):
         text = ht.observability.prometheus_text()
         self.assertNotIn('dispatcher="promgauge"', text)
 
-
-# --------------------------------------------------------------------- #
-# 9. attribution: the model-vs-measured join                            #
-# --------------------------------------------------------------------- #
-class TestAttribution(TracingCase):
-    def _synthetic_rows(self, sched, stage_s=0.002):
-        """Hand-built span rows shaped like one traced+fenced run."""
-        rows = []
-        sid = iter(range(1, 100))
-        for k in range(3):
-            rows.append({
-                "id": next(sid), "parent": None, "name": "redist.issue",
-                "thread": 1, "t0_s": 0.0, "dur_s": 0.0001,
-                "attrs": {"plan_id": sched.plan_id, "traced": True,
-                          "step": "all_to_all", "tier": "ici", "lap": k},
-            })
-        rows.append({
-            "id": next(sid), "parent": None, "name": "staging.stage_in",
-            "thread": 1, "t0_s": 0.0, "dur_s": stage_s,
-            "attrs": {"plan_id": sched.plan_id, "step": "stage_in",
-                      "tier": "pcie", "window": 0, "bytes": 1 << 20},
-        })
-        rows.append({
-            "id": next(sid), "parent": None, "name": "bench.execute",
-            "thread": 1, "t0_s": 0.0, "dur_s": 0.5,
-            "attrs": {"plan_id": sched.plan_id, "step": "execute",
-                      "fenced": True},
-        })
-        # another plan's span must not leak into the join
-        rows.append({
-            "id": next(sid), "parent": None, "name": "redist.issue",
-            "thread": 1, "t0_s": 0.0, "dur_s": 0.1,
-            "attrs": {"plan_id": "other", "traced": True},
-        })
-        return rows
-
-    def test_join_reports_census_and_model_error(self):
-        spec = RedistSpec.normalize((1000, 250000), "float32", 0, 1, 8)
-        sched = planner.plan(spec, 256 << 20, topology="flat")
-        rep = ht.observability.attribution(
-            sched, span_rows=self._synthetic_rows(sched)
-        )
-        self.assertEqual(rep["plan_id"], sched.plan_id)
-        self.assertEqual(rep["census"], {"redist.issue:ici": 3})
-        legs = {(l["step"], l["tier"]): l for l in rep["legs"]}
-        execute = legs[("execute", None)]
-        self.assertEqual(execute["measured_s"], 0.5)
-        self.assertEqual(execute["model_s"], rep["model"]["wall_s"])
-        self.assertAlmostEqual(
-            execute["model_error"],
-            round(0.5 / rep["model"]["wall_s"] - 1.0, 4), places=4,
-        )
-        stage = legs[("stage_in", "pcie")]
-        self.assertEqual(stage["calls"], 1)
-        # no pcie leg in a flat in-HBM plan's model: measured-only —
-        # attribution never invents a bound it cannot defend
-        self.assertNotIn("model_error", stage)
-        # the modeled wall reflects the overlap critical path
-        self.assertLess(rep["model"]["wall_s"], rep["model"]["total_s"])
-
-    def test_lookup_by_plan_id_and_unknown_raises(self):
-        spec = RedistSpec.normalize((64, 48), "float32", 0, 1, 8)
-        sched = planner.plan(spec, 256 << 20)
-        attribution.register_plan(sched)
-        rep = ht.observability.attribution(sched.plan_id, span_rows=[])
-        self.assertEqual(rep["plan_id"], sched.plan_id)
-        with self.assertRaises(KeyError):
-            ht.observability.attribution("no-such-plan", span_rows=[])
-
-    def test_staged_plan_uses_critical_path_model(self):
-        sched = staging.golden_staged_plans()[0][1]
-        rep = ht.observability.attribution(sched, span_rows=[])
-        self.assertEqual(
-            rep["model"]["wall_s"],
-            round(float(sched.staging["model"]["critical_path_s"]), 9),
-        )
-        self.assertIn("staging", rep["model"])
-
-    def test_serving_breakdown_percentiles(self):
-        rows = [
-            {"id": i, "parent": None, "name": "serving.request", "thread": 1,
-             "t0_s": 0.0, "dur_s": i / 1000.0, "attrs": {}}
-            for i in range(1, 21)
-        ]
-        rows.append({"id": 99, "parent": None, "name": "redist.execute",
-                     "thread": 1, "t0_s": 0.0, "dur_s": 1.0, "attrs": {}})
-        out = attribution.serving_breakdown(span_rows=rows)
-        self.assertEqual(list(out), ["serving.request"])
-        ent = out["serving.request"]
-        self.assertEqual(ent["calls"], 20)
-        self.assertAlmostEqual(ent["total_s"], sum(r / 1000 for r in range(1, 21)))
-        self.assertGreaterEqual(ent["p99_s"], ent["p95_s"])
-        self.assertGreaterEqual(ent["p95_s"], ent["p50_s"])
+    def test_flight_dropped_counter_exported(self):
+        before = tracing.flight_dropped()
+        for i in range(tracing.flight_capacity() + 5):
+            tracing.flight_record("test.fill", "x", i)
+        self.assertGreaterEqual(tracing.flight_dropped(), before + 5)
+        text = telemetry.prometheus_text()
+        self.assertIn("heat_tpu_flight_dropped_total", text)
 
 
 if __name__ == "__main__":
