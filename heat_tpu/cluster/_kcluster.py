@@ -11,6 +11,11 @@ iteration is the subclass's step: for KMeans on a TPU one fused pass over
 f32 ``X`` that also serves the label pass (``_pallas``; PERF.md section
 6, PR 28), otherwise XLA: distances by the quadratic expansion, masked
 per-cluster reductions lowering to one all-reduce over the mesh.
+
+The L1 family (KMedians, KMedoids) shares ``l1_step_for``: an L1 assignment
+that never holds ``n x k x d``, and ``_cluster_medians``, every
+per-cluster, per-feature median at once by a radix selection that counts
+(``_RADIX_BITS`` bits a pass over ``X``) instead of sorting k masked copies.
 """
 
 from __future__ import annotations
@@ -29,8 +34,11 @@ from ..core.base import BaseEstimator, ClusteringMixin
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
 from ..core.communication import place as _place
+from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import span as _span
+from . import _pallas_l1
+from ._pallas_l1 import _N_THR, _RADIX_BITS, _from_key, _key_type, _to_key
 
 __all__ = ["_KCluster"]
 
@@ -91,30 +99,37 @@ def _fused_fit_program(step, k: int, shape, jdtype: str, tol: float, max_iter: i
     time for cb-scale inputs. ``init_arg`` is a PRNG
     key when ``seeded`` else the (k, d) initial centers.
 
-    A step that offers ``step.assign(arr, centers) -> labels`` (KMeans'
-    fused pass, which then also ``returns_inertia``) gives the final
-    assignment itself; for every other step it is ``_pairwise`` + argmin."""
+    A step may offer the final assignment itself, from its own pass:
+    ``step.assign(arr, centers)`` gives the labels where the step
+    ``returns_inertia`` (KMeans' fused pass: the loop's last inertia is the
+    value), else (labels, functional value) (``_l1_step``). Every other
+    step gets ``_labels_and_value``: ``_pairwise`` + argmin."""
     loop = make_fit_loop(step, jdtype, tol, max_iter, returns_inertia)
     seed_prog = _kmeanspp_program(k, shape, jdtype) if seeded else None
-    assign = getattr(step, "assign", None) if returns_inertia else None
+    assign = getattr(step, "assign", None)
 
     @jax.jit
     def run(arr, init_arg):
         centers0 = seed_prog(arr, init_arg) if seeded else init_arg.astype(arr.dtype)
         res = loop(arr, centers0)
         centers, n_iter = res[0], res[1]
-        if assign is not None:
-            return centers, n_iter, assign(arr, centers).astype(types.index_jax_type()), res[2]
-        d = _KCluster._pairwise(arr, centers, metric)
-        labels = jnp.argmin(d, axis=1).astype(types.index_jax_type())
-        if metric == "manhattan":
-            fun = jnp.sum(jnp.min(d, axis=1))
+        if assign is None:
+            labels, fun = _labels_and_value(arr, centers, metric)
+        elif returns_inertia:
+            labels, fun = assign(arr, centers), None
         else:
-            fun = jnp.sum(jnp.min(d, axis=1) ** 2)
-        inertia = res[2] if returns_inertia else fun
-        return centers, n_iter, labels, inertia
+            labels, fun = assign(arr, centers)
+        return centers, n_iter, labels.astype(types.index_jax_type()), res[2] if returns_inertia else fun
 
     return run
+
+
+def _labels_and_value(arr: jax.Array, centers: jax.Array, metric: str):
+    """Label of the nearest centre by ``_pairwise`` and the functional value:
+    the sum of the least distances (manhattan) or of their squares."""
+    d = _KCluster._pairwise(arr, centers, metric)
+    least = jnp.min(d, axis=1)
+    return jnp.argmin(d, axis=1), jnp.sum(least if metric == "manhattan" else least ** 2)
 
 
 @observed_program_cache("kcluster.predict", maxsize=64)
@@ -128,15 +143,9 @@ def _predict_program(metric: str, eval_fv: bool):
     the same cached program."""
 
     def run(arr, centers):
-        d = _KCluster._pairwise(arr, centers, metric)
-        labels = jnp.argmin(d, axis=1).astype(types.index_jax_type())
-        if not eval_fv:
-            return labels
-        if metric == "manhattan":
-            fun = jnp.sum(jnp.min(d, axis=1))
-        else:
-            fun = jnp.sum(jnp.min(d, axis=1) ** 2)
-        return labels, fun
+        labels, fun = _labels_and_value(arr, centers, metric)
+        labels = labels.astype(types.index_jax_type())
+        return (labels, fun) if eval_fv else labels
 
     return jax.jit(run)
 
@@ -190,6 +199,161 @@ def _kmeanspp_program(k: int, shape, jdtype: str):
         return centers
 
     return jax.jit(run)
+
+
+# ---------------------------------------------------------------------- #
+# the L1 family: assignment and per-cluster medians without a copy of X  #
+# ---------------------------------------------------------------------- #
+def _members(labels: jax.Array, k: int) -> jax.Array:
+    """(n, k) int32: 1 where the row is of the cluster."""
+    return (labels[:, None] == jnp.arange(k)).astype(jnp.int32)
+
+
+def _l1_passes_xla(k: int) -> "_pallas_l1.L1Passes":
+    """The three passes of an L1 iteration in plain ``jax.numpy``
+    (``_pallas_l1`` holds the chip's form of the same three). Nothing is
+    larger than ``X``: the clusters are walked, not broadcast. Under a mesh
+    the sums over the split sample axis lower to all-reduces."""
+
+    def assign(arr, centers):
+        dist = jax.lax.map(lambda c: jnp.sum(jnp.abs(arr - c), axis=1), centers.astype(arr.dtype))  # (k, n)
+        labels = jnp.argmin(dist, axis=0).astype(jnp.int32)
+        return labels, jnp.sum(_members(labels, k), axis=0), jnp.sum(jnp.min(dist, axis=0))
+
+    def count_below(arr, labels, thr0, step):
+        key, thr, member_of = _to_key(arr), thr0[labels], _members(labels, k).T
+        return jnp.stack([
+            jnp.matmul(member_of, (key < thr + t * step).astype(jnp.int32), preferred_element_type=jnp.int32)
+            for t in range(_N_THR)
+        ])
+
+    def next_above(arr, labels, at):
+        key = _to_key(arr)
+        top = jnp.iinfo(key.dtype).max
+        above = jnp.where(key > at[labels], key, top)
+        return jax.lax.map(lambda c: jnp.min(jnp.where((labels == c)[:, None], above, top), axis=0), jnp.arange(k))
+
+    return _pallas_l1.L1Passes(assign, count_below, next_above)
+
+
+def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
+                     counts: Optional[jax.Array] = None, passes=None) -> jax.Array:
+    """The (k, d) coordinate-wise medians of the rows of ``arr`` by label:
+    exactly what ``jnp.nanmedian`` of each cluster's rows gives (the middle
+    order statistic, the mean of the two middle ones for an even count); an
+    empty cluster keeps its row of ``prev``. ``arr`` holds no NaN.
+
+    A radix selection on ``_to_key``'s integer image, all k x d order
+    statistics at once. Every pass counts, by cluster and feature, the keys
+    under each of ``_N_THR`` thresholds that cut the bracket
+    ``[base, base + 2**bits)`` evenly, and the bracket that holds rank
+    ``(count - 1) // 2`` becomes the next: after ``bits / _RADIX_BITS``
+    passes ``base`` is that order statistic's key. The upper middle one is
+    the same key where the count under the bracket's end says a duplicate
+    fills the next rank, else the least key above (one more pass). Beside
+    ``arr`` and ``labels`` it holds O(k x d x thresholds) integers, the
+    number of passes does not depend on ``k``, and on a split array the
+    counts of the shards are summed before a bracket narrows."""
+    if passes is None:
+        passes = _l1_passes_xla(k)
+    ktype, bits = _key_type(arr.dtype)
+    d = arr.shape[1]
+    labels = labels.astype(jnp.int32)
+    if counts is None:
+        counts = jnp.sum(_members(labels, k), axis=0)
+    counts = counts.astype(jnp.int32)[:, None]
+    lower = jnp.maximum(counts - 1, 0) // 2  # rank of the lower middle, 0-based
+    upper = counts // 2
+
+    def narrow(p, state):
+        base, under_end = state
+        step = jnp.asarray(1, ktype) << (bits - _RADIX_BITS * (p + 1)).astype(ktype)
+        under = passes.count_below(arr, labels, base + step, step)  # (_N_THR, k, d)
+        digit = jnp.sum((under <= lower).astype(jnp.int32), axis=0)
+        # keys under the end of the chosen bracket: the next threshold's, or the last end's
+        ends = jnp.concatenate([under, under_end[None]])
+        under_end = jnp.take_along_axis(ends, digit[None], axis=0)[0]
+        return base + digit.astype(ktype) * step, under_end
+
+    first = jnp.full((k, d), -(1 << (bits - 1)), ktype)
+    low, under_end = jax.lax.fori_loop(
+        0, bits // _RADIX_BITS, narrow, (first, jnp.broadcast_to(counts, (k, d)))
+    )
+    # under_end counts the keys <= low: a duplicate of low fills the upper rank
+    high = jnp.where(under_end > upper, low, passes.next_above(arr, labels, low).astype(ktype))
+    med = 0.5 * _from_key(low, arr.dtype) + 0.5 * _from_key(high, arr.dtype)
+    return jnp.where(counts > 0, med, prev.astype(arr.dtype))
+
+
+def _snap_to_members(arr: jax.Array, labels: jax.Array, k: int, centers: jax.Array,
+                     counts: jax.Array, prev: jax.Array) -> jax.Array:
+    """Each centre moved to the row of its own cluster nearest to it in L1
+    (the first of equals); an empty cluster keeps its row of ``prev``. One
+    cluster at a time, so nothing is larger than ``X``."""
+    # on x.T, and the row picked by a masked sum: on the chip a tall narrow
+    # array lies feature-major, and a reduction along ``arr`` itself or a row
+    # sliced out of it costs this loop a row-major copy of X (9.6 GB at the
+    # north-star shard; tests/test_chip_compile.py)
+    xt = arr.T
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, arr.shape[0]), 1)
+
+    def one(args):
+        c, centre = args
+        dist = jnp.where(labels == c, jnp.sum(jnp.abs(xt - centre[:, None]), axis=0), jnp.inf)
+        return jnp.sum(jnp.where(rows == jnp.argmin(dist).astype(jnp.int32), xt, 0), axis=1)
+
+    snapped = jax.lax.map(one, (jnp.arange(k), centers))
+    return jnp.where(counts[:, None] > 0, snapped, prev.astype(arr.dtype))
+
+
+@functools.lru_cache(maxsize=64)
+def _l1_step(name: str, k: int, shape, jdtype: str, split, mesh, axis_name, snap: bool):
+    """One L1 iteration for ``_fit_fused`` (``l1_step_for`` says what it
+    is), cached by where the data lies; ``step.on_chip`` says which form of
+    the passes it got."""
+    devices = 1 if mesh is None else mesh.devices.size
+    on_chip = _pallas_l1.l1_passes_serve(jax.default_backend(), jdtype, shape, k, split, devices)
+    if on_chip:
+        where = (mesh, axis_name if split == 0 else None) if devices > 1 else ()
+        passes = _pallas_l1.l1_passes(k, tuple(shape), *where)
+    else:
+        passes = _l1_passes_xla(k)
+
+    def step(arr, centers):
+        with jax.named_scope(f"{name}.assign"):
+            labels, counts, _ = passes.assign(arr, centers)
+        with jax.named_scope(f"{name}.select"):
+            new_centers = _cluster_medians(arr, labels, k, centers, counts, passes)
+            if snap:
+                new_centers = _snap_to_members(arr, labels, k, new_centers, counts, centers)
+        return new_centers, jnp.sum((new_centers - centers) ** 2)
+
+    def assign(arr, centers):
+        with jax.named_scope(f"{name}.assign"):
+            labels, _, fun = passes.assign(arr, centers)
+        return labels, fun
+
+    step.assign = assign
+    step.on_chip = on_chip
+    return step
+
+
+def l1_step_for(x: DNDarray, name: str, snap: bool = False):
+    """The ``step_factory`` of the L1 family for ``_fit_fused``, bound to
+    where ``x`` lies: ``step(arr, centers) -> (new_centers, shift)`` is the
+    L1 assignment, then every cluster's coordinate-wise median
+    (``_cluster_medians``) and, with ``snap``, the member nearest to it
+    (KMedoids); ``step.assign`` is the fit's label pass. Which form
+    of the passes runs (``_pallas_l1`` on a TPU for tall narrow f32,
+    ``jax.numpy`` elsewhere) reads backend, dtype, shape and split only, and
+    is counted once a fit: ``<name>.step.select.pallas`` / ``.xla``."""
+
+    def factory(k: int, shape, jdtype: str):
+        step = _l1_step(name, k, tuple(shape), jdtype, x.split, x.comm.mesh, x.comm.axis_name, snap)
+        _telemetry.inc(f"{name}.step.select." + ("pallas" if step.on_chip else "xla"))
+        return step
+
+    return factory
 
 
 class _KCluster(BaseEstimator, ClusteringMixin):

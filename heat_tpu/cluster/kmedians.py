@@ -3,50 +3,38 @@
 API parity with /root/reference/heat/cluster/kmedians.py: Lloyd-style
 iterations where the centroid update is the per-cluster coordinate-wise
 median (reference computes distributed medians with extra comm per
-cluster). Here the masked median over the sharded sample axis is one jnp
-reduction per iteration.
+cluster). Here an iteration is an L1 assignment that never holds
+``n x k x d`` and one selection of all ``k x d`` medians at once
+(``_kcluster._cluster_medians``): a radix selection that counts keys under
+thresholds, two bits a pass over ``X``, exact, with no copy of ``X`` and a
+number of passes that does not grow with ``k``. On a TPU, for tall narrow
+f32 data, the passes are Pallas kernels (``_pallas_l1``); on a split array
+the counts of the shards are summed before a bracket narrows.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
 from typing import Optional, Union
 
 from ..core.dndarray import DNDarray
-from ._kcluster import _KCluster
+from ..observability.tracing import span as _span
+from ._kcluster import _KCluster, l1_step_for
 
 __all__ = ["KMedians"]
 
 
-@functools.lru_cache(maxsize=64)
-def _median_step(k: int, shape, jdtype: str):
-    @jax.jit
-    def step(arr, centers):
-        # L1 assignment matches the coordinate-wise-median update
-        d1 = jnp.sum(jnp.abs(arr[:, None, :] - centers[None, :, :]), axis=-1)
-        labels = jnp.argmin(d1, axis=1)
-        # masked per-cluster coordinate-wise median via NaN-masking
-        def one_cluster(i):
-            mask = labels == i
-            masked = jnp.where(mask[:, None], arr, jnp.nan)
-            med = jnp.nanmedian(masked, axis=0)
-            return jnp.where(jnp.any(mask), med, centers[i])
-
-        new_centers = jax.vmap(one_cluster)(jnp.arange(k))
-        shift = jnp.sum((new_centers - centers) ** 2)
-        return new_centers, shift
-
-    return step
-
-
 class KMedians(_KCluster):
-    """K-Medians: cluster centers are coordinate-wise medians; assignment
-    and functional value use the Manhattan metric (reference:
-    kmedians.py:49 passes ht.spatial.distance.manhattan)."""
+    """K-Medians: centers are the exact coordinate-wise medians, found by counting, not sorting.
+
+    Assignment and functional value use the Manhattan metric (reference:
+    kmedians.py:49 passes ht.spatial.distance.manhattan). The update is
+    ``_kcluster._cluster_medians``: all ``k x d`` medians of one iteration
+    at once, by a radix selection over the order-preserving integer image
+    of the values (two bits a pass over ``X``, then the upper middle value
+    of the even counts): the value ``numpy.median`` of each cluster's rows
+    gives, with no copy of ``X`` and a number of passes that does not grow
+    with ``k``. ``inertia_`` is the sum of the L1 distances to the final
+    centres."""
 
     _assignment_metric = "manhattan"
 
@@ -71,5 +59,7 @@ class KMedians(_KCluster):
 
     def fit(self, x: DNDarray) -> "KMedians":
         """Seeding + convergence loop + assignment as ONE compiled program
-        (see ``_kcluster._fused_fit_program``)."""
-        return self._fit_fused(x, _median_step, returns_inertia=False)
+        (see ``_kcluster._fused_fit_program``); ``inertia_`` is the sum of
+        the L1 distances to the final centres, from the label pass."""
+        with _span("ht.call.kmedians.fit"):
+            return self._fit_fused(x, l1_step_for(x, "kmedians"), returns_inertia=False)

@@ -3,54 +3,31 @@
 API parity with /root/reference/heat/cluster/kmedoids.py: Lloyd-style
 iterations where each new center snaps to the closest actual data point
 of the cluster (reference kmedoids.py:116 performs the snap with extra
-comm). Here the snap is an argmin over the sharded distance column —
-one reduction.
+comm). Here an iteration is KMedians' (an L1 assignment and one counting
+selection of all ``k x d`` medians, ``_kcluster._cluster_medians``), then
+the snap: for one cluster at a time, the argmin of the L1 distance to its
+median over its own rows.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
 from typing import Optional, Union
 
 from ..core.dndarray import DNDarray
-from ._kcluster import _KCluster
+from ..observability.tracing import span as _span
+from ._kcluster import _KCluster, l1_step_for
 
 __all__ = ["KMedoids"]
 
 
-@functools.lru_cache(maxsize=64)
-def _medoid_step(k: int, shape, jdtype: str):
-    @jax.jit
-    def step(arr, centers):
-        # L1 assignment; medoid snap also by L1 (reference kmedoids.py:48)
-        d1 = jnp.sum(jnp.abs(arr[:, None, :] - centers[None, :, :]), axis=-1)
-        labels = jnp.argmin(d1, axis=1)
-
-        # median per cluster, then snap to nearest member point in L1
-        def one_cluster(i):
-            mask = labels == i
-            cnt = jnp.sum(mask)
-            masked = jnp.where(mask[:, None], arr, jnp.nan)
-            med_i = jnp.where(cnt > 0, jnp.nanmedian(masked, axis=0), centers[i])
-            dist_to_med = jnp.sum(jnp.abs(arr - med_i), axis=1)
-            dist_masked = jnp.where(mask, dist_to_med, jnp.inf)
-            idx = jnp.argmin(dist_masked)
-            return jnp.where(cnt > 0, arr[idx], centers[i])
-
-        new_centers = jax.vmap(one_cluster)(jnp.arange(k))
-        shift = jnp.sum((new_centers - centers) ** 2)
-        return new_centers, shift
-
-    return step
-
-
 class KMedoids(_KCluster):
-    """K-Medoids: centers are actual data points; Manhattan metric
-    throughout (reference: kmedoids.py:48)."""
+    """K-Medoids: centers are actual data points, the member nearest (L1) to its cluster's median.
+
+    Manhattan metric throughout (reference: kmedoids.py:48). An iteration
+    is KMedians' (an L1 assignment, then all ``k x d`` exact medians by one
+    counting selection, ``_kcluster._cluster_medians``) followed by the
+    snap, one cluster at a time: the row of the cluster with the least L1
+    distance to its median."""
 
     _assignment_metric = "manhattan"
 
@@ -74,5 +51,7 @@ class KMedoids(_KCluster):
 
     def fit(self, x: DNDarray) -> "KMedoids":
         """Seeding + convergence loop + assignment as ONE compiled program
-        (see ``_kcluster._fused_fit_program``)."""
-        return self._fit_fused(x, _medoid_step, returns_inertia=False)
+        (see ``_kcluster._fused_fit_program``); ``inertia_`` is the sum of
+        the L1 distances to the final medoids, from the label pass."""
+        with _span("ht.call.kmedoids.fit"):
+            return self._fit_fused(x, l1_step_for(x, "kmedoids", snap=True), returns_inertia=False)

@@ -78,6 +78,7 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 |---|---|---|
 | `ht.call.kmeans.fit`, `ht.call.kmeans.predict` | `KMeans.fit` (the fused fit), `predict` | `host_wrapper_ms_per_call` |
 | `ht.call.kmeans.init`, `.program`, `.wrap` | initial centres or seed key; lookup of the step and the fused program and the call; placement and the two `DNDarray`s (shared by `KMedians`/`KMedoids.fit`) | `host_wrapper_ms_per_call` |
+| `ht.call.kmedians.fit`, `ht.call.kmedoids.fit` | `KMedians.fit`, `KMedoids.fit` (the fused fit: the three spans above nest in it) | `host_wrapper_ms_per_call` |
 | `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
 | `ht.program.hit` | entered right after a lookup that a builder's `lru_cache` served (`cache=` names the builder) | `host_launch_ms_per_call` |
 | `ht.program.miss` | a lookup that built; the builder's time | `program_cache_misses`, `host_launch_ms_per_call` |
@@ -104,6 +105,20 @@ and split: the Pallas pass that reads f32 `X` once an iteration, or XLA's two st
 for an operator's `ht.telemetry.report()`, read by no benchmark metric. On the device the pass is
 named `kmeans_lloyd_pass` (the kernel) under `jax.named_scope("kmeans.lloyd_pass")`: a trace's op
 line and the ledger's `breakdown.device_ops` show it by that name.
+
+The L1 family (since PR 32): once per `KMedians.fit` / `KMedoids.fit` the counter
+`kmedians.step.select.pallas` / `.xla` (`kmedoids.step.select.*`) says which form of the three passes
+the fit's program runs, as `cluster._pallas_l1.l1_passes_serve` decided from backend, dtype, shape and
+split: Pallas kernels on a TPU for tall narrow f32, `jax.numpy` elsewhere. Inside the one fit program
+the device ops of an iteration lie under `jax.named_scope("kmedians.assign")` (the L1 assignment; also
+the fit's label pass) and `jax.named_scope("kmedians.select")` (the counting selection of all k x d
+medians and, for `KMedoids`, the snap); the scopes are named for the estimator (`kmedoids.*`). Every
+kernel that reads `X` once is named for its phase: `kmedians.assign.pass` (one an iteration, one for
+the labels) and `kmedians.select.pass` (sixteen counting passes and one successor pass an iteration for
+f32), for both estimators. `breakdown.device_ops` shows them by these names, and the benchmark's
+readers `kmedians_assign_ms_per_call`, `kmedians_select_ms_per_call`, `kmedians_x_reads_per_call` and
+`kmedians_pass_hbm_pct` find the passes by them (an op is named by the text before ` = `). The
+selection has no fallback: it is exact in a fixed number of passes, so there is nothing to count.
 """,
 }
 

@@ -1,7 +1,11 @@
 """The four-chip cell's two readers (``benchmarks/layers/collective_ms_per_call.py``,
 ``chip_skew_pct.py``) on hand-written events with known answers: the
 two-device list of ``benchmarks/selftest.py``, whose own checks cover the
-readers PR 24 brought. No JAX needed: a reader sees a list of events."""
+readers PR 24 brought. And the four readers of ``kmedians-northstar.fit5``
+(PR 32), on hand-written events and on the cell's recorded fixture. No JAX
+needed: a reader sees a list of events."""
+
+import json
 
 import os
 import sys
@@ -81,3 +85,88 @@ def test_collective_in_flight_from_start_to_done(bench):
                st.dev(i, done, end, 10)]
     got = harness.load_module("layers", "collective_ms_per_call").reduce(ev, {})
     assert got == pytest.approx((210 + 110) / 2 * 1e-6, rel=1e-12)
+
+
+# --------------------------------------------------------------------- #
+# the KMedians cell's readers (PR 32)                                    #
+# --------------------------------------------------------------------- #
+KMEDIANS = ["kmedians_assign_ms_per_call", "kmedians_select_ms_per_call", "kmedians_x_reads_per_call",
+            "kmedians_pass_hbm_pct"]
+ASSIGN = "%kmedians.assign.pass.8 = (s32[100]{0}, s32[8,128]{1,0}, f32[1,128]{1,0}) custom-call(f32[64,100]{1,0} %x)"
+SELECT = "%kmedians.select.pass.15 = s32[3,8,64]{2,1,0} custom-call(s32[1]{0} %s, f32[64,100]{1,0} %x)"
+
+
+def kmedians_fits(st, T, devices=1):
+    """Two fits in a 2000 ns window, the same on every device. A fit: an
+    assignment pass of 100 ns, three selection passes of 60, a fusion that
+    reads a pass's counts (it names the pass as its operand) and no ``X``,
+    the label pass of 100: five reads of ``X``, 200 ns of assignment, 180
+    of selection."""
+    ev = []
+    for call in (0, 1000):
+        ev += [st.host(T.CALL, call, 100), st.host(T.WAIT, call + 100, 900)]
+        for i in range(devices):
+            ev += [st.dev(i, ASSIGN, call + 100, 100), st.dev(i, "%while.3 = (s32[]) while(%t)", call + 200, 200),
+                   st.dev(i, SELECT, call + 200, 60), st.dev(i, SELECT, call + 260, 60),
+                   st.dev(i, SELECT, call + 320, 60),
+                   st.dev(i, "%fusion.4 = s32[8,64]{1,0} fusion(s32[3,8,64]{2,1,0} %kmedians.select.pass.15)", call + 380, 20),
+                   st.dev(i, ASSIGN.replace(".8 =", ".9 ="), call + 400, 100)]
+    return ev
+
+
+# one read is least / 2 assignment passes = 1000 B: 50 ns at 2e10 B/s; 5 reads in 380 ns of passes
+KMEDIANS_RUN = {"least_bytes_per_call": 2000, "peak": {"hbm_bytes_per_s": 2e10}}
+KMEDIANS_WANT = {"kmedians_assign_ms_per_call": 200e-6, "kmedians_select_ms_per_call": 180e-6,
+                 "kmedians_x_reads_per_call": 5.0, "kmedians_pass_hbm_pct": 100.0 * 5 * 50 / 380}
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("metric", KMEDIANS)
+def test_kmedians_reader_on_known_events(bench, metric, devices):
+    harness, st, T = bench
+    got = harness.load_module("layers", metric).reduce(kmedians_fits(st, T, devices), KMEDIANS_RUN)
+    assert got == pytest.approx(KMEDIANS_WANT[metric], rel=1e-12)
+
+
+def test_kmedians_readers_count_a_fused_pass_once(bench):
+    """An assignment fused with a counting pass carries both names: one
+    read of ``X``, and its time is in both phases."""
+    harness, st, T = bench
+    both = "%kmedians.assign.pass_kmedians.select.pass.2 = (s32[100]{0}) custom-call(f32[64,100]{1,0} %x)"
+    ev = [st.host(T.CALL, 0, 100), st.host(T.WAIT, 100, 900), st.dev(0, both, 100, 300), st.dev(0, SELECT, 400, 200)]
+    got = {m: harness.load_module("layers", m).reduce(ev, KMEDIANS_RUN) for m in KMEDIANS}
+    assert got["kmedians_x_reads_per_call"] == 2.0
+    assert got["kmedians_assign_ms_per_call"] == pytest.approx(300e-6)
+    assert got["kmedians_select_ms_per_call"] == pytest.approx(500e-6)
+    # the bytes of one read are least_bytes over the one assignment pass: 2000 B, 100 ns
+    assert got["kmedians_pass_hbm_pct"] == pytest.approx(100.0 * 2 * 100 / 500)
+
+
+def test_kmedians_reads_leave_out_the_slivers_the_clocks_offset_leaves(bench):
+    """The device's clock runs ahead of the host's: the window keeps 1 ns of
+    the first pass of the call after its last one. That is no read."""
+    harness, st, T = bench
+    ev = kmedians_fits(st, T) + [st.dev(0, ASSIGN, 1999, 100)]
+    assert harness.load_module("layers", "kmedians_x_reads_per_call").reduce(ev, KMEDIANS_RUN) == 5.0
+
+
+@pytest.mark.parametrize("metric", KMEDIANS)
+def test_kmedians_reader_finds_nothing_in_another_programs_trace(bench, metric):
+    """A program without the passes (the parent's, another cell's): nothing
+    to read, so the line leaves the metric out, and nothing raises."""
+    harness, st, T = bench
+    reduce = harness.load_module("layers", metric).reduce
+    ev = [e for e in two_devices(st, T)]
+    assert reduce(ev, KMEDIANS_RUN) is None
+    assert reduce([e for e in ev if e.plane == T.HOST_PLANE], KMEDIANS_RUN) is None
+    assert reduce([], {}) is None
+
+
+@pytest.mark.parametrize("metric", KMEDIANS)
+def test_kmedians_reader_on_the_recorded_fixture(bench, metric):
+    """The cell's trimmed chip trace reduces to what that run printed."""
+    harness, st, T = bench
+    with open(os.path.join(ROOT, "benchmarks", "fixtures", "kmedians-northstar.fit5.json")) as f:
+        fx = json.load(f)
+    got = harness.load_module("layers", metric).reduce([T.Event(*e) for e in fx["events"]], fx["run"])
+    assert got == pytest.approx(float(fx["expected"][metric]), rel=1e-6)

@@ -240,6 +240,76 @@ def test_kmeans_fused_assign_four_chips(mesh4, split):
     assert ("all-reduce" in compiled.as_text()) == (split == 0)
 
 
+def _l1_fit_compiled(n, d, k, x_sharding, mesh=None, axis_name=None, snap=False):
+    """The whole fused KMedians (``snap``: KMedoids) fit on the Pallas
+    passes, compiled for the described chip(s). ``_l1_step`` asks
+    ``jax.default_backend()``, which is the CPU here, so the step is put
+    together from its two parts as ``_l1_step`` does."""
+    from heat_tpu.cluster import _kcluster as kc, _pallas_l1 as pl1
+
+    passes = pl1.l1_passes(k, (n, d), mesh, axis_name)
+
+    def step(arr, centers):
+        labels, counts, _ = passes.assign(arr, centers)
+        new = kc._cluster_medians(arr, labels, k, centers, counts, passes)
+        if snap:
+            new = kc._snap_to_members(arr, labels, k, new, counts, centers)
+        return new, jnp.sum((new - centers) ** 2)
+
+    step.assign = lambda arr, centers: passes.assign(arr, centers)[::2]
+    builder = kc._fused_fit_program
+    builder.cache_clear()
+    try:
+        prog = builder(step, k, (n, d), "float32", 0.0, 5, False, "manhattan", False)
+        x = jax.ShapeDtypeStruct((n, d), F32, sharding=x_sharding)
+        c = jax.ShapeDtypeStruct((k, d), F32)
+        return prog.program.lower(x, c).compile()
+    finally:
+        builder.cache_clear()
+
+
+def _assert_l1_fit_holds_x_alone(compiled, rows, d):
+    txt = compiled.as_text()
+    # the loop's assignment, its counting pass and its successor pass, and the label pass
+    assert txt.count("tpu_custom_call") >= 4
+    assert "kmedians.assign.pass" in txt and "kmedians.select.pass" in txt  # the names the benchmark reads
+    sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
+    assert sized == []  # no copy, transpose or cast of X
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * d * 4 // 100
+
+
+@pytest.mark.parametrize("snap", [False, True], ids=["kmedians", "kmedoids"])
+def test_l1_fit_at_the_north_star_shard(one_chip, snap):
+    """``kmedians-northstar``'s shard, 18 750 000 x 64 f32, k 8 (no multiple
+    of 128: the last tile is masked in every kernel): the fit program holds
+    ``X``, the label vector and k x d x thresholds of integers, where the
+    masked-``nanmedian`` ``vmap`` asked for 38.4 GB. KMedoids' snap, in
+    XLA, reads ``x.T`` and picks its row by a masked sum (a slice of a row
+    costs a 9.6 GB row-major copy)."""
+    _assert_l1_fit_holds_x_alone(_l1_fit_compiled(18_750_000, 64, 8, one_chip, snap=snap), 18_750_000, 64)
+
+
+@pytest.mark.parametrize("n,d,k", [(1_000_003, 8, 2), (1_000_003, 120, 32), (300, 16, 4), (1025, 16, 4)],
+                         ids=["d8_k2", "d120_k32", "short", "two_blocks"])
+def test_l1_fit_gate_corners(one_chip, n, d, k):
+    """The corners of ``l1_passes_serve``: the narrowest and the widest
+    feature-major ``d``, the least and the largest ``k`` the passes serve, fewer rows
+    than one block of the 1-D label vector."""
+    assert _l1_fit_compiled(n, d, k, one_chip).as_text().count("tpu_custom_call") >= 4
+
+
+@pytest.mark.parametrize("split", [0, None], ids=["split0", "replicated"])
+def test_l1_fit_four_chips(mesh4, split):
+    """The same fit over the 2 x 2, under ``shard_map``. ``X`` split 0: each
+    chip passes over its rows and the counts are summed (an all-reduce a
+    counting pass) before a bracket narrows. Replicated: nothing crosses."""
+    rows = 4_687_500
+    n, spec, axis = (4 * rows, P("d", None), "d") if split == 0 else (rows, P(), None)
+    compiled = _l1_fit_compiled(n, 64, 8, NamedSharding(mesh4, spec), mesh4, axis)
+    _assert_l1_fit_holds_x_alone(compiled, rows, 64)
+    assert ("all-reduce" in compiled.as_text()) == (split == 0)
+
+
 @pytest.mark.parametrize("dtype,family", [("bfloat16", "splash"), ("float32", "flash")])
 def test_attention_ring_step(one_chip, dtype, family):
     """The per-ring-step kernels at the smoke's block, S=16384, D=128:

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 import heat_tpu as ht
-from heat_tpu.cluster import _kcluster, _pallas, kmeans, kmedians, kmedoids
+from heat_tpu.cluster import _kcluster, _pallas, kmeans
 from heat_tpu.core import types
 
 
@@ -190,13 +190,14 @@ def test_cpu_fit_runs_and_counts_the_xla_step():
 
 
 @pytest.mark.parametrize("name", ["kmedians", "kmedoids"])
-def test_shared_fit_builder_leaves_the_other_estimators_programs_alone(name):
-    """``_fused_fit_program`` asks a step for its own assignment; the median
-    and medoid steps offer none, and lower to the text of the builder as it
-    was before PR 28 (written out here)."""
-    step_factory = {"kmedians": kmedians._median_step, "kmedoids": kmedoids._medoid_step}[name]
+def test_shared_fit_builder_takes_the_l1_steps_own_label_pass(name):
+    """``_fused_fit_program`` asks a step for its own assignment. The L1
+    step (PR 32) returns no inertia, so its ``assign`` gives (labels,
+    functional value): both come from one more run of its assignment pass,
+    and the builder lowers to the text written out here (no ``_pairwise``:
+    nothing of ``n x k x d``)."""
     n, d, k = 96, 5, 3
-    step = step_factory(k, (n, d), "float32")
+    step = _kcluster._l1_step(name, k, (n, d), "float32", None, None, None, name == "kmedoids")
     loop = _kcluster.make_fit_loop(step, "float32", 1e-4, 7, False)
 
     @jax.jit
@@ -204,9 +205,8 @@ def test_shared_fit_builder_leaves_the_other_estimators_programs_alone(name):
         centers0 = init_arg.astype(arr.dtype)
         res = loop(arr, centers0)
         centers, n_iter = res[0], res[1]
-        dist = _kcluster._KCluster._pairwise(arr, centers, "manhattan")
-        labels = jnp.argmin(dist, axis=1).astype(types.index_jax_type())
-        return centers, n_iter, labels, jnp.sum(jnp.min(dist, axis=1))
+        labels, fun = step.assign(arr, centers)
+        return centers, n_iter, labels.astype(types.index_jax_type()), fun
 
     prog = _kcluster._fused_fit_program(step, k, (n, d), "float32", 1e-4, 7, False, "manhattan", False)
     a, c = jax.ShapeDtypeStruct((n, d), jnp.float32), jax.ShapeDtypeStruct((k, d), jnp.float32)

@@ -36,6 +36,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import heat_tpu as ht
 
@@ -759,8 +760,29 @@ def _kmeans_fit():
     )
 
 
+def _l1_fit(est):
+    def case():
+        from heat_tpu.cluster import _kcluster
+
+        _kcluster._l1_step.cache_clear()
+        _kcluster._fused_fit_program.cache_clear()
+        x = ht.random.randn(8 * 53, 7, split=0)
+        init = ht.array(np.asarray(x.numpy()[:3]))
+        root = f"ht.call.{est.lower()}.fit"
+        return (
+            lambda: getattr(ht.cluster, est)(3, init=init, max_iter=2).fit(x),
+            {root, "ht.call.kmeans.init", "ht.call.kmeans.program", "ht.call.kmeans.wrap",
+             "ht.comm.place", "ht.comm.shard"},
+            root,
+        )
+
+    case.__name__ = f"_{est.lower()}_fit"
+    return case
+
+
 @pytest.mark.skipif(P < 2, reason="the split path needs a real mesh")
-@pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _hsvd_split_staged, _kmeans_fit],
+@pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _hsvd_split_staged, _kmeans_fit,
+                                  _l1_fit("KMedians"), _l1_fit("KMedoids")],
                          ids=lambda f: f.__name__.strip("_"))
 def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     """One call that misses and one that hits, under a profiler session and
@@ -779,11 +801,49 @@ def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     assert "ht.program.miss" in in_first and "ht.program.compile" in in_first
     assert "ht.program.miss" not in in_second and "ht.program.compile" not in in_second
     assert "ht.program.hit" in in_second and "ht.program.launch" in in_second
+    if root in ("ht.call.kmedians.fit", "ht.call.kmedoids.fit"):
+        assert in_second.count("ht.program.launch") == 1  # the whole L1 fit is one program
     if case is _hsvd_split:
         # the whole split call is ONE launch, with no op, shard or reshard beside it
         assert in_second.count("ht.program.launch") == 1
         assert not [n for n in in_second if n.startswith("ht.op.") or n in ("ht.comm.shard", "ht.comm.reshard")]
     assert tracing.spans() == []  # a profiler session does not turn the ring on
+
+
+@pytest.mark.parametrize("est", ["KMedians", "KMedoids"])
+def test_l1_fit_counts_its_form_and_scopes_its_phases(est):
+    """Once a fit, ``<name>.step.select.xla`` (here: no TPU) or ``.pallas``
+    says which form of the passes the factory chose; the device ops of the
+    two phases lie under the scopes ``<name>.assign`` and ``<name>.select``,
+    and the Pallas passes carry the names the benchmark's readers count."""
+    from heat_tpu.cluster import _kcluster, _pallas_l1
+
+    name = est.lower()
+    x = ht.random.randn(8 * 31, 8, split=0)
+    init = ht.array(np.asarray(x.numpy()[:3]))
+    was = ht.telemetry.enabled()
+    ht.telemetry.enable()
+    try:
+        before = ht.telemetry.snapshot()["counters"]
+        for _ in range(2):  # the second fit is a cache hit and counts all the same
+            getattr(ht.cluster, est)(3, init=init, max_iter=2).fit(x)
+        after = ht.telemetry.snapshot()["counters"]
+    finally:
+        if not was:
+            ht.telemetry.disable()
+            ht.telemetry.reset()  # later tests of this file read the builders' counters from zero
+    assert after.get(f"{name}.step.select.xla", 0) - before.get(f"{name}.step.select.xla", 0) == 2
+    assert after.get(f"{name}.step.select.pallas", 0) == before.get(f"{name}.step.select.pallas", 0)
+    step = _kcluster._l1_step(name, 3, (248, 8), "float32", 0, ht.MPI_WORLD.mesh, ht.MPI_WORLD.axis_name,
+                              est == "KMedoids")
+    a, c = jax.ShapeDtypeStruct((248, 8), jnp.float32), jax.ShapeDtypeStruct((3, 8), jnp.float32)
+    text = jax.jit(step).lower(a, c).as_text(debug_info=True)
+    assert f"{name}.assign" in text and f"{name}.select" in text
+    passes = _pallas_l1.l1_passes(3, (256, 8), interpret=False)
+    lowered = jax.jit(lambda arr, cen: _kcluster._cluster_medians(
+        arr, passes.assign(arr, cen)[0], 3, cen, passes=passes)).trace(
+            jax.ShapeDtypeStruct((256, 8), jnp.float32), c).jaxpr
+    assert "kmedians.assign.pass" in str(lowered) and "kmedians.select.pass" in str(lowered)
 
 
 def test_profiler_trace_holds_predict_and_op_spans(tmp_path):
