@@ -15,7 +15,8 @@ per-cluster reductions lowering to one all-reduce over the mesh.
 The L1 family (KMedians, KMedoids) shares ``l1_step_for``: an L1 assignment
 that never holds ``n x k x d``, and ``_cluster_medians``, every
 per-cluster, per-feature median at once by a radix selection that counts
-(``_RADIX_BITS`` bits a pass over ``X``) instead of sorting k masked copies.
+(``_RADIX_BITS`` bits a pass over ``X``; on the chip the last digits on the
+few keys a gathering pass keeps) instead of sorting k masked copies.
 """
 
 from __future__ import annotations
@@ -38,7 +39,7 @@ from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import span as _span
 from . import _pallas_l1
-from ._pallas_l1 import _N_THR, _RADIX_BITS, _from_key, _key_type, _to_key
+from ._pallas_l1 import _N_THR, _NARROW_ON_X, _RADIX_BITS, _WINDOW_FIRST_DIGIT, _WINDOW_MIN_KEYS, _from_key, _key_type, _to_key
 
 __all__ = ["_KCluster"]
 
@@ -233,7 +234,7 @@ def _l1_passes_xla(k: int) -> "_pallas_l1.L1Passes":
         above = jnp.where(key > at[labels], key, top)
         return jax.lax.map(lambda c: jnp.min(jnp.where((labels == c)[:, None], above, top), axis=0), jnp.arange(k))
 
-    return _pallas_l1.L1Passes(assign, count_below, next_above)
+    return _pallas_l1.L1Passes(assign, count_below, next_above)  # no gather: the selection ends on X
 
 
 def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
@@ -253,7 +254,14 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
     fills the next rank, else the least key above (one more pass). Beside
     ``arr`` and ``labels`` it holds O(k x d x thresholds) integers, the
     number of passes does not depend on ``k``, and on a split array the
-    counts of the shards are summed before a bracket narrows."""
+    counts of the shards are summed before a bracket narrows.
+
+    Where the passes come with a ``gather`` (the kernels, on enough rows:
+    ``_pallas_l1.gather_pays``) only the first ``_NARROW_ON_X`` digits are
+    counted on ``arr``: by then a bracket holds a few keys in ten thousand,
+    one more pass keeps those, and the other digits and the upper middle
+    value are found among them (``finish_on_kept``): the same counts, so
+    the same key bit for bit."""
     if passes is None:
         passes = _l1_passes_xla(k)
     ktype, bits = _key_type(arr.dtype)
@@ -264,23 +272,91 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
     counts = counts.astype(jnp.int32)[:, None]
     lower = jnp.maximum(counts - 1, 0) // 2  # rank of the lower middle, 0-based
     upper = counts // 2
+    digits = bits // _RADIX_BITS
 
-    def narrow(p, state):
-        base, under_end = state
-        step = jnp.asarray(1, ktype) << (bits - _RADIX_BITS * (p + 1)).astype(ktype)
-        under = passes.count_below(arr, labels, base + step, step)  # (_N_THR, k, d)
-        digit = jnp.sum((under <= lower).astype(jnp.int32), axis=0)
-        # keys under the end of the chosen bracket: the next threshold's, or the last end's
-        ends = jnp.concatenate([under, under_end[None]])
-        under_end = jnp.take_along_axis(ends, digit[None], axis=0)[0]
-        return base + digit.astype(ktype) * step, under_end
+    bits_left = lambda p: jnp.asarray(bits - _RADIX_BITS * p).astype(ktype)  # of a bracket after p digits
+
+    def narrow_by(count_below):
+        """One digit of every bracket ``(base, keys under its start, keys
+        under its end)``; ``count_below(thr0, step)`` gives a pair's keys
+        under ``thr0 + t * step``, ``(_N_THR, k, d)``."""
+
+        def narrow(p, state):
+            base, under_base, under_end = state
+            step = jnp.asarray(1, ktype) << bits_left(p + 1)
+            under = count_below(base + step, step)
+            digit = jnp.sum((under <= lower).astype(jnp.int32), axis=0)
+            # keys under the edges of the four brackets: the chosen one lies between two of them
+            edges = jnp.concatenate([under_base[None], under, under_end[None]])
+            edge = lambda i: jnp.take_along_axis(edges, i[None], axis=0)[0]
+            return base + digit.astype(ktype) * step, edge(digit), edge(digit + 1)
+
+        return narrow
+
+    on_x = narrow_by(lambda thr0, step: passes.count_below(arr, labels, thr0, step))
+
+    def digits_on_x(p, state):
+        low, _, under_end = jax.lax.fori_loop(p, digits, on_x, state)
+        return low, under_end
+
+    def upper_from_x(low, under_end):
+        """(lower, upper) middle key, the upper one by the successor pass."""
+        # under_end counts the keys <= low: a duplicate of low fills the upper rank
+        return low, jnp.where(under_end > upper, low, passes.next_above(arr, labels, low).astype(ktype))
+
+    def note_window(p, carry):
+        """A digit on ``X``, and the pair's window: its newest bracket that
+        still holds ``_WINDOW_MIN_KEYS`` keys (none before the fourth), and
+        the bracket above it unless that lies past the last key."""
+        state, window = carry
+        base, under_base, under_end = state = on_x(p, state)
+        held = under_end - under_base
+        fits = (p < _WINDOW_FIRST_DIGIT) | (held >= _WINDOW_MIN_KEYS)
+        two = (jnp.asarray(2, ktype) << bits_left(p + 1)) - 1  # from the base to the last key of the bracket above
+        wide = bits_left(p + 1) + (base <= jnp.iinfo(ktype).max - two).astype(ktype)
+        return state, tuple(jnp.where(fits, new, old) for new, old in zip((base, under_base, wide, held), window))
+
+    def finish_on_kept(state, window):
+        """The same digits and the same upper middle value from the keys one
+        gathering pass keeps: those of each row's own window ``[base, base +
+        2 ** wide)``, by cluster and offset, so that a count among them is
+        the count over ``X`` less the keys under ``base``. Where the counts
+        say beforehand that the windows hold more keys than the slots are
+        made for (``_pallas_l1.crowded``: the pass is told to skip) or a slot
+        spilled, the digits are counted on ``X``; there, and where the upper
+        rank of some pair lies beyond its window (a median within 1e-6 of
+        zero, where f32 keys are sparse), the successor pass runs on ``X``."""
+        base, under_base, wide, held = window
+        kept, spilled = passes.gather(arr, labels, base, wide, _pallas_l1.crowded(held, arr.shape[0]))
+        ahead, in_window = _pallas_l1.kept_by_cluster(passes, kept, k)
+        beyond = (in_window > 0) & (upper - under_base >= in_window)
+
+        def count_below(thr0, step):
+            off = thr0 - base + step * jnp.arange(_N_THR, dtype=ktype)[:, None, None]
+            return under_base + _pallas_l1.kept_under(passes, kept, off, ahead)
+
+        def digits_on_kept(state):
+            low, _, under_end = jax.lax.fori_loop(_NARROW_ON_X, digits, narrow_by(count_below), state)
+            return low, under_end
+
+        def upper_from_kept(low, under_end):
+            return low, jnp.where(under_end > upper, low, base + _pallas_l1.kept_next(passes, kept, low - base))
+
+        def among_kept(state):
+            return upper_from_kept(*digits_on_kept(state))
+
+        def back_to_x(state):
+            return upper_from_x(*jax.lax.cond(spilled, functools.partial(digits_on_x, _NARROW_ON_X), digits_on_kept, state))
+
+        return jax.lax.cond(spilled | jnp.any(beyond), back_to_x, among_kept, state)
 
     first = jnp.full((k, d), -(1 << (bits - 1)), ktype)
-    low, under_end = jax.lax.fori_loop(
-        0, bits // _RADIX_BITS, narrow, (first, jnp.broadcast_to(counts, (k, d)))
-    )
-    # under_end counts the keys <= low: a duplicate of low fills the upper rank
-    high = jnp.where(under_end > upper, low, passes.next_above(arr, labels, low).astype(ktype))
+    none = jnp.zeros((k, d), jnp.int32)
+    state = (first, none, jnp.broadcast_to(counts, (k, d)))
+    if passes.gather is None:
+        low, high = upper_from_x(*digits_on_x(0, state))
+    else:  # the first digit's bracket is every pair's first window
+        low, high = finish_on_kept(*jax.lax.fori_loop(0, _NARROW_ON_X, note_window, (state, (first, none, none.astype(ktype), none))))
     med = 0.5 * _from_key(low, arr.dtype) + 0.5 * _from_key(high, arr.dtype)
     return jnp.where(counts > 0, med, prev.astype(arr.dtype))
 
@@ -335,6 +411,7 @@ def _l1_step(name: str, k: int, shape, jdtype: str, split, mesh, axis_name, snap
 
     step.assign = assign
     step.on_chip = on_chip
+    step.gathers = passes.gather is not None
     return step
 
 
@@ -346,11 +423,15 @@ def l1_step_for(x: DNDarray, name: str, snap: bool = False):
     (KMedoids); ``step.assign`` is the fit's label pass. Which form
     of the passes runs (``_pallas_l1`` on a TPU for tall narrow f32,
     ``jax.numpy`` elsewhere) reads backend, dtype, shape and split only, and
-    is counted once a fit: ``<name>.step.select.pallas`` / ``.xla``."""
+    is counted once a fit: ``<name>.step.select.pallas`` / ``.xla``, and
+    ``<name>.step.select.gather`` where the step was built with the
+    gathering pass (``_pallas_l1.gather_pays``)."""
 
     def factory(k: int, shape, jdtype: str):
         step = _l1_step(name, k, tuple(shape), jdtype, x.split, x.comm.mesh, x.comm.axis_name, snap)
         _telemetry.inc(f"{name}.step.select." + ("pallas" if step.on_chip else "xla"))
+        if step.gathers:
+            _telemetry.inc(f"{name}.step.select.gather")
         return step
 
     return factory

@@ -1,6 +1,7 @@
-"""Pallas TPU kernels of the L1 family (KMedians, KMedoids): the three passes
-over f32 ``X`` that one iteration is made of, none of which holds anything
-of ``X``'s size besides ``X`` and the label vector.
+"""Pallas TPU kernels of the L1 family (KMedians, KMedoids): the four passes
+over f32 ``X`` that one iteration is made of, and two small ones over the
+keys the fourth keeps. Nothing is of ``X``'s size besides ``X`` and the label
+vector.
 
 ``X`` is tiled as KMeans' pass tiles it (``_pallas``): the chip keeps a tall
 ``f32[n, d]`` with ``d < 128`` feature-major, ``x.T`` is a bitcast, a grid
@@ -12,6 +13,8 @@ step takes a block ``(d, tn)`` with the rows on the lanes.
              thr = thr0[lab] + t * step,  t < T           k selects, then T = _N_THR compares
              out[t, c, j] += #{rows of c: key_j < thr}    one-hot dot on the MXU, exact
     next     out[c, j] = min{key_j > at[lab, j]}          the successor, by cluster
+    gather   off = key - base[lab],  0 <= off < 2**bits[lab]    the keys still in their pair's window:
+             kept <- lab << _LABEL_SHIFT | off                  sorted slots, lane by lane
 
 ``count`` is one digit of a radix selection (``_kcluster._cluster_medians``
 drives it): every per-cluster, per-feature order statistic at once, for a
@@ -19,15 +22,41 @@ price that does not grow with ``k`` beyond the ``k`` selects. The counts of a
 tile (at most ``tn`` < 2^24) are exact in the f32 accumulator of the dot and
 are added up as int32.
 
-Each kernel is one read of ``X`` and is named for its phase:
-``kmedians.assign.pass``, ``kmedians.select.pass``. The benchmark's readers
-count reads of ``X`` by these names (``docs/API.md``, observability).
+After ``_NARROW_ON_X`` digits a bracket holds a few keys in ten thousand, and
+the other digits are counted on them (and on those of the bracket above, for
+the upper middle value of an even count; a pair whose ninth bracket holds
+under ``_WINDOW_MIN_KEYS`` keys, because its median lies near zero where f32
+keys are sparse, gets the window of an earlier, wider bracket). The chip has no vector scatter, so
+``gather`` folds the lanes: a block's ``tn / 128`` lane chunks ``(d, 128)``
+go, one after the other, into ``_SLOTS`` ascending slots ``(d, 128)`` by a
+chain of min / max (a kept key, or the type's max, ripples to its place; what
+falls off the end is a spill), and the slots of ``_KEPT_STEPS`` grid steps go
+the same way into the ``_KEPT_SLOTS`` slots of one output block. The kept
+array is ``int32[d, kept_lanes]``: at 18 750 000 x 64, 64 x 293 888, 75 MB,
+1.6 % of ``X``. A kept key carries its row's label above its offset, so one
+comparison with ``c << _LABEL_SHIFT | off`` says cluster and side:
+``kept_below`` counts a feature's kept keys under each of ``q`` such
+thresholds, ``kept_above`` finds the least one over each
+(``kept_by_cluster``, ``kept_under`` and ``kept_next`` speak in clusters and
+offsets: the format stays in this module). A spill (rows sorted by a
+feature, many equal values) sends the selection back to ``X`` for its last
+digits (``_kcluster._cluster_medians``); where the counts of the counting
+passes say beforehand that the windows hold more keys than the slots are
+made for (``crowded``), ``gather`` is told to skip: every grid step asks for
+the first block, so nothing more is read, and does nothing.
+
+Every kernel that reads all of ``X`` is named for its phase,
+``kmedians.assign.pass`` or ``kmedians.select.pass`` (``count``, ``next`` and
+``gather``): one op of such a name is one whole read of ``X``, and the
+benchmark's readers count reads of ``X`` by these names (``docs/API.md``,
+observability). The two kernels over the kept keys are named
+``kmedians.select.candidates``: they read no ``X``.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -45,17 +74,66 @@ _VMEM = pltpu.VMEM
 _SMEM = pltpu.SMEM
 _I32_MAX = np.iinfo(np.int32).max
 
-__all__ = ["L1Passes", "l1_passes", "l1_passes_serve"]
+__all__ = ["L1Passes", "crowded", "gather_pays", "kept_by_cluster", "kept_lanes", "kept_next", "kept_under", "l1_passes",
+           "l1_passes_serve"]
 
 
 # bits of the key a counting pass settles: 2**bits - 1 thresholds a pass,
 # 32 / bits passes for f32. At 18.75M x 64 on a v5e a pass of 3 thresholds
-# reads at the rate of a bare read (6.4 ms: the k selects of a row's own
-# threshold and three one-hot dots hide under it), one of 7 takes 9.3 ms and
-# one of 15 15.3, one of 1 still 7.2: two bits are the fewest ms a bit
-# (3.2; three bits 3.1 with an uneven first pass; builder's chip runs, PR 32)
+# reads at the rate of a bare read (6.44 ms: the k selects of a row's own
+# threshold and three one-hot dots hide under it; ledger, PR 32). Against a
+# pass of 3 that still took 7.3 ms (an earlier form of this kernel; builder's
+# chip runs, PR 32): one of 7 took 9.25, one of 15 15.3, one of 1 7.2, so two
+# bits were the fewest ms a bit (3.2; three bits 3.1 with an uneven first
+# pass), and every threshold past the third is VPU time the read cannot hide
 _RADIX_BITS = 2
 _N_THR = 2 ** _RADIX_BITS - 1
+
+# the selection narrows on X for _NARROW_ON_X digits, then ``gather`` keeps
+# the keys of each row's own window: a bracket of its (cluster, feature) pair
+# and the one above it. The bracket is the pair's last with _WINDOW_MIN_KEYS
+# keys in it, so that the upper middle value is in the window too (a median
+# near zero lies where f32 keys are sparse: its ninth bracket holds a handful
+# of 2.3 M keys, its seventh a hundred), and no earlier than the fourth, so
+# that an offset fits under the label. A kept key is one int32: its offset in
+# the window with the label above it, from bit _LABEL_SHIFT. Per grid step
+# the tn / 128 lane chunks fold onto _SLOTS sorted slots (d, 128), and
+# _KEPT_STEPS steps fold their slots onto the _KEPT_SLOTS slots of one
+# output block. Chosen on the chip at 18.75M x 64, k 8 (builder's runs, PR 33,
+# PERF.md section 6): a lane position of 64 rows holds 0.015 kept keys on
+# average, so a fifth one is one in 10 ** 4 iterations and a fourth one in 30;
+# the pass takes 8.55 ms with 3 slots, 9.37 with 4, 10.96 with 6 (unrolled;
+# as the loop it is 10.6 with 4). One block a step (300 MB kept) makes an op
+# over the kept keys take 0.56-1.0 ms, 8 steps a block (75 MB) 0.24-0.28, 16
+# steps and 12 slots 0.22-0.25. Eight digits on X keep four times the keys (a
+# fifth at a lane position every tenth iteration), ten gain nothing but a pass
+_NARROW_ON_X = 9
+_WINDOW_MIN_KEYS = 32
+_WINDOW_FIRST_DIGIT = 4
+_LABEL_SHIFT = 26  # a window of the fourth digit is 2 ** 25 keys; five bits of label above it
+_MOST_CLUSTERS = 32  # the last one's kept keys end under the type's max, which is what an empty slot holds
+assert 32 - _RADIX_BITS * _WINDOW_FIRST_DIGIT < _LABEL_SHIFT and (_MOST_CLUSTERS << _LABEL_SHIFT) - 1 <= _I32_MAX
+_SLOTS = 4
+_KEPT_STEPS = 8
+_KEPT_SLOTS = 8
+# rows a cluster from which the gather pays. A window holds a number of keys that does not grow with n (32 to a few
+# hundred), so what is kept of X goes as k / n, and under some 2 ** 16 rows a cluster a lane position holds more keys
+# than slots now and then: the selection then ends on X and the gathering pass was for nothing. Five iterations, ms,
+# selection on X to its end / with the gather (builder's chip runs, PR 33; d 64 unless said): k 8: 262 144 rows 11.2 /
+# 11.9, 524 288 20.1 / 13.8, 1 048 576 38.0 / 25.6, 18 750 000 640.2 / 420.9; k 16: 1 048 576 55.8 / 59.7, 4 194 304
+# 213.0 / 140.6; k 32: 4 194 304 392.3 / 280.0; k 4, d 16: 1 048 576 10.5 / 7.2; k 2, d 8: 2 097 152 14.0 / 13.6
+_GATHER_MIN_ROWS_A_CLUSTER = 1 << 17
+# and the share of a feature's rows that its windows' brackets may hold. The counting passes say how many keys each
+# bracket holds, so the selection knows before the gathering pass whether the slots are made for them, and tells the
+# pass to skip where they are not (its grid then runs empty over one block: under 0.5 ms an iteration at 512 steps). The densest feature's
+# brackets hold (builder's chip runs, PR 33, review round; fits of 4 194 304 x 64 in ms, selection on X to its end /
+# this): the cell's blobs one row in 3 800 to 6 100 (two seeds, five iterations each); the same at 4 194 304 rows, k 8,
+# one in 3 400 to 4 400: 145.3 / 97.1; k 32 one in 1 600: 392.3 / 280.9; unit blobs around 3 one in 470: skipped, 88.7
+# / 90.2 (three iterations); the cell's blobs around 10, where f32 keys lie sixteen times as dense, one in 135 to 150,
+# around 100 one in 17: skipped, 146.2 (the parent) / 146.8 and 146.7, where the pass run for nothing made it 157.2.
+# By (64 x 2 x share) ** 5 / 5! a lane position and step a fifth key is then expected 0.1 times a pass at one row in
+# 1 600 (4 194 304 x 64) and 5 times at one in 2 ** 10 (18.75M x 64, every feature that dense)
+_GATHER_MOST_OF_X = 1 << 10
 
 
 def _key_type(dtype):
@@ -86,11 +164,15 @@ def _from_key(key: jax.Array, dtype) -> jax.Array:
 
 class L1Passes(NamedTuple):
     """The passes an L1 iteration is made of, on whole (global) arrays.
-    ``_kcluster`` holds the ``jax.numpy`` form of the same three."""
+    ``_kcluster`` holds the ``jax.numpy`` form of the first three; the last
+    three are ``None`` where the selection stays on ``X`` to its end."""
 
     assign: Callable      # (arr, centers) -> labels int32 (n,), counts int32 (k,), fun f32 ()
     count_below: Callable  # (arr, labels, thr0 (k, d), step ()) -> int32 (T, k, d)
     next_above: Callable  # (arr, labels, at (k, d)) -> (k, d) keys, the type's max where none
+    gather: Optional[Callable] = None      # (arr, labels, base (k, d), bits (k, d), skip bool ()) -> kept int32 (d, m), spilled ()
+    kept_below: Optional[Callable] = None  # (kept, thr (q, d)) -> int32 (q, d): kept[j] < thr[i, j]
+    kept_above: Optional[Callable] = None  # (kept, at (q, d)) -> int32 (q, d): least kept[j] > at[i, j]
 
 
 def l1_passes_serve(backend: str, dtype, shape, k: int, split, devices: int = 1) -> bool:
@@ -100,33 +182,45 @@ def l1_passes_serve(backend: str, dtype, shape, k: int, split, devices: int = 1)
     ``2 <= k <= 32`` (the assignment and the selects are unrolled over
     ``k``; at ``k = 1`` the chip's compiler aborts on the assignment, whose
     least distance is then the bare sublane reduction)."""
-    return 2 <= k <= 32 and lloyd_pass_serves(backend, dtype, shape, k, split, devices)
+    return 2 <= k <= _MOST_CLUSTERS and lloyd_pass_serves(backend, dtype, shape, k, split, devices)
 
 
-def _grid_kernel(tile, n: int, tn: int, n_acc: int, init):
+def _grid_kernel(tile, n: int, tn: int, n_acc: int, init, before=None, skips: bool = False):
     """``tile(refs, valid)`` over the grid: the accumulators (the last
     ``n_acc`` refs) set to ``init`` at step 0; ``valid`` is ``None`` on a
-    whole tile and the (1, tn) mask of the rows that exist on the last."""
+    whole tile and the (1, tn) mask of the rows that exist on the last.
+    ``before(refs, i, when)`` runs first in every step ``i``. With ``skips``
+    the first ref is a scalar, and where it is not 0 no step does anything
+    (``when`` is ``pl.when`` with that in it: no ``cond`` around the rest)."""
     steps = pl.cdiv(n, tn)
     tail = n - (steps - 1) * tn
 
     def kernel(*refs):
         i = pl.program_id(0)
+        if skips:
+            go, refs = refs[0][0] == 0, refs[1:]
+        when = lambda cond: pl.when(cond & go if skips else cond)
 
-        @pl.when(i == 0)
+        @when(i == 0)
         def _init():
             for ref in refs[len(refs) - n_acc:]:
                 ref[...] = jnp.full(ref.shape, init, ref.dtype)
 
-        if tail == tn:
+        if before is not None:
+            before(refs, i, when)
+        whole = steps - 1 if tail < tn else steps  # the steps whose tile is whole
+        if whole == steps and not skips:
             tile(refs, None)
             return
 
-        @pl.when(i < steps - 1)
+        @when(i < whole)
         def _whole():
             tile(refs, None)
 
-        @pl.when(i == steps - 1)
+        if whole == steps:
+            return
+
+        @when(i == steps - 1)
         def _last():
             # what the last block holds past row n is unspecified
             tile(refs, jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1) < tail)
@@ -142,9 +236,14 @@ def _own(lab, table_ref, k: int):
     return sel
 
 
-def _call(kernel, name: str, n: int, tn: int, in_specs, out_shape, out_specs, interpret: bool):
+def _call(kernel, name: str, n: int, tn: int, in_specs, out_shape, out_specs, interpret: bool, prefetch: int = 0):
+    """``kernel`` over the blocks of ``tn`` rows; its first ``prefetch``
+    operands are scalars that the index maps are given too."""
+    grid = dict(grid=(pl.cdiv(n, tn),), in_specs=in_specs, out_specs=out_specs)
+    if prefetch:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=prefetch, **grid))
     return pl.pallas_call(
-        kernel, grid=(pl.cdiv(n, tn),), in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        kernel, out_shape=out_shape, **grid,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
         name=name, interpret=interpret,
     )
@@ -162,13 +261,21 @@ def _vpu_tn(n: int, d: int, k8: int) -> int:
     return tn if n <= 1024 else max(1024, tn // 4096 * 1024)
 
 
+def _lane_least(v, tn: int):
+    """(r, tn) -> (r, 128): the least of the tile's 128-lane groups, lane by lane."""
+    acc = v[:, :128]
+    for j in range(1, tn // 128):
+        acc = jnp.minimum(acc, v[:, j * 128:(j + 1) * 128])
+    return acc
+
+
 def _table(values, k8: int, dtype):
     """A (k, d) table as the kernels read it: (d, k8), a cluster a column."""
     return jnp.pad(values.astype(dtype), ((0, k8 - values.shape[0]), (0, 0))).T
 
 
 def _const(shape):
-    return pl.BlockSpec(shape, lambda i: (0,) * len(shape), memory_space=_VMEM)
+    return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape), memory_space=_VMEM)
 
 
 @functools.lru_cache(maxsize=64)
@@ -252,11 +359,8 @@ def _next_program(n: int, d: int, k: int, interpret: bool):
         above = jnp.where(key > _own(lab, at_ref, k), key, _I32_MAX)
         for c in range(k):
             mine = lab == c if valid is None else (lab == c) & valid
-            m = jnp.where(mine, above, _I32_MAX)
-            acc = m[:, :128]
-            for j in range(1, tn // 128):
-                acc = jnp.minimum(acc, m[:, j * 128:(j + 1) * 128])
-            out_ref[c * d:(c + 1) * d, :] = jnp.minimum(out_ref[c * d:(c + 1) * d, :], acc)
+            least = _lane_least(jnp.where(mine, above, _I32_MAX), tn)
+            out_ref[c * d:(c + 1) * d, :] = jnp.minimum(out_ref[c * d:(c + 1) * d, :], least)
 
     call = _call(
         _grid_kernel(tile, n, tn, 1, _I32_MAX), "kmedians.select.pass", n, tn,
@@ -271,34 +375,215 @@ def _next_program(n: int, d: int, k: int, interpret: bool):
     return run
 
 
+def kept_lanes(n: int, d: int, k: int) -> int:
+    """Lanes of the array ``gather`` keeps of ``n`` rows: ``_KEPT_SLOTS``
+    x 128 for every ``_KEPT_STEPS`` grid steps (8 x 128 of 8 x 8192 rows at
+    ``d`` 64: 1.6 % of ``X``)."""
+    return pl.cdiv(pl.cdiv(n, _pick_tn(n, d, _round_up(k, 8))), _KEPT_STEPS) * _KEPT_SLOTS * 128
+
+
+def _insert(slots, v):
+    """``v`` into the ascending ``slots``, lane by lane; returns what fell
+    off their end (the largest)."""
+    for s in range(len(slots)):
+        slots[s], v = jnp.minimum(slots[s], v), jnp.maximum(slots[s], v)
+    return v
+
+
+@functools.lru_cache(maxsize=64)
+def _gather_program(n: int, d: int, k: int, interpret: bool):
+    tn = _pick_tn(n, d, _round_up(k, 8))
+    width = _KEPT_SLOTS * 128
+    empty = lambda: jnp.full((d, 128), _I32_MAX, jnp.int32)
+
+    group = min(tn, 1024)  # rows a turn of the loop: a tile of the 1-D label block, eight lane chunks
+    tail = n - (pl.cdiv(n, tn) - 1) * tn
+
+    def tile(refs, valid):
+        xt_ref, lab_ref, base_ref, kept_ref, spill_ref = refs
+
+        def fold(g, carry):
+            slots, spill = list(carry[:-1]), carry[-1]
+            start = pl.multiple_of(g * group, group)
+            lab = lab_ref[pl.ds(start, group)].reshape(1, group)
+            if valid is not None:  # the last block: its rows from ``tail`` on do not exist
+                lab = jnp.where(start + jax.lax.broadcasted_iota(jnp.int32, (1, group), 1) < tail, lab, -1)
+            for j in range(group // 128):
+                own = lab[:, j * 128:(j + 1) * 128]  # (1, 128)
+                base = base_ref[0:d, :]
+                for c in range(1, k):
+                    base = jnp.where(own == c, base_ref[c * d:(c + 1) * d, :], base)
+                bits = base & 31  # a window's bits ride in the five lowest of its base, which are zero
+                off = _to_key(xt_ref[:, pl.ds(pl.multiple_of(start + j * 128, 128), 128)]) - (base - bits)
+                inside = jax.lax.shift_right_logical(off, bits) == 0  # 0 <= off < 2 ** bits
+                if valid is not None:
+                    inside = inside & (own >= 0)
+                spill = jnp.minimum(spill, _insert(slots, jnp.where(inside, off | (own << _LABEL_SHIFT), _I32_MAX)))
+            return (*slots, spill)
+
+        *slots, spill = jax.lax.fori_loop(0, tn // group, fold, (empty(),) * (_SLOTS + 1))
+        for v in slots:
+            for s in range(_KEPT_SLOTS):
+                held = kept_ref[:, s * 128:(s + 1) * 128]
+                kept_ref[:, s * 128:(s + 1) * 128] = jnp.minimum(held, v)
+                v = jnp.maximum(held, v)
+            spill = jnp.minimum(spill, v)
+        spill_ref[...] = jnp.minimum(spill_ref[...], spill)
+
+    def fresh_block(refs, i, when):
+        @when(i % _KEPT_STEPS == 0)
+        def _():
+            refs[3][...] = jnp.full((d, width), _I32_MAX, jnp.int32)
+
+    # told to skip, every step asks for the first block: nothing is read after it, and what comes out means nothing
+    at = lambda i, skip_ref: jnp.where(skip_ref[0] == 0, i, 0)
+    call = _call(
+        _grid_kernel(tile, n, tn, 1, _I32_MAX, fresh_block, skips=True), "kmedians.select.pass", n, tn,
+        [pl.BlockSpec((d, tn), lambda i, skip: (0, at(i, skip)), memory_space=_VMEM),
+         pl.BlockSpec((tn,), lambda i, skip: (at(i, skip),), memory_space=_VMEM), _const((k * d, 128))],
+        [jax.ShapeDtypeStruct((d, kept_lanes(n, d, k)), jnp.int32), jax.ShapeDtypeStruct((d, 128), jnp.int32)],
+        [pl.BlockSpec((d, width), lambda i, skip: (0, at(i, skip) // _KEPT_STEPS), memory_space=_VMEM), _const((d, 128))],
+        interpret, prefetch=1,
+    )
+
+    def run(x, labels, base, bits, skip):
+        # a cluster's windows along all 128 lanes: the kernel loads them, it does not broadcast
+        lanes = jnp.broadcast_to((base | bits).astype(jnp.int32)[:, :, None], (k, d, 128)).reshape(k * d, 128)
+        kept, spill = call(jnp.reshape(skip, (1,)).astype(jnp.int32), x.T, labels, lanes)
+        return kept, skip | (jnp.min(spill) < _I32_MAX)
+
+    return run
+
+
+@functools.lru_cache(maxsize=64)
+def _kept_program(m: int, d: int, q: int, above: bool, interpret: bool):
+    """A pass over the kept array ``(d, m)`` against ``q`` thresholds a
+    feature: how many of a feature's kept lie under each, or (``above``)
+    the least kept over each. Empty slots hold the type's max: under no
+    threshold, over every one."""
+    tm = _KEPT_SLOTS * 128  # one block of ``gather`` a step: no block is cut
+
+    def tile(refs, _):
+        kept_ref, thr_ref, out_ref = refs
+        kept = kept_ref[...]
+        for i in range(q):
+            thr, rows = thr_ref[:, i:i + 1], slice(i * d, (i + 1) * d)
+            if above:
+                out_ref[rows, :] = jnp.minimum(out_ref[rows, :], _lane_least(jnp.where(kept > thr, kept, _I32_MAX), tm))
+            else:
+                out_ref[rows, :] += _lane_partials(jnp.where(kept < thr, 1, 0), tm)
+
+    call = _call(
+        _grid_kernel(tile, m, tm, 1, _I32_MAX if above else 0), "kmedians.select.candidates", m, tm,
+        [pl.BlockSpec((d, tm), lambda i: (0, i), memory_space=_VMEM), _const((d, q))],
+        jax.ShapeDtypeStruct((q * d, 128), jnp.int32), _const((q * d, 128)), interpret,
+    )
+
+    def run(kept, thr):
+        out = call(kept, thr.astype(jnp.int32).T).reshape(q, d, 128)
+        return jnp.min(out, axis=2) if above else jnp.sum(out, axis=2, dtype=jnp.int32)
+
+    return run
+
+
+def _kept_key(off):
+    """What ``gather`` keeps of a key ``off`` above the base of its window,
+    for ``off`` of shape (..., k, d): the keys of one cluster compare by
+    offset, and every key of a cluster lies under the next one's zero."""
+    return (jnp.arange(off.shape[-2], dtype=jnp.int32)[:, None] << _LABEL_SHIFT) + off.astype(jnp.int32)
+
+
+def kept_by_cluster(passes: L1Passes, kept, k: int):
+    """``(ahead, in_window)``, each int32 (k, d): a pair's kept keys of the
+    clusters before its own (what a count under one of its thresholds holds
+    besides its own), and its own."""
+    d = kept.shape[0]
+    # where each cluster's kept keys start; the last one's end is the type's max (``k << _LABEL_SHIFT`` is 2 ** 31 at
+    # k 32), over every kept key and under no empty slot
+    starts = jnp.concatenate([_kept_key(jnp.zeros((k, d), jnp.int32)), jnp.full((1, d), _I32_MAX, jnp.int32)])
+    ahead = passes.kept_below(kept, starts)
+    return ahead[:k], ahead[1:] - ahead[:k]
+
+
+def kept_under(passes: L1Passes, kept, off, ahead):
+    """int32 (q, k, d): a pair's kept keys under the offset ``off[i]`` of
+    its window."""
+    q, k, d = off.shape
+    return passes.kept_below(kept, _kept_key(off).reshape(q * k, d)).reshape(q, k, d) - ahead
+
+
+def kept_next(passes: L1Passes, kept, off):
+    """int32 (k, d): the least offset over ``off`` among a pair's kept keys
+    (no offset of a window where it keeps none)."""
+    return passes.kept_above(kept, _kept_key(off)) - _kept_key(jnp.zeros_like(off))
+
+
+def gather_pays(n: int, k: int) -> bool:
+    """Can finishing on the kept keys beat the remaining passes over ``X``?
+    It trades ``bits / 2 - _NARROW_ON_X`` counting passes and the successor
+    pass for one gathering pass and as many small ops, and wins where the
+    slots do not spill: from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a cluster
+    on one device, on data whose windows are not ``crowded``."""
+    return n >= k * _GATHER_MIN_ROWS_A_CLUSTER
+
+
+def crowded(held, n: int):
+    """Do the windows hold more keys than the slots are made for?
+    ``held`` (k, d) are the keys in each pair's bracket, as the counting
+    passes over the ``n`` rows gave them: in some feature more than one row
+    in ``_GATHER_MOST_OF_X``."""
+    return jnp.max(jnp.sum(held, axis=0)) > n // _GATHER_MOST_OF_X
+
+
 @functools.lru_cache(maxsize=64)
 def l1_passes(k: int, shape, mesh=None, axis_name=None, interpret: bool = False) -> L1Passes:
-    """The three passes for ``arr`` of ``shape``. On one device they are called bare. With a ``mesh`` they run
-    under ``shard_map``, as KMeans' pass does: with an ``axis_name``,
-    ``arr`` and the labels are split 0 over it in equal shards, each device
-    passes over its rows, and the counts are ``psum``med (the successor:
-    ``pmin``) before any bracket narrows; without one ``arr`` is replicated
-    and every device runs the whole pass."""
+    """The passes for ``arr`` of ``shape``; those over the kept keys where
+    ``gather_pays`` for one device's rows. On one device they are called
+    bare. With a ``mesh`` they run under ``shard_map``, as KMeans' pass
+    does: with an ``axis_name``, ``arr`` and the labels are split 0 over it
+    in equal shards, each device passes over its rows (and keeps their
+    keys), and the counts are ``psum``med (the successors: ``pmin``, the
+    spill flag: ``pmax``) before any bracket narrows; without one ``arr``
+    is replicated and every device runs the whole pass."""
     n, d = int(shape[0]), int(shape[1])
     p = mesh.devices.size if axis_name is not None else 1
     assign = _assign_program(n // p, d, k, interpret)
     count = _count_program(n // p, d, k, interpret)
     nxt = _next_program(n // p, d, k, interpret)
+    gather = below = above = None
+    if gather_pays(n // p, k):
+        m = kept_lanes(n // p, d, k)
+        gather = _gather_program(n // p, d, k, interpret)
+        below = lambda kept, thr: _kept_program(m, d, thr.shape[0], False, interpret)(kept, thr)
+        above = lambda kept, at: _kept_program(m, d, at.shape[0], True, interpret)(kept, at)
     if mesh is None:
-        return L1Passes(assign, count, nxt)
-    rows, vec = P(axis_name, None), P(axis_name)
+        return L1Passes(assign, count, nxt, gather, below, above)
+    rows, vec, lanes = P(axis_name, None), P(axis_name), P(None, axis_name)
     if axis_name is not None:
         local_assign, local_count, local_next = assign, count, nxt
+        local_gather, local_below, local_above = gather, below, above
 
         def assign(arr, centers):
             labels, cnt, fun = local_assign(arr, centers)
             return labels, jax.lax.psum(cnt, axis_name), jax.lax.psum(fun, axis_name)
 
+        def gather(*a):
+            kept, spilled = local_gather(*a)
+            return kept, jax.lax.pmax(spilled.astype(jnp.int32), axis_name) > 0
+
         count = lambda *a: jax.lax.psum(local_count(*a), axis_name)
         nxt = lambda *a: jax.lax.pmin(local_next(*a), axis_name)
+        below = lambda *a: jax.lax.psum(local_below(*a), axis_name)
+        above = lambda *a: jax.lax.pmin(local_above(*a), axis_name)
     sm = functools.partial(_shard_map, mesh=mesh, check_vma=False)
+    over_kept = (
+        sm(gather, in_specs=(rows, vec, P(), P(), P()), out_specs=(lanes, P())),
+        sm(below, in_specs=(lanes, P()), out_specs=P()),
+        sm(above, in_specs=(lanes, P()), out_specs=P()),
+    ) if gather_pays(n // p, k) else ()
     return L1Passes(
         sm(assign, in_specs=(rows, P()), out_specs=(vec, P(), P())),
         sm(count, in_specs=(rows, vec, P(), P()), out_specs=P()),
         sm(nxt, in_specs=(rows, vec, P()), out_specs=P()),
+        *over_kept,
     )

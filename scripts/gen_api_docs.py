@@ -107,18 +107,34 @@ named `kmeans_lloyd_pass` (the kernel) under `jax.named_scope("kmeans.lloyd_pass
 line and the ledger's `breakdown.device_ops` show it by that name.
 
 The L1 family (since PR 32): once per `KMedians.fit` / `KMedoids.fit` the counter
-`kmedians.step.select.pallas` / `.xla` (`kmedoids.step.select.*`) says which form of the three passes
+`kmedians.step.select.pallas` / `.xla` (`kmedoids.step.select.*`) says which form of the passes
 the fit's program runs, as `cluster._pallas_l1.l1_passes_serve` decided from backend, dtype, shape and
-split: Pallas kernels on a TPU for tall narrow f32, `jax.numpy` elsewhere. Inside the one fit program
-the device ops of an iteration lie under `jax.named_scope("kmedians.assign")` (the L1 assignment; also
-the fit's label pass) and `jax.named_scope("kmedians.select")` (the counting selection of all k x d
-medians and, for `KMedoids`, the snap); the scopes are named for the estimator (`kmedoids.*`). Every
-kernel that reads `X` once is named for its phase: `kmedians.assign.pass` (one an iteration, one for
-the labels) and `kmedians.select.pass` (sixteen counting passes and one successor pass an iteration for
-f32), for both estimators. `breakdown.device_ops` shows them by these names, and the benchmark's
-readers `kmedians_assign_ms_per_call`, `kmedians_select_ms_per_call`, `kmedians_x_reads_per_call` and
-`kmedians_pass_hbm_pct` find the passes by them (an op is named by the text before ` = `). The
-selection has no fallback: it is exact in a fixed number of passes, so there is nothing to count.
+split: Pallas kernels on a TPU for tall narrow f32, `jax.numpy` elsewhere; and, since PR 33,
+`kmedians.step.select.gather` (`kmedoids.*`) where that program was built with the gathering pass
+(`cluster._pallas_l1.gather_pays`: the kernels, from 2^17 rows a cluster and device on). Inside the one fit
+program the device ops of an iteration lie under `jax.named_scope("kmedians.assign")` (the L1 assignment;
+also the fit's label pass) and `jax.named_scope("kmedians.select")` (the counting selection of all k x d
+medians and, for `KMedoids`, the snap); the scopes are named for the estimator (`kmedoids.*`). One op
+named `.pass` is one whole read of `X`, for both estimators: `kmedians.assign.pass` (one an iteration,
+one for the labels) and `kmedians.select.pass`, which three kernels carry: the counting pass (a digit of
+the radix selection: nine an iteration where the program gathers, sixteen for f32 where it does not),
+the gathering pass (one an iteration: it keeps the keys still in their (cluster, feature) pair's
+window, its last bracket with 32 keys and the one above, in an array of 1.6 % of `X`'s size) and the
+successor pass (the upper middle value of the even counts: only where the selection ends on `X`). The ops that finish the selection on the kept keys (a count by
+cluster, seven digits, one successor) are named `kmedians.select.candidates` and read no `X`.
+`breakdown.device_ops` shows all of them by these names, and the benchmark's readers
+`kmedians_assign_ms_per_call`, `kmedians_select_ms_per_call`, `kmedians_x_reads_per_call` and
+`kmedians_pass_hbm_pct` find the passes by them (an op is named by the text before ` = `). How often the
+selection went back to `X` the device trace says: `kmedians_x_reads_per_call` is `max_iter x 11 + 1` (56 at
+five iterations) when every iteration finished on the kept keys; it grows by 8 (seven counting passes and
+the successor) for each one in which a lane position held more kept keys than slots (rows sorted by a
+feature, many equal values), and by 1 (the successor pass alone) for each one in which only an upper
+middle value lay beyond its window (a median within 1e-6 of zero, where f32 keys are sparse). Where
+the counting passes say beforehand that the windows hold more keys than the slots are made for (in some
+feature over one row in 2^10, `cluster._pallas_l1.crowded`: values far from zero, many equal ones), the
+gathering pass is told to skip: its op is there, runs empty over one block (under a tenth of a read's time:
+no read to `kmedians_x_reads_per_call`, which takes an op under half the median pass for a sliver), and
+the iteration has 18 reads as before PR 33.
 """,
 }
 

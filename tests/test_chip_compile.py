@@ -268,14 +268,39 @@ def _l1_fit_compiled(n, d, k, x_sharding, mesh=None, axis_name=None, snap=False)
         builder.cache_clear()
 
 
-def _assert_l1_fit_holds_x_alone(compiled, rows, d):
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%(\S+) = .*? custom-call\((.*?)\), custom_call_target=\"tpu_custom_call\"", re.M)
+_DEFINED = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])", re.M)
+
+
+def _kernels(txt: str) -> list:
+    """(name, shapes of the array operands) of the compiled module's Mosaic kernels."""
+    shape_of = dict(_DEFINED.findall(txt))
+    return [(m.group(1), [shape_of.get(op.strip().lstrip("%"), "") for op in m.group(2).split(",")])
+            for m in _CUSTOM_CALL.finditer(txt)]
+
+
+def _assert_l1_fit_holds_x_alone(compiled, rows, d, gathers=True):
     txt = compiled.as_text()
     # the loop's assignment, its counting pass and its successor pass, and the label pass
     assert txt.count("tpu_custom_call") >= 4
     assert "kmedians.assign.pass" in txt and "kmedians.select.pass" in txt  # the names the benchmark reads
     sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
     assert sized == []  # no copy, transpose or cast of X
-    assert compiled.memory_analysis().temp_size_in_bytes < rows * d * 4 // 100
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * d * 4 // 100  # the 75 MB kept lie in fast memory
+    # one op named ``.pass`` is one whole read of X, and no other kernel reads X: the benchmark's
+    # readers count the first by name, and divide their bytes by their time
+    kernels = _kernels(txt)
+    assert len(kernels) == txt.count("tpu_custom_call")
+    for name, operands in kernels:
+        reads_x = f"f32[{d},{rows}]" in operands or f"f32[{rows},{d}]" in operands
+        assert reads_x == (".pass" in name), (name, operands)
+        assert name.startswith(("kmedians.assign.pass", "kmedians.select.pass", "kmedians.select.candidates")), name
+    over_kept = [name for name, _ in kernels if name.startswith("kmedians.select.candidates")]
+    assert bool(over_kept) == gathers
+    if gathers:  # the loop's gathering pass, the counts under ``_N_THR`` x k and under k + 1 thresholds, the successor
+        from heat_tpu.cluster import _pallas_l1 as pl1
+
+        assert len(over_kept) >= 3 and f"s32[{d},{pl1.kept_lanes(rows, d, 8)}]" in txt
 
 
 @pytest.mark.parametrize("snap", [False, True], ids=["kmedians", "kmedoids"])
@@ -283,19 +308,24 @@ def test_l1_fit_at_the_north_star_shard(one_chip, snap):
     """``kmedians-northstar``'s shard, 18 750 000 x 64 f32, k 8 (no multiple
     of 128: the last tile is masked in every kernel): the fit program holds
     ``X``, the label vector and k x d x thresholds of integers, where the
-    masked-``nanmedian`` ``vmap`` asked for 38.4 GB. KMedoids' snap, in
-    XLA, reads ``x.T`` and picks its row by a masked sum (a slice of a row
-    costs a 9.6 GB row-major copy)."""
+    masked-``nanmedian`` ``vmap`` asked for 38.4 GB. Since PR 33 also the
+    gathering pass, the array it keeps (``int32[64, 293888]``, 75 MB) and
+    the ops that finish the selection on it, in both branches of the
+    ``cond``. KMedoids' snap, in XLA, reads ``x.T`` and picks its row by a
+    masked sum (a slice of a row costs a 9.6 GB row-major copy)."""
     _assert_l1_fit_holds_x_alone(_l1_fit_compiled(18_750_000, 64, 8, one_chip, snap=snap), 18_750_000, 64)
 
 
-@pytest.mark.parametrize("n,d,k", [(1_000_003, 8, 2), (1_000_003, 120, 32), (300, 16, 4), (1025, 16, 4)],
-                         ids=["d8_k2", "d120_k32", "short", "two_blocks"])
+@pytest.mark.parametrize("n,d,k", [(1_000_003, 8, 2), (1_000_003, 120, 32), (300, 16, 4), (1025, 16, 4), (4_194_304, 64, 32)],
+                         ids=["d8_k2", "d120_k32", "short", "two_blocks", "k32_gathers"])
 def test_l1_fit_gate_corners(one_chip, n, d, k):
     """The corners of ``l1_passes_serve``: the narrowest and the widest
-    feature-major ``d``, the least and the largest ``k`` the passes serve, fewer rows
-    than one block of the 1-D label vector."""
-    assert _l1_fit_compiled(n, d, k, one_chip).as_text().count("tpu_custom_call") >= 4
+    feature-major ``d``, the least and the largest ``k`` the passes serve
+    (and the largest with the gather: the last cluster's kept keys end at the
+    type's max), fewer rows than one block of the 1-D label vector."""
+    txt = _l1_fit_compiled(n, d, k, one_chip).as_text()
+    assert txt.count("tpu_custom_call") >= 4
+    assert ("kmedians.select.candidates" in txt) == (n >= k << 17)  # ``gather_pays``: d8_k2 and k32_gathers
 
 
 @pytest.mark.parametrize("split", [0, None], ids=["split0", "replicated"])

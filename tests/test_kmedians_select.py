@@ -198,6 +198,291 @@ def test_pallas_passes_on_a_mesh_sum_the_shards_counts(split):
     np.testing.assert_array_equal(got, _median_by_cluster(x, want_labels, k, centers))
 
 
+# --------------------------------------------------------------------- #
+# the selection that ends on the keys one gathering pass keeps (PR 33)   #
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def gather_at_any_size(monkeypatch):
+    """``gather_pays`` asks for 2**17 rows a cluster and device, and
+    ``crowded`` for windows that hold at most one row in 2**10 of a feature;
+    the kernels run here in interpret mode, on a few hundred rows, most of
+    them in a window."""
+    monkeypatch.setattr(pl1, "_GATHER_MIN_ROWS_A_CLUSTER", 0)
+    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", 1)
+    pl1.l1_passes.cache_clear()
+    yield
+    pl1.l1_passes.cache_clear()
+
+
+def _near(rng, shape, span, at=1.0):
+    """f32 values ``at`` + i ulps, i uniform under ``span``: neighbours in
+    key space, so that the window of a ninth bracket (2 ** 15 keys) holds
+    them all."""
+    return (np.float32(at).view(np.int32) + rng.integers(0, span, size=shape).astype(np.int32)).view(np.float32)
+
+
+@pytest.fixture
+def any_bracket_is_a_window(monkeypatch):
+    """A window is a pair's last bracket with ``_WINDOW_MIN_KEYS`` keys: 32.
+    The ``_cores`` put six in a ninth bracket, so that a block of a few
+    thousand rows keeps few enough not to spill."""
+    monkeypatch.setattr(kc, "_WINDOW_MIN_KEYS", 1)
+
+
+def _cores(rng, n, d, k, core=6):
+    """Random labels; in every (cluster, feature) ``core`` values that are
+    neighbours in key space hold the middle ranks, the rest lie far below
+    and far above: few keys are kept, and the medians are among them."""
+    labels = rng.integers(0, k, size=n)
+    x = np.empty((n, d), np.float32)
+    for c in range(k):
+        rows = np.flatnonzero(labels == c)
+        below = (len(rows) - core) // 2
+        for j in range(d):
+            col = np.concatenate([-rng.uniform(100, 1000, size=below), _near(rng, core, 3000, 1.0 + c + j),
+                                  rng.uniform(100, 1000, size=len(rows) - core - below)]).astype(np.float32)
+            x[rows, j] = rng.permutation(col)
+    return x, labels, k
+
+
+def _gather_case(name, rng):
+    """(x, labels, k, where the selection has to end: "kept", "x" or None
+    for either) of one named case of the gather path."""
+    if name in ("odd_counts", "even_counts"):  # all in one window
+        m = 101 if name == "odd_counts" else 100
+        return _near(rng, (3 * m, 5), 3000), np.repeat(np.arange(3), m), 3, "kept"
+    if name == "duplicates_at_the_median":
+        return _near(rng, (400, 4), 6), rng.integers(0, 3, size=400), 3, "kept"
+    if name == "one_empty_cluster":
+        labels = rng.integers(0, 4, size=300)
+        labels[labels == 2] = 0
+        return _near(rng, (300, 7), 3000), labels, 4, "kept"
+    if name in ("even_with_tied_middle", "negative_and_zeros"):  # the second: windows that straddle -0.0 | 0.0
+        return _case(name, rng) + ("kept",)
+    if name == "masked_tail":  # 2500 rows: 20 lane chunks in one masked block
+        return _cores(rng, 2500, 64, 3) + ("kept",)
+    if name == "medians_near_zero":  # where f32 keys are sparse: the window is of the fourth digit, a third of the rows
+        return rng.normal(size=(3 * 401, 5)) + 0.01, np.repeat(np.arange(3), 401), 3, None
+    if name == "window_of_an_earlier_digit":
+        # twenty keys in the ninth bracket (and in the eighth) of the lower middle value, the upper one 2 ** 16 keys
+        # on: the seventh bracket is the last with 32 keys, and its window holds both
+        core = np.float32(1.0).view(np.int32) + np.concatenate([100 * np.arange(20), (1 << 16) + 100 * np.arange(20)])
+        col = np.concatenate([-rng.uniform(100, 1000, size=105), core.astype(np.int32).view(np.float32),
+                              rng.uniform(100, 1000, size=105)]).astype(np.float32)
+        return np.stack([rng.permutation(col) for _ in range(2 * 3)]).reshape(2, 3, 250).transpose(0, 2, 1).reshape(500, 3), \
+            np.repeat(np.arange(2), 250), 2, "kept"
+    if name == "upper_rank_beyond_the_window":  # an even count whose middle values are far apart
+        x = np.concatenate([_near(rng, (50, 3), 3000, 1.0), _near(rng, (50, 3), 3000, 2.0)])
+        return rng.permutation(x), np.zeros(100, int), 1, "x"
+    if name in ("k32_last_cluster_beyond_its_window", "k32_all_on_kept"):
+        # the last of 32 clusters: its kept keys end at the type's max, not at ``32 << 26``. 512 rows fill the four
+        # slots; sixteen keys a pair: the windows are of the fourth digit, [0.5, 8). In the first case the last
+        # cluster's two middle values lie far apart, and the selection has to see its upper rank beyond the window
+        x = _near(rng, (32 * 16, 2), 3000)
+        if name == "k32_last_cluster_beyond_its_window":
+            x[31 * 16 + 8:] = _near(rng, (8, 2), 3000, 100.0)
+        return x, np.repeat(np.arange(32), 16), 32, "x" if name == "k32_last_cluster_beyond_its_window" else "kept"
+    if name == "sorted_column":  # 2000 neighbours in key space, row after row: 16 to a lane position, 4 slots
+        x = (np.float32(1.0).view(np.int32) + np.arange(2000, dtype=np.int32)).view(np.float32)
+        return np.stack([x, x[::-1]], axis=1), rng.integers(0, 2, size=2000), 2, "x"
+    if name == "one_repeated_value":
+        return np.full((2000, 2), 0.5, np.float32), rng.integers(0, 2, size=2000), 2, "x"
+    return _case(name, rng) + (None,)  # wide data: most upper ranks lie beyond their windows
+
+
+GATHER_CASES = ["odd_counts", "even_counts", "duplicates_at_the_median", "one_empty_cluster", "even_with_tied_middle",
+                "negative_and_zeros", "masked_tail", "medians_near_zero", "window_of_an_earlier_digit",
+                "upper_rank_beyond_the_window", "sorted_column",
+                "one_repeated_value", "zero_straddling", "duplicates", "huge_and_tiny", "single_rows",
+                "k32_last_cluster_beyond_its_window", "k32_all_on_kept"]
+
+
+def _ends_where(passes, ends):
+    """``passes`` whose passes over ``X`` say when they run: the digits
+    ended on ``X`` iff all sixteen counting passes ran there, the upper
+    middle value came from ``X`` iff the successor pass ran."""
+
+    def count_below(*a):
+        jax.debug.callback(lambda: ends.append("count"))
+        return passes.count_below(*a)
+
+    def next_above(*a):
+        jax.debug.callback(lambda: ends.append("x"))
+        return passes.next_above(*a)
+
+    return passes._replace(count_below=count_below, next_above=next_above)
+
+
+def _medians_and_end(x, labels, k, prev, passes, counted=None):
+    """The medians, and where the selection ended: "x" where anything of it
+    came from ``X`` after the gathering pass, else "kept". ``counted``, a
+    list, is given the number of counting passes over ``X``."""
+    ends = []
+    run = jax.jit(lambda a, l, p: kc._cluster_medians(a, l, k, p, passes=_ends_where(passes, ends)))
+    got = np.asarray(run(x, labels, prev))
+    jax.effects_barrier()
+    if counted is not None:
+        counted.append(ends.count("count"))
+    return got, "x" if "x" in ends else "kept"
+
+
+@pytest.mark.parametrize("name", GATHER_CASES)
+def test_selection_on_the_kept_keys_gives_numpys_medians(name, gather_at_any_size, request):
+    """Nine digits on ``X``, one gathering pass, the other seven digits and
+    the upper middle value among the kept keys: ``numpy.median`` by cluster.
+    Where a lane position holds more keys than slots, or an upper rank lies
+    beyond its window, the selection ends on ``X`` and gives the same."""
+    if name == "masked_tail":
+        request.getfixturevalue("any_bracket_is_a_window")
+    rng = np.random.default_rng(GATHER_CASES.index(name))
+    x, labels, k, end = _gather_case(name, rng)
+    x = x.astype(np.float32)
+    prev = rng.normal(size=(k, x.shape[1])).astype(np.float32)
+    passes = pl1.l1_passes(k, x.shape, interpret=True)
+    assert passes.gather is not None
+    got, ended = _medians_and_end(x, labels.astype(np.int32), k, prev, passes)
+    with np.errstate(invalid="ignore"):
+        np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
+    assert end in (None, ended)
+
+
+@pytest.mark.parametrize("name, on_x, successor", [("odd_counts", 9, "kept"), ("upper_rank_beyond_the_window", 9, "x"),
+                                                   ("k32_last_cluster_beyond_its_window", 9, "x"), ("sorted_column", 16, "x")])
+def test_only_what_the_kept_keys_lack_comes_from_x(name, on_x, successor, gather_at_any_size):
+    """A spill sends the other seven digits and the successor back to
+    ``X``; an upper rank beyond its window (a median within 1e-6 of zero on
+    the cell's data) only the successor pass: the digits are the kept keys'."""
+    rng = np.random.default_rng(GATHER_CASES.index(name))
+    x, labels, k, _ = _gather_case(name, rng)
+    x, prev, counted = x.astype(np.float32), np.zeros((k, x.shape[1]), np.float32), []
+    got, ended = _medians_and_end(x, labels.astype(np.int32), k, prev, pl1.l1_passes(k, x.shape, interpret=True), counted)
+    np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
+    assert (counted, ended) == ([on_x], successor)
+
+
+def test_window_never_runs_past_the_last_key(gather_at_any_size):
+    """Medians of 2 ** 127 or more: the bracket above the fourth digit's
+    lies past the type's max, and a window that took it in would wrap around
+    to the most negative values. The window is the bracket alone. (Not under
+    ``jit``: compiled, ``0.5 a + 0.5 b`` of such values is ``0.5 (a + b)``,
+    inf, whichever path found them.)"""
+    rng = np.random.default_rng(11)
+    huge = lambda m: (rng.uniform(1.0, 1.9, size=(m, 2)) * 2.0 ** 127).astype(np.float32)
+    x = np.concatenate([rng.permutation(np.concatenate([-huge(8), huge(13)])),
+                        rng.permutation(np.concatenate([-huge(8), huge(12), np.ones((10, 2), np.float32)]))])
+    labels, bits_seen = np.repeat(np.arange(2), [21, 30]).astype(np.int32), []
+    passes = pl1.l1_passes(2, x.shape, interpret=True)
+
+    def gather(arr, lab, base, bits, skip):
+        bits_seen.append(np.asarray(bits))
+        return passes.gather(arr, lab, base, bits, skip)
+
+    got = kc._cluster_medians(jnp.asarray(x), labels, 2, np.zeros((2, 2), np.float32), passes=passes._replace(gather=gather))
+    want = _median_by_cluster(x.astype(np.float64), labels, 2, None).astype(np.float32)  # rounded once, as 0.5 a + 0.5 b is
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(bits_seen, [[[24, 24], [25, 25]]])  # the second cluster's medians: 1.0 and one of the huge
+
+
+def test_gather_folds_steps_onto_blocks_of_slots(gather_at_any_size, any_bracket_is_a_window, monkeypatch):
+    """Twenty grid steps of 256 rows (two lane chunks each, the last one
+    masked) fold onto three blocks of ``_KEPT_SLOTS`` slots: the kept array
+    is the size ``kept_lanes`` says, holds every key of a window once, and
+    the selection ends on it. Told to skip, the pass says "spilled"."""
+    n, d, k = 256 * 19 + 7, 8, 3
+    monkeypatch.setattr(pl1, "_pick_tn", lambda n, d, k8: 256)  # for 2 MiB of X a step it picks 65536 rows at d 8
+    pl1._gather_program.cache_clear()
+    x, labels, _ = _cores(np.random.default_rng(1), n, d, k)
+    lanes = pl1.kept_lanes(n, d, k)
+    assert lanes == 3 * pl1._KEPT_SLOTS * 128
+    passes = pl1.l1_passes(k, (n, d), interpret=True)
+    base = np.full((k, d), np.float32(1.0).view(np.int32), np.int32) + (np.arange(k)[:, None] << 23)
+    bits = np.full((k, d), 15, np.int32)
+    kept, spilled = passes.gather(x, labels.astype(np.int32), base, bits, False)  # 2 ** 15 keys from 1.0, 2.0 and 4.0 on
+    assert kept.shape == (d, lanes) and not bool(spilled)
+    kept = np.asarray(kept)
+    for c in range(k):
+        off = np.asarray(kc._to_key(jnp.asarray(x[labels == c]))) - base[c]
+        for j in range(d):
+            mine = kept[j][kept[j] >> pl1._LABEL_SHIFT == c] & ((1 << pl1._LABEL_SHIFT) - 1)
+            np.testing.assert_array_equal(np.sort(mine), np.sort(off[:, j][(off[:, j] >= 0) & (off[:, j] < 1 << 15)]))
+    ahead, in_window = pl1.kept_by_cluster(passes, kept, k)
+    np.testing.assert_array_equal(in_window, [[((off := kc._to_key(jnp.asarray(x[labels == c, j])) - base[c, j]) >= 0).sum()
+                                               - (off >= 1 << 15).sum() for j in range(d)] for c in range(k)])
+    np.testing.assert_array_equal(ahead, np.cumsum(in_window, axis=0) - in_window)
+    assert bool(passes.gather(x, labels.astype(np.int32), base, bits, True)[1])
+    prev = np.zeros((k, d), np.float32)
+    got, ended = _medians_and_end(x, labels.astype(np.int32), k, prev, passes)
+    np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
+    assert ended == "kept"
+    pl1._gather_program.cache_clear()
+
+
+@pytest.mark.parametrize("data, skips", [("cores", False), ("sorted_column", True), ("one_repeated_value", True),
+                                         ("one_crowded_feature", True)])
+def test_crowded_windows_skip_the_gathering_pass(data, skips, gather_at_any_size, any_bracket_is_a_window, monkeypatch):
+    """The counting passes say how many keys each window's bracket holds:
+    where some feature's hold more than one row in ``_GATHER_MOST_OF_X``
+    (here 64) the gathering pass is told to skip, reads nothing, and the
+    selection ends on ``X``; numpy's medians either way."""
+    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", 64)
+    rng = np.random.default_rng(7)
+    if data in ("cores", "one_crowded_feature"):  # 18 keys a feature in the windows' brackets, of 2500 rows
+        x, labels, k = _cores(rng, 2500, 8, 3)
+        if data == "one_crowded_feature":
+            x[:, 5] = _near(rng, 2500, 3000)
+    else:
+        x, labels, k, _ = _gather_case(data, rng)
+    told = []
+    passes = pl1.l1_passes(k, x.shape, interpret=True)
+
+    def gather(arr, lab, base, bits, skip):
+        jax.debug.callback(lambda s: told.append(bool(s)), skip)
+        return passes.gather(arr, lab, base, bits, skip)
+
+    prev = np.zeros((k, x.shape[1]), np.float32)
+    got, ended = _medians_and_end(x.astype(np.float32), labels.astype(np.int32), k, prev, passes._replace(gather=gather))
+    np.testing.assert_array_equal(got, _median_by_cluster(x.astype(np.float32), labels, k, prev))
+    assert told == [skips] and ended == ("x" if skips else "kept")
+
+
+@pytest.mark.parametrize("data", ["cores", "wide"], ids=["ends_on_kept", "ends_on_x"])
+@pytest.mark.parametrize("split", [0, None], ids=["split0", "replicated"])
+def test_selection_on_the_kept_keys_on_a_mesh(split, data, gather_at_any_size, any_bracket_is_a_window):
+    """Under ``shard_map`` every device gathers the keys of its own rows;
+    the counts among them are ``psum``med, the successors ``pmin``ned, and
+    the spill flag ``pmax``ed, so every device takes the same branch."""
+    comm = ht.MPI_WORLD
+    n, d, k = 8 * 160, 8, 3
+    rng = np.random.default_rng(4)
+    if data == "cores":
+        x, labels, _ = _cores(rng, n, d, k)
+    else:
+        x, labels = rng.normal(size=(n, d)).astype(np.float32), rng.integers(0, k, size=n)
+    prev = rng.normal(size=(k, d)).astype(np.float32)
+    passes = pl1.l1_passes(k, (n, d), comm.mesh, comm.axis_name if split == 0 else None, interpret=True)
+    assert passes.gather is not None
+    xs = jax.device_put(x, comm.sharding(2, split))
+    ls = jax.device_put(labels.astype(np.int32), comm.sharding(1, split))
+    got, ended = _medians_and_end(xs, ls, k, prev, passes)
+    np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
+    assert ended == ("kept" if data == "cores" else "x")
+
+
+def test_gather_pays_from_a_size_on():
+    """Which path a selection takes reads the shape a device holds and the
+    form of the passes only: the ``jax.numpy`` form has no gather, the
+    kernels from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a cluster and device
+    on (what a window keeps does not grow with ``n``)."""
+    assert kc._l1_passes_xla(8).gather is None
+    assert pl1.gather_pays(18_750_000, 8) and pl1.gather_pays(4_687_500, 8) and pl1.gather_pays(4_194_304, 32)
+    assert not pl1.gather_pays(524_288, 8) and not pl1.gather_pays(1_048_576, 16)
+    mesh, axis = ht.MPI_WORLD.mesh, ht.MPI_WORLD.axis_name
+    assert pl1.l1_passes(8, (1 << 20, 64)).gather is not None
+    assert pl1.l1_passes(8, (1 << 19, 64)).gather is None
+    assert pl1.l1_passes(8, (1 << 22, 64), mesh, axis).gather is None  # 2**19 rows a device
+    assert pl1.l1_passes(8, (1 << 20, 64), mesh, None).kept_below is not None  # replicated: all rows on each
+
+
 def test_gate_reads_backend_dtype_shape_and_split_only():
     serves = pl1.l1_passes_serve
     with jax.enable_x64(True):  # Mosaic refuses 64-bit traces
@@ -220,16 +505,19 @@ def _gate_corners(serves):
 # --------------------------------------------------------------------- #
 # nothing of k x n x d                                                   #
 # --------------------------------------------------------------------- #
-def _largest_value(jaxpr) -> int:
-    """Elements of the largest value any equation of ``jaxpr`` (and of the
-    jaxprs inside it) makes."""
-    largest = 0
+def _value_sizes(jaxpr) -> set:
+    """Elements of every value the equations of ``jaxpr`` (and of the
+    jaxprs inside it) make."""
+    sizes = set()
     for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            largest = max(largest, int(np.prod(getattr(v.aval, "shape", ()), dtype=np.int64)))
+        sizes.update(int(np.prod(getattr(v.aval, "shape", ()), dtype=np.int64)) for v in eqn.outvars)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            largest = max(largest, _largest_value(sub))
-    return largest
+            sizes |= _value_sizes(sub)
+    return sizes
+
+
+def _largest_value(jaxpr) -> int:
+    return max(_value_sizes(jaxpr), default=0)
 
 
 @pytest.mark.parametrize("est", ["kmedians", "kmedoids"])
@@ -243,6 +531,31 @@ def test_fit_program_holds_no_k_fold_copy_of_x(est):
     a, c = jax.ShapeDtypeStruct((n, d), jnp.float32), jax.ShapeDtypeStruct((k, d), jnp.float32)
     assert _largest_value(jax.make_jaxpr(prog.program)(a, c).jaxpr) <= n * d
     assert prog.program.lower(a, c).compile().memory_analysis().temp_size_in_bytes < k * n * d * 4
+
+
+@pytest.mark.parametrize("est", ["kmedians", "kmedoids"])
+def test_fit_program_on_the_kernels_keeps_a_sixtieth_of_x(est):
+    """The fit program on the Pallas passes at the cell's shard, traced and
+    not run: the kept array is ``int32[d, kept_lanes]``, 8 x 128 lanes for
+    every 8 x 8192 rows (1.6 % of ``X``: 75 MB of 4.8 GB), and no value is
+    larger than ``X``."""
+    n, d, k = 18_750_000, 64, 8
+    assert pl1.kept_lanes(n, d, k) == 287 * 1024  # 2289 steps of 8192 rows, eight to a block
+    passes = pl1.l1_passes(k, (n, d))
+
+    def step(arr, centers):
+        labels, counts, _ = passes.assign(arr, centers)
+        new = kc._cluster_medians(arr, labels, k, centers, counts, passes)
+        if est == "kmedoids":
+            new = kc._snap_to_members(arr, labels, k, new, counts, centers)
+        return new, jnp.sum((new - centers) ** 2)
+
+    step.assign = lambda arr, centers: passes.assign(arr, centers)[::2]
+    a, c = jax.ShapeDtypeStruct((n, d), jnp.float32), jax.ShapeDtypeStruct((k, d), jnp.float32)
+    with jax.enable_x64(False):  # the chip's policy: Mosaic refuses 64-bit traces
+        jaxpr = jax.make_jaxpr(kc.make_fit_loop(step, "float32", 0.0, 5, False))(a, c).jaxpr
+    assert _largest_value(jaxpr) == n * d
+    assert d * pl1.kept_lanes(n, d, k) in _value_sizes(jaxpr) and 64 * d * pl1.kept_lanes(n, d, k) < 1.01 * n * d
 
 
 # --------------------------------------------------------------------- #

@@ -834,6 +834,7 @@ def test_l1_fit_counts_its_form_and_scopes_its_phases(est):
             ht.telemetry.reset()  # later tests of this file read the builders' counters from zero
     assert after.get(f"{name}.step.select.xla", 0) - before.get(f"{name}.step.select.xla", 0) == 2
     assert after.get(f"{name}.step.select.pallas", 0) == before.get(f"{name}.step.select.pallas", 0)
+    assert after.get(f"{name}.step.select.gather", 0) == before.get(f"{name}.step.select.gather", 0)  # the kernels' alone
     step = _kcluster._l1_step(name, 3, (248, 8), "float32", 0, ht.MPI_WORLD.mesh, ht.MPI_WORLD.axis_name,
                               est == "KMedoids")
     a, c = jax.ShapeDtypeStruct((248, 8), jnp.float32), jax.ShapeDtypeStruct((3, 8), jnp.float32)
