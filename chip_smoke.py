@@ -44,6 +44,7 @@ REAL = dict(
     chain_n=67_108_864,            # 256 MB f32 per elementwise pass
     mm_n=8192,                     # MXU-saturating square matmul
     hsvd=(65536, 8192), rank=10,   # 2.1 GB: the headline per-chip shard
+    qr=(524288, 1024),             # 2.1 GB: tall-skinny, one size under the benchmark's cell
     km=(15_625_000, 64), km_k=8,   # 4 GB: 1B x 64 over a v5e-64
     sort_n=1 << 24,
     mlp_batch=8192, mlp_steps=5,
@@ -54,6 +55,7 @@ TOY = dict(
     chain_n=1 << 14,
     mm_n=256,
     hsvd=(2048, 256), rank=10,
+    qr=(4096, 64),
     km=(20_000, 64), km_k=8,
     sort_n=1 << 14,
     mlp_batch=256, mlp_steps=5,
@@ -363,6 +365,58 @@ def hsvd(rec: dict) -> None:
              f"{label}: no tpu_custom_call in the compiled hsvd_rank program — the Pallas sketch kernel did not run")
         need(not rec[label]["copy_of_a_in_hlo"],
              f"{label}: the compiled hsvd_rank program casts all of A to bf16[{m},{n}] — a pass more than the schedule has")
+
+
+# QR: max |Q^T Q - I| and ||A - Q R||_F / ||A||_F, R against a plain fold relative
+# to its largest entry. The limits are the benchmark configuration's (PERF.md, PR 34).
+QR_TOL = {"orthonormal": 5e-5, "residual": 3e-5, "r": 2e-4}
+QR_BLOCK = 8192
+
+
+def qr(rec: dict) -> None:
+    """``ht.linalg.qr`` of a tall-skinny uniform matrix (BASELINE.json's config
+    3 with ``hsvd``), against plain ``jax.numpy``: the factors' own invariants
+    a row block at a time, and ``R`` from a fold of Householder QRs of ``[R;
+    block]`` with its diagonal made positive."""
+    m, n = SZ["qr"]
+    blk = min(QR_BLOCK, m)
+    need(m % blk == 0, f"qr: {m} rows are no multiple of the check's block {blk}")
+    aj = jax.random.uniform(key(35), (m, n), jnp.float32)
+    a = ht.array(aj, split=0)
+    (q, r), first, warm = timed(lambda: ht.linalg.qr(a))
+    hlo = compiled_text(lambda a_: ht.linalg.qr(a_), a)
+
+    def positive(x):
+        return x * jnp.where(jnp.diagonal(x) < 0, -1.0, 1.0)[:, None]
+
+    @jax.jit
+    def errors(aj, qj, rj):
+        def fold(i, c):
+            r_ref, gram, resid_sq, norm_sq = c
+            ab, qb = jax.lax.dynamic_slice_in_dim(aj, i * blk, blk), jax.lax.dynamic_slice_in_dim(qj, i * blk, blk)
+            with jax.default_matmul_precision("highest"):
+                r_ref = jnp.linalg.qr(jnp.concatenate([r_ref, ab]), mode="r")
+            gram = gram + jnp.matmul(qb.T, qb, precision=HI)
+            resid_sq = resid_sq + jnp.sum(jnp.square(ab - jnp.matmul(qb, rj, precision=HI)))
+            return r_ref, gram, resid_sq, norm_sq + jnp.sum(jnp.square(ab))
+
+        zero = jnp.zeros((n, n), jnp.float32)
+        r_ref, gram, resid_sq, norm_sq = jax.lax.fori_loop(0, m // blk, fold, (zero, zero, 0.0, 0.0))
+        r_ref = positive(r_ref)
+        return {"orthonormal": jnp.max(jnp.abs(gram - jnp.eye(n))), "residual": jnp.sqrt(resid_sq / norm_sq),
+                "r": jnp.max(jnp.abs(positive(rj) - r_ref)) / jnp.max(jnp.abs(r_ref)),
+                "below_diagonal": jnp.max(jnp.abs(jnp.tril(rj, -1)))}
+
+    e = {k: float(v) for k, v in errors(aj, q.larray, r.larray).items()}
+    gram_form = "cholesky" in hlo.lower()
+    rec.update(shape=[m, n], **times(first, warm), **e, tol=QR_TOL["orthonormal"], tols=QR_TOL,
+               max_err=e["orthonormal"],
+               path="qr.local: Cholesky-QR with a second pass (MXU products over row blocks)" if gram_form
+               else "qr.local: XLA's Householder QR (no TPU backend)")
+    need(gram_form or not ON_CHIP, "qr: the compiled program has no Cholesky: the Gram form did not run on the chip")
+    need(e["below_diagonal"] == 0.0, f"qr: R has {e['below_diagonal']:.3e} below its diagonal")
+    for name, tol in QR_TOL.items():
+        check(f"qr {name}", e[name], tol)
 
 
 def blobs(n: int, d: int, k: int, kk):
@@ -747,7 +801,7 @@ def main(argv=None) -> int:
     if ARGS.chips == 4:
         four_chips()
     else:
-        for name, fn in (("dispatch", dispatch), ("matmul", matmul), ("hsvd", hsvd), ("kmeans", kmeans),
+        for name, fn in (("dispatch", dispatch), ("matmul", matmul), ("hsvd", hsvd), ("qr", qr), ("kmeans", kmeans),
                          ("sort", sort), ("train_step", train), ("attention", attention),
                          ("dispatch.native_complex64", native_complex)):
             phase(name, fn)

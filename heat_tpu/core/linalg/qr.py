@@ -20,6 +20,13 @@ else is local MXU work, the whole thing one XLA program. The reference's
 ``tiles_per_proc`` knob tuned CPU cache blocking; XLA tiles for the MXU
 itself, so the knob is accepted for API parity and ignored.
 
+The factorization of one device's own rows (the whole call on one device,
+level 0 of TSQR on many) is ``_local_qr``: where ``_gram_serves`` says so (a
+TPU, f32 or f64, at least twice as many rows as columns) it is the Gram form
+``_gram_qr``, whose work over the tall operand is matrix products for the MXU
+(a Cholesky-QR with a second pass; shifted repair steps decided on the device
+for what a Gram matrix cannot factor); elsewhere XLA's Householder QR.
+
 Pad-safety: TSQR runs on the physical (zero-padded) array — zero rows
 contribute zero R rows, so R is exact; Q's pad rows are re-masked to zero
 afterwards (see ``_padding``).
@@ -43,11 +50,243 @@ from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
+from ...observability import telemetry as _telemetry
 from ...observability.instrument import observed_program_cache
+from ...observability.tracing import span as _span
 
 __all__ = ["qr"]
 
 QR = collections.namedtuple("QR", "Q, R")
+
+
+# --------------------------------------------------------------------- #
+# the factorization of one device's own rows                             #
+# --------------------------------------------------------------------- #
+_HI = jax.lax.Precision.HIGHEST
+# The MXU runs an f32 product as one, three or six bf16 passes. What each
+# tall product of the Gram form needs (PERF.md, PR 34, has the chip's readings):
+# - the first Gram matrix only preconditions: any R1 gives A = Q1 R1 as exactly
+#   as the product A R1^-1 is computed, and what R1 leaves of Q1^T Q1 - I the
+#   second pass measures and repairs;
+# - the products that make a Q_k, and the Gram matrix that the last Cholesky
+#   factors, decide the residual and the orthogonality: six passes;
+# - the last product is Q + Q (R^-1 - I) with R^-1 - I small.
+_TALL = collections.namedtuple("_TALL", "gram_first, apply, gram, finish")
+_TALL_PRECISION = _TALL(jax.lax.Precision.HIGH, _HI, _HI, jax.lax.Precision.HIGH)
+_BLOCK_BYTES = 32 << 20  # a row block of the tall passes: 8192 x 1024 f32
+_ORTH_OK = 0.1  # ||Q^T Q - I||_F up to which one unshifted Cholesky step leaves Q orthonormal to rounding
+_MAX_REPAIRS = 8  # repair steps at most: each gains a factor of about 1 / sqrt(n eps) in the condition number
+_LADDER = 12  # shifts tried: 0, then n eps max(diag G) times 1, 10, ..., 1e10
+
+
+def _gram_serves(m: int, n: int, dtype) -> bool:
+    """Does the Gram form factor an ``m x n`` block of ``dtype`` here? On a
+    TPU (its products are what the MXU is for; on a CPU LAPACK's Householder
+    QR is the better one), in f32 or f64, from twice as many rows as columns
+    (the block the repair perturbs is ``2n x n``)."""
+    return (
+        jax.default_backend() == "tpu"
+        and np.dtype(dtype) in (np.dtype(np.float32), np.dtype(np.float64))
+        and n >= 1
+        and m >= 2 * n
+    )
+
+
+def _row_blocks(m: int, n: int, itemsize: int) -> Tuple[int, int]:
+    """(rows of a block, whole blocks): ``_BLOCK_BYTES`` a block, rows a multiple of 8."""
+    b = min(m, max(8, _BLOCK_BYTES // (n * itemsize) // 8 * 8))
+    return b, m // b
+
+
+def _gram_of(x, precision):
+    """``x^T x``: with ``_times`` every product over a tall operand."""
+    return jax.lax.dot_general(x, x, (((0,), (0,)), ((), ())), precision=precision)
+
+
+def _times(x, w, precision):
+    return jnp.matmul(x, w, precision=precision)
+
+
+def _gram(a, precision):
+    """``A^T A``, a row block at a time (the sum of the blocks' products)."""
+    m, n = a.shape
+    b, nb = _row_blocks(m, n, a.dtype.itemsize)
+    if b == m:  # one block: no loop
+        return _gram_of(a, precision)
+    g = jax.lax.fori_loop(
+        0, nb,
+        lambda i, g: g + _gram_of(jax.lax.dynamic_slice_in_dim(a, i * b, b), precision),
+        jnp.zeros((n, n), a.dtype),
+    )
+    return g + _gram_of(a[nb * b:], precision) if m > nb * b else g
+
+
+def _apply(src, q, w, precision, gram_precision=None, finish=False):
+    """``q <- src w`` a row block at a time, in place where ``src`` is ``q``
+    (``q`` None: a new array), and with ``gram_precision`` the Gram matrix of
+    the new ``q`` from the same blocks. ``finish``: ``w`` is close to the
+    identity, so the product is ``src + src (w - I)``."""
+    m, n = src.shape
+    b, nb = _row_blocks(m, n, src.dtype.itemsize)
+    in_place = src is q
+    if finish:
+        w = w - jnp.eye(n, dtype=w.dtype)
+    want_gram = gram_precision is not None
+
+    def one(blk):
+        x = _times(blk, w, precision)
+        x = blk + x if finish else x
+        return x, (_gram_of(x, gram_precision) if want_gram else None)
+
+    g0 = jnp.zeros((n, n), src.dtype) if want_gram else None
+    if b == m:  # one block: no loop
+        x, g = one(src)
+        return x, g
+    if q is None:
+        q = jnp.zeros_like(src)
+
+    def step(i, carry):
+        q, g = carry
+        x, gx = one(jax.lax.dynamic_slice_in_dim(q if in_place else src, i * b, b))
+        return jax.lax.dynamic_update_slice_in_dim(q, x, i * b, 0), (g + gx if want_gram else g)
+
+    q, g = jax.lax.fori_loop(0, nb, step, (q, g0))
+    if m > nb * b:
+        x, gx = one((q if in_place else src)[nb * b:])
+        q = jax.lax.dynamic_update_slice_in_dim(q, x, nb * b, 0)
+        g = g + gx if want_gram else g
+    return q, g
+
+
+def _factor(g):
+    """``(L, shifted)`` with ``L L^T = G + s I`` for the least shift of the
+    ladder 0, c, 10 c, ... (c = n eps max(diag G)) whose Cholesky factor is
+    finite with no pivot under half of max(c, s): a pivot at the level of
+    the rounding of G is noise, and what it divides would be too."""
+    n = g.shape[0]
+    eps = jnp.finfo(g.dtype).eps
+    eye = jnp.eye(n, dtype=g.dtype)
+    c = jnp.maximum(n * eps * jnp.max(jnp.diagonal(g)), jnp.finfo(g.dtype).tiny * 2.0 ** 40)
+
+    def attempt(j):
+        s = jnp.where(j == 0, 0.0, c * 10.0 ** (j - 1).astype(g.dtype)).astype(g.dtype)
+        low = jnp.linalg.cholesky(g + s * eye)
+        d = jnp.diagonal(low)
+        return low, jnp.all(jnp.isfinite(low)) & (jnp.min(d * d) >= 0.5 * jnp.maximum(c, s))
+
+    def again(state):
+        j, _, ok = state
+        return ~ok & (j < _LADDER)
+
+    def nxt(state):
+        j = state[0] + 1
+        return (j, *attempt(j))
+
+    j, low, _ = jax.lax.while_loop(again, nxt, (jnp.int32(0), *attempt(jnp.int32(0))))
+    return low, j > 0
+
+
+def _upper_inverse(low):
+    """``R^-1`` of ``R = L^T``, upper triangular."""
+    eye = jnp.eye(low.shape[0], dtype=low.dtype)
+    return jnp.triu(jax.lax.linalg.triangular_solve(low, eye, left_side=True, lower=True, transpose_a=True))
+
+
+def _perturb(q, g, amp):
+    """``q`` with ``amp`` x a fixed Gaussian ``2n x n`` block added to its
+    first rows (spectral norm ``amp`` x 1: about ``(sqrt(2n) + sqrt(n))`` x
+    the entries' size), and its Gram matrix. ``amp`` 0 changes nothing. A
+    direction in which ``q`` has no component at all (a zero or repeated
+    column) no Cholesky step can scale up; after this it has one of about
+    0.2 ``amp``, and ``A + E = Q R`` with ``||E|| <= amp ||R||``: a backward
+    error at the level of the precision."""
+    n = g.shape[0]
+    top = q[: 2 * n]
+    noise = jax.random.normal(jax.random.key(0), top.shape, q.dtype) * (amp / ((2 * n) ** 0.5 + n ** 0.5))
+    cross = jnp.matmul(top.T, noise, precision=_HI)
+    g = g + cross + cross.T + _gram_of(noise, _HI)
+    return jax.lax.dynamic_update_slice_in_dim(q, top + noise, 0, 0), g
+
+
+def _gram_qr(a, calc_q: bool = True, tall: _TALL = _TALL_PRECISION):
+    """Thin QR of a tall block by Cholesky steps on Gram matrices.
+
+    ``R1 = chol(A^T A)``, ``Q1 = A R1^-1``, ``R2 = chol(Q1^T Q1)``, ``Q = Q1
+    R2^-1``, ``R = R2 R1`` (Cholesky-QR with a second pass): every flop over
+    the tall operand is a matrix product, a row block at a time, ``Q`` made in
+    place in its own array, nothing else of ``A``'s size. That is right while
+    ``cond(A)^2 eps`` is well under 1. What it cannot factor it sees on the
+    device, with no host read: a Cholesky factor that breaks down or has a
+    pivot at the rounding level takes the least shift of a ladder
+    (``_factor``), and while the Gram matrix of ``Q_k`` is a shifted one or
+    is further than ``_ORTH_OK`` from the identity, one more step ``Q_k <-
+    Q_k R_k^-1`` runs (a ``while_loop``; none on well-conditioned input). A
+    ``Q_k`` whose own Gram matrix still needs a shift has directions with next
+    to nothing in them (rank-deficient to rounding, which no Cholesky step can
+    scale up): ``_perturb`` gives them something, once. So
+    ``Q`` is orthonormal and ``A = Q R`` to rounding for every finite input;
+    ``R`` is upper triangular with exact zeros below a positive diagonal."""
+    n = a.shape[1]
+    eps = jnp.finfo(a.dtype).eps
+    with jax.named_scope("qr.tall.gram"):
+        g = _gram(a, tall.gram_first)
+    with jax.named_scope("qr.small.factor"):
+        low, _ = _factor(g)
+        r = low.T
+        w = _upper_inverse(low)
+    with jax.named_scope("qr.tall.apply"):
+        q, g = _apply(a, None, w, tall.apply, tall.gram)
+
+    def probe(g):
+        low, shifted = _factor(g)
+        off = jnp.sqrt(jnp.sum(jnp.square(g - jnp.eye(n, dtype=g.dtype))))
+        return low, shifted, shifted | ~(off <= _ORTH_OK)
+
+    def more(state):
+        return state[-1] & (state[3] < _MAX_REPAIRS)
+
+    def repair(state):
+        q, g, r, k, perturbed, _, shifted, _ = state
+        with jax.named_scope("qr.small.repair"):
+            amp = jnp.where(shifted & ~perturbed, 8.0 * eps, 0.0).astype(q.dtype)
+            q, g = _perturb(q, g, amp)
+            low, _ = _factor(g)
+            r = jnp.triu(jnp.matmul(low.T, r, precision=_HI))
+            w = _upper_inverse(low)
+        with jax.named_scope("qr.tall.repair"):
+            q, g = _apply(q, q, w, tall.apply, tall.gram)
+        with jax.named_scope("qr.small.repair"):
+            return (q, g, r, k + 1, perturbed | (amp > 0), *probe(g))
+
+    with jax.named_scope("qr.small.factor"):
+        state = (q, g, r, jnp.int32(0), jnp.bool_(False), *probe(g))
+    q, g, r, _, _, low, _, _ = jax.lax.while_loop(more, repair, state)
+    with jax.named_scope("qr.small.factor"):
+        r = jnp.triu(jnp.matmul(low.T, r, precision=_HI))
+        w = _upper_inverse(low)
+    if not calc_q:
+        return None, r
+    with jax.named_scope("qr.tall.finish"):
+        q, _ = _apply(q, q, w, tall.finish, finish=True)
+    return q, r
+
+
+def _local_qr(a, calc_q: bool = True):
+    """Thin QR of one device's ``m x n`` rows, ``m >= n``, real floating, as a
+    traceable function: ``(Q or None, R)``. The one-device program
+    (``_local_qr_fn``) and level 0 of ``_tsqr_kernel`` both call it; the form
+    follows backend, dtype and shape (``_gram_serves``)."""
+    if _gram_serves(a.shape[0], a.shape[1], a.dtype):
+        return _gram_qr(a, calc_q)
+    if calc_q:
+        return jnp.linalg.qr(a, mode="reduced")
+    return None, jnp.linalg.qr(a, mode="r")
+
+
+@observed_program_cache("qr.local")
+def _local_qr_fn(m: int, n: int, jdtype: str, calc_q: bool):
+    """The one-device (or replicated) call as one jitted program."""
+    return jax.jit(lambda a: _local_qr(a, calc_q))
 
 
 def _tsqr_group_size(p: int) -> int:
@@ -140,9 +379,12 @@ def _tsqr_kernel(p: int, axis_name: str, calc_q: bool, ring: bool = False, topo=
             return _cm.ring_all_gather(x, axis_name, size, pos, perm, pipelined=True)
 
     def kernel(a):
-        # a: local shard (lrows, cols)
-        q1, r1 = jnp.linalg.qr(a, mode="reduced")
-        k = q1.shape[1]
+        # a: local shard (lrows, cols); a shard wider than tall keeps XLA's QR
+        if a.shape[0] >= a.shape[1] and not jnp.iscomplexobj(a):
+            q1, r1 = _local_qr(a, calc_q)
+        else:
+            q1, r1 = jnp.linalg.qr(a, mode="reduced")
+        k = r1.shape[0]
         if not two_level:
             i = jax.lax.axis_index(axis_name)
             if ring:
@@ -221,96 +463,103 @@ def qr(
     ``tiles_per_proc`` is accepted for reference-API parity; XLA performs
     its own MXU tiling.
     """
-    sanitize_in(a)
-    if a.ndim != 2:
-        raise ValueError(f"qr requires a 2-dimensional array, got {a.ndim}")
-    if not isinstance(calc_q, bool):
-        raise TypeError(f"calc_q must be a bool, got {type(calc_q)}")
-    if not isinstance(tiles_per_proc, (int, np.integer)) or isinstance(tiles_per_proc, bool):
-        raise TypeError(f"tiles_per_proc must be an int, got {type(tiles_per_proc)}")
-    if tiles_per_proc != 1:
-        import warnings
+    with _span("ht.call.qr"):
+        with _span("ht.call.qr.prepare"):
+            sanitize_in(a)
+            if a.ndim != 2:
+                raise ValueError(f"qr requires a 2-dimensional array, got {a.ndim}")
+            if not isinstance(calc_q, bool):
+                raise TypeError(f"calc_q must be a bool, got {type(calc_q)}")
+            if not isinstance(tiles_per_proc, (int, np.integer)) or isinstance(tiles_per_proc, bool):
+                raise TypeError(f"tiles_per_proc must be an int, got {type(tiles_per_proc)}")
+            if tiles_per_proc != 1:
+                import warnings
 
-        # reference code tunes this against CPU cache blocking; here XLA
-        # owns MXU tiling — a silent no-op would surprise ported callers
-        warnings.warn(
-            "tiles_per_proc is accepted for reference-API parity but has no "
-            "effect: XLA performs its own MXU tiling (TSQR replaces tiled CAQR)",
-            UserWarning,
-            stacklevel=2,
-        )
-    if not isinstance(overwrite_a, bool):
-        raise TypeError(f"overwrite_a must be a bool, got {type(overwrite_a)}")
+                # reference code tunes this against CPU cache blocking; here XLA
+                # owns MXU tiling — a silent no-op would surprise ported callers
+                warnings.warn(
+                    "tiles_per_proc is accepted for reference-API parity but has no "
+                    "effect: XLA performs its own MXU tiling (TSQR replaces tiled CAQR)",
+                    UserWarning,
+                    stacklevel=2,
+                )
+            if not isinstance(overwrite_a, bool):
+                raise TypeError(f"overwrite_a must be a bool, got {type(overwrite_a)}")
 
-    dtype = a.dtype
-    if types.heat_type_is_exact(dtype):
-        dtype = types.float32
-    jt = dtype.jax_type()
-    m, n = a.shape
-    comm: MeshCommunication = a.comm
+            dtype = a.dtype
+            if types.heat_type_is_exact(dtype):
+                dtype = types.float32
+            jt = dtype.jax_type()
+            m, n = a.shape
+            comm: MeshCommunication = a.comm
 
-    # TSQR applies to tall matrices (m >= n): the stacked R merge is then a
-    # strict reduction and R comes out (n, n); wide matrices take the
-    # gathered XLA path
-    use_tsqr = a.split == 0 and comm.is_distributed() and m >= n and n <= 4096
+            # TSQR applies to tall matrices (m >= n): the stacked R merge is then a
+            # strict reduction and R comes out (n, n); wide matrices take the
+            # gathered XLA path
+            use_tsqr = a.split == 0 and comm.is_distributed() and m >= n and n <= 4096
+            # one device's own rows (or every device's copy of them): the local factorization
+            local = (
+                not use_tsqr
+                and (a.split is None or not comm.is_distributed())
+                and m >= n >= 1
+                and not jnp.issubdtype(jt, jnp.complexfloating)
+                and not a._is_planar
+            )
+            arr = a._phys.astype(jt) if use_tsqr else a.larray.astype(jt)
 
-    if use_tsqr:
-        phys = a._phys.astype(jt)
-        lrows = phys.shape[0] // comm.size
-        topo_t = comm.topology
-        fn = _tsqr_fn(
-            comm.mesh, comm.axis_name, lrows, n, np.dtype(jt).name, calc_q,
-            ring=_tsqr_ring_active(),
-            topo=(topo_t.n_slices, topo_t.chips_per_slice) if topo_t.tiered else None,
-        )
-        if calc_q:
-            q_phys, r = fn(phys)
-            # restore the zero-pad invariant on Q (see module docstring)
-            q_phys = _padding.mask_phys(q_phys, (m, q_phys.shape[1]), 0)
-            k = int(q_phys.shape[1])
-            q_arr = DNDarray(q_phys, (m, k), dtype, 0, a.device, comm)
+        if use_tsqr:
+            lrows = arr.shape[0] // comm.size
+            topo_t = comm.topology
+            fn = _tsqr_fn(
+                comm.mesh, comm.axis_name, lrows, n, np.dtype(jt).name, calc_q,
+                ring=_tsqr_ring_active(),
+                topo=(topo_t.n_slices, topo_t.chips_per_slice) if topo_t.tiered else None,
+            )
+            _count_form(lrows, n, jt)
+            if calc_q:
+                q_phys, r = fn(arr)
+                # restore the zero-pad invariant on Q (see module docstring)
+                q_phys = _padding.mask_phys(q_phys, (m, q_phys.shape[1]), 0)
+                k = int(q_phys.shape[1])
+                q_arr = DNDarray(q_phys, (m, k), dtype, 0, a.device, comm)
+            else:
+                r = fn(arr)
+                q_arr = None
+            r_arr = DNDarray(
+                _place(r, comm.sharding(2, None)), tuple(int(s) for s in r.shape), dtype, None, a.device, comm
+            )
+            return QR(q_arr, r_arr)
+
+        if local:
+            _count_form(m, n, jt)
+            q, r = _local_qr_fn(m, n, np.dtype(jt).name, calc_q)(arr)
+        elif calc_q:
+            # split=1, wide or complex: XLA QR on the logical global array (GSPMD
+            # partitions the panel updates; the reference's split=1 loop at
+            # qr.py:858 broadcasts panels rank-by-rank instead)
+            q, r = jnp.linalg.qr(arr, mode="reduced")
         else:
-            r = fn(phys)
-            q_arr = None
-        r_arr = DNDarray(
-            _place(r, comm.sharding(2, None)), tuple(int(s) for s in r.shape), dtype, None, a.device, comm
-        )
-        return QR(q_arr, r_arr)
+            q, r = None, jnp.linalg.qr(arr, mode="r")
+        with _span("ht.call.qr.wrap"):
+            r_split = 1 if a.split == 1 else None
+            r_arr = DNDarray(
+                comm.shard(r, r_split) if r_split is not None else r,
+                tuple(int(s) for s in r.shape), dtype, r_split, a.device, comm,
+            )
+            if q is None:
+                return QR(None, r_arr)
+            q_arr = DNDarray(
+                comm.shard(q, a.split) if a.split is not None else q,
+                tuple(int(s) for s in q.shape), dtype, a.split, a.device, comm,
+            )
+            return QR(q_arr, r_arr)
 
-    # split=1 / replicated: XLA QR on the logical global array (GSPMD
-    # partitions the panel updates; the reference's split=1 loop at
-    # qr.py:858 broadcasts panels rank-by-rank instead)
-    arr = a.larray.astype(jt)
-    if calc_q:
-        q, r = jnp.linalg.qr(arr, mode="reduced")
-        q_gshape = tuple(int(s) for s in q.shape)
-        r_gshape = tuple(int(s) for s in r.shape)
-        q_split = a.split
-        q_arr = DNDarray(
-            comm.shard(q, q_split) if q_split is not None else q,
-            q_gshape,
-            dtype,
-            q_split,
-            a.device,
-            comm,
-        )
-        r_split = 1 if a.split == 1 else None
-        r_arr = DNDarray(
-            comm.shard(r, r_split) if r_split is not None else r,
-            r_gshape,
-            dtype,
-            r_split,
-            a.device,
-            comm,
-        )
-        return QR(q_arr, r_arr)
-    r = jnp.linalg.qr(arr, mode="r")
-    r_gshape = tuple(int(s) for s in r.shape)
-    r_split = 1 if a.split == 1 else None
-    r_arr = DNDarray(
-        comm.shard(r, r_split) if r_split is not None else r, r_gshape, dtype, r_split, a.device, comm
-    )
-    return QR(None, r_arr)
+
+def _count_form(rows: int, n: int, jt) -> None:
+    """Which form of the local factorization this call's program has (what a
+    repair did is on the device, and in the trace)."""
+    if rows >= n:
+        _telemetry.inc("qr.local.gram" if _gram_serves(rows, n, jt) else "qr.local.householder")
 
 
 DNDarray.qr = qr

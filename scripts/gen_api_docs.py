@@ -79,6 +79,8 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 | `ht.call.kmeans.fit`, `ht.call.kmeans.predict` | `KMeans.fit` (the fused fit), `predict` | `host_wrapper_ms_per_call` |
 | `ht.call.kmeans.init`, `.program`, `.wrap` | initial centres or seed key; lookup of the step and the fused program and the call; placement and the two `DNDarray`s (shared by `KMedians`/`KMedoids.fit`) | `host_wrapper_ms_per_call` |
 | `ht.call.kmedians.fit`, `ht.call.kmedoids.fit` | `KMedians.fit`, `KMedoids.fit` (the fused fit: the three spans above nest in it) | `host_wrapper_ms_per_call` |
+| `ht.call.qr` | the whole public `ht.linalg.qr` call (since PR 34) | `host_wrapper_ms_per_call` (self time) |
+| `ht.call.qr.prepare`, `.wrap` | `sanitize_in`, checks, dtype, `astype`, which path; the `DNDarray`s of `Q` and `R` and their placement. Between them the lookup and call of the program (`qr.local` on one device or a replicated array, `qr.tsqr` on a split one): the program spans nest in `ht.call.qr` itself | `host_wrapper_ms_per_call` |
 | `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
 | `ht.program.hit` | entered right after a lookup that a builder's `lru_cache` served (`cache=` names the builder) | `host_launch_ms_per_call` |
 | `ht.program.miss` | a lookup that built; the builder's time | `program_cache_misses`, `host_launch_ms_per_call` |
@@ -89,7 +91,7 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`, `.miss`,
 `.build`, `.compile` for every observed builder (`op.binary`, `op.unary`, `op.reduce`, `op.cum`,
 `hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`, `hsvd.dist_rank`,
-`hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `kmeans.lloyd_step`,
+`hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `qr.local`, `kmeans.lloyd_step`,
 `kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`), `ht.jit.cache.hit`/`.miss`,
 `comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`, and `hsvd.pass2.one_dot` /
 `hsvd.pass2.tiled` (which form of the two-pass sketch's second pass a program was built with: one
@@ -105,6 +107,20 @@ and split: the Pallas pass that reads f32 `X` once an iteration, or XLA's two st
 for an operator's `ht.telemetry.report()`, read by no benchmark metric. On the device the pass is
 named `kmeans_lloyd_pass` (the kernel) under `jax.named_scope("kmeans.lloyd_pass")`: a trace's op
 line and the ledger's `breakdown.device_ops` show it by that name.
+
+`ht.linalg.qr` (since PR 34): once per call on a tall real array the counter `qr.local.gram` /
+`qr.local.householder` says which form of the local factorization the call's program has
+(`core.linalg.qr._gram_serves`, from backend, dtype and shape: on a TPU, f32 or f64, from twice as many
+rows as columns, a Cholesky-QR with a second pass whose tall work is matrix products; elsewhere XLA's
+Householder QR), on one device (`qr.local`) and as level 0 of TSQR (`qr.tsqr`) alike. Whether the Gram
+form's repair steps ran (ill-conditioned or rank-deficient input) is decided on the device and shows in
+a device trace only: the ops of the program lie under `jax.named_scope`s `qr.tall.gram`,
+`qr.tall.apply`, `qr.tall.finish` (products over row blocks of `A` and `Q`) and `qr.small.factor`
+(Cholesky factors, triangular inverses, products of `R` factors), a repair step's under
+`qr.tall.repair` / `qr.small.repair`, which a run on well-conditioned data never executes. The
+benchmark's readers `qr_tall_ms_per_call` and `qr_small_ms_per_call` tell the two kinds apart by the
+shapes in an op's instruction text (an extent over the configuration's `cols` is tall), and
+`qr_mxu_roofline_pct` holds the call's device time against the Householder flop count at the bf16 peak.
 
 The L1 family (since PR 32): once per `KMedians.fit` / `KMedoids.fit` the counter
 `kmedians.step.select.pallas` / `.xla` (`kmedoids.step.select.*`) says which form of the passes

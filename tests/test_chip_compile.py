@@ -400,6 +400,15 @@ def _dots_below_highest(hlo: str) -> list:
     ]
 
 
+def _dots_at(hlo: str, precision: str) -> list:
+    """Result shapes of the dots whose operands are both at ``precision``
+    (``"default"``: the instruction states none)."""
+    want = f"operand_precision={{{precision},{precision}}}"
+    lines = ((m.group(1), hlo[m.start(): hlo.index("\n", m.end())]) for m in _DOT.finditer(hlo))
+    return [shape for shape, line in lines
+            if (want in line if precision != "default" else "operand_precision=" not in line)]
+
+
 @pytest.mark.parametrize("tsqr", [False, True], ids=["gather_merge", "tsqr_merge"])
 def test_hsvd_dist_rank_program_four_chips(mesh4, monkeypatch, tsqr):
     """The one program of ``hsvd_rank`` on a split-0 array over four chips,
@@ -427,7 +436,42 @@ def test_hsvd_dist_rank_program_four_chips(mesh4, monkeypatch, tsqr):
     for copy in (f"bf16[{rows},{n}]", f"f32[{n},{rows}]", f"bf16[{n},{rows}]"):
         assert copy not in txt
     assert compiled.memory_analysis().temp_size_in_bytes < rows * n  # a quarter of the block's bytes
-    assert _dots_below_highest(txt) == [f"f32[{rows},{l}]"]
+    below = _dots_below_highest(txt)
+    if tsqr:
+        # level 0 of the merge's TSQR is the Gram form since PR 34: its first Gram matrix (a
+        # preconditioner) and its last product (Q + Q (R^-1 - I)) run at three bf16 passes, by design
+        assert sorted(below) == sorted([f"f32[{rows},{l}]", "f32[60,60]", "f32[2048,60]"])
+        below = [shape for shape in below if shape not in _dots_at(txt, "high")]
+    assert below == [f"f32[{rows},{l}]"]
+
+
+@pytest.mark.parametrize("calc_q", [True, False], ids=["with_q", "r_only"])
+def test_local_qr_gram_form_at_the_cells_shape(one_chip, monkeypatch, calc_q):
+    """``ht.linalg.qr``'s one-device program at the benchmark's shape
+    (1048576 x 1024 f32, ``qr-northstar``): the Gram form, no product over
+    the tall operand at one bf16 pass, ``Q`` made in place in its own array
+    (the output) with no temporary of ``A``'s size beside it: the harness
+    holds the last call's ``Q`` through the next call, so ``A``, two ``Q``s
+    and this program's temporaries have to fit in 16 GB."""
+    import importlib
+
+    qr = importlib.import_module("heat_tpu.core.linalg.qr")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m, n = 1048576, 1024
+    qr._local_qr_fn.cache_clear()
+    try:
+        compiled = qr._local_qr_fn(m, n, "float32", calc_q).lower(
+            jax.ShapeDtypeStruct((m, n), F32, sharding=one_chip)).compile()
+    finally:
+        qr._local_qr_fn.cache_clear()
+    txt = compiled.as_text()
+    assert "cholesky" in txt.lower()
+    assert len(_DOT.findall(txt)) >= 4  # Gram, apply, Gram, finish, and the small ones
+    assert _dots_at(txt, "default") == []
+    mem = compiled.memory_analysis()
+    a_bytes = m * n * 4
+    # with Q: the output; without: one working array of A's size, nothing more
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < a_bytes + (256 << 20)
 
 
 def test_tsqr_q_updates_at_highest(mesh4):

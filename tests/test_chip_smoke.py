@@ -16,7 +16,7 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(ROOT, "chip_smoke.py")
-ONE_CHIP = ["dispatch", "matmul", "hsvd", "kmeans", "sort", "train_step", "attention",
+ONE_CHIP = ["dispatch", "matmul", "hsvd", "qr", "kmeans", "sort", "train_step", "attention",
             "dispatch.native_complex64"]
 FOUR_CHIPS = ["mesh.hsvd", "mesh.resplit", "mesh.sort", "mesh.matmul", "mesh.train_step"]
 

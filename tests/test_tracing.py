@@ -745,6 +745,28 @@ def _hsvd_split_staged():
     )
 
 
+def _qr_one_device():
+    """``ht.linalg.qr`` on a replicated array: one observed program (``qr.local``)."""
+    importlib.import_module("heat_tpu.core.linalg.qr")._local_qr_fn.cache_clear()
+    a = ht.random.randn(331, 24, split=None)
+    return (
+        lambda: ht.linalg.qr(a),
+        {"ht.call.qr", "ht.call.qr.prepare", "ht.call.qr.wrap"},
+        "ht.call.qr",
+    )
+
+
+def _qr_split():
+    """On a split array: TSQR (``qr.tsqr``), whose level 0 is the same local factorization."""
+    importlib.import_module("heat_tpu.core.linalg.qr")._tsqr_fn.cache_clear()
+    a = ht.random.randn(8 * 331, 24, split=0)
+    return (
+        lambda: ht.linalg.qr(a),
+        {"ht.call.qr", "ht.call.qr.prepare", "ht.comm.place"},
+        "ht.call.qr",
+    )
+
+
 def _kmeans_fit():
     from heat_tpu.cluster import _kcluster, kmeans
 
@@ -782,7 +804,7 @@ def _l1_fit(est):
 
 @pytest.mark.skipif(P < 2, reason="the split path needs a real mesh")
 @pytest.mark.parametrize("case", [_hsvd_one_device, _hsvd_split, _hsvd_split_staged, _kmeans_fit,
-                                  _l1_fit("KMedians"), _l1_fit("KMedoids")],
+                                  _l1_fit("KMedians"), _l1_fit("KMedoids"), _qr_one_device, _qr_split],
                          ids=lambda f: f.__name__.strip("_"))
 def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     """One call that misses and one that hits, under a profiler session and
@@ -801,8 +823,10 @@ def test_profiler_trace_holds_the_spans_of_the_call(case, tmp_path):
     assert "ht.program.miss" in in_first and "ht.program.compile" in in_first
     assert "ht.program.miss" not in in_second and "ht.program.compile" not in in_second
     assert "ht.program.hit" in in_second and "ht.program.launch" in in_second
-    if root in ("ht.call.kmedians.fit", "ht.call.kmedoids.fit"):
-        assert in_second.count("ht.program.launch") == 1  # the whole L1 fit is one program
+    if root in ("ht.call.kmedians.fit", "ht.call.kmedoids.fit", "ht.call.qr"):
+        assert in_second.count("ht.program.launch") == 1  # the whole L1 fit, and the whole QR, is one program
+    if case is _qr_one_device:
+        assert not [n for n in in_second if n.startswith("ht.op.")]
     if case is _hsvd_split:
         # the whole split call is ONE launch, with no op, shard or reshard beside it
         assert in_second.count("ht.program.launch") == 1
@@ -845,6 +869,38 @@ def test_l1_fit_counts_its_form_and_scopes_its_phases(est):
         arr, passes.assign(arr, cen)[0], 3, cen, passes=passes)).trace(
             jax.ShapeDtypeStruct((256, 8), jnp.float32), c).jaxpr
     assert "kmedians.assign.pass" in str(lowered) and "kmedians.select.pass" in str(lowered)
+
+
+def test_qr_counts_its_form_scopes_its_phases_and_is_in_the_contract():
+    """``qr.local.householder`` (here: no TPU) or ``.gram`` once a call on a tall
+    real array, with the builder's own counters; the Gram form's device ops
+    lie under ``qr.tall.*`` / ``qr.small.*``; every name is in ``docs/API.md``."""
+    qr = importlib.import_module("heat_tpu.core.linalg.qr")
+    qr._local_qr_fn.cache_clear()
+    a = ht.random.randn(331, 24, split=None)
+    was = ht.telemetry.enabled()
+    ht.telemetry.enable()
+    try:
+        before = ht.telemetry.snapshot()["counters"]
+        for _ in range(2):
+            ht.linalg.qr(a)
+        ht.linalg.qr(ht.random.randn(8 * 40, 24, split=0), calc_q=False)  # level 0 of TSQR counts too
+        after = ht.telemetry.snapshot()["counters"]
+    finally:
+        if not was:
+            ht.telemetry.disable()
+            ht.telemetry.reset()
+    grew = {k: after.get(k, 0) - before.get(k, 0) for k in
+            ("qr.local.householder", "qr.local.gram", "qr.local.miss", "qr.local.hit")}
+    assert grew == {"qr.local.householder": 3, "qr.local.gram": 0, "qr.local.miss": 1, "qr.local.hit": 1}
+    text = jax.jit(qr._gram_qr).lower(jax.ShapeDtypeStruct((512, 24), jnp.float32)).as_text(debug_info=True)
+    scopes = ("qr.tall.gram", "qr.tall.apply", "qr.tall.finish", "qr.tall.repair", "qr.small.factor", "qr.small.repair")
+    assert all(scope in text for scope in scopes)
+    with open(os.path.join(ROOT, "docs", "API.md")) as f:
+        contract = f.read()
+    for name in ("`ht.call.qr`", "`ht.call.qr.prepare`", "`qr.local`", "`qr.local.gram`", "`qr.local.householder`",
+                 *(f"`{scope}`" for scope in scopes)):
+        assert name in contract, name
 
 
 def test_profiler_trace_holds_predict_and_op_spans(tmp_path):
@@ -928,6 +984,7 @@ def _observed_builders():
         "hsvd.staged_rank_tail": svdtools._staged_rank_tail_fn,
         "hsvd.staged_oneview_tail": svdtools._staged_oneview_tail_fn,
         "qr.tsqr": qr._tsqr_fn,
+        "qr.local": qr._local_qr_fn,
         "kmeans.lloyd_step": kmeans._lloyd_step,
         "kmeans.partial_fit_step": kmeans._partial_fit_step,
         "kcluster.fused_fit": _kcluster._fused_fit_program,
@@ -937,8 +994,8 @@ def _observed_builders():
 
 @pytest.mark.parametrize("name", [
     "op.binary", "op.unary", "op.reduce", "op.cum", "hsvd.sketched_rank", "hsvd.one_view_rank", "hsvd.sketched",
-    "hsvd.local_svd", "hsvd.staged_rank_tail", "hsvd.staged_oneview_tail", "qr.tsqr", "kmeans.lloyd_step",
-    "kmeans.partial_fit_step", "kcluster.fused_fit", "kcluster.predict",
+    "hsvd.local_svd", "hsvd.staged_rank_tail", "hsvd.staged_oneview_tail", "qr.tsqr", "qr.local",
+    "kmeans.lloyd_step", "kmeans.partial_fit_step", "kcluster.fused_fit", "kcluster.predict",
 ])
 def test_observed_builder_keeps_the_lru_cache_surface(name):
     builder = _observed_builders()[name]
