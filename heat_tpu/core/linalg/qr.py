@@ -46,6 +46,7 @@ from typing import Optional, Tuple, Union
 
 from .. import types
 from .. import _padding
+from . import _pallas_qr
 from jax import shard_map as _shard_map
 from ..communication import MeshCommunication
 from ..dndarray import DNDarray
@@ -67,12 +68,20 @@ _HI = jax.lax.Precision.HIGHEST
 # tall product of the Gram form needs (PERF.md, PR 34, has the chip's readings):
 # - the first Gram matrix only preconditions: any R1 gives A = Q1 R1 as exactly
 #   as the product A R1^-1 is computed, and what R1 leaves of Q1^T Q1 - I the
-#   second pass measures and repairs;
+#   second pass measures and repairs: three passes;
 # - the products that make a Q_k, and the Gram matrix that the last Cholesky
 #   factors, decide the residual and the orthogonality: six passes;
-# - the last product is Q + Q (R^-1 - I) with R^-1 - I small.
+# - the last product is Q + Q (R^-1 - I) with R^-1 - I small: three.
+# Where they run: as the two kernels of _pallas_qr where _panels_serve says so
+# (six passes as Mosaic's HIGHEST, three as sums over bf16 parts the kernel
+# splits off itself), else as XLA's whole products at these precisions
+# (_gram_of, _times). The kernels skip
+# the blocks of a Gram matrix under its diagonal blocks and those of R^-1 there:
+# the first are what the mirror holds, the second exact zeros (_upper_inverse
+# ends in triu), so what is left out is no approximation.
 _TALL = collections.namedtuple("_TALL", "gram_first, apply, gram, finish")
 _TALL_PRECISION = _TALL(jax.lax.Precision.HIGH, _HI, _HI, jax.lax.Precision.HIGH)
+_PASSES = {jax.lax.Precision.DEFAULT: 1, jax.lax.Precision.HIGH: 3, _HI: 6}  # the bf16 passes of an f32 product
 _BLOCK_BYTES = 32 << 20  # a row block of the tall passes: 8192 x 1024 f32
 _ORTH_OK = 0.1  # ||Q^T Q - I||_F up to which one unshifted Cholesky step leaves Q orthonormal to rounding
 _MAX_REPAIRS = 8  # repair steps at most: each gains a factor of about 1 / sqrt(n eps) in the condition number
@@ -92,6 +101,21 @@ def _gram_serves(m: int, n: int, dtype) -> bool:
     )
 
 
+def _panels_serve(m: int, n: int, dtype) -> bool:
+    """Do the tall products of an ``m x n`` block of ``dtype`` run as the
+    kernels of ``_pallas_qr``, which do only the blocks that symmetry and the
+    triangle leave? On a TPU with x64 off (Mosaic refuses 64-bit traces), in
+    f32, at the shapes the kernels take (``_pallas_qr.serves``: ``n`` a
+    multiple of 128 up to 1024, more than one of their row blocks). Every
+    other input keeps XLA's whole products (``_gram_of``, ``_times``)."""
+    return (
+        jax.default_backend() == "tpu"
+        and not jax.config.jax_enable_x64
+        and np.dtype(dtype) == np.dtype(np.float32)
+        and _pallas_qr.serves(m, n)
+    )
+
+
 def _row_blocks(m: int, n: int, itemsize: int) -> Tuple[int, int]:
     """(rows of a block, whole blocks): ``_BLOCK_BYTES`` a block, rows a multiple of 8."""
     b = min(m, max(8, _BLOCK_BYTES // (n * itemsize) // 8 * 8))
@@ -108,8 +132,11 @@ def _times(x, w, precision):
 
 
 def _gram(a, precision):
-    """``A^T A``, a row block at a time (the sum of the blocks' products)."""
+    """``A^T A``, a row block at a time (the sum of the blocks' products; the
+    kernel's where ``_panels_serve`` says so)."""
     m, n = a.shape
+    if _panels_serve(m, n, a.dtype):
+        return _pallas_qr.gram(a, _PASSES[precision])
     b, nb = _row_blocks(m, n, a.dtype.itemsize)
     if b == m:  # one block: no loop
         return _gram_of(a, precision)
@@ -125,13 +152,18 @@ def _apply(src, q, w, precision, gram_precision=None, finish=False):
     """``q <- src w`` a row block at a time, in place where ``src`` is ``q``
     (``q`` None: a new array), and with ``gram_precision`` the Gram matrix of
     the new ``q`` from the same blocks. ``finish``: ``w`` is close to the
-    identity, so the product is ``src + src (w - I)``."""
+    identity, so the product is ``src + src (w - I)``. ``w`` is upper
+    triangular: where ``_panels_serve`` says so the kernel does its blocks on
+    and over the diagonal alone."""
     m, n = src.shape
-    b, nb = _row_blocks(m, n, src.dtype.itemsize)
     in_place = src is q
     if finish:
         w = w - jnp.eye(n, dtype=w.dtype)
     want_gram = gram_precision is not None
+    if _panels_serve(m, n, src.dtype):
+        return _pallas_qr.apply(src, w, _PASSES[precision], gram_passes=_PASSES[gram_precision] if want_gram else None,
+                                finish=finish, in_place=in_place)
+    b, nb = _row_blocks(m, n, src.dtype.itemsize)
 
     def one(blk):
         x = _times(blk, w, precision)
@@ -214,7 +246,10 @@ def _gram_qr(a, calc_q: bool = True, tall: _TALL = _TALL_PRECISION):
     ``R1 = chol(A^T A)``, ``Q1 = A R1^-1``, ``R2 = chol(Q1^T Q1)``, ``Q = Q1
     R2^-1``, ``R = R2 R1`` (Cholesky-QR with a second pass): every flop over
     the tall operand is a matrix product, a row block at a time, ``Q`` made in
-    place in its own array, nothing else of ``A``'s size. That is right while
+    place in its own array, nothing else of ``A``'s size; where
+    ``_panels_serve`` says so the products are kernels that do only the blocks
+    the Gram matrices' symmetry and the triangle of ``R^-1`` leave and write
+    ``Q`` themselves (``_pallas_qr``). That is right while
     ``cond(A)^2 eps`` is well under 1. What it cannot factor it sees on the
     device, with no host read: a Cholesky factor that breaks down or has a
     pivot at the rounding level takes the least shift of a ladder
@@ -559,7 +594,10 @@ def _count_form(rows: int, n: int, jt) -> None:
     """Which form of the local factorization this call's program has (what a
     repair did is on the device, and in the trace)."""
     if rows >= n:
-        _telemetry.inc("qr.local.gram" if _gram_serves(rows, n, jt) else "qr.local.householder")
+        gram = _gram_serves(rows, n, jt)
+        _telemetry.inc("qr.local.gram" if gram else "qr.local.householder")
+        if gram:
+            _telemetry.inc("qr.tall.kernel" if _panels_serve(rows, n, jt) else "qr.tall.xla")
 
 
 DNDarray.qr = qr

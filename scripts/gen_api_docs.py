@@ -121,6 +121,13 @@ a device trace only: the ops of the program lie under `jax.named_scope`s `qr.tal
 benchmark's readers `qr_tall_ms_per_call` and `qr_small_ms_per_call` tell the two kinds apart by the
 shapes in an op's instruction text (an extent over the configuration's `cols` is tall), and
 `qr_mxu_roofline_pct` holds the call's device time against the Householder flop count at the bf16 peak.
+Since PR 35 a call in the Gram form also counts `qr.tall.kernel` / `qr.tall.xla`: which form the
+products over the tall operand have in its program (`core.linalg.qr._panels_serve`, from backend, dtype
+and shape: on a TPU with x64 off, f32, `n` a multiple of 128 up to 1024, more than one of the kernels'
+row blocks, the two Pallas kernels of `core/linalg/_pallas_qr.py`, which do only the blocks that the
+Gram matrix's symmetry and the triangle of `R^-1` leave; elsewhere XLA's whole products). A device
+trace shows the kernels by name: `qr.tall.gram` (the first Gram matrix) and `qr.tall.apply` (`Q1 = A
+R1^-1` with the Gram matrix of `Q1`, the repair step's product, the finish), under the scopes above.
 
 The L1 family (since PR 32): once per `KMedians.fit` / `KMedoids.fit` the counter
 `kmedians.step.select.pallas` / `.xla` (`kmedoids.step.select.*`) says which form of the passes

@@ -448,11 +448,14 @@ def test_hsvd_dist_rank_program_four_chips(mesh4, monkeypatch, tsqr):
 @pytest.mark.parametrize("calc_q", [True, False], ids=["with_q", "r_only"])
 def test_local_qr_gram_form_at_the_cells_shape(one_chip, monkeypatch, calc_q):
     """``ht.linalg.qr``'s one-device program at the benchmark's shape
-    (1048576 x 1024 f32, ``qr-northstar``): the Gram form, no product over
-    the tall operand at one bf16 pass, ``Q`` made in place in its own array
-    (the output) with no temporary of ``A``'s size beside it: the harness
-    holds the last call's ``Q`` through the next call, so ``A``, two ``Q``s
-    and this program's temporaries have to fit in 16 GB."""
+    (1048576 x 1024 f32, ``qr-northstar``): the Gram form with its products
+    over the tall operand as the two kernels of ``_pallas_qr`` (a custom call
+    hides its dots: ``tests/test_qr_local.py`` reads their terms), no XLA
+    product left over it and none anywhere at one bf16 pass, ``Q`` written
+    by the kernels themselves into its own array (the output: no memset of
+    it, no copy of it or of a block) with no temporary of ``A``'s size beside
+    it: the harness holds the last call's ``Q`` through the next call, so
+    ``A``, two ``Q``s and this program's temporaries have to fit in 16 GB."""
     import importlib
 
     qr = importlib.import_module("heat_tpu.core.linalg.qr")
@@ -466,8 +469,14 @@ def test_local_qr_gram_form_at_the_cells_shape(one_chip, monkeypatch, calc_q):
         qr._local_qr_fn.cache_clear()
     txt = compiled.as_text()
     assert "cholesky" in txt.lower()
-    assert len(_DOT.findall(txt)) >= 4  # Gram, apply, Gram, finish, and the small ones
+    kernels = [re.sub(r"\.\d+$", "", name) for name, _ in _kernels(txt)]
+    # first Gram; apply with the second Gram; the same in the repair loop; the finish
+    assert sorted(set(kernels)) == ["qr.tall.apply", "qr.tall.gram"] and len(kernels) == (4 if calc_q else 3), kernels
     assert _dots_at(txt, "default") == []
+    assert not [shape for shape in _DOT.findall(txt) if str(m) in shape or "[8192," in shape], "a product over the tall operand outside the kernels"
+    tall = f"f32[{m},{n}]"
+    assert not re.search(rf"= {re.escape(tall)}\S* (broadcast|copy)\(", txt), "a memset or a copy of Q"
+    assert not re.search(r"= f32\[8192,1024\]\S* ", txt), "a block sliced out of Q"
     mem = compiled.memory_analysis()
     a_bytes = m * n * 4
     # with Q: the output; without: one working array of A's size, nothing more

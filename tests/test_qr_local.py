@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 import heat_tpu as ht
 from heat_tpu.core.communication import MeshCommunication
+from test_qr_kernels import K, kernels_form, small_blocks  # noqa: F401 (the kernels' module; fixtures: _gram_qr's tall products as the interpreted kernels)
 
 Q = importlib.import_module("heat_tpu.core.linalg.qr")  # the package exports the function under that name
 ht.use_x64()  # a CPU world runs x64: settle the policy before the first jax.numpy call here makes a float64
@@ -316,40 +317,83 @@ def test_non_finite_input_ends_and_says_so():
 # --------------------------------------------------------------------- #
 # the precision of the tall products                                     #
 # --------------------------------------------------------------------- #
-def _dots(jaxpr, found):
+def _dots(jaxpr, found, kernels):
+    """The ``dot_general``s of a jaxpr outside any kernel, and each kernel's
+    (``pallas_call``) own, in program order."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general":
             found.append(eqn)
+        elif eqn.primitive.name == "pallas_call":
+            kernels.append((eqn.params["name"], _dots(eqn.params["jaxpr"], [], [])[0]))
+            continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _dots(sub, found)
-    return found
+            _dots(sub, found, kernels)
+    return found, kernels
 
 
-def test_no_tall_product_runs_at_one_bf16_pass():
+def _stated(eqn):
+    precision = eqn.params["precision"]
+    return set(precision) if isinstance(precision, tuple) else {precision}
+
+
+FORMS = ["whole_products", "kernels"]
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_no_tall_product_runs_at_one_bf16_pass(form, request):
     """On the chip an f32 product at ``Precision.DEFAULT`` is one bf16 pass.
-    Every product over an operand with the block's rows states more."""
-    n = 64
-    found = _dots(jax.make_jaxpr(Q._gram_qr)(jnp.ones((4096, n), jnp.float32)).jaxpr, [])
+    Every product over an operand with the block's rows states more: XLA's
+    whole products by their precision, the kernels', which split their
+    operands into bf16 parts themselves, by the terms they add up for each
+    panel: at least three for the first Gram matrix and the finish, six for
+    the apply and the Gram matrix of what it made."""
+    kernels = form == "kernels"
+    if kernels:
+        request.getfixturevalue("kernels_form")
+    n = 256 if kernels else 64
+    found, in_kernels = _dots(jax.make_jaxpr(lambda v: Q._gram_qr(v))(jnp.ones((4096, n), jnp.float32)).jaxpr, [], [])
     tall = [e for e in found if any(d > 2 * n for v in e.invars for d in v.aval.shape)]
-    assert len(tall) >= 4  # Gram, apply, Gram, finish
     for e in tall:
-        precision = e.params["precision"]
-        passes = set(precision) if isinstance(precision, tuple) else {precision}
-        assert passes <= {jax.lax.Precision.HIGH, jax.lax.Precision.HIGHEST}, e
+        assert _stated(e) <= {jax.lax.Precision.HIGH, jax.lax.Precision.HIGHEST}, e
+    if not kernels:
+        assert len(tall) >= 4 and not in_kernels  # Gram, apply, Gram, finish
+        return
+    assert not tall, "every product over the tall operand is in a kernel"
+    assert [name for name, _ in in_kernels] == ["qr.tall.gram", "qr.tall.apply", "qr.tall.apply", "qr.tall.apply"]
+    panels = n // K._PANEL
+    passes = []
+    for _, dots in in_kernels:  # first Gram; apply and Gram; the same in the repair loop; finish
+        paid = 0
+        for e in dots:  # a dot of bf16 parts is one pass, Mosaic's HIGHEST on f32 operands six
+            f32 = [v.aval.dtype == jnp.float32 for v in e.invars]
+            assert (not any(f32)) or (all(f32) and _stated(e) == {jax.lax.Precision.HIGHEST}), e
+            paid += 6 if all(f32) else 1
+        assert paid % panels == 0
+        passes.append(paid // panels)
+    assert passes[0] >= 3 and passes[-1] >= 3 and all(p >= 6 + 6 for p in passes[1:-1]), passes
 
 
-def test_one_bf16_pass_would_miss_the_limits(monkeypatch):
-    """The same program with its tall products on operands rounded to bf16
-    (what one MXU pass multiplies; the CPU ignores ``precision``): the
+@pytest.mark.parametrize("form", FORMS)
+def test_one_bf16_pass_would_miss_the_limits(form, request, monkeypatch):
+    """The same program with its tall products at one bf16 pass: the whole
+    products on operands rounded to bf16 (what one MXU pass multiplies; the
+    CPU ignores ``precision``), the kernels with one part an operand. The
     orthogonality limit of every case above catches it."""
     def bf16(x):
         return x.astype(jnp.bfloat16).astype(x.dtype)
 
+    if form == "kernels":
+        request.getfixturevalue("kernels_form")
+    # one pass, whichever form the products take
     monkeypatch.setattr(Q, "_gram_of", lambda x, p: jax.lax.dot_general(bf16(x), bf16(x), (((0,), (0,)), ((), ()))))
     monkeypatch.setattr(Q, "_times", lambda x, w, p: jnp.matmul(bf16(x), bf16(w)))
-    a = seeded(4096, 64)
+    monkeypatch.setattr(Q, "_PASSES", dict.fromkeys(Q._PASSES, 1))
+    n = 128 if form == "kernels" else 64
+    a = seeded(4096, n)
+    jaxpr = str(jax.make_jaxpr(lambda v: Q._gram_qr(v))(jnp.asarray(a)))
+    assert ("pallas_call" in jaxpr) == (form == "kernels")
     q, r = (np.asarray(x, np.float64) for x in jax.jit(lambda v: Q._gram_qr(v))(jnp.asarray(a)))
-    orth = np.abs(q.T @ q - np.eye(64)).max()
+    orth = np.abs(q.T @ q - np.eye(n)).max()
     resid = np.linalg.norm(a - q @ r) / np.linalg.norm(a)
     eps = np.finfo(np.float32).eps
     assert orth > ORTH * eps or resid > RESIDUAL * eps, (orth, resid)
