@@ -37,7 +37,7 @@ from ..core.sanitation import sanitize_in
 from ..core.communication import place as _place
 from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
-from ..observability.tracing import span as _span
+from ..observability.tracing import call_span as _call_span, span as _span
 from . import _pallas_l1
 from ._pallas_l1 import _N_THR, _NARROW_ON_X, _RADIX_BITS, _WINDOW_FIRST_DIGIT, _WINDOW_MIN_KEYS, _from_key, _key_type, _to_key
 
@@ -526,7 +526,8 @@ class _KCluster(BaseEstimator, ClusteringMixin):
         if self._inertia is None:
             return None
         if not isinstance(self._inertia, float):
-            self._inertia = float(self._inertia)
+            with _span("ht.sync.read", what="inertia_"):
+                self._inertia = float(self._inertia)
         return self._inertia
 
     @property
@@ -535,7 +536,8 @@ class _KCluster(BaseEstimator, ClusteringMixin):
         if self._n_iter is None:
             return None
         if not isinstance(self._n_iter, int):
-            self._n_iter = int(self._n_iter)
+            with _span("ht.sync.read", what="n_iter_"):
+                self._n_iter = int(self._n_iter)
         return self._n_iter
 
     # ------------------------------------------------------------------ #
@@ -698,7 +700,7 @@ class _KCluster(BaseEstimator, ClusteringMixin):
         """Labels of the closest cluster center for new data (reference:
         _kcluster.py predict). One fused program dispatch (see
         ``_predict_program``)."""
-        with _span("ht.call.kmeans.predict"):
+        with _call_span("ht.call.kmeans.predict"):
             sanitize_in(x)
             if self._cluster_centers is None:
                 raise RuntimeError("fit needs to be called before predict")

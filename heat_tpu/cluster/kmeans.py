@@ -37,7 +37,7 @@ from ._kcluster import _KCluster
 from ..core.communication import place as _place
 from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
-from ..observability.tracing import span as _span
+from ..observability.tracing import call_span as _call_span
 from . import _pallas
 
 __all__ = ["KMeans"]
@@ -233,7 +233,7 @@ class KMeans(_KCluster):
                         "streaming window path, which HEAT_TPU_OOC=0 "
                         "disables — unset the gate or drop ckpt="
                     )
-                with _span("ht.call.kmeans.fit"):
+                with _call_span("ht.call.kmeans.fit"):
                     x = _staging.materialize(x, what="KMeans.fit")
                     return self._fit_fused(x, _lloyd_step_for(x), returns_inertia=True)
             return self._partial_fit_stream(
@@ -246,7 +246,7 @@ class KMeans(_KCluster):
                 "stream a staging.HostArray (or drive partial_fit batches) "
                 "to checkpoint mid-fit"
             )
-        with _span("ht.call.kmeans.fit"):
+        with _call_span("ht.call.kmeans.fit"):
             return self._fit_fused(x, _lloyd_step_for(x), returns_inertia=True)
 
     # ------------------------------------------------------------------ #

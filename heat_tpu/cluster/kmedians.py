@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Optional, Union
 
 from ..core.dndarray import DNDarray
-from ..observability.tracing import span as _span
+from ..observability.tracing import call_span as _call_span
 from ._kcluster import _KCluster, l1_step_for
 
 __all__ = ["KMedians"]
@@ -61,5 +61,5 @@ class KMedians(_KCluster):
         """Seeding + convergence loop + assignment as ONE compiled program
         (see ``_kcluster._fused_fit_program``); ``inertia_`` is the sum of
         the L1 distances to the final centres, from the label pass."""
-        with _span("ht.call.kmedians.fit"):
+        with _call_span("ht.call.kmedians.fit"):
             return self._fit_fused(x, l1_step_for(x, "kmedians"), returns_inertia=False)

@@ -103,7 +103,7 @@ class Spectral(BaseEstimator, ClusteringMixin):
         order = np.argsort(eval_)
         eval_, evec = eval_[order], evec[:, order]
         # approximate eigenvectors of L
-        emb = V.larray @ jnp.asarray(evec.astype(np.asarray(V.larray).dtype))
+        emb = V.larray @ jnp.asarray(evec.astype(V.larray.dtype))
         embedding = DNDarray(
             V.comm.shard(emb, 0 if x.split is not None else None) if x.split is not None else emb,
             tuple(int(s) for s in emb.shape),

@@ -62,6 +62,7 @@ from . import types
 from . import _padding
 from .dndarray import DNDarray
 from .stride_tricks import broadcast_shape, sanitize_axis
+from ..observability.tracing import span as _span
 
 __all__ = []
 
@@ -369,12 +370,13 @@ def from_host_complex(np_data: np.ndarray, split, device, comm) -> DNDarray:
 def host_complex(x: DNDarray) -> np.ndarray:
     """Planar DNDarray -> host complex64 ndarray (pad sliced off)."""
     arr = x._planar_phys
-    if jax.process_count() > 1 and not arr.is_fully_addressable:
-        from jax.experimental import multihost_utils
+    with _span("ht.sync.read", what="host_complex"):
+        if jax.process_count() > 1 and not arr.is_fully_addressable:
+            from jax.experimental import multihost_utils
 
-        host = np.asarray(multihost_utils.process_allgather(arr, tiled=True))
-    else:
-        host = np.asarray(jax.device_get(arr))
+            host = np.asarray(multihost_utils.process_allgather(arr, tiled=True))
+        else:
+            host = np.asarray(jax.device_get(arr))
     host = host[tuple(slice(0, s) for s in x.gshape)]  # plane axis kept
     return assemble_host(host)
 
@@ -914,7 +916,8 @@ def array_factory(obj, split, is_split, ndmin, order, device, comm) -> DNDarray:
     if isinstance(obj, DNDarray):
         np_data = host_complex(obj) if obj._is_planar else np.asarray(obj.numpy())
     elif isinstance(obj, jax.Array):
-        np_data = np.asarray(jax.device_get(obj))
+        with _span("ht.sync.read", what="array_factory"):
+            np_data = np.asarray(jax.device_get(obj))
     else:
         np_data = np.asarray(obj, order=order)
     np_data = np.asarray(np_data, dtype=np.complex64, order=order)

@@ -36,6 +36,7 @@ from .devices import Device
 from .stride_tricks import sanitize_axis
 from ..observability import events as _obs_events
 from ..observability import telemetry as _telemetry
+from ..observability.tracing import span as _span
 
 __all__ = ["DNDarray"]
 
@@ -442,23 +443,26 @@ class DNDarray:
         sliced off). In multi-process mode the array spans non-addressable
         devices; the host copy comes from a cross-process allgather (the
         analog of the reference's Allgatherv in resplit(None)). Shared by
-        numpy()/cpu() so no caller can forget the pad slice."""
+        numpy()/cpu() so no caller can forget the pad slice. The one
+        ``ht.sync.read`` span of ``numpy``, ``__array__``, ``tolist``,
+        ``item`` and the scalar casts."""
         if self.__planar:
             from . import complex_planar as _cp
 
-            return _cp.host_complex(self)
-        arr = self.__array
-        if self.__dtype is types.bfloat16:
-            arr = arr.astype(jnp.float32)
-        if jax.process_count() > 1 and not arr.is_fully_addressable:
-            from jax.experimental import multihost_utils
+            return _cp.host_complex(self)  # its own ht.sync.read
+        with _span("ht.sync.read", what="host_logical"):
+            arr = self.__array
+            if self.__dtype is types.bfloat16:
+                arr = arr.astype(jnp.float32)
+            if jax.process_count() > 1 and not arr.is_fully_addressable:
+                from jax.experimental import multihost_utils
 
-            host = np.asarray(multihost_utils.process_allgather(arr, tiled=True))
-        else:
-            host = np.asarray(jax.device_get(arr))
-        if host.shape != tuple(self.__gshape):
-            host = host[tuple(slice(0, s) for s in self.__gshape)]
-        return host
+                host = np.asarray(multihost_utils.process_allgather(arr, tiled=True))
+            else:
+                host = np.asarray(jax.device_get(arr))
+            if host.shape != tuple(self.__gshape):
+                host = host[tuple(slice(0, s) for s in self.__gshape)]
+            return host
 
     def numpy(self) -> np.ndarray:
         """Global array as numpy (reference dndarray.py:1168: resplit(None)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from . import types
 from . import _operations
 from .dndarray import DNDarray
+from ..observability.tracing import span as _span
 from .sanitation import sanitize_in
 
 __all__ = ["nonzero", "where"]
@@ -42,7 +43,8 @@ def nonzero(x: DNDarray) -> DNDarray:
         if nnz == 0:
             return DNDarray(comm.shard(phys, 0), gshape, types.int64, 0, x.device, comm)
         return DNDarray(phys, gshape, types.int64, 0, x.device, comm)
-    idx = jnp.nonzero(x.larray)
+    with _span("ht.sync.read", what="nonzero.eager"):  # jnp.nonzero reads the count: its shape is the data's
+        idx = jnp.nonzero(x.larray)
     stacked = jnp.stack(idx, axis=1) if x.ndim > 0 else jnp.zeros((0, 0), dtype=types.index_jax_type())
     stacked = stacked.astype(types.index_jax_type())
     split = 0 if x.split is not None else None

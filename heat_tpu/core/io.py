@@ -27,6 +27,7 @@ from .communication import Communication, sanitize_comm
 from .devices import Device, sanitize_device
 from .dndarray import DNDarray
 from .stride_tricks import sanitize_axis
+from ..observability.tracing import span as _span
 
 __all__ = ["load", "load_csv", "save_csv", "save", "supports_hdf5", "supports_netcdf"]
 
@@ -163,7 +164,8 @@ def _multiprocess_save_slabs(data: DNDarray):
     cast = data.dtype is types.bfloat16
     split = data.split
     if split is None or arr.is_fully_addressable:
-        host = np.asarray(jax.device_get(arr))
+        with _span("ht.sync.read", what="io.save"):
+            host = np.asarray(jax.device_get(arr))
         if cast:
             host = host.astype(np.float32)
         if host.shape != tuple(data.shape):
@@ -182,7 +184,8 @@ def _multiprocess_save_slabs(data: DNDarray):
         slab = arr[tuple(idx)]  # global slice of the sharded array
         if cast:
             slab = slab.astype(jnp.float32)  # one block, bounded
-        host = np.asarray(multihost_utils.process_allgather(slab, tiled=True))
+        with _span("ht.sync.read", what="io.save"):
+            host = np.asarray(multihost_utils.process_allgather(slab, tiled=True))
         sl = tuple(
             slice(start, stop) if i == split else slice(0, s)
             for i, s in enumerate(data.shape)
@@ -218,7 +221,9 @@ def _write_shards(data: DNDarray, write_slab) -> None:
         arr = data._phys
         if data.dtype is types.bfloat16:
             arr = arr.astype(jnp.float32)
-        write_slab(tuple(slice(0, s) for s in data.shape), np.asarray(jax.device_get(arr)))
+        with _span("ht.sync.read", what="io.save"):
+            host = np.asarray(jax.device_get(arr))
+        write_slab(tuple(slice(0, s) for s in data.shape), host)
         return
     split = data.split
     n = data.shape[split]
@@ -244,7 +249,8 @@ def _write_shards(data: DNDarray, write_slab) -> None:
             continue  # non-addressable in multi-process; another host writes it
         valid = [slice(None)] * data.ndim
         valid[split] = slice(0, stop - start)
-        host = np.asarray(jax.device_get(shard[tuple(valid)]))
+        with _span("ht.sync.read", what="io.save"):
+            host = np.asarray(jax.device_get(shard[tuple(valid)]))
         if data.dtype is types.bfloat16:
             host = host.astype(np.float32)
         sl = tuple(

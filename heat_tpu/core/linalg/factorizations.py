@@ -60,6 +60,7 @@ from jax import shard_map as _shard_map
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from ...kernels import cmatmul as _cm
+from ...observability.tracing import span as _span
 from . import basics
 
 __all__ = [
@@ -886,7 +887,8 @@ def _projector_rank(p_arr: jax.Array) -> int:
     reports the sync as a known algorithmic decision point, not a
     stray host round-trip."""
     tr = jnp.real(jnp.trace(p_arr))
-    return int(np.round(float(np.asarray(jax.device_get(tr)))))
+    with _span("ht.sync.read", what="eigh.projector_rank"):
+        return int(np.round(float(np.asarray(jax.device_get(tr)))))
 
 
 def _range_probe(n: int, k: int, depth: int, branch: int, jt) -> jax.Array:
@@ -1084,10 +1086,12 @@ def _solve_host_rhs(a: DNDarray, b, assume_a: str = "gen"):
                 x = _solve_factored("chol", bd, l_arr)
             else:
                 x = _solve_factored("lu", bd, l_arr, u_arr, pvec)
-            out[:, start:stop] = np.asarray(jax.device_get(x.larray))
+            with _span("ht.sync.read", what="solve.window"):
+                out[:, start:stop] = np.asarray(jax.device_get(x.larray))
         else:
             x = _apply_factor_local(kind, win, l_loc, u_loc, perm_loc)
-            out[:, start:stop] = np.asarray(jax.device_get(x))
+            with _span("ht.sync.read", what="solve.window"):
+                out[:, start:stop] = np.asarray(jax.device_get(x))
 
     _staging.stream_windows(b, 1, wins, consume, plan_id=sched.plan_id)
     return _staging.HostArray(out)

@@ -53,7 +53,7 @@ from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from ...observability import telemetry as _telemetry
 from ...observability.instrument import observed_program_cache
-from ...observability.tracing import span as _span
+from ...observability.tracing import call_span as _call_span, span as _span
 
 __all__ = ["qr"]
 
@@ -498,7 +498,7 @@ def qr(
     ``tiles_per_proc`` is accepted for reference-API parity; XLA performs
     its own MXU tiling.
     """
-    with _span("ht.call.qr"):
+    with _call_span("ht.call.qr"):
         with _span("ht.call.qr.prepare"):
             sanitize_in(a)
             if a.ndim != 2:

@@ -54,6 +54,7 @@ from .. import _padding
 from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from ._lapack import safe_svd, safe_svdvals
+from ...observability.tracing import span as _span
 
 __all__ = ["FullMatricesNotSupported", "svd"]
 
@@ -284,7 +285,9 @@ def _svd_host(host, full_matrices: bool, compute_uv: bool, method: str):
             a = _staging.materialize(host, what="svd operand")
             return svd(a, compute_uv=False, method=method)
         s = _host_svdvals(host, jt)
-        return factories.array(np.asarray(jax.device_get(s)), split=None)
+        with _span("ht.sync.read", what="svd.values"):
+            s_host = np.asarray(jax.device_get(s))
+        return factories.array(s_host, split=None)
     if full_matrices:
         raise FullMatricesNotSupported(
             "svd(full_matrices=True) on a host-resident operand: use "

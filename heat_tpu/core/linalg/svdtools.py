@@ -64,7 +64,7 @@ from ..dndarray import DNDarray
 from ..sanitation import sanitize_in
 from ...observability import telemetry as _telemetry
 from ...observability.instrument import observed_program_cache
-from ...observability.tracing import span as _span
+from ...observability.tracing import call_span as _call_span, span as _span
 from ._lapack import safe_svd, svd_x32_scope
 
 __all__ = ["hsvd", "hsvd_rank", "hsvd_rtol"]
@@ -996,7 +996,7 @@ def hsvd_rank(
     """
     from ...redistribution import staging as _staging
 
-    with _span("ht.call.hsvd_rank"):
+    with _call_span("ht.call.hsvd_rank"):
         if isinstance(A, _staging.HostArray):
             if not isinstance(maxrank, (int, np.integer)) or maxrank < 1:
                 raise ValueError(f"maxrank must be a positive integer, got {maxrank}")
@@ -1040,7 +1040,7 @@ def hsvd_rtol(
     svdtools.py:124): the returned factorization satisfies
     ‖A − UΣVᵀ‖_F ≤ rtol·‖A‖_F (upper-bound estimate).
     """
-    with _span("ht.call.hsvd_rtol"):
+    with _call_span("ht.call.hsvd_rtol"):
         with _span("ht.call.hsvd.prepare"):
             sanitize_in(A)
             if A.ndim != 2:
@@ -1070,7 +1070,7 @@ def hsvd(
     warnings_off: bool = False,
 ):
     """General hierarchical SVD entry point (reference: svdtools.py:259)."""
-    with _span("ht.call.hsvd"):
+    with _call_span("ht.call.hsvd"):
         with _span("ht.call.hsvd.prepare"):
             sanitize_in(A)
             if maxrank is None and rtol is None:
@@ -1168,7 +1168,8 @@ def _hsvd_impl(
                     # pipeline — the fixed-grain tile streams make
                     # the result bit-identical by construction,
                     # and the pinned sweep proves it
-                    host = _staging.HostArray(np.asarray(arr))
+                    with _span("ht.sync.read", what="hsvd.ooc_operand"):
+                        host = _staging.HostArray(np.asarray(arr))
                     u_t, v_t, s_t, err_dev = _staged_sketch_rank(
                         host, keep, sketch_l=sketch_l, r_final=r_final,
                         want=want, one_view=ov, jt=jt,
@@ -1194,7 +1195,8 @@ def _hsvd_impl(
                     keep, sketch_l, want
                 )(arr)
             with _span("ht.call.hsvd.merge"):
-                s_host, err0_sq, norm_sq = jax.device_get((s_dev, err0_sq_dev, norm_sq_dev))
+                with _span("ht.sync.read", what="hsvd.spectrum"):
+                    s_host, err0_sq, norm_sq = jax.device_get((s_dev, err0_sq_dev, norm_sq_dev))
                 a_norm = float(np.sqrt(max(float(norm_sq), 0.0)))
                 r_final = _choose_rank(
                     np.asarray(s_host), maxrank, rtol, a_norm, float(err0_sq), full_rank_cap
@@ -1216,7 +1218,8 @@ def _hsvd_impl(
                 u, s, vt = safe_svd(arr, full_matrices=False)
             with _span("ht.call.hsvd.merge"):
                 # one combined transfer for norm + spectrum
-                s_host = np.asarray(jax.device_get(s))
+                with _span("ht.sync.read", what="hsvd.spectrum"):
+                    s_host = np.asarray(jax.device_get(s))
                 a_norm = float(np.sqrt(np.sum(s_host.astype(np.float64) ** 2)))
                 err_sq = 0.0
                 r_final = _choose_rank(s_host, maxrank, rtol, a_norm, err_sq, full_rank_cap)
@@ -1279,9 +1282,10 @@ def _hsvd_impl(
                     jnp.sum(err_blocks) + jnp.sum(s_all[r_final:] ** 2)
                 ) / jnp.maximum(jnp.sqrt(jnp.sum(normsq_blocks)), 1e-30)
             else:
-                s_np_all, lvl_sq, nrm_sq = jax.device_get(
-                    (s_all, jnp.sum(err_blocks), jnp.sum(normsq_blocks))
-                )
+                with _span("ht.sync.read", what="hsvd.spectrum"):
+                    s_np_all, lvl_sq, nrm_sq = jax.device_get(
+                        (s_all, jnp.sum(err_blocks), jnp.sum(normsq_blocks))
+                    )
                 s_np_all = np.asarray(s_np_all)
                 a_norm = float(np.sqrt(max(float(nrm_sq), 0.0)))
                 level_err_sq = float(lvl_sq)

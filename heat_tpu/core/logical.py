@@ -17,6 +17,7 @@ from typing import Optional, Union
 from . import _operations
 from . import types
 from .dndarray import DNDarray
+from ..observability.tracing import span as _span
 
 __all__ = [
     "all",
@@ -46,7 +47,8 @@ def allclose(x: DNDarray, y: DNDarray, rtol: float = 1e-05, atol: float = 1e-08,
     """Scalar verdict: all elements of x and y within tolerances
     (reference: logical.py allclose)."""
     close = isclose(x, y, rtol=rtol, atol=atol, equal_nan=equal_nan)
-    return bool(jnp.all(close.larray))
+    with _span("ht.sync.read", what="allclose"):
+        return bool(jnp.all(close.larray))
 
 
 def any(x: DNDarray, axis=None, out=None, keepdims: bool = False) -> DNDarray:

@@ -29,6 +29,7 @@ from . import types
 from . import _operations
 from .communication import sanitize_comm
 from .dndarray import DNDarray
+from ..observability.tracing import span as _span
 from .sanitation import sanitize_in, sanitize_sequence
 from .stride_tricks import broadcast_shape, sanitize_axis, sanitize_shape
 
@@ -937,10 +938,11 @@ def unique(a: DNDarray, sorted: bool = False, return_inverse: bool = False, axis
             inv = _wrap(jnp.asarray(inv_phys), 0 if a.split is not None else None, a)
             return vals, inv
         return vals
-    if return_inverse:
-        values, inverse = jnp.unique(a.larray, return_inverse=True, axis=axis)
-    else:
-        values = jnp.unique(a.larray, axis=axis)
+    with _span("ht.sync.read", what="unique.eager"):  # jnp.unique reads the count: its shape is the data's
+        if return_inverse:
+            values, inverse = jnp.unique(a.larray, return_inverse=True, axis=axis)
+        else:
+            values = jnp.unique(a.larray, axis=axis)
     split = 0 if a.split is not None else None
     vals = _wrap(values, split, a, dtype=a.dtype)
     if return_inverse:

@@ -36,6 +36,7 @@ from typing import Callable, Optional, Tuple
 
 from . import types
 from ..kernels.sort import block_sort as _local_block_sort, _mode as _sort_kernel_mode
+from ..observability.tracing import span as _span
 
 __all__ = ["halo_exchange", "ring_pairwise", "distributed_sort", "distributed_topk"]
 
@@ -564,11 +565,12 @@ def _host_counts(counts: jax.Array) -> np.ndarray:
     sync these schedules need (the analog of the reference's size
     Allgather). Cross-process worlds cannot ``device_get`` a globally
     sharded array; the allgather of a (p,) int vector is negligible."""
-    if jax.process_count() > 1:
-        from jax.experimental import multihost_utils
+    with _span("ht.sync.read", what="unique.counts"):
+        if jax.process_count() > 1:
+            from jax.experimental import multihost_utils
 
-        return np.asarray(multihost_utils.process_allgather(counts, tiled=True))
-    return np.asarray(jax.device_get(counts))
+            return np.asarray(multihost_utils.process_allgather(counts, tiled=True))
+        return np.asarray(jax.device_get(counts))
 
 
 @functools.lru_cache(maxsize=64)
@@ -944,7 +946,9 @@ def distributed_unique_rows(
     merged, total = _unique_rows_merge_program(
         mesh, axis_name, p, cap, np.dtype(phys.dtype).name
     )(cand, counts)
-    return merged[: int(jax.device_get(total))]
+    with _span("ht.sync.read", what="unique.total"):
+        n_unique = int(jax.device_get(total))
+    return merged[:n_unique]
 
 
 def distributed_unique(
@@ -967,7 +971,9 @@ def distributed_unique(
     merged, total = _unique_merge_program(
         mesh, axis_name, p, cap, np.dtype(phys.dtype).name
     )(cand, counts)
-    return merged[: int(jax.device_get(total))]
+    with _span("ht.sync.read", what="unique.total"):
+        n_unique = int(jax.device_get(total))
+    return merged[:n_unique]
 
 
 __all__ += [

@@ -15,6 +15,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..observability.tracing import span as _span
+
 __all__ = ["get_printoptions", "global_printing", "local_printing", "print0", "set_printoptions"]
 
 # printing profiles mirroring torch defaults (reference printing.py:14-28)
@@ -104,7 +106,8 @@ def __str__(dndarray) -> str:
             data = dndarray.numpy()
     elif LOCAL_PRINT:
         arr = dndarray.larray
-        data = np.asarray(arr.addressable_shards[0].data) if arr.addressable_shards else np.asarray(arr)
+        with _span("ht.sync.read", what="print"):
+            data = np.asarray(arr.addressable_shards[0].data) if arr.addressable_shards else np.asarray(arr)
     else:
         # summarize without materializing huge arrays on host
         if dndarray.size > opts["threshold"] and dndarray.ndim > 0:
@@ -156,10 +159,12 @@ def _planar_summarized(dndarray, edgeitems: int) -> np.ndarray:
     if jax.process_count() > 1 and not view.is_fully_addressable:
         return _edge_take(dndarray.numpy(), dndarray.shape, edgeitems)
     sub = _edge_take(view, dndarray.shape, edgeitems)
-    return _cp.assemble_host(np.asarray(sub))
+    with _span("ht.sync.read", what="print"):
+        return _cp.assemble_host(np.asarray(sub))
 
 
 def _summarized_numpy(dndarray, edgeitems: int) -> np.ndarray:
     """Fetch only the displayed edge slices to host (the analog of the
     reference's threshold-summarized gather, printing.py:208)."""
-    return np.asarray(_edge_take(dndarray.larray, dndarray.shape, edgeitems))
+    with _span("ht.sync.read", what="print"):
+        return np.asarray(_edge_take(dndarray.larray, dndarray.shape, edgeitems))

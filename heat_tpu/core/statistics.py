@@ -24,6 +24,7 @@ from . import _operations
 from .dndarray import DNDarray
 from .sanitation import sanitize_in
 from .stride_tricks import sanitize_axis
+from ..observability.tracing import span as _span
 
 __all__ = [
     "argmax",
@@ -110,7 +111,9 @@ def average(x: DNDarray, axis=None, weights: Optional[DNDarray] = None, returned
         shape[axis_s] = w.shape[0]
         w = w.reshape(shape)
     wsum = jnp.sum(w * jnp.ones_like(arr), axis=axis_s)
-    if bool(jnp.any(wsum == 0)):
+    with _span("ht.sync.read", what="average.weights"):
+        no_weight = bool(jnp.any(wsum == 0))
+    if no_weight:
         raise ZeroDivisionError("Weights sum to zero, can't be normalized")
     result = jnp.sum(arr * w, axis=axis_s) / wsum
     res = _wrap_reduce(result, x, axis_s, False)
@@ -151,14 +154,16 @@ def bincount(x: DNDarray, weights: Optional[DNDarray] = None, minlength: int = 0
     if x.ndim != 1:
         raise ValueError("bincount expects a 1-d array")
     arr = x.larray
-    if arr.size and int(jnp.min(arr)) < 0:
+    with _span("ht.sync.read", what="bincount.range"):
+        lowest, highest = (int(jnp.min(arr)), int(jnp.max(arr))) if arr.size else (0, -1)
+    if lowest < 0:
         raise ValueError("bincount requires non-negative input values")
     w = weights.larray if isinstance(weights, DNDarray) else weights
     # jnp.bincount requires static length: compute it eagerly
     if arr.shape[0] == 0:
         length = minlength
     else:
-        length = int(builtins_max(int(jnp.max(arr)) + 1, minlength)) if arr.size else minlength
+        length = int(builtins_max(highest + 1, minlength)) if arr.size else minlength
     result = jnp.bincount(arr, weights=w, length=length if length > 0 else None)
     gshape = tuple(int(s) for s in result.shape)
     return DNDarray(
@@ -385,7 +390,8 @@ def percentile(
             )
         # declared host boundary "percentile-q" (analysis/boundaries.py):
         # the ONLY whitelisted sync in core/ — pinned by tier-1
-        q_host = np.asarray(jax.device_get(q_dev), dtype=np.float64)
+        with _span("ht.sync.read", what="percentile.q"):
+            q_host = np.asarray(jax.device_get(q_dev), dtype=np.float64)
     else:
         q_host = np.asarray(q, dtype=np.float64)
     scalar_q = q_host.ndim == 0

@@ -30,6 +30,7 @@ import numpy as np
 from typing import List, Optional, Tuple, Union
 
 from .dndarray import DNDarray
+from ..observability.tracing import span as _span
 
 __all__ = ["SplitTiles", "SquareDiagTiles"]
 
@@ -125,7 +126,8 @@ class SplitTiles:
         """Tile values as numpy (the reference returns the rank-local torch
         slice; under a single controller every tile is addressable)."""
         # slice on device first: only the tile travels to host
-        return np.asarray(self.__arr.larray[self.__tile_slices(key)])
+        with _span("ht.sync.read", what="tile"):
+            return np.asarray(self.__arr.larray[self.__tile_slices(key)])
 
     def __setitem__(self, key, value) -> None:
         """Assign to a tile — writes through to the underlying DNDarray
@@ -276,7 +278,8 @@ class SquareDiagTiles:
 
     def __getitem__(self, key) -> np.ndarray:
         rs, re, cs, ce = self.__tile_bounds(key)
-        return np.asarray(self.__arr.larray[rs:re, cs:ce])
+        with _span("ht.sync.read", what="tile"):
+            return np.asarray(self.__arr.larray[rs:re, cs:ce])
 
     def __setitem__(self, key, value) -> None:
         """Assign to a tile — writes through to the underlying DNDarray

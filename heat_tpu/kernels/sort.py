@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import gates as _gates
+from ..observability.tracing import span as _span
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -526,14 +527,16 @@ def _autotune(n: int, dtype_name: str) -> str:
     for path in cand:
         fn = jax.jit(functools.partial(_run_pair_path, path=path, n=n))
         try:
-            jax.block_until_ready(fn(u, idx))  # compile + warm
+            with _span("ht.sync.wait", what="sort.autotune"):
+                jax.block_until_ready(fn(u, idx))  # compile + warm
         except Exception as e:  # the backend refused this candidate
             refused[path] = f"{type(e).__name__}: {e}"
             continue
         best = float("inf")
         for _ in range(2):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(u, idx))
+            with _span("ht.sync.wait", what="sort.autotune"):
+                jax.block_until_ready(fn(u, idx))
             best = min(best, time.perf_counter() - t0)
         timings[path] = best
     if not timings:

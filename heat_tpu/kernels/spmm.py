@@ -63,6 +63,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import gates as _gates
 from ..core import _padding
+from ..observability.tracing import span as _span
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -180,11 +181,13 @@ def _autotune(sig) -> dict:
             )
 
     def _time(fn) -> float:
-        jax.block_until_ready(fn())  # compile + warm
+        with _span("ht.sync.wait", what="spmm.autotune"):
+            jax.block_until_ready(fn())  # compile + warm
         ts = []
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn())
+            with _span("ht.sync.wait", what="spmm.autotune"):
+                jax.block_until_ready(fn())
             ts.append(time.perf_counter() - t0)
         ts.sort()
         return ts[1]

@@ -30,7 +30,12 @@ about exactly that per-step structure. This module is the instrument:
   and is tagged ``traced=True`` (its duration is tracing time: it
   counts for the census only). The names of the ``ht.*`` spans
   and the metric that reads each are listed in ``docs/API.md``
-  (observability) and root ``PERF.md`` section 3.
+  (observability) and root ``PERF.md`` section 3. Two of them say what
+  the wait side of a call holds (PR 36): ``ht.sync.read`` wherever the
+  library itself brings a device value to the host, ``ht.sync.wait``
+  wherever it waits for a program; and the outermost span of a public
+  call (:func:`call_span`) carries, under a profiler session only, the
+  calling thread's and the process's CPU time as the event's arguments.
 - **flight recorder** — a small ALWAYS-ON fixed-field ring, independent
   of the trace gate and of telemetry: one bool check + one bounded
   append per record. Its tail is attached to ``WorldChangedError``,
@@ -78,6 +83,7 @@ __all__ = [
     "TRACE_ENV",
     "Span",
     "add_span",
+    "call_span",
     "capacity",
     "clear",
     "context",
@@ -308,18 +314,24 @@ class span:
     session it costs the constructor and one atomic read. When collection
     is enabled (``HEAT_TPU_TRACE``) it is also recorded in the ring, with
     its parent, and ``__enter__`` returns the :class:`Span` (else
-    ``None``)."""
+    ``None``). :func:`call_span` makes the one that also carries the
+    calling thread's counters."""
 
-    __slots__ = ("_name", "_parent_id", "_attrs", "_ann", "_sp")
+    __slots__ = ("_name", "_parent_id", "_attrs", "_counted", "_ann", "_sp")
 
     def __init__(self, name: str, parent_id: Optional[int] = None, **attrs):
         self._name = name
         self._parent_id = parent_id
         self._attrs = attrs
+        self._counted = False
 
     def __enter__(self) -> Optional[Span]:
         ann = self._ann = (_annotation or _bind_annotation())(self._name, **self._attrs)
         ann.__enter__()
+        if self._counted and ann.is_enabled():
+            # read inside the span, so their cost is the span's; the
+            # annotation's arguments only: the ring's record keeps attrs
+            ann.set_metadata(**_thread_counters())
         if _ENABLED:
             self._sp = sp = start_span(self._name, parent_id=self._parent_id, **self._attrs)
             return sp
@@ -330,6 +342,48 @@ class span:
         if self._sp is not None:
             end_span(self._sp)
         self._ann.__exit__(exc_type, exc, tb)
+
+
+def call_span(name: str, **attrs) -> span:
+    """The :class:`span` of an OUTERMOST public call (``ht.call.hsvd_rank``,
+    ``ht.call.qr``, ``ht.call.kmeans.fit``, ...). Under a live profiler
+    session, and only then (without one: the same ``is_enabled()`` read a
+    plain span pays), it reads two CPU clocks at entry and passes them as
+    the annotation's arguments, which the profiler keeps as the event's
+    integer stats:
+
+    - ``thread_cpu_ns`` (``time.thread_time_ns()``): CPU time of the
+      calling thread;
+    - ``process_cpu_ns`` (``time.process_time_ns()``): CPU time of all
+      threads of the process.
+
+    The thread's context switches are not among them: the chip machines'
+    kernel (gVisor) counts none, and a zero from a source that cannot count
+    rules nothing out (``PERF.md`` section 6, PR 36). It counts CPU time in
+    ticks of 10 ms, so a reader needs a window of calls and says what that
+    resolves. Nothing is computed here: a reader takes the difference
+    between two consecutive calls (one entry to the next is one cycle: the
+    call, the caller's wait, the caller's loop).
+    ``benchmarks/hostside.py`` does, and ``docs/API.md`` (observability)
+    says what each difference means. The counters go to the profiler alone,
+    never to the ring."""
+    sp = span(name, **attrs)
+    sp._counted = True
+    return sp
+
+
+def _thread_counters() -> Dict[str, int]:
+    """What :func:`call_span` reads at entry: two system calls (0.5 us on
+    a Linux host, 12 us under the chip machine's gVisor). Observing must
+    not fail the call observed: a platform that refuses one of the clocks
+    gives what was read before it."""
+    out: Dict[str, int] = {}
+    try:
+        out["thread_cpu_ns"] = time.thread_time_ns()
+        out["process_cpu_ns"] = time.process_time_ns()
+    except (AttributeError, OSError, ValueError):
+        pass
+    return out
 
 
 def add_span(

@@ -523,7 +523,8 @@ class Dispatcher:
         out, reqs, batch_sp = inflight
         parent = None if batch_sp is None else batch_sp.id
         try:
-            with _tracing.span("serving.fence", parent_id=parent, endpoint=self.name):
+            with _tracing.span("serving.fence", parent_id=parent, endpoint=self.name), \
+                    _tracing.span("ht.sync.wait", what="serving.fence"):
                 jax.block_until_ready(out)
         except Exception as e:
             for r in reqs:

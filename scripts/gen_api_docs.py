@@ -87,6 +87,41 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 | `ht.program.launch` | every call of a built program: the host side of the jitted call, argument handling to enqueue | `launches_per_call`, `host_prelaunch_ms_per_call`, `host_launch_ms_per_call` |
 | `ht.program.compile` | the first call after a miss (trace + compile) | as `launch` |
 | `ht.comm.place`, `ht.comm.shard`, `ht.comm.reshard` | `communication.place`, `MeshCommunication.shard`, `reshard_phys` | `host_comm_ms_per_call` |
+| `ht.sync.read` | wherever the library itself brings a device value to the host (since PR 36; `what=` says which): `DNDarray.numpy` / `__array__` / `tolist` / `item` / `float()` and the other scalar casts (one span, in the shared gather), the first read of an estimator's `n_iter_` / `inertia_` (the cached value enters none), the spectrum of hSVD's tolerance and staged modes, `percentile`'s `q`, `unique`'s counts, the eigensolver's projector rank, a staged solve's windows, `save`'s slabs, `print`, a tile | `sync_read_ms_per_call` |
+| `ht.sync.wait` | wherever the library itself waits for a program without reading it: the sort and spmm autotuners' timed runs, the dispatcher's fence | `sync_read_ms_per_call` |
+
+**The calling thread's counters** (since PR 36). The outermost span of a public call (`ht.call.hsvd_rank`,
+`ht.call.hsvd_rtol`, `ht.call.hsvd`, `ht.call.qr`, `ht.call.kmeans.fit`, `ht.call.kmedians.fit`,
+`ht.call.kmedoids.fit`, `ht.call.kmeans.predict`) is entered through `ht.tracing.call_span`. Under a live
+profiler session, and only then (without one it costs the `is_enabled()` read every span pays), it reads two
+CPU clocks at entry and passes them as the annotation's arguments; the profiler keeps them as the event's
+integer stats (`ProfileData` event `.stats`; Perfetto shows them as the slice's arguments). Nothing is
+computed in the program: the difference between two consecutive calls is one cycle (the call, the caller's
+wait, the caller's loop).
+
+| argument | from | what its difference over a cycle says | read by |
+|---|---|---|---|
+| `thread_cpu_ns` | `time.thread_time_ns()` | CPU time of the calling thread: against the cycle's wall less the device time, whether the thread computed or slept | `host_thread_cpu_ms_per_call` |
+| `process_cpu_ns` | `time.process_time_ns()` | CPU time of all threads: a runtime that polls for completion shows the cycle's wall here, one that sleeps shows little | `host_process_cpu_ms_per_call` |
+
+The starts of the same spans give the cycles, and `late_call_ms_in_window` the time of those far over
+the median. The chip machines run gVisor (`uname`: `runsc`, Linux 4.4.0; my chip run, PR 36): its CPU clocks
+tick in 10 ms, so a window of n cycles resolves a mean to 10 / n ms and no finer, and the count of ticks in
+a window is itself a sample (1 to 9 over twelve windows of one state): a level is a mean over runs, and a
+window that resolves no finer than 0.5 ms a call (under twenty cycles) reads nothing (the two metrics list
+the cells whose windows do). A read is a system call of 5 us there, a traced entry 15 us in all. (PR 36
+also read the thread's context switches and major faults from `getrusage(RUSAGE_THREAD)` and Python's
+collections. gVisor counts no switch and no fault, so a zero there ruled nothing out, and no collection ran
+in 1100 cycles: all were taken out again, and with them a metric of preemptions; a source that works under
+gVisor is a later issue's.) For an operator, on a trace of the benchmark (`bench.call` / `bench.wait` say
+where a call starts and its wait ends; `run.py` removes its own once it is read, so a wrapper that copies
+`benchmarks/.trace` first keeps one):
+
+    python benchmarks/hostside.py <file.xplane.pb> [--census]
+
+prints one line a call (the cycle's wall, the wall of `bench.call` and `bench.wait`, the time under
+`ht.sync.*`, the two differences; `late` marks a cycle over 1.25 x the median) and, with `--census`, the
+runtime's own host events inside `ht.program.launch` and inside `bench.wait`, by name and thread.
 
 Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`, `.miss`,
 `.build`, `.compile` for every observed builder (`op.binary`, `op.unary`, `op.reduce`, `op.cum`,

@@ -35,7 +35,7 @@ ALLOWED = {
     "core.linalg": {"core", "kernels.cmatmul", "redistribution"} | _OBS,
     "datasets": set(),
     "graph": {"core", "core.linalg", "redistribution.staging", "sparse"},
-    "kernels": {"core", "observability.telemetry"},
+    "kernels": {"core", "observability.telemetry", "observability.tracing"},
     "naive_bayes": {"core"},
     "nn": {"core"},
     "observability": {"core.gates", "core.dndarray", "core.jit"},

@@ -660,10 +660,11 @@ import sys
 import timeit
 
 
-def _profiled(tmp_path, fn):
+def _profiled(tmp_path, fn, stats=False):
     """Run ``fn`` under a ``jax.profiler`` session; the ``ht.*`` events of
     the host plane as (name, thread line, start_ns, end_ns), in order of
-    start, outermost first."""
+    start, outermost first; with ``stats`` also each event's arguments as
+    the profiler kept them (a dict)."""
     from jax.profiler import ProfileData
 
     opts = jax.profiler.ProfileOptions()
@@ -682,7 +683,8 @@ def _profiled(tmp_path, fn):
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith("ht."):
-                    rows.append((ev.name, line.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+                    row = (ev.name, line.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    rows.append(row + (dict(ev.stats),) if stats else row)
     return sorted(rows, key=lambda r: (r[2], -r[3]))
 
 
@@ -1092,3 +1094,130 @@ def test_selftest_of_the_span_readers():
     )
     assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-2000:]
     assert "selftest_spans: all passed" in done.stdout
+
+
+# The wait side (PR 36): ht.sync.read where the library itself brings a
+# device value to the host, and the calling thread's counters on the
+# outermost span of a public call, under a profiler session only.
+COUNTERS = {"thread_cpu_ns", "process_cpu_ns"}
+
+
+def _ring_names(fn):
+    """The names of the spans ``fn`` enters, by the ring (with their attrs)."""
+    tracing.clear()
+    tracing.enable()
+    try:
+        fn()
+        return [(r["name"], r["attrs"]) for r in tracing.spans()]
+    finally:
+        tracing.disable()
+        tracing.clear()
+
+
+@pytest.mark.parametrize("attr", ["n_iter_", "inertia_"])
+@pytest.mark.parametrize("est", ["KMeans", "KMedians"])
+def test_sync_read_is_entered_by_the_first_read_of_a_fitted_estimator_and_not_by_the_second(est, attr):
+    x = ht.random.randn(8 * 53, 7, split=0)
+    init = ht.array(np.asarray(x.numpy()[:3]))
+    fitted = getattr(ht.cluster, est)(3, init=init, max_iter=2).fit(x)
+    first = _ring_names(lambda: getattr(fitted, attr))
+    assert first == [("ht.sync.read", {"what": attr})]
+    assert _ring_names(lambda: getattr(fitted, attr)) == []  # the cached value: no read, no span
+    assert isinstance(getattr(fitted, attr), int if attr == "n_iter_" else float)
+
+
+@pytest.mark.parametrize("read", [
+    lambda a: a.item(), float, int, bool, complex, lambda a: a.numpy(), np.asarray, lambda a: a.tolist(),
+], ids=["item", "float", "int", "bool", "complex", "numpy", "__array__", "tolist"])
+def test_sync_read_is_entered_once_by_a_host_read_of_a_dndarray(read):
+    a = ht.sum(ht.ones((8,), split=0))
+    jax.block_until_ready(a._phys)
+    assert [n for n, _ in _ring_names(lambda: read(a))].count("ht.sync.read") == 1
+
+
+def _counted_calls():
+    x = ht.random.randn(8 * 53, 7, split=0)
+    init = ht.array(np.asarray(x.numpy()[:3]))
+    a = ht.random.randn(331, 24, split=None)
+    return {
+        "hsvd_rank": (lambda: ht.linalg.hsvd_rank(a, 2, compute_sv=True), "ht.call.hsvd_rank", "ht.call.hsvd.prepare"),
+        "qr": (lambda: ht.linalg.qr(a), "ht.call.qr", "ht.call.qr.prepare"),
+        "kmeans_fit": (lambda: ht.cluster.KMeans(3, init=init, max_iter=2, tol=0.0).fit(x), "ht.call.kmeans.fit",
+                       "ht.call.kmeans.program"),
+    }
+
+
+@pytest.mark.parametrize("which", ["hsvd_rank", "qr", "kmeans_fit"])
+def test_outermost_call_span_carries_the_threads_counters_under_a_session(which, tmp_path):
+    """Under a CPU profiler session the outermost ``ht.call.*`` event holds
+    the two counters as integer stats, read at entry (so they do not fall
+    from one call to the next), and an inner span holds none."""
+    call, root, inner = _counted_calls()[which]
+    rows = _profiled(tmp_path, lambda: (call(), call()), stats=True)
+    first, second = [r[4] for r in rows if r[0] == root]
+    for got in (first, second):
+        assert COUNTERS <= set(got), sorted(COUNTERS - set(got))
+        assert all(isinstance(got[c], int) for c in COUNTERS), got
+    assert all(second[c] >= first[c] for c in COUNTERS)
+    assert second["thread_cpu_ns"] > first["thread_cpu_ns"]  # the first call ran on this thread
+    inners = [r[4] for r in rows if r[0] == inner]
+    assert inners and not any(COUNTERS & set(got) for got in inners), inners
+    assert tracing.spans() == []  # the counters go to the profiler, never to the ring
+
+
+def test_no_session_reads_no_counter_and_the_ring_never_gets_one(monkeypatch):
+    """With no profiler session a public call reads neither CPU clock at
+    all; with the ring on and no session the ring's record of the outermost
+    span holds no counter."""
+    import time
+
+    reads = []
+    for clock in ("thread_time_ns", "process_time_ns"):
+        real = getattr(time, clock)
+        monkeypatch.setattr(time, clock, lambda real=real, clock=clock: (reads.append(clock), real())[1])
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    calls = _counted_calls()
+    for call, _, _ in calls.values():
+        call()
+    assert reads == []
+    assert dict(_ring_names(calls["qr"][0]))["ht.call.qr"] == {}
+    assert reads == []
+    # the helper itself reads both, and a platform that refuses a clock gives what was read before it
+    assert set(tracing._thread_counters()) == COUNTERS and reads == ["thread_time_ns", "process_time_ns"]
+    monkeypatch.setattr(time, "process_time_ns", lambda: (_ for _ in ()).throw(OSError("no such clock")))
+    assert set(tracing._thread_counters()) == {"thread_cpu_ns"}
+
+
+def test_programs_are_the_same_with_the_counters_read(tmp_path):
+    """The counters are read on the host, at the entry of the call: the
+    program a public call builds under a session (counters read) has the
+    text of the one it builds without (none read), and the counters go to
+    the profiler alone."""
+    qr = importlib.import_module("heat_tpu.core.linalg.qr")
+    shape = jax.ShapeDtypeStruct((331, 24), np.float32)
+    a = ht.random.randn(331, 24, split=None)
+
+    def texts():
+        qr._local_qr_fn.cache_clear()
+        ht.linalg.qr(a)  # through call_span: under a session the counters are read
+        return qr._local_qr_fn(331, 24, "float32", True).lower(shape).as_text()
+
+    outside = texts()
+    inside, ring = [], []
+    rows = _profiled(tmp_path, lambda: (inside.append(texts()), ring.extend(_ring_names(lambda: ht.linalg.qr(a)))),
+                     stats=True)
+    assert all(COUNTERS <= set(r[4]) for r in rows if r[0] == "ht.call.qr")
+    assert inside[0] == outside
+    assert dict(ring)["ht.call.qr"] == {}  # a session and the ring at once: the ring's record holds no counter
+
+
+def test_selftest_of_the_wait_side_readers():
+    """``benchmarks/selftest_hostside.py``: hand-written events with known
+    answers through ``hostside.py`` and the four reducers that read it (no
+    rehearsal here: about 15 s a cell)."""
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "selftest_hostside.py"), "--no-rehearse"],
+        capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-2000:]
+    assert "selftest_hostside: all passed" in done.stdout
