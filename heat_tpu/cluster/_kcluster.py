@@ -39,7 +39,7 @@ from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import call_span as _call_span, span as _span
 from . import _pallas_l1
-from ._pallas_l1 import _N_THR, _NARROW_ON_X, _RADIX_BITS, _WINDOW_FIRST_DIGIT, _WINDOW_MIN_KEYS, _from_key, _key_type, _to_key
+from ._pallas_l1 import _MOST_DIGITS_ON_X, _N_THR, _RADIX_BITS, _WINDOW_FIRST_DIGIT, _WINDOW_MIN_KEYS, _from_key, _key_type, _to_key
 
 __all__ = ["_KCluster"]
 
@@ -257,11 +257,17 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
     counts of the shards are summed before a bracket narrows.
 
     Where the passes come with a ``gather`` (the kernels, on enough rows:
-    ``_pallas_l1.gather_pays``) only the first ``_NARROW_ON_X`` digits are
-    counted on ``arr``: by then a bracket holds a few keys in ten thousand,
-    one more pass keeps those, and the other digits and the upper middle
-    value are found among them (``finish_on_kept``): the same counts, so
-    the same key bit for bit."""
+    ``_pallas_l1.gather_pays``) only the first digits are counted on
+    ``arr``, and the counts say how many: after every digit they give the
+    keys each pair's window holds, and the loop stops at the first digit
+    (from ``_WINDOW_FIRST_DIGIT`` on) after which no feature's windows hold
+    more than the slots are made for (``_pallas_l1.crowded``: eight digits
+    on unit blobs near zero, ten or eleven where f32 keys lie denser), or at
+    ``_MOST_DIGITS_ON_X``. One more pass keeps the windows' keys, and the
+    other digits and the upper middle value are found among them
+    (``finish_on_kept``). A count among the kept keys is the count over
+    ``arr`` less the keys under the window, whatever digit the loop stopped
+    at: the same brackets, so the same key bit for bit."""
     if passes is None:
         passes = _l1_passes_xla(k)
     ktype, bits = _key_type(arr.dtype)
@@ -304,30 +310,41 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
         # under_end counts the keys <= low: a duplicate of low fills the upper rank
         return low, jnp.where(under_end > upper, low, passes.next_above(arr, labels, low).astype(ktype))
 
-    def note_window(p, carry):
+    def note_window(carry):
         """A digit on ``X``, and the pair's window: its newest bracket that
         still holds ``_WINDOW_MIN_KEYS`` keys (none before the fourth), and
         the bracket above it unless that lies past the last key."""
-        state, window = carry
+        p, state, window = carry
         base, under_base, under_end = state = on_x(p, state)
         held = under_end - under_base
         fits = (p < _WINDOW_FIRST_DIGIT) | (held >= _WINDOW_MIN_KEYS)
         two = (jnp.asarray(2, ktype) << bits_left(p + 1)) - 1  # from the base to the last key of the bracket above
         wide = bits_left(p + 1) + (base <= jnp.iinfo(ktype).max - two).astype(ktype)
-        return state, tuple(jnp.where(fits, new, old) for new, old in zip((base, under_base, wide, held), window))
+        return p + 1, state, tuple(jnp.where(fits, new, old) for new, old in zip((base, under_base, wide, held), window))
 
-    def finish_on_kept(state, window):
-        """The same digits and the same upper middle value from the keys one
-        gathering pass keeps: those of each row's own window ``[base, base +
-        2 ** wide)``, by cluster and offset, so that a count among them is
-        the count over ``X`` less the keys under ``base``. Where the counts
-        say beforehand that the windows hold more keys than the slots are
-        made for (``_pallas_l1.crowded``: the pass is told to skip) or a slot
-        spilled, the digits are counted on ``X``; there, and where the upper
-        rank of some pair lies beyond its window (a median within 1e-6 of
-        zero, where f32 keys are sparse), the successor pass runs on ``X``."""
-        base, under_base, wide, held = window
-        kept, spilled = passes.gather(arr, labels, base, wide, _pallas_l1.crowded(held, arr.shape[0]))
+    def windows_crowded(window):
+        return _pallas_l1.crowded(window[3], arr.shape[0])
+
+    def another_digit_on_x(carry):
+        """After ``p`` digits: an offset does not fit under the label yet,
+        or the windows hold more keys than the slots are made for and one
+        more digit on ``X`` may still pay."""
+        p, _, window = carry
+        return (p < _WINDOW_FIRST_DIGIT) | ((p < _MOST_DIGITS_ON_X) & windows_crowded(window))
+
+    def finish_on_kept(p, state, window):
+        """The digits from the ``p``-th on and the upper middle value from
+        the keys one gathering pass keeps: those of each row's own window
+        ``[base, base + 2 ** wide)``, by cluster and offset, so that a count
+        among them is the count over ``X`` less the keys under ``base``.
+        Where the windows still hold more keys than the slots are made for
+        (the loop stopped at ``_MOST_DIGITS_ON_X``: the pass is told to skip)
+        or a slot spilled, the digits are counted on ``X``; there, and where
+        the upper rank of some pair lies beyond its window (a median within
+        1e-6 of zero, where f32 keys are sparse), the successor pass runs on
+        ``X``."""
+        base, under_base, wide, _ = window
+        kept, spilled = passes.gather(arr, labels, base, wide, windows_crowded(window))
         ahead, in_window = _pallas_l1.kept_by_cluster(passes, kept, k)
         beyond = (in_window > 0) & (upper - under_base >= in_window)
 
@@ -336,7 +353,7 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
             return under_base + _pallas_l1.kept_under(passes, kept, off, ahead)
 
         def digits_on_kept(state):
-            low, _, under_end = jax.lax.fori_loop(_NARROW_ON_X, digits, narrow_by(count_below), state)
+            low, _, under_end = jax.lax.fori_loop(p, digits, narrow_by(count_below), state)
             return low, under_end
 
         def upper_from_kept(low, under_end):
@@ -346,7 +363,7 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
             return upper_from_kept(*digits_on_kept(state))
 
         def back_to_x(state):
-            return upper_from_x(*jax.lax.cond(spilled, functools.partial(digits_on_x, _NARROW_ON_X), digits_on_kept, state))
+            return upper_from_x(*jax.lax.cond(spilled, functools.partial(digits_on_x, p), digits_on_kept, state))
 
         return jax.lax.cond(spilled | jnp.any(beyond), back_to_x, among_kept, state)
 
@@ -356,7 +373,8 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
     if passes.gather is None:
         low, high = upper_from_x(*digits_on_x(0, state))
     else:  # the first digit's bracket is every pair's first window
-        low, high = finish_on_kept(*jax.lax.fori_loop(0, _NARROW_ON_X, note_window, (state, (first, none, none.astype(ktype), none))))
+        window = (first, none, none.astype(ktype), none)
+        low, high = finish_on_kept(*jax.lax.while_loop(another_digit_on_x, note_window, (jnp.int32(0), state, window)))
     med = 0.5 * _from_key(low, arr.dtype) + 0.5 * _from_key(high, arr.dtype)
     return jnp.where(counts > 0, med, prev.astype(arr.dtype))
 

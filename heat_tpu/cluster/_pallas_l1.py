@@ -22,17 +22,24 @@ price that does not grow with ``k`` beyond the ``k`` selects. The counts of a
 tile (at most ``tn`` < 2^24) are exact in the f32 accumulator of the dot and
 are added up as int32.
 
-After ``_NARROW_ON_X`` digits a bracket holds a few keys in ten thousand, and
-the other digits are counted on them (and on those of the bracket above, for
-the upper middle value of an even count; a pair whose ninth bracket holds
-under ``_WINDOW_MIN_KEYS`` keys, because its median lies near zero where f32
-keys are sparse, gets the window of an earlier, wider bracket). The chip has no vector scatter, so
+After a few digits a bracket holds a few keys in a thousand, and the other
+digits are counted on them (and on those of the bracket above, for the upper
+middle value of an even count; a pair whose newest bracket holds under
+``_WINDOW_MIN_KEYS`` keys, because its median lies near zero where f32 keys
+are sparse, keeps the window of an earlier, wider bracket). How many digits
+that takes the counts say themselves: after every digit they give the keys
+each pair's window holds, and the selection goes on counting on ``X`` while
+some feature's windows hold more than the slots below are made for
+(``crowded``: over one row in ``_GATHER_MOST_OF_X``), from the
+``_WINDOW_FIRST_DIGIT``-th digit to the ``_MOST_DIGITS_ON_X``-th: eight digits
+on unit blobs near zero, eleven on the same blobs around 10, twelve around
+100. The chip has no vector scatter, so
 ``gather`` folds the lanes: a block's ``tn / 128`` lane chunks ``(d, 128)``
 go, one after the other, into ``_SLOTS`` ascending slots ``(d, 128)`` by a
 chain of min / max (a kept key, or the type's max, ripples to its place; what
 falls off the end is a spill), and the slots of ``_KEPT_STEPS`` grid steps go
 the same way into the ``_KEPT_SLOTS`` slots of one output block. The kept
-array is ``int32[d, kept_lanes]``: at 18 750 000 x 64, 64 x 293 888, 75 MB,
+array is ``int32[d, kept_lanes]``: at 18 750 000 x 64, 64 x 294 912, 75 MB,
 1.6 % of ``X``. A kept key carries its row's label above its offset, so one
 comparison with ``c << _LABEL_SHIFT | off`` says cluster and side:
 ``kept_below`` counts a feature's kept keys under each of ``q`` such
@@ -40,10 +47,11 @@ thresholds, ``kept_above`` finds the least one over each
 (``kept_by_cluster``, ``kept_under`` and ``kept_next`` speak in clusters and
 offsets: the format stays in this module). A spill (rows sorted by a
 feature, many equal values) sends the selection back to ``X`` for its last
-digits (``_kcluster._cluster_medians``); where the counts of the counting
-passes say beforehand that the windows hold more keys than the slots are
-made for (``crowded``), ``gather`` is told to skip: every grid step asks for
-the first block, so nothing more is read, and does nothing.
+digits, from the digit it had reached (``_kcluster._cluster_medians``); where
+the windows are still ``crowded`` after ``_MOST_DIGITS_ON_X`` digits (many
+equal values, values beyond a thousand noise widths from zero), ``gather`` is
+told to skip: every grid step asks for the first block, so nothing more is
+read, and does nothing.
 
 Every kernel that reads all of ``X`` is named for its phase,
 ``kmedians.assign.pass`` or ``kmedians.select.pass`` (``count``, ``next`` and
@@ -89,51 +97,64 @@ __all__ = ["L1Passes", "crowded", "gather_pays", "kept_by_cluster", "kept_lanes"
 _RADIX_BITS = 2
 _N_THR = 2 ** _RADIX_BITS - 1
 
-# the selection narrows on X for _NARROW_ON_X digits, then ``gather`` keeps
-# the keys of each row's own window: a bracket of its (cluster, feature) pair
-# and the one above it. The bracket is the pair's last with _WINDOW_MIN_KEYS
-# keys in it, so that the upper middle value is in the window too (a median
-# near zero lies where f32 keys are sparse: its ninth bracket holds a handful
-# of 2.3 M keys, its seventh a hundred), and no earlier than the fourth, so
-# that an offset fits under the label. A kept key is one int32: its offset in
-# the window with the label above it, from bit _LABEL_SHIFT. Per grid step
-# the tn / 128 lane chunks fold onto _SLOTS sorted slots (d, 128), and
-# _KEPT_STEPS steps fold their slots onto the _KEPT_SLOTS slots of one
-# output block. Chosen on the chip at 18.75M x 64, k 8 (builder's runs, PR 33,
-# PERF.md section 6): a lane position of 64 rows holds 0.015 kept keys on
-# average, so a fifth one is one in 10 ** 4 iterations and a fourth one in 30;
-# the pass takes 8.55 ms with 3 slots, 9.37 with 4, 10.96 with 6 (unrolled;
-# as the loop it is 10.6 with 4). One block a step (300 MB kept) makes an op
-# over the kept keys take 0.56-1.0 ms, 8 steps a block (75 MB) 0.24-0.28, 16
-# steps and 12 slots 0.22-0.25. Eight digits on X keep four times the keys (a
-# fifth at a lane position every tenth iteration), ten gain nothing but a pass
-_NARROW_ON_X = 9
+# ``gather`` keeps the keys of each row's own window: a bracket of its (cluster, feature) pair and the one above it.
+# The bracket is the pair's newest with _WINDOW_MIN_KEYS keys in it, so that the upper middle value is in the window too
+# (a median near zero lies where f32 keys are sparse: its ninth bracket holds a handful of 2.3 M keys, its seventh a
+# hundred), and no earlier than the fourth, so that an offset fits under the label. A kept key is one int32: its offset
+# in the window with the label above it, from bit _LABEL_SHIFT.
 _WINDOW_MIN_KEYS = 32
 _WINDOW_FIRST_DIGIT = 4
 _LABEL_SHIFT = 26  # a window of the fourth digit is 2 ** 25 keys; five bits of label above it
 _MOST_CLUSTERS = 32  # the last one's kept keys end under the type's max, which is what an empty slot holds
 assert 32 - _RADIX_BITS * _WINDOW_FIRST_DIGIT < _LABEL_SHIFT and (_MOST_CLUSTERS << _LABEL_SHIFT) - 1 <= _I32_MAX
-_SLOTS = 4
-_KEPT_STEPS = 8
-_KEPT_SLOTS = 8
+# Per grid step the tn / 128 lane chunks fold onto _SLOTS sorted slots (d, 128), and _KEPT_STEPS steps fold their slots
+# onto the _KEPT_SLOTS slots of one output block. Both are sized for the densest windows the rule below lets through, at
+# 18.75M x 64, k 8, tn 8192. A window is two brackets, so where a feature's brackets hold a share s of its rows a lane
+# position of a step (64 rows) holds 128 s kept keys on average and one of a block of 16 steps 2048 s; over its slots by
+# Poisson, summed over the 18.75M positions of a pass (1.17M of blocks). Passes that spilled of ten on the cell's data
+# after eight digits (unit blobs near zero: the densest feature one row in 970 to 1 270, the mean one in 1 680 to 2 250;
+# builder's chip runs, PR 37, five iterations of two seeds) against that reckoning, by slots / steps / kept slots: 4 / 8 /
+# 8 six (expected 0.53 spills a pass); 6 / 8 / 8 two (0.12: the block's eight slots, a lane position of eight steps
+# expects one key); 5 / 16 / 16 none (0.006, one pass in 150); 6 / 16 / 12 none (0.008); 6 / 8 / 12 and 6 / 16 / 16 none
+# (9e-5: one pass in 10 ** 4). Where every feature is as dense as the rule allows, one row in 768, 6 / 16 / 16 expects
+# 0.012 + 0.005. What a slot costs: the pass takes 10.67 ms with 4 slots (ledger, PR 33 to 36), 12.63 with 6 and blocks
+# of 8 / 8, 13.26 with blocks of 16 / 16 (the fold of six slots onto sixteen; builder's chip runs, PR 37, device trace;
+# alone on the host's clock 11.7, 13.8, 14.4, and 13.2 with 5 / 16 / 16), against 6.44 for the counting pass it stands in
+# for. The kept array is as large as with 8 / 8 (75 MB) and an op over it takes what it took (0.25 ms at 24 thresholds a
+# feature); 8 / 12 keeps 113 MB
+_SLOTS = 6
+_KEPT_STEPS = 16
+_KEPT_SLOTS = 16
 # rows a cluster from which the gather pays. A window holds a number of keys that does not grow with n (32 to a few
 # hundred), so what is kept of X goes as k / n, and under some 2 ** 16 rows a cluster a lane position holds more keys
 # than slots now and then: the selection then ends on X and the gathering pass was for nothing. Five iterations, ms,
-# selection on X to its end / with the gather (builder's chip runs, PR 33; d 64 unless said): k 8: 262 144 rows 11.2 /
-# 11.9, 524 288 20.1 / 13.8, 1 048 576 38.0 / 25.6, 18 750 000 640.2 / 420.9; k 16: 1 048 576 55.8 / 59.7, 4 194 304
-# 213.0 / 140.6; k 32: 4 194 304 392.3 / 280.0; k 4, d 16: 1 048 576 10.5 / 7.2; k 2, d 8: 2 097 152 14.0 / 13.6
+# selection on X to its end / with the gather (builder's chip runs, PR 33, nine digits and four slots; d 64 unless said):
+# k 8: 262 144 rows 11.2 / 11.9, 524 288 20.1 / 13.8, 1 048 576 38.0 / 25.6, 18 750 000 640.2 / 420.9; k 16: 1 048 576
+# 55.8 / 59.7, 4 194 304 213.0 / 140.6; k 32: 4 194 304 392.3 / 280.0; k 4, d 16: 1 048 576 10.5 / 7.2; k 2, d 8:
+# 2 097 152 14.0 / 13.6
 _GATHER_MIN_ROWS_A_CLUSTER = 1 << 17
-# and the share of a feature's rows that its windows' brackets may hold. The counting passes say how many keys each
-# bracket holds, so the selection knows before the gathering pass whether the slots are made for them, and tells the
-# pass to skip where they are not (its grid then runs empty over one block: under 0.5 ms an iteration at 512 steps). The densest feature's
-# brackets hold (builder's chip runs, PR 33, review round; fits of 4 194 304 x 64 in ms, selection on X to its end /
-# this): the cell's blobs one row in 3 800 to 6 100 (two seeds, five iterations each); the same at 4 194 304 rows, k 8,
-# one in 3 400 to 4 400: 145.3 / 97.1; k 32 one in 1 600: 392.3 / 280.9; unit blobs around 3 one in 470: skipped, 88.7
-# / 90.2 (three iterations); the cell's blobs around 10, where f32 keys lie sixteen times as dense, one in 135 to 150,
-# around 100 one in 17: skipped, 146.2 (the parent) / 146.8 and 146.7, where the pass run for nothing made it 157.2.
-# By (64 x 2 x share) ** 5 / 5! a lane position and step a fifth key is then expected 0.1 times a pass at one row in
-# 1 600 (4 194 304 x 64) and 5 times at one in 2 ** 10 (18.75M x 64, every feature that dense)
-_GATHER_MOST_OF_X = 1 << 10
+# and the share of a feature's rows that its windows' brackets may hold for the gathering pass to run: the rule by which
+# the selection stops counting on X. Every digit leaves the windows a quarter of what they held, and the counting passes
+# say how much that is, so the selection gathers after the first digit that leaves no feature over one row in
+# _GATHER_MOST_OF_X. The densest feature's brackets hold, by digits counted (builder's chip runs, PR 37, 18.75M x 64, k 8):
+# the cell's blobs, seven: one row in 240 to 310, eight: 970 to 1 270, nine: 3 800 to 5 000; the same blobs around 10
+# (f32 keys lie sixteen times as dense there, and every feature alike), nine: 130 to 150, ten: 520 to 600, eleven: 2 060
+# to 2 370; around 100, eleven: 260 to 300, twelve: 1 030 to 1 190. Where the limit lies: a spill after eight digits
+# costs the eight counting passes left and the successor (64 ms), and one digit fewer on X saves 6.44 ms less the 2.6 that
+# two more slots and the larger blocks cost the gathering pass, so a spill has to stay rarer than one pass in 17 however the features lie. With
+# every feature at one row in 768 it is one in 60 (above); at one in 640, one in 10; at one in 512 three in two (blobs
+# around 10 after ten digits, one row in 517 to 597 of every feature: a block of 16 / 16 spilled in one pass of three).
+# On the cell's data (2 000 simulated seeds) one seed in a thousand has a feature over one in 768 after eight digits and
+# counts a ninth; none fits after seven
+_GATHER_MOST_OF_X = 768
+# and the digits after which it stops counting whatever the windows hold. The gathering pass and the ops over what it
+# keeps cost two counting passes, the successor pass that they save costs two, so a gather after any digit before the
+# last is cheaper than the end on X; but windows that are crowded after twelve digits (2 ** 9 keys, 2 ** 8 a bracket) are
+# crowded by equal values or values a thousand noise widths from zero (blobs around 1 000 would fit after fourteen),
+# which is where lane positions spill whatever the counts say (a sorted column, one repeated value), and a gathering
+# pass that spills is 13 ms for nothing. Stopping here costs such data nothing: the digits left are counted on X either way
+_MOST_DIGITS_ON_X = 12
+assert _WINDOW_FIRST_DIGIT <= _MOST_DIGITS_ON_X <= 32 // _RADIX_BITS  # the kernels are f32's
 
 
 def _key_type(dtype):
@@ -377,8 +398,8 @@ def _next_program(n: int, d: int, k: int, interpret: bool):
 
 def kept_lanes(n: int, d: int, k: int) -> int:
     """Lanes of the array ``gather`` keeps of ``n`` rows: ``_KEPT_SLOTS``
-    x 128 for every ``_KEPT_STEPS`` grid steps (8 x 128 of 8 x 8192 rows at
-    ``d`` 64: 1.6 % of ``X``)."""
+    x 128 for every ``_KEPT_STEPS`` grid steps (16 x 128 of 16 x 8192 rows
+    at ``d`` 64: 1.6 % of ``X``)."""
     return pl.cdiv(pl.cdiv(n, _pick_tn(n, d, _round_up(k, 8))), _KEPT_STEPS) * _KEPT_SLOTS * 128
 
 
@@ -520,18 +541,25 @@ def kept_next(passes: L1Passes, kept, off):
 
 def gather_pays(n: int, k: int) -> bool:
     """Can finishing on the kept keys beat the remaining passes over ``X``?
-    It trades ``bits / 2 - _NARROW_ON_X`` counting passes and the successor
-    pass for one gathering pass and as many small ops, and wins where the
-    slots do not spill: from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a cluster
-    on one device, on data whose windows are not ``crowded``."""
+    It trades the counting passes of the digits the selection has not
+    counted on ``X`` when it stops (``crowded`` says when: eight of sixteen
+    on unit blobs near zero, at least four, twelve at most) and the
+    successor pass for one gathering pass and as many small ops, and wins
+    where the slots do not spill: from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a
+    cluster on one device, on data whose windows fit the slots by the
+    ``_MOST_DIGITS_ON_X``-th digit."""
     return n >= k * _GATHER_MIN_ROWS_A_CLUSTER
 
 
 def crowded(held, n: int):
     """Do the windows hold more keys than the slots are made for?
     ``held`` (k, d) are the keys in each pair's bracket, as the counting
-    passes over the ``n`` rows gave them: in some feature more than one row
-    in ``_GATHER_MOST_OF_X``."""
+    passes over the ``n`` rows gave them (summed over the devices of a split
+    array, so that every device reads the same answer): in some feature
+    more than one row in ``_GATHER_MOST_OF_X``. The selection asks after
+    every digit it counts on ``X``: while the answer is yes it counts
+    another, up to ``_MOST_DIGITS_ON_X``, and then tells ``gather`` to
+    skip."""
     return jnp.max(jnp.sum(held, axis=0)) > n // _GATHER_MOST_OF_X
 
 

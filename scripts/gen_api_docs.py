@@ -175,21 +175,27 @@ also the fit's label pass) and `jax.named_scope("kmedians.select")` (the countin
 medians and, for `KMedoids`, the snap); the scopes are named for the estimator (`kmedoids.*`). One op
 named `.pass` is one whole read of `X`, for both estimators: `kmedians.assign.pass` (one an iteration,
 one for the labels) and `kmedians.select.pass`, which three kernels carry: the counting pass (a digit of
-the radix selection: nine an iteration where the program gathers, sixteen for f32 where it does not),
+the radix selection: sixteen an iteration for f32 where the program does not gather; where it does, as
+many as the counts ask for, since PR 37: after every digit they say how many keys the windows hold, and
+the selection counts on `X` until no feature's windows hold over one row in 768
+(`cluster._pallas_l1.crowded`), four digits at least and twelve at most: eight on unit blobs near zero,
+eleven on the same blobs around 10, twelve around 100),
 the gathering pass (one an iteration: it keeps the keys still in their (cluster, feature) pair's
-window, its last bracket with 32 keys and the one above, in an array of 1.6 % of `X`'s size) and the
+window, its newest bracket with 32 keys and the one above, in an array of 1.6 % of `X`'s size) and the
 successor pass (the upper middle value of the even counts: only where the selection ends on `X`). The ops that finish the selection on the kept keys (a count by
-cluster, seven digits, one successor) are named `kmedians.select.candidates` and read no `X`.
+cluster, the digits not counted on `X`, one successor) are named `kmedians.select.candidates` and read no `X`.
 `breakdown.device_ops` shows all of them by these names, and the benchmark's readers
 `kmedians_assign_ms_per_call`, `kmedians_select_ms_per_call`, `kmedians_x_reads_per_call` and
 `kmedians_pass_hbm_pct` find the passes by them (an op is named by the text before ` = `). How often the
-selection went back to `X` the device trace says: `kmedians_x_reads_per_call` is `max_iter x 11 + 1` (56 at
-five iterations) when every iteration finished on the kept keys; it grows by 8 (seven counting passes and
-the successor) for each one in which a lane position held more kept keys than slots (rows sorted by a
-feature, many equal values), and by 1 (the successor pass alone) for each one in which only an upper
-middle value lay beyond its window (a median within 1e-6 of zero, where f32 keys are sparse). Where
-the counting passes say beforehand that the windows hold more keys than the slots are made for (in some
-feature over one row in 2^10, `cluster._pallas_l1.crowded`: values far from zero, many equal ones), the
+selection counted on `X`, and how often it went back there, the device trace says:
+`kmedians_x_reads_per_call` is `max_iter x (1 + digits + 1) + 1` when every iteration finished on the kept
+keys: 51 at five iterations that gathered after eight digits, 56 after nine (every iteration before PR 37),
+one more for each iteration that counted one more digit; it grows by the digits left and the successor
+(nine reads after eight digits) for each iteration in which a lane position held more kept keys than
+slots (rows sorted by a feature, many equal values), and by 1 (the successor pass alone) for each one in
+which only an upper middle value lay beyond its window (a median within 1e-6 of zero, where f32 keys are
+sparse). Where the windows still hold more keys than the slots are made for after the twelfth digit (many
+equal values, values a thousand noise widths from zero), the
 gathering pass is told to skip: its op is there, runs empty over one block (under a tenth of a read's time:
 no read to `kmedians_x_reads_per_call`, which takes an op under half the median pass for a sliver), and
 the iteration has 18 reads as before PR 33.

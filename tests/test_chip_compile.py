@@ -301,6 +301,7 @@ def _assert_l1_fit_holds_x_alone(compiled, rows, d, gathers=True):
         from heat_tpu.cluster import _pallas_l1 as pl1
 
         assert len(over_kept) >= 3 and f"s32[{d},{pl1.kept_lanes(rows, d, 8)}]" in txt
+        assert 60 * pl1.kept_lanes(rows, d, 8) < rows  # the kept array: under a sixtieth of X, 75.5 MB of 4.8 GB
 
 
 @pytest.mark.parametrize("snap", [False, True], ids=["kmedians", "kmedoids"])
@@ -309,9 +310,11 @@ def test_l1_fit_at_the_north_star_shard(one_chip, snap):
     of 128: the last tile is masked in every kernel): the fit program holds
     ``X``, the label vector and k x d x thresholds of integers, where the
     masked-``nanmedian`` ``vmap`` asked for 38.4 GB. Since PR 33 also the
-    gathering pass, the array it keeps (``int32[64, 293888]``, 75 MB) and
-    the ops that finish the selection on it, in both branches of the
-    ``cond``. KMedoids' snap, in XLA, reads ``x.T`` and picks its row by a
+    gathering pass, the array it keeps (``int32[64, 294912]``, 75 MB: blocks
+    of sixteen slots for sixteen steps since PR 37) and the ops that finish
+    the selection on it, in both branches of the ``cond``; since PR 37 the
+    digits on ``X`` are a ``while`` whose condition reads the counts.
+    KMedoids' snap, in XLA, reads ``x.T`` and picks its row by a
     masked sum (a slice of a row costs a 9.6 GB row-major copy)."""
     _assert_l1_fit_holds_x_alone(_l1_fit_compiled(18_750_000, 64, 8, one_chip, snap=snap), 18_750_000, 64)
 

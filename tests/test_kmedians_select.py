@@ -204,9 +204,11 @@ def test_pallas_passes_on_a_mesh_sum_the_shards_counts(split):
 @pytest.fixture
 def gather_at_any_size(monkeypatch):
     """``gather_pays`` asks for 2**17 rows a cluster and device, and
-    ``crowded`` for windows that hold at most one row in 2**10 of a feature;
-    the kernels run here in interpret mode, on a few hundred rows, most of
-    them in a window."""
+    ``crowded`` for windows that hold at most one row in
+    ``_GATHER_MOST_OF_X`` of a feature; the kernels run here in interpret
+    mode, on a few hundred rows, most of them in a window. With no window
+    ever crowded the selection gathers as soon as an offset fits under the
+    label: after the fourth digit on ``X``."""
     monkeypatch.setattr(pl1, "_GATHER_MIN_ROWS_A_CLUSTER", 0)
     monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", 1)
     pl1.l1_passes.cache_clear()
@@ -216,31 +218,33 @@ def gather_at_any_size(monkeypatch):
 
 def _near(rng, shape, span, at=1.0):
     """f32 values ``at`` + i ulps, i uniform under ``span``: neighbours in
-    key space, so that the window of a ninth bracket (2 ** 15 keys) holds
-    them all."""
+    key space, so that one window holds them all, at whatever digit it is
+    taken (2 ** 25 keys at the fourth, 2 ** 9 at the twelfth)."""
     return (np.float32(at).view(np.int32) + rng.integers(0, span, size=shape).astype(np.int32)).view(np.float32)
 
 
 @pytest.fixture
 def any_bracket_is_a_window(monkeypatch):
     """A window is a pair's last bracket with ``_WINDOW_MIN_KEYS`` keys: 32.
-    The ``_cores`` put six in a ninth bracket, so that a block of a few
-    thousand rows keeps few enough not to spill."""
+    The ``_cores`` put six in a bracket, so that a block of a few thousand
+    rows keeps few enough not to spill, and ``_spread`` a few thousand rows
+    over brackets that hold a handful by the digit at which they fit."""
     monkeypatch.setattr(kc, "_WINDOW_MIN_KEYS", 1)
 
 
 def _cores(rng, n, d, k, core=6):
     """Random labels; in every (cluster, feature) ``core`` values that are
     neighbours in key space hold the middle ranks, the rest lie far below
-    and far above: few keys are kept, and the medians are among them."""
+    and far above (beyond the window of a fourth bracket, which spans a
+    factor of sixteen): few keys are kept, and the medians are among them."""
     labels = rng.integers(0, k, size=n)
     x = np.empty((n, d), np.float32)
     for c in range(k):
         rows = np.flatnonzero(labels == c)
         below = (len(rows) - core) // 2
         for j in range(d):
-            col = np.concatenate([-rng.uniform(100, 1000, size=below), _near(rng, core, 3000, 1.0 + c + j),
-                                  rng.uniform(100, 1000, size=len(rows) - core - below)]).astype(np.float32)
+            col = np.concatenate([-rng.uniform(1e4, 1e5, size=below), _near(rng, core, 3000, 1.0 + c + j),
+                                  rng.uniform(1e4, 1e5, size=len(rows) - core - below)]).astype(np.float32)
             x[rows, j] = rng.permutation(col)
     return x, labels, k
 
@@ -261,28 +265,29 @@ def _gather_case(name, rng):
         return _case(name, rng) + ("kept",)
     if name == "masked_tail":  # 2500 rows: 20 lane chunks in one masked block
         return _cores(rng, 2500, 64, 3) + ("kept",)
-    if name == "medians_near_zero":  # where f32 keys are sparse: the window is of the fourth digit, a third of the rows
+    if name == "medians_near_zero":  # where f32 keys are sparse: a window of the fourth digit holds a third of the rows
         return rng.normal(size=(3 * 401, 5)) + 0.01, np.repeat(np.arange(3), 401), 3, None
     if name == "window_of_an_earlier_digit":
         # twenty keys in the ninth bracket (and in the eighth) of the lower middle value, the upper one 2 ** 16 keys
-        # on: the seventh bracket is the last with 32 keys, and its window holds both
+        # on: the seventh bracket is the last with 32 keys, and its window holds both (here the fourth's does:
+        # ``test_counts_decide_the_digits_on_x`` has the selection go on to the tenth digit on these columns)
         core = np.float32(1.0).view(np.int32) + np.concatenate([100 * np.arange(20), (1 << 16) + 100 * np.arange(20)])
         col = np.concatenate([-rng.uniform(100, 1000, size=105), core.astype(np.int32).view(np.float32),
                               rng.uniform(100, 1000, size=105)]).astype(np.float32)
         return np.stack([rng.permutation(col) for _ in range(2 * 3)]).reshape(2, 3, 250).transpose(0, 2, 1).reshape(500, 3), \
             np.repeat(np.arange(2), 250), 2, "kept"
     if name == "upper_rank_beyond_the_window":  # an even count whose middle values are far apart
-        x = np.concatenate([_near(rng, (50, 3), 3000, 1.0), _near(rng, (50, 3), 3000, 2.0)])
+        x = np.concatenate([_near(rng, (50, 3), 3000, 1.0), _near(rng, (50, 3), 3000, 100.0)])
         return rng.permutation(x), np.zeros(100, int), 1, "x"
     if name in ("k32_last_cluster_beyond_its_window", "k32_all_on_kept"):
-        # the last of 32 clusters: its kept keys end at the type's max, not at ``32 << 26``. 512 rows fill the four
-        # slots; sixteen keys a pair: the windows are of the fourth digit, [0.5, 8). In the first case the last
+        # the last of 32 clusters: its kept keys end at the type's max, not at ``32 << 26``. 512 rows: four to a lane
+        # position; sixteen keys a pair: the windows are of the fourth digit, [0.5, 8). In the first case the last
         # cluster's two middle values lie far apart, and the selection has to see its upper rank beyond the window
         x = _near(rng, (32 * 16, 2), 3000)
         if name == "k32_last_cluster_beyond_its_window":
             x[31 * 16 + 8:] = _near(rng, (8, 2), 3000, 100.0)
         return x, np.repeat(np.arange(32), 16), 32, "x" if name == "k32_last_cluster_beyond_its_window" else "kept"
-    if name == "sorted_column":  # 2000 neighbours in key space, row after row: 16 to a lane position, 4 slots
+    if name == "sorted_column":  # 2000 neighbours in key space, row after row: 16 to a lane position, 6 slots
         x = (np.float32(1.0).view(np.int32) + np.arange(2000, dtype=np.int32)).view(np.float32)
         return np.stack([x, x[::-1]], axis=1), rng.integers(0, 2, size=2000), 2, "x"
     if name == "one_repeated_value":
@@ -300,7 +305,9 @@ GATHER_CASES = ["odd_counts", "even_counts", "duplicates_at_the_median", "one_em
 def _ends_where(passes, ends):
     """``passes`` whose passes over ``X`` say when they run: the digits
     ended on ``X`` iff all sixteen counting passes ran there, the upper
-    middle value came from ``X`` iff the successor pass ran."""
+    middle value came from ``X`` iff the successor pass ran; the gathering
+    pass says what it was told: to skip or not, and the bits of the windows
+    (a window taken after digit ``p`` is 33 - 2 ``p`` bits wide)."""
 
     def count_below(*a):
         jax.debug.callback(lambda: ends.append("count"))
@@ -310,28 +317,36 @@ def _ends_where(passes, ends):
         jax.debug.callback(lambda: ends.append("x"))
         return passes.next_above(*a)
 
-    return passes._replace(count_below=count_below, next_above=next_above)
+    def gather(arr, lab, base, bits, skip):
+        jax.debug.callback(lambda b, s: ends.append(("gather", np.asarray(b), bool(s))), bits, skip)
+        return passes.gather(arr, lab, base, bits, skip)
+
+    return passes._replace(count_below=count_below, next_above=next_above, gather=gather)
 
 
-def _medians_and_end(x, labels, k, prev, passes, counted=None):
+def _medians_and_end(x, labels, k, prev, passes, counted=None, gathers=None):
     """The medians, and where the selection ended: "x" where anything of it
     came from ``X`` after the gathering pass, else "kept". ``counted``, a
-    list, is given the number of counting passes over ``X``."""
+    list, is given the number of counting passes over ``X``; ``gathers``,
+    a list, what each gathering pass was told: (bits (k, d), skip)."""
     ends = []
     run = jax.jit(lambda a, l, p: kc._cluster_medians(a, l, k, p, passes=_ends_where(passes, ends)))
     got = np.asarray(run(x, labels, prev))
     jax.effects_barrier()
     if counted is not None:
         counted.append(ends.count("count"))
+    if gathers is not None:
+        gathers.extend(e[1:] for e in ends if isinstance(e, tuple))
     return got, "x" if "x" in ends else "kept"
 
 
 @pytest.mark.parametrize("name", GATHER_CASES)
 def test_selection_on_the_kept_keys_gives_numpys_medians(name, gather_at_any_size, request):
-    """Nine digits on ``X``, one gathering pass, the other seven digits and
-    the upper middle value among the kept keys: ``numpy.median`` by cluster.
-    Where a lane position holds more keys than slots, or an upper rank lies
-    beyond its window, the selection ends on ``X`` and gives the same."""
+    """Four digits on ``X`` (no window is crowded here), one gathering pass,
+    the other twelve digits and the upper middle value among the kept keys:
+    ``numpy.median`` by cluster. Where a lane position holds more keys than
+    slots, or an upper rank lies beyond its window, the selection ends on
+    ``X`` and gives the same."""
     if name == "masked_tail":
         request.getfixturevalue("any_bracket_is_a_window")
     rng = np.random.default_rng(GATHER_CASES.index(name))
@@ -346,12 +361,13 @@ def test_selection_on_the_kept_keys_gives_numpys_medians(name, gather_at_any_siz
     assert end in (None, ended)
 
 
-@pytest.mark.parametrize("name, on_x, successor", [("odd_counts", 9, "kept"), ("upper_rank_beyond_the_window", 9, "x"),
-                                                   ("k32_last_cluster_beyond_its_window", 9, "x"), ("sorted_column", 16, "x")])
+@pytest.mark.parametrize("name, on_x, successor", [("odd_counts", 4, "kept"), ("upper_rank_beyond_the_window", 4, "x"),
+                                                   ("k32_last_cluster_beyond_its_window", 4, "x"), ("sorted_column", 16, "x")])
 def test_only_what_the_kept_keys_lack_comes_from_x(name, on_x, successor, gather_at_any_size):
-    """A spill sends the other seven digits and the successor back to
-    ``X``; an upper rank beyond its window (a median within 1e-6 of zero on
-    the cell's data) only the successor pass: the digits are the kept keys'."""
+    """A spill sends the other digits (here twelve: the windows fit after
+    the fourth) and the successor back to ``X``; an upper rank beyond its
+    window (a median within 1e-6 of zero on the cell's data) only the
+    successor pass: the digits are the kept keys'."""
     rng = np.random.default_rng(GATHER_CASES.index(name))
     x, labels, k, _ = _gather_case(name, rng)
     x, prev, counted = x.astype(np.float32), np.zeros((k, x.shape[1]), np.float32), []
@@ -384,11 +400,12 @@ def test_window_never_runs_past_the_last_key(gather_at_any_size):
 
 
 def test_gather_folds_steps_onto_blocks_of_slots(gather_at_any_size, any_bracket_is_a_window, monkeypatch):
-    """Twenty grid steps of 256 rows (two lane chunks each, the last one
+    """Thirty-six grid steps of 256 rows (two lane chunks each, the last one
     masked) fold onto three blocks of ``_KEPT_SLOTS`` slots: the kept array
     is the size ``kept_lanes`` says, holds every key of a window once, and
     the selection ends on it. Told to skip, the pass says "spilled"."""
-    n, d, k = 256 * 19 + 7, 8, 3
+    n, d, k = 256 * 35 + 7, 8, 3
+    assert -(-36 // pl1._KEPT_STEPS) == 3
     monkeypatch.setattr(pl1, "_pick_tn", lambda n, d, k8: 256)  # for 2 MiB of X a step it picks 65536 rows at d 8
     pl1._gather_program.cache_clear()
     x, labels, _ = _cores(np.random.default_rng(1), n, d, k)
@@ -432,17 +449,108 @@ def test_crowded_windows_skip_the_gathering_pass(data, skips, gather_at_any_size
             x[:, 5] = _near(rng, 2500, 3000)
     else:
         x, labels, k, _ = _gather_case(data, rng)
-    told = []
-    passes = pl1.l1_passes(k, x.shape, interpret=True)
-
-    def gather(arr, lab, base, bits, skip):
-        jax.debug.callback(lambda s: told.append(bool(s)), skip)
-        return passes.gather(arr, lab, base, bits, skip)
-
-    prev = np.zeros((k, x.shape[1]), np.float32)
-    got, ended = _medians_and_end(x.astype(np.float32), labels.astype(np.int32), k, prev, passes._replace(gather=gather))
+    told, prev = [], np.zeros((k, x.shape[1]), np.float32)
+    got, ended = _medians_and_end(x.astype(np.float32), labels.astype(np.int32), k, prev,
+                                  pl1.l1_passes(k, x.shape, interpret=True), gathers=told)
     np.testing.assert_array_equal(got, _median_by_cluster(x.astype(np.float32), labels, k, prev))
-    assert told == [skips] and ended == ("x" if skips else "kept")
+    assert [skip for _, skip in told] == [skips] and ended == ("x" if skips else "kept")
+
+
+# --------------------------------------------------------------------- #
+# the counts decide how many digits are counted on X (PR 37)             #
+# --------------------------------------------------------------------- #
+def _spread(rng, n, d, k, log2_span, at=2.0):
+    """Random labels; every value one of ``2 ** log2_span`` neighbours in key
+    space from ``at`` on, uniformly: a bracket of ``2 ** b`` keys (after
+    digit ``16 - b / 2``) holds one row in ``2 ** (log2_span - b)``, so the
+    data say after which digit the windows fit the slots."""
+    return _near(rng, (n, d), 1 << log2_span, at), rng.integers(0, k, size=n), k
+
+
+def _cores_at_one_lane_position(rng, n, d, k):
+    """``_cores`` whose middle values all sit in the rows at lane position 0
+    (every 128th): few keys in the windows, which the counts can see, and
+    all of them in one lane position, which they cannot."""
+    labels = rng.integers(0, k, size=n)
+    x = np.empty((n, d), np.float32)
+    for c in range(k):
+        rows = np.flatnonzero(labels == c)
+        core, rest = rows[rows % 128 == 0], rng.permutation(rows[rows % 128 != 0])
+        below = (len(rows) - len(core)) // 2
+        for j in range(d):
+            x[core, j] = _near(rng, len(core), 3000, 2.0 + c + j)
+            x[rest[:below], j] = -rng.uniform(1e4, 1e5, size=below)
+            x[rest[below:], j] = rng.uniform(1e4, 1e5, size=len(rest) - below)
+    return x, labels, k
+
+
+def _rule_case(name, rng):
+    """(x, labels, k, one row in how many a feature's windows may hold) of a
+    case of the rule. 4096 rows are one grid step, 32 rows a lane position
+    (eight steps of 4 on the mesh of eight)."""
+    if name.startswith("fits_after_"):  # windows that hold one row in 256 after that digit, one in 64 a digit earlier
+        digit = int(name.rsplit("_", 1)[1])
+        return _spread(rng, 4096, 4, 2, 40 - 2 * digit) + (128,)
+    if name == "never_fits":  # one row in 64 after the twelfth digit: crowded at the cap
+        return _spread(rng, 4096, 4, 2, 14) + (128,)
+    if name == "one_repeated_value":
+        return _gather_case(name, rng)[:3] + (128,)
+    if name == "spills_after_four":  # 64 keys a feature in the windows, of 8192 rows: they fit by the counts; at
+        # lane position 0 they are 64 on one device and 8 on each of eight, over the six slots
+        return _cores_at_one_lane_position(rng, 8192, 3, 2) + (64,)
+    if name == "a_window_of_an_earlier_digit":
+        # the columns of ``window_of_an_earlier_digit`` (40 keys a pair in the seventh bracket, 20 in those after it:
+        # the window stays the seventh's, 80 of 500 rows a feature) beside one whose windows hold all 500 rows after
+        # the eighth digit and a quarter of them after the ninth
+        x, labels, k, _ = _gather_case("window_of_an_earlier_digit", rng)
+        return np.concatenate([x, _near(rng, (500, 1), 1 << 16, 2.0)], axis=1), labels, k, 2
+    raise KeyError(name)
+
+
+RULE_CASES = [("fits_after_8", 8, "kept"), ("fits_after_10", 10, "kept"), ("fits_after_11", 11, "kept"),
+              ("fits_after_12", 12, "kept"), ("never_fits", 12, "skipped"), ("one_repeated_value", 12, "skipped"),
+              ("spills_after_four", 4, "spilled"), ("a_window_of_an_earlier_digit", 9, "kept")]
+
+
+@pytest.mark.parametrize("name, digits, then", RULE_CASES, ids=[c[0] for c in RULE_CASES])
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "split0_mesh8"])
+def test_counts_decide_the_digits_on_x(name, digits, then, mesh, gather_at_any_size, request, monkeypatch):
+    """The selection counts digits on ``X`` until no feature's windows hold
+    more than one row in ``_GATHER_MOST_OF_X`` (never fewer than four, never
+    more than ``_MOST_DIGITS_ON_X``), gathers, and finishes on the kept keys
+    from the digit it reached; windows still crowded at the cap tell the
+    gathering pass to skip, and a spill after an early stop sends the other
+    digits back to ``X`` from the digit reached. On a split array the counts
+    are ``psum``med before they are looked at: every device stops at the
+    digit one device stops at. ``numpy.median`` by cluster whatever the
+    digit."""
+    if name != "a_window_of_an_earlier_digit":
+        request.getfixturevalue("any_bracket_is_a_window")
+    rng = np.random.default_rng([c[0] for c in RULE_CASES].index(name))
+    x, labels, k, most = _rule_case(name, rng)
+    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", most)
+    x, labels = x.astype(np.float32), labels.astype(np.int32)
+    (n, d), own = x.shape, k
+    counted, gathers = [], []
+    if mesh:
+        comm = ht.MPI_WORLD
+        if n % comm.size:  # equal shards: rows far above every window, in a cluster of their own, so that no median moves
+            x = np.concatenate([x, np.full((-n % comm.size, d), 1e30, np.float32)])
+            labels, k = np.concatenate([labels, np.full(-n % comm.size, k, np.int32)]), k + 1
+        passes = pl1.l1_passes(k, x.shape, comm.mesh, comm.axis_name, interpret=True)
+        xs, ls = jax.device_put(x, comm.sharding(2, 0)), jax.device_put(labels, comm.sharding(1, 0))
+    else:
+        passes, xs, ls = pl1.l1_passes(k, x.shape, interpret=True), x, labels
+    prev = np.zeros((k, d), np.float32)
+    got, ended = _medians_and_end(xs, ls, k, prev, passes, counted, gathers)
+    np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
+    (bits, told_to_skip), = gathers
+    assert told_to_skip == (then == "skipped")
+    if name == "a_window_of_an_earlier_digit":  # the seventh bracket's window beside the ninth's, in one gathering pass
+        np.testing.assert_array_equal(bits[:own], np.repeat([[33 - 2 * 7] * 3 + [33 - 2 * digits]], own, axis=0))
+    else:
+        assert set(bits[:own].ravel()) == {33 - 2 * digits}
+    assert (counted, ended) == ([digits if then == "kept" else 16], "kept" if then == "kept" else "x")
 
 
 @pytest.mark.parametrize("data", ["cores", "wide"], ids=["ends_on_kept", "ends_on_x"])
@@ -536,11 +644,11 @@ def test_fit_program_holds_no_k_fold_copy_of_x(est):
 @pytest.mark.parametrize("est", ["kmedians", "kmedoids"])
 def test_fit_program_on_the_kernels_keeps_a_sixtieth_of_x(est):
     """The fit program on the Pallas passes at the cell's shard, traced and
-    not run: the kept array is ``int32[d, kept_lanes]``, 8 x 128 lanes for
-    every 8 x 8192 rows (1.6 % of ``X``: 75 MB of 4.8 GB), and no value is
+    not run: the kept array is ``int32[d, kept_lanes]``, 16 x 128 lanes for
+    every 16 x 8192 rows (1.6 % of ``X``: 75 MB of 4.8 GB), and no value is
     larger than ``X``."""
     n, d, k = 18_750_000, 64, 8
-    assert pl1.kept_lanes(n, d, k) == 287 * 1024  # 2289 steps of 8192 rows, eight to a block
+    assert pl1.kept_lanes(n, d, k) == 144 * 2048  # 2289 steps of 8192 rows, sixteen to a block of sixteen slots
     passes = pl1.l1_passes(k, (n, d))
 
     def step(arr, centers):
