@@ -14,9 +14,10 @@ per-cluster reductions lowering to one all-reduce over the mesh.
 
 The L1 family (KMedians, KMedoids) shares ``l1_step_for``: an L1 assignment
 that never holds ``n x k x d``, and ``_cluster_medians``, every
-per-cluster, per-feature median at once by a radix selection that counts
-(``_RADIX_BITS`` bits a pass over ``X``; on the chip the last digits on the
-few keys a gathering pass keeps) instead of sorting k masked copies.
+per-cluster, per-feature median at once by the exact counting selection of
+``core/_selection.py`` (the one ``ht.percentile`` runs along the sample axis;
+here by label: a row counts for its own cluster's medians) instead of sorting
+k masked copies.
 """
 
 from __future__ import annotations
@@ -38,8 +39,10 @@ from ..core.communication import place as _place
 from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import call_span as _call_span, span as _span
+from ..core import _selection
+from ..core._pallas_select import _from_key
+from ..core._selection import _members
 from . import _pallas_l1
-from ._pallas_l1 import _MOST_DIGITS_ON_X, _N_THR, _RADIX_BITS, _WINDOW_FIRST_DIGIT, _WINDOW_MIN_KEYS, _from_key, _key_type, _to_key
 
 __all__ = ["_KCluster"]
 
@@ -205,14 +208,10 @@ def _kmeanspp_program(k: int, shape, jdtype: str):
 # ---------------------------------------------------------------------- #
 # the L1 family: assignment and per-cluster medians without a copy of X  #
 # ---------------------------------------------------------------------- #
-def _members(labels: jax.Array, k: int) -> jax.Array:
-    """(n, k) int32: 1 where the row is of the cluster."""
-    return (labels[:, None] == jnp.arange(k)).astype(jnp.int32)
-
-
 def _l1_passes_xla(k: int) -> "_pallas_l1.L1Passes":
     """The three passes of an L1 iteration in plain ``jax.numpy``
-    (``_pallas_l1`` holds the chip's form of the same three). Nothing is
+    (``_pallas_l1`` holds the chip's form of the same three): the assignment
+    here, the selection's two from ``_selection.passes_xla``. Nothing is
     larger than ``X``: the clusters are walked, not broadcast. Under a mesh
     the sums over the split sample axis lower to all-reduces."""
 
@@ -221,20 +220,7 @@ def _l1_passes_xla(k: int) -> "_pallas_l1.L1Passes":
         labels = jnp.argmin(dist, axis=0).astype(jnp.int32)
         return labels, jnp.sum(_members(labels, k), axis=0), jnp.sum(jnp.min(dist, axis=0))
 
-    def count_below(arr, labels, thr0, step):
-        key, thr, member_of = _to_key(arr), thr0[labels], _members(labels, k).T
-        return jnp.stack([
-            jnp.matmul(member_of, (key < thr + t * step).astype(jnp.int32), preferred_element_type=jnp.int32)
-            for t in range(_N_THR)
-        ])
-
-    def next_above(arr, labels, at):
-        key = _to_key(arr)
-        top = jnp.iinfo(key.dtype).max
-        above = jnp.where(key > at[labels], key, top)
-        return jax.lax.map(lambda c: jnp.min(jnp.where((labels == c)[:, None], above, top), axis=0), jnp.arange(k))
-
-    return _pallas_l1.L1Passes(assign, count_below, next_above)  # no gather: the selection ends on X
+    return _pallas_l1.L1Passes(assign, *_selection.passes_xla(k)[:2])  # no gather: the selection ends on X
 
 
 def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
@@ -244,137 +230,20 @@ def _cluster_medians(arr: jax.Array, labels: jax.Array, k: int, prev: jax.Array,
     order statistic, the mean of the two middle ones for an even count); an
     empty cluster keeps its row of ``prev``. ``arr`` holds no NaN.
 
-    A radix selection on ``_to_key``'s integer image, all k x d order
-    statistics at once. Every pass counts, by cluster and feature, the keys
-    under each of ``_N_THR`` thresholds that cut the bracket
-    ``[base, base + 2**bits)`` evenly, and the bracket that holds rank
-    ``(count - 1) // 2`` becomes the next: after ``bits / _RADIX_BITS``
-    passes ``base`` is that order statistic's key. The upper middle one is
-    the same key where the count under the bracket's end says a duplicate
-    fills the next rank, else the least key above (one more pass). Beside
-    ``arr`` and ``labels`` it holds O(k x d x thresholds) integers, the
-    number of passes does not depend on ``k``, and on a split array the
-    counts of the shards are summed before a bracket narrows.
-
-    Where the passes come with a ``gather`` (the kernels, on enough rows:
-    ``_pallas_l1.gather_pays``) only the first digits are counted on
-    ``arr``, and the counts say how many: after every digit they give the
-    keys each pair's window holds, and the loop stops at the first digit
-    (from ``_WINDOW_FIRST_DIGIT`` on) after which no feature's windows hold
-    more than the slots are made for (``_pallas_l1.crowded``: eight digits
-    on unit blobs near zero, ten or eleven where f32 keys lie denser), or at
-    ``_MOST_DIGITS_ON_X``. One more pass keeps the windows' keys, and the
-    other digits and the upper middle value are found among them
-    (``finish_on_kept``). A count among the kept keys is the count over
-    ``arr`` less the keys under the window, whatever digit the loop stopped
-    at: the same brackets, so the same key bit for bit."""
+    The two middle order statistics, ranks ``(count - 1) // 2`` and
+    ``count // 2`` of a cluster's rows, come from
+    ``_selection.order_statistics`` by label: all k x d at once by passes
+    that count, exact, with the passes given (``l1_step_for``: the chip's
+    kernels or ``jax.numpy``)."""
     if passes is None:
         passes = _l1_passes_xla(k)
-    ktype, bits = _key_type(arr.dtype)
-    d = arr.shape[1]
     labels = labels.astype(jnp.int32)
     if counts is None:
         counts = jnp.sum(_members(labels, k), axis=0)
     counts = counts.astype(jnp.int32)[:, None]
     lower = jnp.maximum(counts - 1, 0) // 2  # rank of the lower middle, 0-based
     upper = counts // 2
-    digits = bits // _RADIX_BITS
-
-    bits_left = lambda p: jnp.asarray(bits - _RADIX_BITS * p).astype(ktype)  # of a bracket after p digits
-
-    def narrow_by(count_below):
-        """One digit of every bracket ``(base, keys under its start, keys
-        under its end)``; ``count_below(thr0, step)`` gives a pair's keys
-        under ``thr0 + t * step``, ``(_N_THR, k, d)``."""
-
-        def narrow(p, state):
-            base, under_base, under_end = state
-            step = jnp.asarray(1, ktype) << bits_left(p + 1)
-            under = count_below(base + step, step)
-            digit = jnp.sum((under <= lower).astype(jnp.int32), axis=0)
-            # keys under the edges of the four brackets: the chosen one lies between two of them
-            edges = jnp.concatenate([under_base[None], under, under_end[None]])
-            edge = lambda i: jnp.take_along_axis(edges, i[None], axis=0)[0]
-            return base + digit.astype(ktype) * step, edge(digit), edge(digit + 1)
-
-        return narrow
-
-    on_x = narrow_by(lambda thr0, step: passes.count_below(arr, labels, thr0, step))
-
-    def digits_on_x(p, state):
-        low, _, under_end = jax.lax.fori_loop(p, digits, on_x, state)
-        return low, under_end
-
-    def upper_from_x(low, under_end):
-        """(lower, upper) middle key, the upper one by the successor pass."""
-        # under_end counts the keys <= low: a duplicate of low fills the upper rank
-        return low, jnp.where(under_end > upper, low, passes.next_above(arr, labels, low).astype(ktype))
-
-    def note_window(carry):
-        """A digit on ``X``, and the pair's window: its newest bracket that
-        still holds ``_WINDOW_MIN_KEYS`` keys (none before the fourth), and
-        the bracket above it unless that lies past the last key."""
-        p, state, window = carry
-        base, under_base, under_end = state = on_x(p, state)
-        held = under_end - under_base
-        fits = (p < _WINDOW_FIRST_DIGIT) | (held >= _WINDOW_MIN_KEYS)
-        two = (jnp.asarray(2, ktype) << bits_left(p + 1)) - 1  # from the base to the last key of the bracket above
-        wide = bits_left(p + 1) + (base <= jnp.iinfo(ktype).max - two).astype(ktype)
-        return p + 1, state, tuple(jnp.where(fits, new, old) for new, old in zip((base, under_base, wide, held), window))
-
-    def windows_crowded(window):
-        return _pallas_l1.crowded(window[3], arr.shape[0])
-
-    def another_digit_on_x(carry):
-        """After ``p`` digits: an offset does not fit under the label yet,
-        or the windows hold more keys than the slots are made for and one
-        more digit on ``X`` may still pay."""
-        p, _, window = carry
-        return (p < _WINDOW_FIRST_DIGIT) | ((p < _MOST_DIGITS_ON_X) & windows_crowded(window))
-
-    def finish_on_kept(p, state, window):
-        """The digits from the ``p``-th on and the upper middle value from
-        the keys one gathering pass keeps: those of each row's own window
-        ``[base, base + 2 ** wide)``, by cluster and offset, so that a count
-        among them is the count over ``X`` less the keys under ``base``.
-        Where the windows still hold more keys than the slots are made for
-        (the loop stopped at ``_MOST_DIGITS_ON_X``: the pass is told to skip)
-        or a slot spilled, the digits are counted on ``X``; there, and where
-        the upper rank of some pair lies beyond its window (a median within
-        1e-6 of zero, where f32 keys are sparse), the successor pass runs on
-        ``X``."""
-        base, under_base, wide, _ = window
-        kept, spilled = passes.gather(arr, labels, base, wide, windows_crowded(window))
-        ahead, in_window = _pallas_l1.kept_by_cluster(passes, kept, k)
-        beyond = (in_window > 0) & (upper - under_base >= in_window)
-
-        def count_below(thr0, step):
-            off = thr0 - base + step * jnp.arange(_N_THR, dtype=ktype)[:, None, None]
-            return under_base + _pallas_l1.kept_under(passes, kept, off, ahead)
-
-        def digits_on_kept(state):
-            low, _, under_end = jax.lax.fori_loop(p, digits, narrow_by(count_below), state)
-            return low, under_end
-
-        def upper_from_kept(low, under_end):
-            return low, jnp.where(under_end > upper, low, base + _pallas_l1.kept_next(passes, kept, low - base))
-
-        def among_kept(state):
-            return upper_from_kept(*digits_on_kept(state))
-
-        def back_to_x(state):
-            return upper_from_x(*jax.lax.cond(spilled, functools.partial(digits_on_x, p), digits_on_kept, state))
-
-        return jax.lax.cond(spilled | jnp.any(beyond), back_to_x, among_kept, state)
-
-    first = jnp.full((k, d), -(1 << (bits - 1)), ktype)
-    none = jnp.zeros((k, d), jnp.int32)
-    state = (first, none, jnp.broadcast_to(counts, (k, d)))
-    if passes.gather is None:
-        low, high = upper_from_x(*digits_on_x(0, state))
-    else:  # the first digit's bracket is every pair's first window
-        window = (first, none, none.astype(ktype), none)
-        low, high = finish_on_kept(*jax.lax.while_loop(another_digit_on_x, note_window, (jnp.int32(0), state, window)))
+    low, high = _selection.order_statistics(arr, lower, upper, counts, passes, labels)
     med = 0.5 * _from_key(low, arr.dtype) + 0.5 * _from_key(high, arr.dtype)
     return jnp.where(counts > 0, med, prev.astype(arr.dtype))
 

@@ -51,70 +51,34 @@ from jax.sharding import PartitionSpec as P
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core._pallas_select import _VMEM_LIMIT, _lane_partials, _pick_tn, _round_up, tall_narrow_serves
+
 _VMEM = pltpu.VMEM
 
 __all__ = ["fused_lloyd_step", "lloyd_pass_program", "lloyd_pass_serves"]
 
 # k up to the widest tile of X (d < 128): the (k, tn) distance block is then no
-# taller than the (d, tn) tile it is made from. A grid step takes 2 MiB of f32 X
-# (8192 rows at d 64: 4096 to 32768 read alike on the chip, 2048 6 % slower;
-# PERF.md, PR 28), fewer where the tile twice (two pipeline buffers), its bf16
-# copy, its square and five (k, tn) f32 temporaries would pass _TILE_BYTES of
-# the _VMEM_LIMIT asked for (k 128 at d 120: 4.2 KB a row, 4096 rows)
+# taller than the (d, tn) tile it is made from. How a grid step is sized
+# (_pick_tn, _TILE_BYTES of the _VMEM_LIMIT asked for) is the tall narrow
+# kernels' own, in core/_pallas_select.py
 _K_MAX = 128
-_VMEM_LIMIT = 32 * 1024 * 1024
-_TILE_BYTES = 24 * 1024 * 1024
-_TILE_X_BYTES = 2 * 1024 * 1024
 
 
 def lloyd_pass_serves(backend: str, dtype, shape, k: int, split, devices: int = 1) -> bool:
     """The gate: does one fit's Lloyd step run the fused pass? A pure
     function of what the code sees in its input.
 
-    Yes where the backend is a TPU (x64 off, its platform default: Mosaic
-    refuses 64-bit traces), the data f32 and 2-D, ``d`` a multiple of 8
-    under 128 (there the chip keeps the array feature-major and ``x.T`` is
-    free; at ``d >= 128`` it is row-major and the XLA step stays until a
-    cell asks), ``k <= 128`` (``_K_MAX``: the distance block no taller
-    than the widest tile of ``X``, so the VMEM budget holds at ``tn >=
-    4096``), and ``X`` lies on one device or is split 0 over ``devices``
-    with equal shards (``n`` a multiple of them: the array is then its
-    physical self, no pad rows). Everything else runs the XLA step."""
-    if backend != "tpu" or jax.config.jax_enable_x64:
-        return False
-    if np.dtype(dtype) != np.float32 or len(shape) != 2:
-        return False
-    n, d = int(shape[0]), int(shape[1])
-    if d % 8 or not 8 <= d < 128 or not 1 <= k <= _K_MAX or n < 1:
-        return False
-    if split is None or devices == 1:
-        return True
-    return split == 0 and n % devices == 0
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
-
-
-def _pick_tn(n: int, d: int, k8: int) -> int:
-    """Rows a grid step: a multiple of 1024, the tiling of the 1-D label
-    output, that keeps the step's VMEM inside ``_TILE_BYTES``. Up to 1024
-    rows the chip tiles that output by the power of two that holds it
-    (128 at least), and the one block has to be just that."""
-    if n <= 1024:
-        return max(128, 1 << (n - 1).bit_length())
-    per_row = d * (4 * 2 + 2 + 4) + k8 * 4 * 5
-    tn = max(1024, min(_TILE_X_BYTES // (4 * d), _TILE_BYTES // per_row) // 1024 * 1024)
-    return min(tn, _round_up(n, 1024))
-
-
-def _lane_partials(v, tn: int):
-    """(r, tn) -> (r, 128): the tile's 128-lane groups added up, the
-    cross-lane sum left to the caller (once a pass, not once a tile)."""
-    acc = v[:, :128]
-    for j in range(1, tn // 128):
-        acc = acc + v[:, j * 128:(j + 1) * 128]
-    return acc
+    Yes where the kernels over ``(d, tn)`` blocks of ``x.T`` serve
+    (``_pallas_select.tall_narrow_serves``: the backend a TPU, x64 off, its
+    platform default, since Mosaic refuses 64-bit traces; the data f32 and
+    2-D; ``d`` a multiple of 8 under 128, where the chip keeps the array
+    feature-major and ``x.T`` is free, while at ``d >= 128`` it is row-major
+    and the XLA step stays until a cell asks; ``X`` on one device or split 0
+    over ``devices`` with equal shards, ``n`` a multiple of them: the array
+    is then its physical self, no pad rows) and ``k <= 128`` (``_K_MAX``:
+    the distance block no taller than the widest tile of ``X``, so the VMEM
+    budget holds at ``tn >= 4096``). Everything else runs the XLA step."""
+    return 1 <= k <= _K_MAX and tall_narrow_serves(backend, dtype, shape, split, devices)
 
 
 def _make_kernel(n: int, k: int, k8: int, tn: int, labels: bool):

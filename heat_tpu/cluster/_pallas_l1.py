@@ -1,7 +1,8 @@
-"""Pallas TPU kernels of the L1 family (KMedians, KMedoids): the four passes
-over f32 ``X`` that one iteration is made of, and two small ones over the
-keys the fourth keeps. Nothing is of ``X``'s size besides ``X`` and the label
-vector.
+"""Pallas TPU kernel of the L1 family's assignment (KMedians, KMedoids), and
+the passes one of their iterations is made of: the assignment here, the median
+selection's from ``core/_pallas_select.py`` (the exact counting selection that
+``ht.percentile`` runs too; by label here: a row counts for its own cluster's
+medians). Nothing is of ``X``'s size besides ``X`` and the label vector.
 
 ``X`` is tiled as KMeans' pass tiles it (``_pallas``): the chip keeps a tall
 ``f32[n, d]`` with ``d < 128`` feature-major, ``x.T`` is a bitcast, a grid
@@ -9,49 +10,7 @@ step takes a block ``(d, tn)`` with the rows on the lanes.
 
     assign   lab = first argmin_c sum_j |x_j - c_cj|      VPU, k x d a row: no matmul form
              counts (int32), sum of the least distances   lane partials
-    count    key = order-preserving int32 image of x      3 VPU ops
-             thr = thr0[lab] + t * step,  t < T           k selects, then T = _N_THR compares
-             out[t, c, j] += #{rows of c: key_j < thr}    one-hot dot on the MXU, exact
-    next     out[c, j] = min{key_j > at[lab, j]}          the successor, by cluster
-    gather   off = key - base[lab],  0 <= off < 2**bits[lab]    the keys still in their pair's window:
-             kept <- lab << _LABEL_SHIFT | off                  sorted slots, lane by lane
-
-``count`` is one digit of a radix selection (``_kcluster._cluster_medians``
-drives it): every per-cluster, per-feature order statistic at once, for a
-price that does not grow with ``k`` beyond the ``k`` selects. The counts of a
-tile (at most ``tn`` < 2^24) are exact in the f32 accumulator of the dot and
-are added up as int32.
-
-After a few digits a bracket holds a few keys in a thousand, and the other
-digits are counted on them (and on those of the bracket above, for the upper
-middle value of an even count; a pair whose newest bracket holds under
-``_WINDOW_MIN_KEYS`` keys, because its median lies near zero where f32 keys
-are sparse, keeps the window of an earlier, wider bracket). How many digits
-that takes the counts say themselves: after every digit they give the keys
-each pair's window holds, and the selection goes on counting on ``X`` while
-some feature's windows hold more than the slots below are made for
-(``crowded``: over one row in ``_GATHER_MOST_OF_X``), from the
-``_WINDOW_FIRST_DIGIT``-th digit to the ``_MOST_DIGITS_ON_X``-th: eight digits
-on unit blobs near zero, eleven on the same blobs around 10, twelve around
-100. The chip has no vector scatter, so
-``gather`` folds the lanes: a block's ``tn / 128`` lane chunks ``(d, 128)``
-go, one after the other, into ``_SLOTS`` ascending slots ``(d, 128)`` by a
-chain of min / max (a kept key, or the type's max, ripples to its place; what
-falls off the end is a spill), and the slots of ``_KEPT_STEPS`` grid steps go
-the same way into the ``_KEPT_SLOTS`` slots of one output block. The kept
-array is ``int32[d, kept_lanes]``: at 18 750 000 x 64, 64 x 294 912, 75 MB,
-1.6 % of ``X``. A kept key carries its row's label above its offset, so one
-comparison with ``c << _LABEL_SHIFT | off`` says cluster and side:
-``kept_below`` counts a feature's kept keys under each of ``q`` such
-thresholds, ``kept_above`` finds the least one over each
-(``kept_by_cluster``, ``kept_under`` and ``kept_next`` speak in clusters and
-offsets: the format stays in this module). A spill (rows sorted by a
-feature, many equal values) sends the selection back to ``X`` for its last
-digits, from the digit it had reached (``_kcluster._cluster_medians``); where
-the windows are still ``crowded`` after ``_MOST_DIGITS_ON_X`` digits (many
-equal values, values beyond a thousand noise widths from zero), ``gather`` is
-told to skip: every grid step asks for the first block, so nothing more is
-read, and does nothing.
+    count, next, gather, and the two kernels over the kept keys: ``core/_pallas_select.py``
 
 Every kernel that reads all of ``X`` is named for its phase,
 ``kmedians.assign.pass`` or ``kmedians.select.pass`` (``count``, ``next`` and
@@ -66,121 +25,20 @@ from __future__ import annotations
 import functools
 from typing import Callable, NamedTuple, Optional
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ._pallas import _VMEM_LIMIT, _lane_partials, _pick_tn, _round_up, lloyd_pass_serves
+from ..core import _pallas_select as _ps
+from ..core._pallas_select import _MOST_CLUSTERS, _call, _const, _grid_kernel, _lane_partials, _round_up, _table, _vpu_tn, _x_block
+from ._pallas import lloyd_pass_serves
 
-_VMEM = pltpu.VMEM
-_SMEM = pltpu.SMEM
-_I32_MAX = np.iinfo(np.int32).max
+__all__ = ["L1Passes", "l1_passes", "l1_passes_serve"]
 
-__all__ = ["L1Passes", "crowded", "gather_pays", "kept_by_cluster", "kept_lanes", "kept_next", "kept_under", "l1_passes",
-           "l1_passes_serve"]
-
-
-# bits of the key a counting pass settles: 2**bits - 1 thresholds a pass,
-# 32 / bits passes for f32. At 18.75M x 64 on a v5e a pass of 3 thresholds
-# reads at the rate of a bare read (6.44 ms: the k selects of a row's own
-# threshold and three one-hot dots hide under it; ledger, PR 32). Against a
-# pass of 3 that still took 7.3 ms (an earlier form of this kernel; builder's
-# chip runs, PR 32): one of 7 took 9.25, one of 15 15.3, one of 1 7.2, so two
-# bits were the fewest ms a bit (3.2; three bits 3.1 with an uneven first
-# pass), and every threshold past the third is VPU time the read cannot hide
-_RADIX_BITS = 2
-_N_THR = 2 ** _RADIX_BITS - 1
-
-# ``gather`` keeps the keys of each row's own window: a bracket of its (cluster, feature) pair and the one above it.
-# The bracket is the pair's newest with _WINDOW_MIN_KEYS keys in it, so that the upper middle value is in the window too
-# (a median near zero lies where f32 keys are sparse: its ninth bracket holds a handful of 2.3 M keys, its seventh a
-# hundred), and no earlier than the fourth, so that an offset fits under the label. A kept key is one int32: its offset
-# in the window with the label above it, from bit _LABEL_SHIFT.
-_WINDOW_MIN_KEYS = 32
-_WINDOW_FIRST_DIGIT = 4
-_LABEL_SHIFT = 26  # a window of the fourth digit is 2 ** 25 keys; five bits of label above it
-_MOST_CLUSTERS = 32  # the last one's kept keys end under the type's max, which is what an empty slot holds
-assert 32 - _RADIX_BITS * _WINDOW_FIRST_DIGIT < _LABEL_SHIFT and (_MOST_CLUSTERS << _LABEL_SHIFT) - 1 <= _I32_MAX
-# Per grid step the tn / 128 lane chunks fold onto _SLOTS sorted slots (d, 128), and _KEPT_STEPS steps fold their slots
-# onto the _KEPT_SLOTS slots of one output block. Both are sized for the densest windows the rule below lets through, at
-# 18.75M x 64, k 8, tn 8192. A window is two brackets, so where a feature's brackets hold a share s of its rows a lane
-# position of a step (64 rows) holds 128 s kept keys on average and one of a block of 16 steps 2048 s; over its slots by
-# Poisson, summed over the 18.75M positions of a pass (1.17M of blocks). Passes that spilled of ten on the cell's data
-# after eight digits (unit blobs near zero: the densest feature one row in 970 to 1 270, the mean one in 1 680 to 2 250;
-# builder's chip runs, PR 37, five iterations of two seeds) against that reckoning, by slots / steps / kept slots: 4 / 8 /
-# 8 six (expected 0.53 spills a pass); 6 / 8 / 8 two (0.12: the block's eight slots, a lane position of eight steps
-# expects one key); 5 / 16 / 16 none (0.006, one pass in 150); 6 / 16 / 12 none (0.008); 6 / 8 / 12 and 6 / 16 / 16 none
-# (9e-5: one pass in 10 ** 4). Where every feature is as dense as the rule allows, one row in 768, 6 / 16 / 16 expects
-# 0.012 + 0.005. What a slot costs: the pass takes 10.67 ms with 4 slots (ledger, PR 33 to 36), 12.63 with 6 and blocks
-# of 8 / 8, 13.26 with blocks of 16 / 16 (the fold of six slots onto sixteen; builder's chip runs, PR 37, device trace;
-# alone on the host's clock 11.7, 13.8, 14.4, and 13.2 with 5 / 16 / 16), against 6.44 for the counting pass it stands in
-# for. The kept array is as large as with 8 / 8 (75 MB) and an op over it takes what it took (0.25 ms at 24 thresholds a
-# feature); 8 / 12 keeps 113 MB
-_SLOTS = 6
-_KEPT_STEPS = 16
-_KEPT_SLOTS = 16
-# rows a cluster from which the gather pays. A window holds a number of keys that does not grow with n (32 to a few
-# hundred), so what is kept of X goes as k / n, and under some 2 ** 16 rows a cluster a lane position holds more keys
-# than slots now and then: the selection then ends on X and the gathering pass was for nothing. Five iterations, ms,
-# selection on X to its end / with the gather (builder's chip runs, PR 33, nine digits and four slots; d 64 unless said):
-# k 8: 262 144 rows 11.2 / 11.9, 524 288 20.1 / 13.8, 1 048 576 38.0 / 25.6, 18 750 000 640.2 / 420.9; k 16: 1 048 576
-# 55.8 / 59.7, 4 194 304 213.0 / 140.6; k 32: 4 194 304 392.3 / 280.0; k 4, d 16: 1 048 576 10.5 / 7.2; k 2, d 8:
-# 2 097 152 14.0 / 13.6
-_GATHER_MIN_ROWS_A_CLUSTER = 1 << 17
-# and the share of a feature's rows that its windows' brackets may hold for the gathering pass to run: the rule by which
-# the selection stops counting on X. Every digit leaves the windows a quarter of what they held, and the counting passes
-# say how much that is, so the selection gathers after the first digit that leaves no feature over one row in
-# _GATHER_MOST_OF_X. The densest feature's brackets hold, by digits counted (builder's chip runs, PR 37, 18.75M x 64, k 8):
-# the cell's blobs, seven: one row in 240 to 310, eight: 970 to 1 270, nine: 3 800 to 5 000; the same blobs around 10
-# (f32 keys lie sixteen times as dense there, and every feature alike), nine: 130 to 150, ten: 520 to 600, eleven: 2 060
-# to 2 370; around 100, eleven: 260 to 300, twelve: 1 030 to 1 190. Where the limit lies: a spill after eight digits
-# costs the eight counting passes left and the successor (64 ms), and one digit fewer on X saves 6.44 ms less the 2.6 that
-# two more slots and the larger blocks cost the gathering pass, so a spill has to stay rarer than one pass in 17 however the features lie. With
-# every feature at one row in 768 it is one in 60 (above); at one in 640, one in 10; at one in 512 three in two (blobs
-# around 10 after ten digits, one row in 517 to 597 of every feature: a block of 16 / 16 spilled in one pass of three).
-# On the cell's data (2 000 simulated seeds) one seed in a thousand has a feature over one in 768 after eight digits and
-# counts a ninth; none fits after seven
-_GATHER_MOST_OF_X = 768
-# and the digits after which it stops counting whatever the windows hold. The gathering pass and the ops over what it
-# keeps cost two counting passes, the successor pass that they save costs two, so a gather after any digit before the
-# last is cheaper than the end on X; but windows that are crowded after twelve digits (2 ** 9 keys, 2 ** 8 a bracket) are
-# crowded by equal values or values a thousand noise widths from zero (blobs around 1 000 would fit after fourteen),
-# which is where lane positions spill whatever the counts say (a sorted column, one repeated value), and a gathering
-# pass that spills is 13 ms for nothing. Stopping here costs such data nothing: the digits left are counted on X either way
-_MOST_DIGITS_ON_X = 12
-assert _WINDOW_FIRST_DIGIT <= _MOST_DIGITS_ON_X <= 32 // _RADIX_BITS  # the kernels are f32's
-
-
-def _key_type(dtype):
-    """(integer type, bits) of the order-preserving image of a float type."""
-    bits = 8 * np.dtype(dtype).itemsize
-    return (jnp.int64 if bits == 64 else jnp.int32), bits
-
-
-def _flip(b, bits: int):
-    """Sign-magnitude <-> two's complement, its own inverse: negative floats
-    order backwards as integers, so their magnitude bits are flipped."""
-    return b ^ ((b >> (bits - 1)) & ((1 << (bits - 1)) - 1))
-
-
-def _to_key(x: jax.Array) -> jax.Array:
-    """Integers with the order of the floats: ``a < b`` as floats iff
-    ``key(a) < key(b)`` (``-0.0`` just under ``0.0``, NaNs beyond the
-    infinities)."""
-    ktype, bits = _key_type(x.dtype)
-    raw = jax.lax.bitcast_convert_type(x, jnp.dtype(f"int{bits}"))
-    return _flip(raw, bits).astype(ktype)
-
-
-def _from_key(key: jax.Array, dtype) -> jax.Array:
-    _, bits = _key_type(dtype)
-    return jax.lax.bitcast_convert_type(_flip(key, bits).astype(jnp.dtype(f"int{bits}")), dtype)
+_SELECT = "kmedians.select"  # the selection's kernels: ``.pass`` over X, ``.candidates`` over the kept keys
 
 
 class L1Passes(NamedTuple):
@@ -206,99 +64,6 @@ def l1_passes_serve(backend: str, dtype, shape, k: int, split, devices: int = 1)
     return 2 <= k <= _MOST_CLUSTERS and lloyd_pass_serves(backend, dtype, shape, k, split, devices)
 
 
-def _grid_kernel(tile, n: int, tn: int, n_acc: int, init, before=None, skips: bool = False):
-    """``tile(refs, valid)`` over the grid: the accumulators (the last
-    ``n_acc`` refs) set to ``init`` at step 0; ``valid`` is ``None`` on a
-    whole tile and the (1, tn) mask of the rows that exist on the last.
-    ``before(refs, i, when)`` runs first in every step ``i``. With ``skips``
-    the first ref is a scalar, and where it is not 0 no step does anything
-    (``when`` is ``pl.when`` with that in it: no ``cond`` around the rest)."""
-    steps = pl.cdiv(n, tn)
-    tail = n - (steps - 1) * tn
-
-    def kernel(*refs):
-        i = pl.program_id(0)
-        if skips:
-            go, refs = refs[0][0] == 0, refs[1:]
-        when = lambda cond: pl.when(cond & go if skips else cond)
-
-        @when(i == 0)
-        def _init():
-            for ref in refs[len(refs) - n_acc:]:
-                ref[...] = jnp.full(ref.shape, init, ref.dtype)
-
-        if before is not None:
-            before(refs, i, when)
-        whole = steps - 1 if tail < tn else steps  # the steps whose tile is whole
-        if whole == steps and not skips:
-            tile(refs, None)
-            return
-
-        @when(i < whole)
-        def _whole():
-            tile(refs, None)
-
-        if whole == steps:
-            return
-
-        @when(i == steps - 1)
-        def _last():
-            # what the last block holds past row n is unspecified
-            tile(refs, jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1) < tail)
-
-    return kernel
-
-
-def _own(lab, table_ref, k: int):
-    """``table[:, lab]``: each row's own column of a (d, k8) table, as (d, tn)."""
-    sel = table_ref[:, 0:1]
-    for c in range(1, k):
-        sel = jnp.where(lab == c, table_ref[:, c:c + 1], sel)
-    return sel
-
-
-def _call(kernel, name: str, n: int, tn: int, in_specs, out_shape, out_specs, interpret: bool, prefetch: int = 0):
-    """``kernel`` over the blocks of ``tn`` rows; its first ``prefetch``
-    operands are scalars that the index maps are given too."""
-    grid = dict(grid=(pl.cdiv(n, tn),), in_specs=in_specs, out_specs=out_specs)
-    if prefetch:
-        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=prefetch, **grid))
-    return pl.pallas_call(
-        kernel, out_shape=out_shape, **grid,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
-        name=name, interpret=interpret,
-    )
-
-
-def _vpu_tn(n: int, d: int, k8: int) -> int:
-    """Rows a grid step of the two passes that are all VPU (assign, next): a
-    quarter of the 2 MiB tile the streaming passes take. Their (d, tn)
-    temporaries then stay near the registers: at 18.75M x 64 the assignment
-    reads 11.7 ms at 2048 rows, 12.4 at 4096, 16.4 at 8192, 13.8 at 1024
-    (the successor 12.6 / 15.8 / 17.2 / 14.5), while a counting pass is
-    fastest on the whole tile (7.3 ms at 8192, 8.1 at 4096; builder's chip
-    runs, PR 32)."""
-    tn = _pick_tn(n, d, k8)
-    return tn if n <= 1024 else max(1024, tn // 4096 * 1024)
-
-
-def _lane_least(v, tn: int):
-    """(r, tn) -> (r, 128): the least of the tile's 128-lane groups, lane by lane."""
-    acc = v[:, :128]
-    for j in range(1, tn // 128):
-        acc = jnp.minimum(acc, v[:, j * 128:(j + 1) * 128])
-    return acc
-
-
-def _table(values, k8: int, dtype):
-    """A (k, d) table as the kernels read it: (d, k8), a cluster a column."""
-    return jnp.pad(values.astype(dtype), ((0, k8 - values.shape[0]), (0, 0))).T
-
-
-def _const(shape):
-    return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape), memory_space=_VMEM)
-
-
 @functools.lru_cache(maxsize=64)
 def _assign_program(n: int, d: int, k: int, interpret: bool):
     k8, tn = _round_up(k, 8), _vpu_tn(n, d, _round_up(k, 8))
@@ -322,10 +87,10 @@ def _assign_program(n: int, d: int, k: int, interpret: bool):
 
     call = _call(
         _grid_kernel(tile, n, tn, 2, 0), "kmedians.assign.pass", n, tn,
-        [pl.BlockSpec((d, tn), lambda i: (0, i), memory_space=_VMEM), _const((d, k8))],
+        [_x_block(d, tn), _const((d, k8))],
         [jax.ShapeDtypeStruct((n,), jnp.int32), jax.ShapeDtypeStruct((k8, 128), jnp.int32),
          jax.ShapeDtypeStruct((1, 128), jnp.float32)],
-        [pl.BlockSpec((tn,), lambda i: (i,), memory_space=_VMEM), _const((k8, 128)), _const((1, 128))],
+        [pl.BlockSpec((tn,), lambda i: (i,), memory_space=_ps._VMEM), _const((k8, 128)), _const((1, 128))],
         interpret,
     )
 
@@ -337,281 +102,29 @@ def _assign_program(n: int, d: int, k: int, interpret: bool):
 
 
 @functools.lru_cache(maxsize=64)
-def _count_program(n: int, d: int, k: int, interpret: bool):
-    k8, tn = _round_up(k, 8), _pick_tn(n, d, _round_up(k, 8))
-
-    def tile(refs, valid):
-        step_ref, xt_ref, lab_ref, thr_ref, out_ref = refs
-        key = _to_key(xt_ref[...])
-        lab = lab_ref[...].reshape(1, tn)
-        thr = _own(lab, thr_ref, k)
-        onehot = jax.lax.broadcasted_iota(jnp.int32, (k8, tn), 0) == lab
-        if valid is not None:
-            onehot = onehot & valid
-        onehot = jnp.where(onehot, 1.0, 0.0).astype(jnp.bfloat16)
-        step = step_ref[0]
-        for t in range(_N_THR):
-            below = jnp.where(key < thr + t * step, 1.0, 0.0).astype(jnp.bfloat16)  # (d, tn)
-            out_ref[t] += jax.lax.dot_general(
-                onehot, below, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            ).astype(jnp.int32)  # (k8, d)
-
-    call = _call(
-        _grid_kernel(tile, n, tn, 1, 0), "kmedians.select.pass", n, tn,
-        [pl.BlockSpec(memory_space=_SMEM), pl.BlockSpec((d, tn), lambda i: (0, i), memory_space=_VMEM),
-         pl.BlockSpec((tn,), lambda i: (i,), memory_space=_VMEM), _const((d, k8))],
-        jax.ShapeDtypeStruct((_N_THR, k8, d), jnp.int32), _const((_N_THR, k8, d)), interpret,
-    )
-
-    def run(x, labels, thr0, step):
-        return call(jnp.reshape(step, (1,)).astype(jnp.int32), x.T, labels, _table(thr0, k8, jnp.int32))[:, :k]
-
-    return run
-
-
-@functools.lru_cache(maxsize=64)
-def _next_program(n: int, d: int, k: int, interpret: bool):
-    k8, tn = _round_up(k, 8), _vpu_tn(n, d, _round_up(k, 8))
-
-    def tile(refs, valid):
-        xt_ref, lab_ref, at_ref, out_ref = refs
-        key = _to_key(xt_ref[...])
-        lab = lab_ref[...].reshape(1, tn)
-        above = jnp.where(key > _own(lab, at_ref, k), key, _I32_MAX)
-        for c in range(k):
-            mine = lab == c if valid is None else (lab == c) & valid
-            least = _lane_least(jnp.where(mine, above, _I32_MAX), tn)
-            out_ref[c * d:(c + 1) * d, :] = jnp.minimum(out_ref[c * d:(c + 1) * d, :], least)
-
-    call = _call(
-        _grid_kernel(tile, n, tn, 1, _I32_MAX), "kmedians.select.pass", n, tn,
-        [pl.BlockSpec((d, tn), lambda i: (0, i), memory_space=_VMEM),
-         pl.BlockSpec((tn,), lambda i: (i,), memory_space=_VMEM), _const((d, k8))],
-        jax.ShapeDtypeStruct((k * d, 128), jnp.int32), _const((k * d, 128)), interpret,
-    )
-
-    def run(x, labels, at):
-        return jnp.min(call(x.T, labels, _table(at, k8, jnp.int32)), axis=1).reshape(k, d)
-
-    return run
-
-
-def kept_lanes(n: int, d: int, k: int) -> int:
-    """Lanes of the array ``gather`` keeps of ``n`` rows: ``_KEPT_SLOTS``
-    x 128 for every ``_KEPT_STEPS`` grid steps (16 x 128 of 16 x 8192 rows
-    at ``d`` 64: 1.6 % of ``X``)."""
-    return pl.cdiv(pl.cdiv(n, _pick_tn(n, d, _round_up(k, 8))), _KEPT_STEPS) * _KEPT_SLOTS * 128
-
-
-def _insert(slots, v):
-    """``v`` into the ascending ``slots``, lane by lane; returns what fell
-    off their end (the largest)."""
-    for s in range(len(slots)):
-        slots[s], v = jnp.minimum(slots[s], v), jnp.maximum(slots[s], v)
-    return v
-
-
-@functools.lru_cache(maxsize=64)
-def _gather_program(n: int, d: int, k: int, interpret: bool):
-    tn = _pick_tn(n, d, _round_up(k, 8))
-    width = _KEPT_SLOTS * 128
-    empty = lambda: jnp.full((d, 128), _I32_MAX, jnp.int32)
-
-    group = min(tn, 1024)  # rows a turn of the loop: a tile of the 1-D label block, eight lane chunks
-    tail = n - (pl.cdiv(n, tn) - 1) * tn
-
-    def tile(refs, valid):
-        xt_ref, lab_ref, base_ref, kept_ref, spill_ref = refs
-
-        def fold(g, carry):
-            slots, spill = list(carry[:-1]), carry[-1]
-            start = pl.multiple_of(g * group, group)
-            lab = lab_ref[pl.ds(start, group)].reshape(1, group)
-            if valid is not None:  # the last block: its rows from ``tail`` on do not exist
-                lab = jnp.where(start + jax.lax.broadcasted_iota(jnp.int32, (1, group), 1) < tail, lab, -1)
-            for j in range(group // 128):
-                own = lab[:, j * 128:(j + 1) * 128]  # (1, 128)
-                base = base_ref[0:d, :]
-                for c in range(1, k):
-                    base = jnp.where(own == c, base_ref[c * d:(c + 1) * d, :], base)
-                bits = base & 31  # a window's bits ride in the five lowest of its base, which are zero
-                off = _to_key(xt_ref[:, pl.ds(pl.multiple_of(start + j * 128, 128), 128)]) - (base - bits)
-                inside = jax.lax.shift_right_logical(off, bits) == 0  # 0 <= off < 2 ** bits
-                if valid is not None:
-                    inside = inside & (own >= 0)
-                spill = jnp.minimum(spill, _insert(slots, jnp.where(inside, off | (own << _LABEL_SHIFT), _I32_MAX)))
-            return (*slots, spill)
-
-        *slots, spill = jax.lax.fori_loop(0, tn // group, fold, (empty(),) * (_SLOTS + 1))
-        for v in slots:
-            for s in range(_KEPT_SLOTS):
-                held = kept_ref[:, s * 128:(s + 1) * 128]
-                kept_ref[:, s * 128:(s + 1) * 128] = jnp.minimum(held, v)
-                v = jnp.maximum(held, v)
-            spill = jnp.minimum(spill, v)
-        spill_ref[...] = jnp.minimum(spill_ref[...], spill)
-
-    def fresh_block(refs, i, when):
-        @when(i % _KEPT_STEPS == 0)
-        def _():
-            refs[3][...] = jnp.full((d, width), _I32_MAX, jnp.int32)
-
-    # told to skip, every step asks for the first block: nothing is read after it, and what comes out means nothing
-    at = lambda i, skip_ref: jnp.where(skip_ref[0] == 0, i, 0)
-    call = _call(
-        _grid_kernel(tile, n, tn, 1, _I32_MAX, fresh_block, skips=True), "kmedians.select.pass", n, tn,
-        [pl.BlockSpec((d, tn), lambda i, skip: (0, at(i, skip)), memory_space=_VMEM),
-         pl.BlockSpec((tn,), lambda i, skip: (at(i, skip),), memory_space=_VMEM), _const((k * d, 128))],
-        [jax.ShapeDtypeStruct((d, kept_lanes(n, d, k)), jnp.int32), jax.ShapeDtypeStruct((d, 128), jnp.int32)],
-        [pl.BlockSpec((d, width), lambda i, skip: (0, at(i, skip) // _KEPT_STEPS), memory_space=_VMEM), _const((d, 128))],
-        interpret, prefetch=1,
-    )
-
-    def run(x, labels, base, bits, skip):
-        # a cluster's windows along all 128 lanes: the kernel loads them, it does not broadcast
-        lanes = jnp.broadcast_to((base | bits).astype(jnp.int32)[:, :, None], (k, d, 128)).reshape(k * d, 128)
-        kept, spill = call(jnp.reshape(skip, (1,)).astype(jnp.int32), x.T, labels, lanes)
-        return kept, skip | (jnp.min(spill) < _I32_MAX)
-
-    return run
-
-
-@functools.lru_cache(maxsize=64)
-def _kept_program(m: int, d: int, q: int, above: bool, interpret: bool):
-    """A pass over the kept array ``(d, m)`` against ``q`` thresholds a
-    feature: how many of a feature's kept lie under each, or (``above``)
-    the least kept over each. Empty slots hold the type's max: under no
-    threshold, over every one."""
-    tm = _KEPT_SLOTS * 128  # one block of ``gather`` a step: no block is cut
-
-    def tile(refs, _):
-        kept_ref, thr_ref, out_ref = refs
-        kept = kept_ref[...]
-        for i in range(q):
-            thr, rows = thr_ref[:, i:i + 1], slice(i * d, (i + 1) * d)
-            if above:
-                out_ref[rows, :] = jnp.minimum(out_ref[rows, :], _lane_least(jnp.where(kept > thr, kept, _I32_MAX), tm))
-            else:
-                out_ref[rows, :] += _lane_partials(jnp.where(kept < thr, 1, 0), tm)
-
-    call = _call(
-        _grid_kernel(tile, m, tm, 1, _I32_MAX if above else 0), "kmedians.select.candidates", m, tm,
-        [pl.BlockSpec((d, tm), lambda i: (0, i), memory_space=_VMEM), _const((d, q))],
-        jax.ShapeDtypeStruct((q * d, 128), jnp.int32), _const((q * d, 128)), interpret,
-    )
-
-    def run(kept, thr):
-        out = call(kept, thr.astype(jnp.int32).T).reshape(q, d, 128)
-        return jnp.min(out, axis=2) if above else jnp.sum(out, axis=2, dtype=jnp.int32)
-
-    return run
-
-
-def _kept_key(off):
-    """What ``gather`` keeps of a key ``off`` above the base of its window,
-    for ``off`` of shape (..., k, d): the keys of one cluster compare by
-    offset, and every key of a cluster lies under the next one's zero."""
-    return (jnp.arange(off.shape[-2], dtype=jnp.int32)[:, None] << _LABEL_SHIFT) + off.astype(jnp.int32)
-
-
-def kept_by_cluster(passes: L1Passes, kept, k: int):
-    """``(ahead, in_window)``, each int32 (k, d): a pair's kept keys of the
-    clusters before its own (what a count under one of its thresholds holds
-    besides its own), and its own."""
-    d = kept.shape[0]
-    # where each cluster's kept keys start; the last one's end is the type's max (``k << _LABEL_SHIFT`` is 2 ** 31 at
-    # k 32), over every kept key and under no empty slot
-    starts = jnp.concatenate([_kept_key(jnp.zeros((k, d), jnp.int32)), jnp.full((1, d), _I32_MAX, jnp.int32)])
-    ahead = passes.kept_below(kept, starts)
-    return ahead[:k], ahead[1:] - ahead[:k]
-
-
-def kept_under(passes: L1Passes, kept, off, ahead):
-    """int32 (q, k, d): a pair's kept keys under the offset ``off[i]`` of
-    its window."""
-    q, k, d = off.shape
-    return passes.kept_below(kept, _kept_key(off).reshape(q * k, d)).reshape(q, k, d) - ahead
-
-
-def kept_next(passes: L1Passes, kept, off):
-    """int32 (k, d): the least offset over ``off`` among a pair's kept keys
-    (no offset of a window where it keeps none)."""
-    return passes.kept_above(kept, _kept_key(off)) - _kept_key(jnp.zeros_like(off))
-
-
-def gather_pays(n: int, k: int) -> bool:
-    """Can finishing on the kept keys beat the remaining passes over ``X``?
-    It trades the counting passes of the digits the selection has not
-    counted on ``X`` when it stops (``crowded`` says when: eight of sixteen
-    on unit blobs near zero, at least four, twelve at most) and the
-    successor pass for one gathering pass and as many small ops, and wins
-    where the slots do not spill: from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a
-    cluster on one device, on data whose windows fit the slots by the
-    ``_MOST_DIGITS_ON_X``-th digit."""
-    return n >= k * _GATHER_MIN_ROWS_A_CLUSTER
-
-
-def crowded(held, n: int):
-    """Do the windows hold more keys than the slots are made for?
-    ``held`` (k, d) are the keys in each pair's bracket, as the counting
-    passes over the ``n`` rows gave them (summed over the devices of a split
-    array, so that every device reads the same answer): in some feature
-    more than one row in ``_GATHER_MOST_OF_X``. The selection asks after
-    every digit it counts on ``X``: while the answer is yes it counts
-    another, up to ``_MOST_DIGITS_ON_X``, and then tells ``gather`` to
-    skip."""
-    return jnp.max(jnp.sum(held, axis=0)) > n // _GATHER_MOST_OF_X
-
-
-@functools.lru_cache(maxsize=64)
 def l1_passes(k: int, shape, mesh=None, axis_name=None, interpret: bool = False) -> L1Passes:
-    """The passes for ``arr`` of ``shape``; those over the kept keys where
-    ``gather_pays`` for one device's rows. On one device they are called
-    bare. With a ``mesh`` they run under ``shard_map``, as KMeans' pass
-    does: with an ``axis_name``, ``arr`` and the labels are split 0 over it
-    in equal shards, each device passes over its rows (and keeps their
-    keys), and the counts are ``psum``med (the successors: ``pmin``, the
-    spill flag: ``pmax``) before any bracket narrows; without one ``arr``
-    is replicated and every device runs the whole pass."""
+    """The passes for ``arr`` of ``shape``: the assignment, and the
+    selection's by label (``_pallas_select.select_passes``; those over the
+    kept keys where ``gather_pays`` for one device's rows). On one device
+    they are called bare. With a ``mesh`` they run under ``shard_map``, as
+    KMeans' pass does: with an ``axis_name``, ``arr`` and the labels are
+    split 0 over it in equal shards, each device passes over its rows (and
+    keeps their keys), and the counts are ``psum``med (the successors:
+    ``pmin``, the spill flag: ``pmax``) before any bracket narrows; without
+    one ``arr`` is replicated and every device runs the whole pass."""
     n, d = int(shape[0]), int(shape[1])
     p = mesh.devices.size if axis_name is not None else 1
     assign = _assign_program(n // p, d, k, interpret)
-    count = _count_program(n // p, d, k, interpret)
-    nxt = _next_program(n // p, d, k, interpret)
-    gather = below = above = None
-    if gather_pays(n // p, k):
-        m = kept_lanes(n // p, d, k)
-        gather = _gather_program(n // p, d, k, interpret)
-        below = lambda kept, thr: _kept_program(m, d, thr.shape[0], False, interpret)(kept, thr)
-        above = lambda kept, at: _kept_program(m, d, at.shape[0], True, interpret)(kept, at)
+    select = _ps.select_passes((n, d), k, True, _SELECT, mesh, axis_name, interpret)[:5]
     if mesh is None:
-        return L1Passes(assign, count, nxt, gather, below, above)
-    rows, vec, lanes = P(axis_name, None), P(axis_name), P(None, axis_name)
+        return L1Passes(assign, *select)
     if axis_name is not None:
-        local_assign, local_count, local_next = assign, count, nxt
-        local_gather, local_below, local_above = gather, below, above
+        local_assign = assign
 
         def assign(arr, centers):
             labels, cnt, fun = local_assign(arr, centers)
             return labels, jax.lax.psum(cnt, axis_name), jax.lax.psum(fun, axis_name)
 
-        def gather(*a):
-            kept, spilled = local_gather(*a)
-            return kept, jax.lax.pmax(spilled.astype(jnp.int32), axis_name) > 0
-
-        count = lambda *a: jax.lax.psum(local_count(*a), axis_name)
-        nxt = lambda *a: jax.lax.pmin(local_next(*a), axis_name)
-        below = lambda *a: jax.lax.psum(local_below(*a), axis_name)
-        above = lambda *a: jax.lax.pmin(local_above(*a), axis_name)
-    sm = functools.partial(_shard_map, mesh=mesh, check_vma=False)
-    over_kept = (
-        sm(gather, in_specs=(rows, vec, P(), P(), P()), out_specs=(lanes, P())),
-        sm(below, in_specs=(lanes, P()), out_specs=P()),
-        sm(above, in_specs=(lanes, P()), out_specs=P()),
-    ) if gather_pays(n // p, k) else ()
-    return L1Passes(
-        sm(assign, in_specs=(rows, P()), out_specs=(vec, P(), P())),
-        sm(count, in_specs=(rows, vec, P(), P()), out_specs=P()),
-        sm(nxt, in_specs=(rows, vec, P()), out_specs=P()),
-        *over_kept,
-    )
+    assign = _shard_map(assign, mesh=mesh, check_vma=False, in_specs=(P(axis_name, None), P()),
+                        out_specs=(P(axis_name), P(), P()))
+    return L1Passes(assign, *select)

@@ -7,9 +7,11 @@ cluster). Here an iteration is an L1 assignment that never holds
 ``n x k x d`` and one selection of all ``k x d`` medians at once
 (``_kcluster._cluster_medians``): a radix selection that counts keys under
 thresholds, two bits a pass over ``X``, exact, with no copy of ``X`` and a
-number of passes that does not grow with ``k``. On a TPU, for tall narrow
-f32 data, the passes are Pallas kernels (``_pallas_l1``); on a split array
-the counts of the shards are summed before a bracket narrows.
+number of passes that does not grow with ``k``. The selection is
+``core/_selection.py``'s, by label (``ht.percentile`` runs it for all rows).
+On a TPU, for tall narrow f32 data, the passes are Pallas kernels (the
+assignment in ``_pallas_l1``, the selection's in ``core/_pallas_select``); on a
+split array the counts of the shards are summed before a bracket narrows.
 """
 
 from __future__ import annotations

@@ -7,7 +7,10 @@ Distribution notes from the reference: ``mean``/``var`` (statistics.py:892/
 ops carrying a value∥index payload (:1369); ``percentile`` (:1407) runs a
 distributed sort plus halo exchange. On TPU all of these are single jnp
 reductions over the sharded global array — XLA emits the same combine
-collectives — so the hand-built merge machinery disappears.
+collectives — so the hand-built merge machinery disappears. ``percentile`` /
+``median`` along the sample axis of a 2-D array do not sort where
+``_selection_form`` says so: they count (``_selection.order_statistics``, the
+exact radix selection KMedians' medians come from, for all rows).
 """
 
 from __future__ import annotations
@@ -21,10 +24,13 @@ from typing import Optional, Tuple, Union
 
 from . import types
 from . import _operations
+from . import _pallas_select, _selection
 from .dndarray import DNDarray
 from .sanitation import sanitize_in
 from .stride_tricks import sanitize_axis
-from ..observability.tracing import span as _span
+from ..observability import telemetry as _telemetry
+from ..observability.instrument import observed_program_cache
+from ..observability.tracing import call_span as _call_span, span as _span
 
 __all__ = [
     "argmax",
@@ -340,6 +346,116 @@ def mean(x: DNDarray, axis=None, keepdims: bool = False) -> DNDarray:
     return _wrap_reduce(jnp.asarray(result), x, axis, bool(keepdims))
 
 
+# rows a device from which the ``jax.numpy`` form of the selection stands in for the distributed sort of a split
+# array. On the CPU mesh of eight (host clock, warm, three percentiles of an f32 array; builder's runs, PR 38): 8 000 x
+# 6: 37 ms against the sort's 9; 80 000 x 16: 70 against 94; 800 000 x 16: 211 against 1 315 (its first call 0.3-0.5 s
+# against 0.5-1.5: the network's program is the larger one)
+_SELECT_MIN_ROWS_A_DEVICE = 1 << 13
+
+
+def _selection_form(backend: str, dtype, shape, axis, split, devices: int, targets: int) -> str:
+    """Which form ``percentile`` takes, a pure function of what it sees in
+    its input: ``"pallas"``, the counting selection on the chip's kernels
+    (2-D along axis 0 where ``_pallas_select.tall_narrow_serves``: a TPU, f32,
+    ``d`` a multiple of 8 under 128, one device or equal split-0 shards; and
+    ``gather_pays`` for the targets of one batch on a device's rows, at
+    least ``2 ** 17`` rows a target: under that the sort of a few MB is as
+    fast); ``"xla"``, the same selection on its ``jax.numpy`` passes (2-D f32
+    / f64 along a split axis 0 in equal shards over more than one device,
+    from ``_SELECT_MIN_ROWS_A_DEVICE`` rows a device on: seventeen
+    elementwise passes whose counts are all-reduced, in place of the
+    odd-even sort network); ``"sort"`` everywhere else."""
+    if len(shape) != 2 or axis != 0 or shape[0] < 1 or shape[1] < 1:
+        return "sort"
+    batch = targets if targets < _pallas_select._MOST_TARGETS else _pallas_select._MOST_TARGETS  # (``min`` is ht's here)
+    if (_pallas_select.tall_narrow_serves(backend, dtype, shape, split, devices)
+            and _pallas_select.gather_pays(shape[0] // (devices if split == 0 else 1), batch)):
+        return "pallas"
+    if split == 0 and devices > 1 and shape[0] % devices == 0 and shape[0] // devices >= _SELECT_MIN_ROWS_A_DEVICE \
+            and np.dtype(dtype) in (np.float32, np.float64):
+        return "xla"
+    return "sort"
+
+
+@observed_program_cache("percentile.select", maxsize=64)
+def _percentile_select_program(shape, jdtype: str, on_chip: bool, ranks, weights, out_shape, mesh, axis_name,
+                               interpret: bool = False):
+    """``arr (n, d) -> (len(ranks), d)``: for every ``(lo, hi)`` of ``ranks``
+    (0-based, host-static, ``hi`` is ``lo`` or ``lo + 1``) and its weight
+    ``w`` the value ``v[lo] + w * (v[hi] - v[lo])`` of every column's order
+    statistics ``v`` (``w`` 0.0: ``v[lo]``, 1.0: ``v[hi]``, both as they
+    are), NaN where the column holds one. One jitted program: the pairs
+    that differ are the targets of ``_selection.order_statistics`` for all
+    rows, ``_MOST_TARGETS`` to a batch, every batch in the same passes over
+    ``arr`` whatever it holds; the first digit's pass, which also looks for
+    NaNs, is made once for all batches. With a ``mesh`` and an
+    ``axis_name``, ``arr`` is split 0 over it in equal shards and the counts
+    are summed over the devices before a bracket narrows."""
+    n, d = int(shape[0]), int(shape[1])
+    targets = sorted(set(ranks))
+    most = _pallas_select._MOST_TARGETS
+    batches = [targets[i:i + most] for i in range(0, len(targets), most)]
+    at = {pair: i for i, pair in enumerate(targets)}
+
+    def passes_for(q: int):
+        if on_chip:
+            return _pallas_select.select_passes((n, d), q, False, "percentile.select", mesh, axis_name, interpret)
+        return _selection.passes_xla()
+
+    def run(arr):
+        with jax.named_scope("percentile.select"):
+            under, nans = passes_for(len(batches[0])).first(arr)
+            lows, highs = [], []
+            for batch in batches:
+                lower = jnp.asarray([lo for lo, _ in batch], jnp.int32)[:, None]
+                upper = jnp.asarray([hi for _, hi in batch], jnp.int32)[:, None]
+                first_under = jnp.broadcast_to(under[:, None, :], (under.shape[0], len(batch), d))
+                low, high = _selection.order_statistics(
+                    arr, lower, upper, jnp.full((len(batch), 1), n, jnp.int32), passes_for(len(batch)), None, first_under)
+                lows.append(_pallas_select._from_key(low, arr.dtype))
+                highs.append(_pallas_select._from_key(high, arr.dtype))
+            vlo, vhi = jnp.concatenate(lows), jnp.concatenate(highs)
+            rows = []
+            for pair, w in zip(ranks, weights):
+                lo, hi = vlo[at[pair]], vhi[at[pair]]
+                rows.append(lo if w == 0.0 else hi if w == 1.0 else lo + jnp.asarray(w, arr.dtype) * (hi - lo))
+            return jnp.where(nans[None, :] > 0, jnp.nan, jnp.stack(rows)).reshape(out_shape)
+
+    return jax.jit(run)
+
+
+def _percentile_by_selection(x: DNDarray, qv: np.ndarray, interpolation: str, form: str, out_shape) -> jax.Array:
+    """The ``len(qv)`` percentiles of every column of a 2-D ``x`` by the
+    counting selection, as ``out_shape``, in one program
+    (``_percentile_select_program``)."""
+    n = x.gshape[0]
+    pos = qv / 100.0 * (n - 1)
+    lo = np.floor(pos).astype(np.int64)
+    hi = np.ceil(pos).astype(np.int64)
+    if interpolation == "lower":
+        w = np.zeros_like(pos)
+    elif interpolation == "higher":
+        w = np.ones_like(pos)
+    elif interpolation == "nearest":
+        w = (np.rint(pos).astype(np.int64) != lo).astype(np.float64)
+    elif interpolation == "midpoint":
+        w = np.where(hi > lo, 0.5, 0.0)
+    else:  # linear
+        w = pos - lo
+    split_over = x.split == 0 and x.comm.size > 1
+    mesh = x.comm.mesh if form == "pallas" and x.comm.size > 1 else None
+    prog = _percentile_select_program(
+        tuple(x.gshape), np.dtype(x.dtype.jax_type()).name, form == "pallas",
+        tuple(zip(lo.tolist(), hi.tolist())), tuple(w.tolist()), tuple(out_shape), mesh,
+        x.comm.axis_name if mesh is not None and split_over else None,
+        form == "pallas" and jax.default_backend() != "tpu",  # the kernels off the chip (the tests): interpret mode
+    )
+    _telemetry.inc(f"percentile.select.{form}")
+    if form == "pallas":
+        _telemetry.inc("percentile.select.gather")
+    return prog(x._phys if split_over else x.larray)
+
+
 def median(x: DNDarray, axis: Optional[int] = None, keepdims: bool = False) -> DNDarray:
     """Median = 50th percentile (reference: statistics.py:1018)."""
     return percentile(x, 50.0, axis=axis, keepdims=keepdims)
@@ -365,114 +481,149 @@ def percentile(
     interpolation: str = "linear",
     keepdims: bool = False,
 ) -> DNDarray:
-    """q-th percentile (reference: statistics.py:1407 — distributed sort +
-    halo + Allgather of index maps).
+    """q-th percentile: along the sample axis of a tall 2-D array an exact counting selection, no sort.
 
-    When the reduction axis is the split axis, this runs the gather-free
-    ``ht.sort`` (odd-even ppermute network, ``core.parallel``) and then
-    fetches only the two bracketing ranks per q — the TPU analog of the
-    reference's sorted-halo rank lookup. Other axes use XLA's lane-local
-    percentile on the sharded array."""
-    sanitize_in(x)
-    axis = sanitize_axis(x.shape, axis)
-    if interpolation not in ("linear", "lower", "higher", "midpoint", "nearest"):
-        raise ValueError(f"unknown interpolation {interpolation}")
-    # q stays a HOST value: the bracketing ranks must be static (they
-    # shape the program), and round-tripping a python float through
-    # jnp.asarray turns it into a tracer under ht.jit (jax inserts a
-    # convert op for the unavailable f64), breaking np.asarray below
-    if isinstance(q, (DNDarray, jax.Array)):
-        q_dev = q.larray if isinstance(q, DNDarray) else q
-        if isinstance(q_dev, jax.core.Tracer):
-            raise TypeError(
-                "percentile: q must be statically known (host value); a "
-                "traced q would make the output shape data-dependent"
-            )
-        # declared host boundary "percentile-q" (analysis/boundaries.py):
-        # the ONLY whitelisted sync in core/ — pinned by tier-1
-        with _span("ht.sync.read", what="percentile.q"):
-            q_host = np.asarray(jax.device_get(q_dev), dtype=np.float64)
-    else:
-        q_host = np.asarray(q, dtype=np.float64)
-    scalar_q = q_host.ndim == 0
-    qv = np.atleast_1d(q_host)
-    if np.any(qv < 0.0) or np.any(qv > 100.0):
-        raise ValueError("percentiles must be in the range [0, 100]")
-    eff_axis = axis
-    if eff_axis is None and x.ndim == 1:
-        eff_axis = 0
-    sorted_x = None
-    if (
-        eff_axis is not None
-        and x.split == eff_axis
-        and x.comm.size > 1
-        and x.dtype not in (types.complex64, types.complex128)
-    ):
-        from . import manipulations
+    (Reference: statistics.py:1407 — distributed sort + halo + Allgather of
+    index maps.) Along axis 0 of a 2-D array, where ``_selection_form`` says so, the two
+    order statistics that bracket every ``q`` are found by the exact counting
+    selection of ``core/_selection.py`` (the one KMedians' medians come from;
+    here every row counts for every target): no sort, no copy of ``x``,
+    nothing of its size allocated, all ``q`` of the call in the same passes
+    over ``x`` (up to ``_pallas_select._MOST_TARGETS`` distinct rank pairs a
+    batch), one jitted program a call. On a TPU that is f32 with a multiple
+    of 8 under 128 columns, on one device or in equal split-0 shards, from
+    ``2 ** 17`` rows a target and device on (the kernels of
+    ``core/_pallas_select.py``); on any backend, f32 / f64 split 0 in equal
+    shards over more than one device (the ``jax.numpy`` form of the same
+    passes; the counts are summed over the devices before a bracket
+    narrows). The result is that of the sorted column bit for bit for
+    ``lower``, ``higher`` and ``nearest``, and ``v[lo] + frac * (v[hi] -
+    v[lo])`` in the array's precision for ``linear`` and ``midpoint``; a
+    column that holds a NaN gives NaN.
 
-        sorted_x = manipulations._sorted_values(x, eff_axis)
-    if sorted_x is not None:
-        sarr = sorted_x.larray
-        if types.heat_type_is_exact(x.dtype):
-            sarr = sarr.astype(jnp.float32)
-        n = x.gshape[eff_axis]
-        pos = qv / 100.0 * (n - 1)
-        lo = np.floor(pos).astype(np.int64)
-        hi = np.ceil(pos).astype(np.int64)
-        # ranks are host-static: only two cross-shard row fetches per q
-        vlo = jnp.take(sarr, jnp.asarray(lo), axis=eff_axis)
-        vhi = jnp.take(sarr, jnp.asarray(hi), axis=eff_axis)
-        if interpolation == "lower":
-            res = vlo
-        elif interpolation == "higher":
-            res = vhi
-        elif interpolation == "midpoint":
-            res = (vlo + vhi) / 2
-        elif interpolation == "nearest":
-            nearest = np.rint(pos).astype(np.int64)
-            res = jnp.take(sarr, jnp.asarray(nearest), axis=eff_axis)
-        else:  # linear
-            frac = jnp.asarray(pos - lo, dtype=sarr.dtype)
-            fshape = [1] * sarr.ndim
-            fshape[eff_axis] = len(qv)
-            res = vlo + frac.reshape(fshape) * (vhi - vlo)
-        if jnp.issubdtype(sarr.dtype, jnp.floating):
-            # NaNs sort to the tail, so a lane contains one iff its last
-            # logical element is NaN — propagate like numpy does
-            vlast = jnp.expand_dims(jnp.take(sarr, n - 1, axis=eff_axis), eff_axis)
-            res = jnp.where(jnp.isnan(vlast), jnp.nan, res)
-        # numpy/jnp put the q dim first
-        result = jnp.moveaxis(res, eff_axis, 0)
-        if scalar_q:
-            result = jnp.squeeze(result, axis=0)
-        if keepdims:
-            # axis=None only reaches here for 1-D input (eff_axis 0)
-            result = jnp.expand_dims(
-                result, (axis if axis is not None else 0) + (0 if scalar_q else 1)
+    Everywhere else the values are sorted: when the reduction axis is the
+    split axis, the gather-free ``ht.sort`` (odd-even ppermute network,
+    ``core.parallel``) and a fetch of only the two bracketing ranks per q —
+    the TPU analog of the reference's sorted-halo rank lookup; other axes use
+    XLA's lane-local percentile on the sharded array."""
+    with _call_span("ht.call.percentile"):
+        with _span("ht.call.percentile.prepare"):
+            sanitize_in(x)
+            axis = sanitize_axis(x.shape, axis)
+            if interpolation not in ("linear", "lower", "higher", "midpoint", "nearest"):
+                raise ValueError(f"unknown interpolation {interpolation}")
+            # q stays a HOST value: the bracketing ranks must be static (they
+            # shape the program), and round-tripping a python float through
+            # jnp.asarray turns it into a tracer under ht.jit (jax inserts a
+            # convert op for the unavailable f64), breaking np.asarray below
+            if isinstance(q, (DNDarray, jax.Array)):
+                q_dev = q.larray if isinstance(q, DNDarray) else q
+                if isinstance(q_dev, jax.core.Tracer):
+                    raise TypeError(
+                        "percentile: q must be statically known (host value); a "
+                        "traced q would make the output shape data-dependent"
+                    )
+                # declared host boundary "percentile-q" (analysis/boundaries.py):
+                # the ONLY whitelisted sync in core/ — pinned by tier-1
+                with _span("ht.sync.read", what="percentile.q"):
+                    q_host = np.asarray(jax.device_get(q_dev), dtype=np.float64)
+            else:
+                q_host = np.asarray(q, dtype=np.float64)
+            scalar_q = q_host.ndim == 0
+            qv = np.atleast_1d(q_host)
+            if np.any(qv < 0.0) or np.any(qv > 100.0):
+                raise ValueError("percentiles must be in the range [0, 100]")
+            eff_axis = axis
+            if eff_axis is None and x.ndim == 1:
+                eff_axis = 0
+            form = "sort"
+            if eff_axis == 0 and x.ndim == 2 and not x._is_planar:
+                form = _selection_form(jax.default_backend(), x.dtype.jax_type(), x.gshape, eff_axis, x.split, x.comm.size,
+                                       len(set(qv.tolist())))
+        if form != "sort":
+            shape = (x.gshape[1],) if scalar_q else (len(qv), x.gshape[1])
+            if keepdims:
+                shape = shape[:-1] + (1, shape[-1])
+            result = _percentile_by_selection(x, qv, interpolation, form, shape)
+            with _span("ht.call.percentile.wrap"):
+                ret = DNDarray(result, shape, types.canonical_heat_type(result.dtype), None, x.device, x.comm)
+                if out is not None:
+                    out.larray = ret.larray
+                    return out
+                return ret
+        _telemetry.inc("percentile.select.sort")
+        sorted_x = None
+        if (
+            eff_axis is not None
+            and x.split == eff_axis
+            and x.comm.size > 1
+            and x.dtype not in (types.complex64, types.complex128)
+        ):
+            from . import manipulations
+
+            sorted_x = manipulations._sorted_values(x, eff_axis)
+        if sorted_x is not None:
+            sarr = sorted_x.larray
+            if types.heat_type_is_exact(x.dtype):
+                sarr = sarr.astype(jnp.float32)
+            n = x.gshape[eff_axis]
+            pos = qv / 100.0 * (n - 1)
+            lo = np.floor(pos).astype(np.int64)
+            hi = np.ceil(pos).astype(np.int64)
+            # ranks are host-static: only two cross-shard row fetches per q
+            vlo = jnp.take(sarr, jnp.asarray(lo), axis=eff_axis)
+            vhi = jnp.take(sarr, jnp.asarray(hi), axis=eff_axis)
+            if interpolation == "lower":
+                res = vlo
+            elif interpolation == "higher":
+                res = vhi
+            elif interpolation == "midpoint":
+                res = (vlo + vhi) / 2
+            elif interpolation == "nearest":
+                nearest = np.rint(pos).astype(np.int64)
+                res = jnp.take(sarr, jnp.asarray(nearest), axis=eff_axis)
+            else:  # linear
+                frac = jnp.asarray(pos - lo, dtype=sarr.dtype)
+                fshape = [1] * sarr.ndim
+                fshape[eff_axis] = len(qv)
+                res = vlo + frac.reshape(fshape) * (vhi - vlo)
+            if jnp.issubdtype(sarr.dtype, jnp.floating):
+                # NaNs sort to the tail, so a lane contains one iff its last
+                # logical element is NaN — propagate like numpy does
+                vlast = jnp.expand_dims(jnp.take(sarr, n - 1, axis=eff_axis), eff_axis)
+                res = jnp.where(jnp.isnan(vlast), jnp.nan, res)
+            # numpy/jnp put the q dim first
+            result = jnp.moveaxis(res, eff_axis, 0)
+            if scalar_q:
+                result = jnp.squeeze(result, axis=0)
+            if keepdims:
+                # axis=None only reaches here for 1-D input (eff_axis 0)
+                result = jnp.expand_dims(
+                    result, (axis if axis is not None else 0) + (0 if scalar_q else 1)
+                )
+        else:
+            arr = x.larray
+            if types.heat_type_is_exact(x.dtype):
+                arr = arr.astype(jnp.float32)
+            # q rides in the widest available float (NOT arr.dtype: a bf16 q
+            # would round 99.9 to 100.0 and return the maximum)
+            result = jnp.percentile(
+                arr, jnp.asarray(q_host, dtype=types.wide_jax_type("f")), axis=axis,
+                method=interpolation, keepdims=keepdims,
             )
-    else:
-        arr = x.larray
-        if types.heat_type_is_exact(x.dtype):
-            arr = arr.astype(jnp.float32)
-        # q rides in the widest available float (NOT arr.dtype: a bf16 q
-        # would round 99.9 to 100.0 and return the maximum)
-        result = jnp.percentile(
-            arr, jnp.asarray(q_host, dtype=types.wide_jax_type("f")), axis=axis,
-            method=interpolation, keepdims=keepdims,
+        # result has leading q dims when q is a vector
+        ret = _wrap_reduce(jnp.asarray(result), x, axis, keepdims) if scalar_q else DNDarray(
+            result,
+            tuple(int(s) for s in result.shape),
+            types.canonical_heat_type(result.dtype),
+            None,
+            x.device,
+            x.comm,
         )
-    # result has leading q dims when q is a vector
-    ret = _wrap_reduce(jnp.asarray(result), x, axis, keepdims) if scalar_q else DNDarray(
-        result,
-        tuple(int(s) for s in result.shape),
-        types.canonical_heat_type(result.dtype),
-        None,
-        x.device,
-        x.comm,
-    )
-    if out is not None:
-        out.larray = ret.larray
-        return out
-    return ret
+        if out is not None:
+            out.larray = ret.larray
+            return out
+        return ret
 
 
 def skew(x: DNDarray, axis: Optional[int] = None, unbiased: bool = True) -> DNDarray:
@@ -520,6 +671,10 @@ def var(x: DNDarray, axis=None, ddof: int = 0, **kwargs) -> DNDarray:
     result = jnp.var(arr, axis=axis, ddof=ddof, keepdims=keepdims)
     return _wrap_reduce(jnp.asarray(result), x, axis, keepdims)
 
+
+from .communication import register_mesh_cache  # noqa: E402  (mesh-keyed program cache)
+
+register_mesh_cache(_percentile_select_program)
 
 DNDarray.argmax = argmax
 DNDarray.argmin = argmin

@@ -3,22 +3,38 @@
 API parity with /root/reference/heat/preprocessing/preprocessing.py
 (``StandardScaler`` :49, ``MinMaxScaler`` :158, ``Normalizer`` :284,
 ``MaxAbsScaler`` :358, ``RobustScaler`` :444). All statistics are sharded
-reductions over the sample axis (mean/var/min/max/percentile — one
-all-reduce each in the reference's terms).
+reductions over the sample axis (mean/var/min/max — one all-reduce each in
+the reference's terms), ``RobustScaler``'s order statistics one
+``ht.percentile`` call (along the sample axis an exact counting selection,
+no sort, where ``statistics._selection_form`` says so), and its
+``transform`` / ``inverse_transform`` one program each (``_affine``: one
+read and one write of the table).
+
+Every scaler accepts ``copy`` for reference parity and does not read it:
+arrays are immutable here, so a transform always returns a new array and the
+caller's stays as it was.
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
+import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
+from jax.sharding import PartitionSpec as P
 
 from typing import Optional, Tuple
 
-from ..core import statistics, types
+from ..core import _pallas_select as _tiles, statistics, types
 from ..core.base import BaseEstimator, TransformMixin
+from ..core.communication import register_mesh_cache
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
+from ..observability.instrument import observed_program_cache
+from ..observability.tracing import call_span as _call_span
 
 __all__ = ["StandardScaler", "MinMaxScaler", "Normalizer", "MaxAbsScaler", "RobustScaler"]
 
@@ -29,7 +45,8 @@ def _float_of(x: DNDarray):
 
 class StandardScaler(BaseEstimator, TransformMixin):
     """Standardize features to zero mean and unit variance (reference:
-    preprocessing.py:49)."""
+    preprocessing.py:49). ``copy`` is accepted for reference parity and not
+    read: a transform returns a new array, the caller's stays."""
 
     def __init__(self, copy: bool = True, with_mean: bool = True, with_std: bool = True):
         self.copy = copy
@@ -66,6 +83,80 @@ class StandardScaler(BaseEstimator, TransformMixin):
         return _like(y, arr)
 
 
+@functools.lru_cache(maxsize=64)
+def _affine_pass(n: int, d: int, inverse: bool, interpret: bool):
+    """The chip's form of ``_affine`` over one device's rows of a tall
+    narrow f32 table, tiled as the selection's passes tile it (blocks
+    ``(d, tn)`` of ``x.T``, a bitcast of the feature-major array): one read
+    and one write, the division a division. Named ``scaler.transform.pass``
+    (``docs/API.md``, observability)."""
+    tn = _tiles._pick_tn(n, d, 8)
+
+    def kernel(xt_ref, shift_ref, scale_ref, yt_ref):
+        x = xt_ref[...]
+        yt_ref[...] = x * scale_ref[...] + shift_ref[...] if inverse else (x - shift_ref[...]) / scale_ref[...]
+
+    block = _tiles._x_block(d, tn)
+    call = _tiles._call(kernel, "scaler.transform.pass", n, tn, [block, _tiles._const((d, 1)), _tiles._const((d, 1))],
+                        jax.ShapeDtypeStruct((d, n), jnp.float32), block, interpret)
+    return lambda x, shift, scale: call(x.T, shift[:, None], scale[:, None]).T
+
+
+@observed_program_cache("scaler.transform", maxsize=64)
+def _affine_program(shape, jdtype: str, out_jdtype: str, inverse: bool, shifts: bool, scales: bool, on_chip: bool,
+                    mesh=None, axis_name=None, interpret: bool = False):
+    """``(arr, shift, scale) -> (arr - shift) / scale`` in ``out_jdtype``
+    (``inverse``: ``arr * scale + shift``), ``shift`` and ``scale`` one value
+    a feature (ignored without ``shifts`` / ``scales``): ONE jitted program a
+    transform, one read of the table with the cast fused into it and one
+    write. ``on_chip`` (``_affine`` decides: ``tall_narrow_serves``) it is
+    the kernel ``_affine_pass``, under ``shard_map`` on a ``mesh`` as the
+    selection's passes are; elsewhere the same expression left to XLA."""
+    out_dtype = jnp.dtype(out_jdtype)
+    if on_chip:
+        n, d = int(shape[0]), int(shape[1])
+        run_pass = _affine_pass(n // (mesh.devices.size if axis_name is not None else 1), d, inverse, interpret)
+        if mesh is not None:
+            run_pass = _shard_map(run_pass, mesh=mesh, check_vma=False, in_specs=(P(axis_name, None), P(), P()),
+                                  out_specs=P(axis_name, None))
+
+    def run(arr, shift, scale):
+        with jax.named_scope("scaler.transform"):
+            arr = arr.astype(out_dtype)
+            if on_chip:
+                d = arr.shape[1]
+                return run_pass(arr, shift.astype(out_dtype) if shifts else jnp.zeros((d,), out_dtype),
+                                scale.astype(out_dtype) if scales else jnp.ones((d,), out_dtype))
+            if inverse:
+                arr = arr * scale if scales else arr
+                return arr + shift if shifts else arr
+            arr = arr - shift if shifts else arr
+            return arr / scale if scales else arr
+
+    return jax.jit(run)
+
+
+register_mesh_cache(_affine_program)
+
+
+def _affine(x: DNDarray, shift, scale, inverse: bool = False) -> DNDarray:
+    """``(x - shift) / scale`` (``inverse``: ``x * scale + shift``) along the
+    feature axis as one program (``_affine_program``); ``shift`` / ``scale``
+    may be ``None``. The result has ``x``'s split."""
+    out = jnp.result_type(_float_of(x).jax_type(), *(a.dtype for a in (shift, scale) if a is not None))
+    devices = x.comm.size
+    on_chip = out == jnp.float32 and _tiles.tall_narrow_serves(
+        jax.default_backend(), x.dtype.jax_type(), x.gshape, x.split, devices)
+    mesh = x.comm.mesh if on_chip and devices > 1 else None
+    split_over = x.split == 0 and devices > 1
+    prog = _affine_program(tuple(x.gshape), np.dtype(x.dtype.jax_type()).name, np.dtype(out).name, inverse,
+                           shift is not None, scale is not None, on_chip, mesh,
+                           x.comm.axis_name if mesh is not None and split_over else None)
+    none = np.zeros((), out)  # a host value: an argument the program ignores, no op dispatched to make it
+    arr = x._phys if on_chip and split_over else x.larray
+    return _like(x, prog(arr, none if shift is None else shift, none if scale is None else scale))
+
+
 def _like(x: DNDarray, arr) -> DNDarray:
     gshape = tuple(int(s) for s in arr.shape)
     split = x.split
@@ -77,7 +168,9 @@ def _like(x: DNDarray, arr) -> DNDarray:
 
 
 class MinMaxScaler(BaseEstimator, TransformMixin):
-    """Scale features to a given range (reference: preprocessing.py:158)."""
+    """Scale features to a given range (reference: preprocessing.py:158).
+    ``copy`` is accepted for reference parity and not read: a transform
+    returns a new array, the caller's stays."""
 
     def __init__(self, feature_range: Tuple[float, float] = (0.0, 1.0), copy: bool = True, clip: bool = False):
         if feature_range[0] >= feature_range[1]:
@@ -119,7 +212,9 @@ class MinMaxScaler(BaseEstimator, TransformMixin):
 
 
 class Normalizer(BaseEstimator, TransformMixin):
-    """Normalize samples to unit norm (reference: preprocessing.py:284)."""
+    """Normalize samples to unit norm (reference: preprocessing.py:284).
+    ``copy`` is accepted for reference parity and not read: a transform
+    returns a new array, the caller's stays."""
 
     def __init__(self, norm: str = "l2", copy: bool = True):
         if norm not in ("l1", "l2", "max"):
@@ -145,7 +240,8 @@ class Normalizer(BaseEstimator, TransformMixin):
 
 class MaxAbsScaler(BaseEstimator, TransformMixin):
     """Scale by the per-feature maximum absolute value (reference:
-    preprocessing.py:358)."""
+    preprocessing.py:358). ``copy`` is accepted for reference parity and not
+    read: a transform returns a new array, the caller's stays."""
 
     def __init__(self, copy: bool = True):
         self.copy = copy
@@ -170,9 +266,31 @@ class MaxAbsScaler(BaseEstimator, TransformMixin):
         return _like(y, y.larray * self.scale_)
 
 
+@observed_program_cache("scaler.robust_stats", maxsize=16)
+def _robust_stats_program(centering: bool, scaling: bool):
+    """``percentiles (q, ...) -> (center, iqr)``: the rows of
+    ``RobustScaler.fit``'s one ``percentile`` call as the scaler keeps them
+    (the range's two first, the median last; a range of 0 scales by 1), as
+    one small program: no op of the fit is dispatched by itself."""
+
+    def run(pct):
+        iqr = pct[1] - pct[0] if scaling else None
+        return pct[-1] if centering else None, None if iqr is None else jnp.where(iqr > 0, iqr, 1.0)
+
+    return jax.jit(run)
+
+
 class RobustScaler(BaseEstimator, TransformMixin):
-    """Scale by median and IQR (reference: preprocessing.py:444 — uses the
-    distributed percentile)."""
+    """Scale by median and IQR: one ``ht.percentile`` call a fit (a counting selection on a tall table), one program a transform.
+
+    (Reference: preprocessing.py:444 — uses the distributed percentile.)
+    ``fit`` is one ``ht.percentile(x, [q_min,
+    q_max, 50], axis=0)`` (the median only with ``with_centering``, the
+    range only with ``with_scaling``): along the sample axis of a tall table
+    an exact counting selection that finds all three in the same passes, not
+    three sorts (``statistics.percentile`` says where). ``transform`` and
+    ``inverse_transform`` are one program each. ``copy`` is accepted and not
+    read."""
 
     def __init__(
         self,
@@ -196,31 +314,26 @@ class RobustScaler(BaseEstimator, TransformMixin):
         self.iqr_ = None
 
     def fit(self, x: DNDarray) -> "RobustScaler":
-        sanitize_in(x)
-        if self.with_centering:
-            self.center_ = statistics.median(x, axis=0)
-        if self.with_scaling:
-            q_min, q_max = self.quantile_range
-            lo = statistics.percentile(x, q_min, axis=0)
-            hi = statistics.percentile(x, q_max, axis=0)
-            iqr = hi.larray - lo.larray
-            self.iqr_ = jnp.where(iqr > 0, iqr, 1.0)
-        return self
+        with _call_span("ht.call.robustscaler.fit"):
+            sanitize_in(x)
+            q = (list(self.quantile_range) if self.with_scaling else []) + ([50.0] if self.with_centering else [])
+            if q:
+                pct = statistics.percentile(x, q, axis=0)
+                center, self.iqr_ = _robust_stats_program(self.with_centering, self.with_scaling)(pct.larray)
+                if self.with_centering:
+                    self.center_ = DNDarray(center, tuple(center.shape), pct.dtype, None, x.device, x.comm)
+            return self
+
+    def _shift_and_scale(self):
+        return (self.center_.larray if self.with_centering and self.center_ is not None else None,
+                self.iqr_ if self.with_scaling and self.iqr_ is not None else None)
 
     def transform(self, x: DNDarray) -> DNDarray:
-        sanitize_in(x)
-        arr = x.larray.astype(_float_of(x).jax_type())
-        if self.with_centering and self.center_ is not None:
-            arr = arr - self.center_.larray
-        if self.with_scaling and self.iqr_ is not None:
-            arr = arr / self.iqr_
-        return _like(x, arr)
+        with _call_span("ht.call.robustscaler.transform"):
+            sanitize_in(x)
+            return _affine(x, *self._shift_and_scale())
 
     def inverse_transform(self, y: DNDarray) -> DNDarray:
-        sanitize_in(y)
-        arr = y.larray
-        if self.with_scaling and self.iqr_ is not None:
-            arr = arr * self.iqr_
-        if self.with_centering and self.center_ is not None:
-            arr = arr + self.center_.larray
-        return _like(y, arr)
+        with _call_span("ht.call.robustscaler.inverse_transform"):
+            sanitize_in(y)
+            return _affine(y, *self._shift_and_scale(), inverse=True)

@@ -81,6 +81,9 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 | `ht.call.kmedians.fit`, `ht.call.kmedoids.fit` | `KMedians.fit`, `KMedoids.fit` (the fused fit: the three spans above nest in it) | `host_wrapper_ms_per_call` |
 | `ht.call.qr` | the whole public `ht.linalg.qr` call (since PR 34) | `host_wrapper_ms_per_call` (self time) |
 | `ht.call.qr.prepare`, `.wrap` | `sanitize_in`, checks, dtype, `astype`, which path; the `DNDarray`s of `Q` and `R` and their placement. Between them the lookup and call of the program (`qr.local` on one device or a replicated array, `qr.tsqr` on a split one): the program spans nest in `ht.call.qr` itself | `host_wrapper_ms_per_call` |
+| `ht.call.percentile` | the whole public `ht.percentile` / `ht.median` call (since PR 38; `RobustScaler.fit`'s one call nests in `ht.call.robustscaler.fit`) | `host_wrapper_ms_per_call` (self time) |
+| `ht.call.percentile.prepare`, `.wrap` | `sanitize_in`, `q` to the host (an `ht.sync.read` where it is a device value), which form (`_selection_form`); where the counting selection serves, the `DNDarray` of the result. Between them the lookup and call of the one program (`percentile.select`): the program spans nest in `ht.call.percentile` itself. A call that sorts has no `.wrap` | `host_wrapper_ms_per_call` |
+| `ht.call.robustscaler.fit`, `.transform`, `.inverse_transform` | `RobustScaler.fit` (one `percentile` call and the small program `scaler.robust_stats`), `transform` and `inverse_transform` (one program each, `scaler.transform`, and the result's placement) | `host_wrapper_ms_per_call` |
 | `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
 | `ht.program.hit` | entered right after a lookup that a builder's `lru_cache` served (`cache=` names the builder) | `host_launch_ms_per_call` |
 | `ht.program.miss` | a lookup that built; the builder's time | `program_cache_misses`, `host_launch_ms_per_call` |
@@ -92,7 +95,8 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 
 **The calling thread's counters** (since PR 36). The outermost span of a public call (`ht.call.hsvd_rank`,
 `ht.call.hsvd_rtol`, `ht.call.hsvd`, `ht.call.qr`, `ht.call.kmeans.fit`, `ht.call.kmedians.fit`,
-`ht.call.kmedoids.fit`, `ht.call.kmeans.predict`) is entered through `ht.tracing.call_span`. Under a live
+`ht.call.kmedoids.fit`, `ht.call.kmeans.predict`, `ht.call.percentile`, `ht.call.robustscaler.fit`,
+`.transform`, `.inverse_transform`) is entered through `ht.tracing.call_span`. Under a live
 profiler session, and only then (without one it costs the `is_enabled()` read every span pays), it reads two
 CPU clocks at entry and passes them as the annotation's arguments; the profiler keeps them as the event's
 integer stats (`ProfileData` event `.stats`; Perfetto shows them as the slice's arguments). Nothing is
@@ -127,7 +131,8 @@ Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`,
 `.build`, `.compile` for every observed builder (`op.binary`, `op.unary`, `op.reduce`, `op.cum`,
 `hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`, `hsvd.dist_rank`,
 `hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `qr.local`, `kmeans.lloyd_step`,
-`kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`), `ht.jit.cache.hit`/`.miss`,
+`kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`, `percentile.select`,
+`scaler.robust_stats`, `scaler.transform`), `ht.jit.cache.hit`/`.miss`,
 `comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`, and `hsvd.pass2.one_dot` /
 `hsvd.pass2.tiled` (which form of the two-pass sketch's second pass a program was built with: one
 dot that reads f32 `A` once, where pass 1 was the Pallas kernel, or the tiled loop `_pass2_tiles`
@@ -169,7 +174,7 @@ The L1 family (since PR 32): once per `KMedians.fit` / `KMedoids.fit` the counte
 the fit's program runs, as `cluster._pallas_l1.l1_passes_serve` decided from backend, dtype, shape and
 split: Pallas kernels on a TPU for tall narrow f32, `jax.numpy` elsewhere; and, since PR 33,
 `kmedians.step.select.gather` (`kmedoids.*`) where that program was built with the gathering pass
-(`cluster._pallas_l1.gather_pays`: the kernels, from 2^17 rows a cluster and device on). Inside the one fit
+(`core._pallas_select.gather_pays`: the kernels, from 2^17 rows a cluster and device on). Inside the one fit
 program the device ops of an iteration lie under `jax.named_scope("kmedians.assign")` (the L1 assignment;
 also the fit's label pass) and `jax.named_scope("kmedians.select")` (the counting selection of all k x d
 medians and, for `KMedoids`, the snap); the scopes are named for the estimator (`kmedoids.*`). One op
@@ -178,7 +183,7 @@ one for the labels) and `kmedians.select.pass`, which three kernels carry: the c
 the radix selection: sixteen an iteration for f32 where the program does not gather; where it does, as
 many as the counts ask for, since PR 37: after every digit they say how many keys the windows hold, and
 the selection counts on `X` until no feature's windows hold over one row in 768
-(`cluster._pallas_l1.crowded`), four digits at least and twelve at most: eight on unit blobs near zero,
+(`core._pallas_select.crowded`), four digits at least and twelve at most: eight on unit blobs near zero,
 eleven on the same blobs around 10, twelve around 100),
 the gathering pass (one an iteration: it keeps the keys still in their (cluster, feature) pair's
 window, its newest bracket with 32 keys and the one above, in an array of 1.6 % of `X`'s size) and the
@@ -199,6 +204,27 @@ equal values, values a thousand noise widths from zero), the
 gathering pass is told to skip: its op is there, runs empty over one block (under a tenth of a read's time:
 no read to `kmedians_x_reads_per_call`, which takes an op under half the median pass for a sliver), and
 the iteration has 18 reads as before PR 33.
+
+`ht.percentile` / `ht.median` and the scalers (since PR 38). The selection above is `core/_selection.py`'s
+(its kernels `core/_pallas_select.py`'s), and `ht.percentile` along axis 0 of a 2-D array runs it for all
+rows (every row counts for every `q`; no label array). Once a call, `percentile.select.pallas` / `.xla` /
+`.sort` says which form it took, as `core.statistics._selection_form` decided from backend, dtype, shape,
+axis and split: the kernels on a TPU for f32 with a multiple of 8 under 128 columns, on one device or in
+equal split-0 shards, from 2^17 rows a target and device on (then also `percentile.select.gather`: the
+program has the gathering pass); the `jax.numpy` form of the same passes for f32 / f64 split 0 in equal
+shards over more than one device from 2^13 rows a device on; the sort everywhere else. The one program's
+device ops lie under `jax.named_scope("percentile.select")`, and the kernels carry the names the
+KMedians kernels carry, under the caller's prefix: `percentile.select.pass` is one whole read of `X` (the
+first digit's pass, which also looks for NaNs; a counting pass a digit after it, as many as the counts ask
+for; the gathering pass; the successor pass, only where the selection ends on `X`: a spill, windows still
+crowded after twelve digits, or two targets' windows that overlap without being the same),
+`percentile.select.candidates` the small kernels over the kept keys. The benchmark's readers
+`percentile_select_ms_per_call`, `percentile_x_reads_per_call` and `percentile_pass_hbm_pct` find them by
+these names; all `q` of a call (up to eight distinct rank pairs a batch) share the passes, so the reads do
+not grow with them. `RobustScaler.transform` / `inverse_transform` (`scaler.transform`, one program: one
+read and one write of the table) run, where `core._pallas_select.tall_narrow_serves`, the kernel named
+`scaler.transform.pass` under `jax.named_scope("scaler.transform")`, which `scaler_transform_ms_per_call`
+and `scaler_transform_hbm_pct` read; elsewhere the same expression is XLA's fusion in the same program.
 """,
 }
 

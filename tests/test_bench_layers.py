@@ -170,3 +170,79 @@ def test_kmedians_reader_on_the_recorded_fixture(bench, metric):
         fx = json.load(f)
     got = harness.load_module("layers", metric).reduce([T.Event(*e) for e in fx["events"]], fx["run"])
     assert got == pytest.approx(float(fx["expected"][metric]), rel=1e-6)
+
+
+# --------------------------------------------------------------------- #
+# the RobustScaler cell's readers (PR 38)                                #
+# --------------------------------------------------------------------- #
+ROBUST = ["percentile_select_ms_per_call", "percentile_x_reads_per_call", "percentile_pass_hbm_pct",
+          "scaler_transform_ms_per_call", "scaler_transform_hbm_pct"]
+PASS = "%percentile.select.pass.15 = s32[576,128]{1,0} custom-call(s32[1]{0} %s, f32[64,100]{1,0} %x, s32[64,3]{1,0} %t)"
+CANDIDATES = "%percentile.select.candidates.11 = s32[576,128]{1,0} custom-call(s32[64,2048]{1,0} %kept, s32[64,9]{1,0} %t)"
+TRANSFORM = "%scaler.transform.pass.1 = f32[64,100]{1,0} custom-call(f32[64,100]{1,0} %x, f32[64,1]{1,0} %c, f32[64,1]{1,0} %s)"
+
+
+def robust_calls(st, T, devices=1):
+    """Two calls in a 2000 ns window, the same on every device. A call: the
+    selection's program (a ``while`` over three passes of 60 ns, a fourth
+    after it, a kernel of 10 ns over the kept keys, a fusion that reads a
+    pass's counts and names it as its operand), then the transform's, 150
+    ns: four reads of ``X``, 250 ns under the selection's names."""
+    ev = []
+    for call in (0, 1000):
+        ev += [st.host(T.CALL, call, 100), st.host(T.WAIT, call + 100, 900)]
+        for i in range(devices):
+            ev += [st.dev(i, "%while.3 = (s32[]) while(%t)", call + 100, 180),
+                   st.dev(i, PASS, call + 100, 60), st.dev(i, PASS, call + 160, 60), st.dev(i, PASS, call + 220, 60),
+                   st.dev(i, PASS.replace(".15 =", ".14 ="), call + 280, 60), st.dev(i, CANDIDATES, call + 340, 10),
+                   st.dev(i, "%fusion.4 = s32[3,64]{1,0} fusion(s32[576,128]{1,0} %percentile.select.pass.15)", call + 350, 20),
+                   st.dev(i, TRANSFORM, call + 400, 150)]
+    return ev
+
+
+# least_bytes is three reads of the chip's rows: one read 1000 B, 50 ns at 2e10 B/s
+ROBUST_RUN = {"least_bytes_per_call": 3000, "peak": {"hbm_bytes_per_s": 2e10}}
+ROBUST_WANT = {"percentile_select_ms_per_call": 250e-6, "percentile_x_reads_per_call": 4.0,
+               "percentile_pass_hbm_pct": 100.0 * 4 * 50 / 240, "scaler_transform_ms_per_call": 150e-6,
+               "scaler_transform_hbm_pct": 100.0 * 2 * 50 / 150}
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("metric", ROBUST)
+def test_robust_reader_on_known_events(bench, metric, devices):
+    harness, st, T = bench
+    got = harness.load_module("layers", metric).reduce(robust_calls(st, T, devices), ROBUST_RUN)
+    assert got == pytest.approx(ROBUST_WANT[metric], rel=1e-12)
+
+
+def test_robust_reads_leave_out_a_skipped_gathering_pass_and_the_clocks_sliver(bench):
+    """A gathering pass that was told to skip runs empty over one block: an
+    op under half the median pass is no read; nor is the 1 ns the window
+    keeps of the first pass after its last call."""
+    harness, st, T = bench
+    ev = robust_calls(st, T) + [st.dev(0, PASS, 350, 5), st.dev(0, PASS, 1999, 60)]
+    assert harness.load_module("layers", "percentile_x_reads_per_call").reduce(ev, ROBUST_RUN) == 4.0
+
+
+@pytest.mark.parametrize("metric", ROBUST)
+def test_robust_reader_finds_nothing_in_another_programs_trace(bench, metric):
+    """A program without the kernels (the parent's, which sorts; another
+    cell's; KMedians', whose passes carry another prefix): nothing to read,
+    so the line leaves the metric out, and nothing raises."""
+    harness, st, T = bench
+    reduce = harness.load_module("layers", metric).reduce
+    for ev in (two_devices(st, T), kmedians_fits(st, T)):
+        assert reduce(ev, ROBUST_RUN) is None
+        assert reduce([e for e in ev if e.plane == T.HOST_PLANE], ROBUST_RUN) is None
+    assert reduce([], {}) is None
+    assert reduce(robust_calls(st, T), {}) is None or "hbm" not in metric  # no peak, no share
+
+
+@pytest.mark.parametrize("metric", ROBUST)
+def test_robust_reader_on_the_recorded_fixture(bench, metric):
+    """The cell's trimmed chip trace reduces to what that run printed."""
+    harness, st, T = bench
+    with open(os.path.join(ROOT, "benchmarks", "fixtures", "robustscale-northstar.fit_transform.json")) as f:
+        fx = json.load(f)
+    got = harness.load_module("layers", metric).reduce([T.Event(*e) for e in fx["events"]], fx["run"])
+    assert got == pytest.approx(float(fx["expected"][metric]), rel=1e-6)

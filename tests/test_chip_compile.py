@@ -298,10 +298,10 @@ def _assert_l1_fit_holds_x_alone(compiled, rows, d, gathers=True):
     over_kept = [name for name, _ in kernels if name.startswith("kmedians.select.candidates")]
     assert bool(over_kept) == gathers
     if gathers:  # the loop's gathering pass, the counts under ``_N_THR`` x k and under k + 1 thresholds, the successor
-        from heat_tpu.cluster import _pallas_l1 as pl1
+        from heat_tpu.core import _pallas_select as ps
 
-        assert len(over_kept) >= 3 and f"s32[{d},{pl1.kept_lanes(rows, d, 8)}]" in txt
-        assert 60 * pl1.kept_lanes(rows, d, 8) < rows  # the kept array: under a sixtieth of X, 75.5 MB of 4.8 GB
+        assert len(over_kept) >= 3 and f"s32[{d},{ps.kept_lanes(rows, d, 8)}]" in txt
+        assert 60 * ps.kept_lanes(rows, d, 8) < rows  # the kept array: under a sixtieth of X, 75.5 MB of 4.8 GB
 
 
 @pytest.mark.parametrize("snap", [False, True], ids=["kmedians", "kmedoids"])
@@ -341,6 +341,155 @@ def test_l1_fit_four_chips(mesh4, split):
     compiled = _l1_fit_compiled(n, 64, 8, NamedSharding(mesh4, spec), mesh4, axis)
     _assert_l1_fit_holds_x_alone(compiled, rows, 64)
     assert ("all-reduce" in compiled.as_text()) == (split == 0)
+
+
+def _canonical(lowered: str) -> str:
+    """The lowered text with every Mosaic kernel's serialized body replaced
+    by the hash of its text without debug locations (they hold the file and
+    line a kernel was traced from, which a move changes and nothing else)."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jmlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(enable_debug_info=False)
+        return "BODY:" + hashlib.sha256(asm.encode()).hexdigest()[:16]
+
+    lowered = re.sub(r"loc\(.*?\)$|^#loc.*$", "", lowered, flags=re.M)
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, lowered)
+
+
+@pytest.mark.parametrize("snap, parents", [(False, "17926adab08fbc8f"), (True, "f67ab39fe7258de8")], ids=["kmedians", "kmedoids"])
+def test_l1_fit_program_is_the_one_before_the_selection_moved(one_chip, snap, parents):
+    """PR 38 moved the selection from ``cluster/`` into ``core/`` and gave it
+    a second way to say which rows count; KMedians' and KMedoids' programs
+    had to stay what they were. The fit program on the kernels at the
+    cell's shape, lowered for the described chip: its text, kernels' bodies
+    included (debug locations left out), hashes as the parent's did
+    (commit b72b990, recorded by PR 38 with this function on that tree). A
+    change to the fit's program is a change to ``kmedians-northstar.fit5``:
+    record the new hash with what the cell read before and after."""
+    import hashlib
+
+    from heat_tpu.cluster import _kcluster as kc, _pallas_l1 as pl1
+
+    n, d, k = 18_750_000, 64, 8
+    passes = pl1.l1_passes(k, (n, d))
+
+    def step(arr, centers):
+        labels, counts, _ = passes.assign(arr, centers)
+        new = kc._cluster_medians(arr, labels, k, centers, counts, passes)
+        if snap:
+            new = kc._snap_to_members(arr, labels, k, new, counts, centers)
+        return new, jnp.sum((new - centers) ** 2)
+
+    step.assign = lambda arr, centers: passes.assign(arr, centers)[::2]
+    kc._fused_fit_program.cache_clear()
+    try:
+        prog = kc._fused_fit_program(step, k, (n, d), "float32", 0.0, 5, False, "manhattan", False)
+        text = prog.program.lower(jax.ShapeDtypeStruct((n, d), F32, sharding=one_chip), jax.ShapeDtypeStruct((k, d), F32)).as_text()
+    finally:
+        kc._fused_fit_program.cache_clear()
+    assert hashlib.sha256(_canonical(text).encode()).hexdigest()[:16] == parents
+
+
+def _percentile_compiled(rows, d, chips, topo_mesh, sharding):
+    """``ht.percentile(x, [25, 75, 50], axis=0)``'s one program on the
+    kernels (``linear``), compiled for the described chip(s)."""
+    from heat_tpu.core import statistics as st
+
+    n = rows * chips
+    pos = np.array([25.0, 75.0, 50.0]) / 100.0 * (n - 1)
+    ranks = tuple(zip(np.floor(pos).astype(int).tolist(), np.ceil(pos).astype(int).tolist()))
+    mesh, axis = (topo_mesh, "d") if chips > 1 else (None, None)
+    st._percentile_select_program.cache_clear()
+    try:
+        prog = st._percentile_select_program((n, d), "float32", True, ranks, tuple((pos - np.floor(pos)).tolist()), (3, d),
+                                             mesh, axis)
+        return prog.program.lower(jax.ShapeDtypeStruct((n, d), F32, sharding=sharding)).compile()
+    finally:
+        st._percentile_select_program.cache_clear()
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "split0_2x2"])
+def test_percentile_select_at_the_cells_shape(one_chip, mesh4, chips):
+    """``robustscale-northstar``'s shard, 18 750 000 x 64 f32 (and a quarter
+    of it on each chip of the 2 x 2, split 0): the one program of
+    ``ht.percentile(x, [25, 75, 50], axis=0)`` holds no sort, no label
+    vector and no array of ``X``'s size (``memory_analysis``: the kept keys,
+    75 MB, and a few counts); every kernel that reads ``X`` is named
+    ``percentile.select.pass`` and no other kernel reads it; across chips
+    the counts are all-reduced."""
+    from heat_tpu.core import _pallas_select as ps
+
+    rows, d = (18_750_000, 64) if chips == 1 else (4_687_500, 64)
+    compiled = _percentile_compiled(rows, d, chips, mesh4, one_chip if chips == 1 else NamedSharding(mesh4, P("d", None)))
+    txt = compiled.as_text()
+    assert " sort(" not in txt
+    sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
+    assert sized == [] and f"s32[{rows}]" not in txt
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < rows * d * 4 // 50 and memory.output_size_in_bytes < 1 << 16
+    kernels = _kernels(txt)
+    assert len(kernels) == txt.count("tpu_custom_call") >= 5  # first digit, counting, gathering, successor; the kept keys'
+    for name, operands in kernels:
+        reads_x = f"f32[{d},{rows}]" in operands or f"f32[{rows},{d}]" in operands
+        assert reads_x == (".pass" in name), (name, operands)
+        assert name.startswith(("percentile.select.pass", "percentile.select.candidates")), name
+    assert f"s32[{d},{ps.kept_lanes(rows, d, 3)}]" in txt and 60 * ps.kept_lanes(rows, d, 3) < rows
+    assert ("all-reduce" in txt) == (chips == 4)
+
+
+@pytest.mark.parametrize("n,d,q", [(1_048_576, 8, 8), (2_000_003, 120, 1), (1 << 21, 64, 11)], ids=["d8_q8", "d120_q1", "two_batches"])
+def test_percentile_select_gate_corners(one_chip, n, d, q):
+    """The narrowest and the widest ``d`` the gate serves, the most targets a
+    batch and one, an odd row count (a masked last block), and a ``q`` of
+    two batches in one program."""
+    from heat_tpu.core import statistics as st
+
+    pos = np.linspace(5.0, 95.0, q) / 100.0 * (n - 1)
+    ranks = tuple(zip(np.floor(pos).astype(int).tolist(), np.ceil(pos).astype(int).tolist()))
+    st._percentile_select_program.cache_clear()
+    try:
+        prog = st._percentile_select_program((n, d), "float32", True, ranks, tuple((pos - np.floor(pos)).tolist()), (q, d), None, None)
+        txt = prog.program.lower(jax.ShapeDtypeStruct((n, d), F32, sharding=one_chip)).compile().as_text()
+    finally:
+        st._percentile_select_program.cache_clear()
+    assert "percentile.select.candidates" in txt and " sort(" not in txt
+
+
+@pytest.mark.parametrize("inverse", [False, True], ids=["transform", "inverse_transform"])
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "split0_2x2"])
+def test_scaler_transform_at_the_cells_shape(one_chip, mesh4, chips, inverse):
+    """``RobustScaler.transform`` / ``inverse_transform`` at the cell's
+    shard: one kernel named ``scaler.transform.pass`` between two bitcasts
+    (``x.T`` in, ``y.T`` out), one read and one write of the table, nothing
+    else of its size."""
+    from heat_tpu.preprocessing import preprocessing as pp
+
+    rows, d = (18_750_000, 64) if chips == 1 else (4_687_500, 64)
+    n = rows * chips
+    mesh, axis = (mesh4, "d") if chips > 1 else (None, None)
+    sharding = one_chip if chips == 1 else NamedSharding(mesh4, P("d", None))
+    pp._affine_program.cache_clear()
+    try:
+        prog = pp._affine_program((n, d), "float32", "float32", inverse, True, True, True, mesh, axis)
+        vec = jax.ShapeDtypeStruct((d,), F32)
+        compiled = prog.program.lower(jax.ShapeDtypeStruct((n, d), F32, sharding=sharding), vec, vec).compile()
+    finally:
+        pp._affine_program.cache_clear()
+    txt = compiled.as_text()
+    assert [name.split(".pass")[0] for name, _ in _kernels(txt)] == ["scaler.transform"]
+    sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
+    assert sized == []
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 20 and memory.output_size_in_bytes // chips < rows * d * 4 + (1 << 20)
 
 
 @pytest.mark.parametrize("dtype,family", [("bfloat16", "splash"), ("float32", "flash")])

@@ -12,8 +12,9 @@ import jax.numpy as jnp
 
 import heat_tpu as ht
 from heat_tpu.cluster import _kcluster as kc, _pallas_l1 as pl1
+from heat_tpu.core import _pallas_select as ps, _selection as sel
 
-N_THR = kc._N_THR
+N_THR = ps._N_THR
 
 
 def _median_by_cluster(x, labels, k, prev):
@@ -90,9 +91,9 @@ def test_cluster_medians_other_float_widths(dtype):
 
 def test_keys_keep_the_order_of_the_floats():
     x = np.array([-np.inf, -3.5, -1e-38, -0.0, 0.0, 1e-45, 2.0, 3.4e38, np.inf], np.float32)
-    key = np.asarray(kc._to_key(jnp.asarray(x)))
+    key = np.asarray(ps._to_key(jnp.asarray(x)))
     assert (np.diff(key.astype(np.int64)) > 0).all()
-    np.testing.assert_array_equal(np.asarray(kc._from_key(jnp.asarray(key), np.float32)), x)
+    np.testing.assert_array_equal(np.asarray(ps._from_key(jnp.asarray(key), np.float32)), x)
 
 
 # --------------------------------------------------------------------- #
@@ -167,7 +168,7 @@ def test_pallas_passes_agree_with_the_xla_form(n, d, k):
     np.testing.assert_array_equal(labels, want_labels)
     np.testing.assert_array_equal(counts, want_counts)
     np.testing.assert_allclose(fun, want_fun, rtol=1e-5)
-    at, step = kc._to_key(jnp.asarray(centers)), jnp.int32(1 << 20)
+    at, step = ps._to_key(jnp.asarray(centers)), jnp.int32(1 << 20)
     np.testing.assert_array_equal(chip.count_below(x, labels, at, step), plain.count_below(x, labels, at, step))
     np.testing.assert_array_equal(chip.next_above(x, labels, at), plain.next_above(x, labels, at))
     got = kc._cluster_medians(jnp.asarray(x), labels, k, jnp.asarray(centers), counts, chip)
@@ -209,11 +210,11 @@ def gather_at_any_size(monkeypatch):
     mode, on a few hundred rows, most of them in a window. With no window
     ever crowded the selection gathers as soon as an offset fits under the
     label: after the fourth digit on ``X``."""
-    monkeypatch.setattr(pl1, "_GATHER_MIN_ROWS_A_CLUSTER", 0)
-    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", 1)
-    pl1.l1_passes.cache_clear()
+    monkeypatch.setattr(ps, "_GATHER_MIN_ROWS_A_CLUSTER", 0)
+    monkeypatch.setattr(ps, "_GATHER_MOST_OF_X", 1)
+    pl1.l1_passes.cache_clear(), ps.select_passes.cache_clear()
     yield
-    pl1.l1_passes.cache_clear()
+    pl1.l1_passes.cache_clear(), ps.select_passes.cache_clear()
 
 
 def _near(rng, shape, span, at=1.0):
@@ -229,7 +230,7 @@ def any_bracket_is_a_window(monkeypatch):
     The ``_cores`` put six in a bracket, so that a block of a few thousand
     rows keeps few enough not to spill, and ``_spread`` a few thousand rows
     over brackets that hold a handful by the digit at which they fit."""
-    monkeypatch.setattr(kc, "_WINDOW_MIN_KEYS", 1)
+    monkeypatch.setattr(sel, "_WINDOW_MIN_KEYS", 1)
 
 
 def _cores(rng, n, d, k, core=6):
@@ -405,12 +406,12 @@ def test_gather_folds_steps_onto_blocks_of_slots(gather_at_any_size, any_bracket
     is the size ``kept_lanes`` says, holds every key of a window once, and
     the selection ends on it. Told to skip, the pass says "spilled"."""
     n, d, k = 256 * 35 + 7, 8, 3
-    assert -(-36 // pl1._KEPT_STEPS) == 3
-    monkeypatch.setattr(pl1, "_pick_tn", lambda n, d, k8: 256)  # for 2 MiB of X a step it picks 65536 rows at d 8
-    pl1._gather_program.cache_clear()
+    assert -(-36 // ps._KEPT_STEPS) == 3
+    monkeypatch.setattr(ps, "_pick_tn", lambda n, d, k8: 256)  # for 2 MiB of X a step it picks 65536 rows at d 8
+    ps._gather_program.cache_clear()
     x, labels, _ = _cores(np.random.default_rng(1), n, d, k)
-    lanes = pl1.kept_lanes(n, d, k)
-    assert lanes == 3 * pl1._KEPT_SLOTS * 128
+    lanes = ps.kept_lanes(n, d, k)
+    assert lanes == 3 * ps._KEPT_SLOTS * 128
     passes = pl1.l1_passes(k, (n, d), interpret=True)
     base = np.full((k, d), np.float32(1.0).view(np.int32), np.int32) + (np.arange(k)[:, None] << 23)
     bits = np.full((k, d), 15, np.int32)
@@ -418,12 +419,12 @@ def test_gather_folds_steps_onto_blocks_of_slots(gather_at_any_size, any_bracket
     assert kept.shape == (d, lanes) and not bool(spilled)
     kept = np.asarray(kept)
     for c in range(k):
-        off = np.asarray(kc._to_key(jnp.asarray(x[labels == c]))) - base[c]
+        off = np.asarray(ps._to_key(jnp.asarray(x[labels == c]))) - base[c]
         for j in range(d):
-            mine = kept[j][kept[j] >> pl1._LABEL_SHIFT == c] & ((1 << pl1._LABEL_SHIFT) - 1)
+            mine = kept[j][kept[j] >> ps._LABEL_SHIFT == c] & ((1 << ps._LABEL_SHIFT) - 1)
             np.testing.assert_array_equal(np.sort(mine), np.sort(off[:, j][(off[:, j] >= 0) & (off[:, j] < 1 << 15)]))
-    ahead, in_window = pl1.kept_by_cluster(passes, kept, k)
-    np.testing.assert_array_equal(in_window, [[((off := kc._to_key(jnp.asarray(x[labels == c, j])) - base[c, j]) >= 0).sum()
+    ahead, in_window = ps.kept_by_target(passes, kept, k)
+    np.testing.assert_array_equal(in_window, [[((off := ps._to_key(jnp.asarray(x[labels == c, j])) - base[c, j]) >= 0).sum()
                                                - (off >= 1 << 15).sum() for j in range(d)] for c in range(k)])
     np.testing.assert_array_equal(ahead, np.cumsum(in_window, axis=0) - in_window)
     assert bool(passes.gather(x, labels.astype(np.int32), base, bits, True)[1])
@@ -431,7 +432,7 @@ def test_gather_folds_steps_onto_blocks_of_slots(gather_at_any_size, any_bracket
     got, ended = _medians_and_end(x, labels.astype(np.int32), k, prev, passes)
     np.testing.assert_array_equal(got, _median_by_cluster(x, labels, k, prev))
     assert ended == "kept"
-    pl1._gather_program.cache_clear()
+    ps._gather_program.cache_clear()
 
 
 @pytest.mark.parametrize("data, skips", [("cores", False), ("sorted_column", True), ("one_repeated_value", True),
@@ -441,7 +442,7 @@ def test_crowded_windows_skip_the_gathering_pass(data, skips, gather_at_any_size
     where some feature's hold more than one row in ``_GATHER_MOST_OF_X``
     (here 64) the gathering pass is told to skip, reads nothing, and the
     selection ends on ``X``; numpy's medians either way."""
-    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", 64)
+    monkeypatch.setattr(ps, "_GATHER_MOST_OF_X", 64)
     rng = np.random.default_rng(7)
     if data in ("cores", "one_crowded_feature"):  # 18 keys a feature in the windows' brackets, of 2500 rows
         x, labels, k = _cores(rng, 2500, 8, 3)
@@ -528,7 +529,7 @@ def test_counts_decide_the_digits_on_x(name, digits, then, mesh, gather_at_any_s
         request.getfixturevalue("any_bracket_is_a_window")
     rng = np.random.default_rng([c[0] for c in RULE_CASES].index(name))
     x, labels, k, most = _rule_case(name, rng)
-    monkeypatch.setattr(pl1, "_GATHER_MOST_OF_X", most)
+    monkeypatch.setattr(ps, "_GATHER_MOST_OF_X", most)
     x, labels = x.astype(np.float32), labels.astype(np.int32)
     (n, d), own = x.shape, k
     counted, gathers = [], []
@@ -582,8 +583,8 @@ def test_gather_pays_from_a_size_on():
     kernels from ``_GATHER_MIN_ROWS_A_CLUSTER`` rows a cluster and device
     on (what a window keeps does not grow with ``n``)."""
     assert kc._l1_passes_xla(8).gather is None
-    assert pl1.gather_pays(18_750_000, 8) and pl1.gather_pays(4_687_500, 8) and pl1.gather_pays(4_194_304, 32)
-    assert not pl1.gather_pays(524_288, 8) and not pl1.gather_pays(1_048_576, 16)
+    assert ps.gather_pays(18_750_000, 8) and ps.gather_pays(4_687_500, 8) and ps.gather_pays(4_194_304, 32)
+    assert not ps.gather_pays(524_288, 8) and not ps.gather_pays(1_048_576, 16)
     mesh, axis = ht.MPI_WORLD.mesh, ht.MPI_WORLD.axis_name
     assert pl1.l1_passes(8, (1 << 20, 64)).gather is not None
     assert pl1.l1_passes(8, (1 << 19, 64)).gather is None
@@ -648,7 +649,7 @@ def test_fit_program_on_the_kernels_keeps_a_sixtieth_of_x(est):
     every 16 x 8192 rows (1.6 % of ``X``: 75 MB of 4.8 GB), and no value is
     larger than ``X``."""
     n, d, k = 18_750_000, 64, 8
-    assert pl1.kept_lanes(n, d, k) == 144 * 2048  # 2289 steps of 8192 rows, sixteen to a block of sixteen slots
+    assert ps.kept_lanes(n, d, k) == 144 * 2048  # 2289 steps of 8192 rows, sixteen to a block of sixteen slots
     passes = pl1.l1_passes(k, (n, d))
 
     def step(arr, centers):
@@ -663,7 +664,7 @@ def test_fit_program_on_the_kernels_keeps_a_sixtieth_of_x(est):
     with jax.enable_x64(False):  # the chip's policy: Mosaic refuses 64-bit traces
         jaxpr = jax.make_jaxpr(kc.make_fit_loop(step, "float32", 0.0, 5, False))(a, c).jaxpr
     assert _largest_value(jaxpr) == n * d
-    assert d * pl1.kept_lanes(n, d, k) in _value_sizes(jaxpr) and 64 * d * pl1.kept_lanes(n, d, k) < 1.01 * n * d
+    assert d * ps.kept_lanes(n, d, k) in _value_sizes(jaxpr) and 64 * d * ps.kept_lanes(n, d, k) < 1.01 * n * d
 
 
 # --------------------------------------------------------------------- #

@@ -40,7 +40,7 @@ ALLOWED = {
     "nn": {"core"},
     "observability": {"core.gates", "core.dndarray", "core.jit"},
     "optim": {"core", "kernels.quant", "nn"},
-    "preprocessing": {"core", "redistribution.staging", "sparse"},
+    "preprocessing": {"core", "redistribution.staging", "sparse"} | _OBS,  # since PR 38: spans and observed programs
     "redistribution": {"core", "kernels.quant", "kernels.relayout"} | _OBS,
     "regression": {"core"},
     "resilience": {"core", "redistribution", "version"} | _OBS,
