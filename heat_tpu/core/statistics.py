@@ -377,14 +377,15 @@ def _selection_form(backend: str, dtype, shape, axis, split, devices: int, targe
     return "sort"
 
 
-@observed_program_cache("percentile.select", maxsize=64)
-def _percentile_select_program(shape, jdtype: str, on_chip: bool, ranks, weights, out_shape, mesh, axis_name,
-                               interpret: bool = False):
-    """``arr (n, d) -> (len(ranks), d)``: for every ``(lo, hi)`` of ``ranks``
+def _percentile_select_body(shape, jdtype: str, on_chip: bool, ranks, weights, out_shape, mesh, axis_name,
+                            interpret: bool = False):
+    """The traced body of ``_percentile_select_program``, for a larger
+    program to call (``preprocessing._robust_fit_transform_program``):
+    ``arr (n, d) -> (len(ranks), d)``: for every ``(lo, hi)`` of ``ranks``
     (0-based, host-static, ``hi`` is ``lo`` or ``lo + 1``) and its weight
     ``w`` the value ``v[lo] + w * (v[hi] - v[lo])`` of every column's order
     statistics ``v`` (``w`` 0.0: ``v[lo]``, 1.0: ``v[hi]``, both as they
-    are), NaN where the column holds one. One jitted program: the pairs
+    are), NaN where the column holds one. The pairs
     that differ are the targets of ``_selection.order_statistics`` for all
     rows, ``_MOST_TARGETS`` to a batch, every batch in the same passes over
     ``arr`` whatever it holds; the first digit's pass, which also looks for
@@ -421,13 +422,32 @@ def _percentile_select_program(shape, jdtype: str, on_chip: bool, ranks, weights
                 rows.append(lo if w == 0.0 else hi if w == 1.0 else lo + jnp.asarray(w, arr.dtype) * (hi - lo))
             return jnp.where(nans[None, :] > 0, jnp.nan, jnp.stack(rows)).reshape(out_shape)
 
-    return jax.jit(run)
+    return run
 
 
-def _percentile_by_selection(x: DNDarray, qv: np.ndarray, interpolation: str, form: str, out_shape) -> jax.Array:
-    """The ``len(qv)`` percentiles of every column of a 2-D ``x`` by the
-    counting selection, as ``out_shape``, in one program
-    (``_percentile_select_program``)."""
+@observed_program_cache("percentile.select", maxsize=64)
+def _percentile_select_program(shape, jdtype: str, on_chip: bool, ranks, weights, out_shape, mesh, axis_name,
+                               interpret: bool = False):
+    """``_percentile_select_body`` as one jitted program: ``ht.percentile``'s
+    own, where ``_form_of`` says so."""
+    return jax.jit(_percentile_select_body(shape, jdtype, on_chip, ranks, weights, out_shape, mesh, axis_name, interpret))
+
+
+def _form_of(x: DNDarray, axis, qv: np.ndarray) -> str:
+    """Which form the percentiles ``qv`` along ``axis`` of ``x`` take: the
+    one test of ``percentile`` and ``RobustScaler.fit_transform`` (2-D, along
+    axis 0, not planar, then ``_selection_form`` on the distinct ``q``)."""
+    if axis != 0 or x.ndim != 2 or x._is_planar:
+        return "sort"
+    return _selection_form(jax.default_backend(), x.dtype.jax_type(), x.gshape, axis, x.split, x.comm.size,
+                           len(set(qv.tolist())))
+
+
+def _selection_key(x: DNDarray, qv: np.ndarray, interpolation: str, form: str, out_shape) -> tuple:
+    """``_percentile_select_program``'s key for the ``len(qv)`` percentiles of
+    every column of a 2-D ``x`` as ``out_shape``: the ranks that bracket
+    each and the upper one's weight, reckoned on the host, and how ``x``
+    lies. The program's operand is ``_selection_operand(x)``."""
     n = x.gshape[0]
     pos = qv / 100.0 * (n - 1)
     lo = np.floor(pos).astype(np.int64)
@@ -444,16 +464,34 @@ def _percentile_by_selection(x: DNDarray, qv: np.ndarray, interpolation: str, fo
         w = pos - lo
     split_over = x.split == 0 and x.comm.size > 1
     mesh = x.comm.mesh if form == "pallas" and x.comm.size > 1 else None
-    prog = _percentile_select_program(
+    return (
         tuple(x.gshape), np.dtype(x.dtype.jax_type()).name, form == "pallas",
         tuple(zip(lo.tolist(), hi.tolist())), tuple(w.tolist()), tuple(out_shape), mesh,
         x.comm.axis_name if mesh is not None and split_over else None,
         form == "pallas" and jax.default_backend() != "tpu",  # the kernels off the chip (the tests): interpret mode
     )
+
+
+def _selection_operand(x: DNDarray) -> jax.Array:
+    """``x`` as a program of the selection takes it: the shards as they lie
+    where it is split 0 over the mesh (equal ones: a form's condition)."""
+    return x._phys if x.split == 0 and x.comm.size > 1 else x.larray
+
+
+def _count_selection(form: str) -> None:
+    """The counters of one call that took the selection's ``form``."""
     _telemetry.inc(f"percentile.select.{form}")
     if form == "pallas":
         _telemetry.inc("percentile.select.gather")
-    return prog(x._phys if split_over else x.larray)
+
+
+def _percentile_by_selection(x: DNDarray, qv: np.ndarray, interpolation: str, form: str, out_shape) -> jax.Array:
+    """The ``len(qv)`` percentiles of every column of a 2-D ``x`` by the
+    counting selection, as ``out_shape``, in one program
+    (``_percentile_select_program``)."""
+    prog = _percentile_select_program(*_selection_key(x, qv, interpolation, form, out_shape))
+    _count_selection(form)
+    return prog(_selection_operand(x))
 
 
 def median(x: DNDarray, axis: Optional[int] = None, keepdims: bool = False) -> DNDarray:
@@ -490,7 +528,9 @@ def percentile(
     here every row counts for every target): no sort, no copy of ``x``,
     nothing of its size allocated, all ``q`` of the call in the same passes
     over ``x`` (up to ``_pallas_select._MOST_TARGETS`` distinct rank pairs a
-    batch), one jitted program a call. On a TPU that is f32 with a multiple
+    batch), one jitted program a call (``RobustScaler.fit_transform`` traces
+    the same body into its own one program, with the statistics and the
+    transform behind it; ``RobustScaler.fit`` is a call of this function). On a TPU that is f32 with a multiple
     of 8 under 128 columns, on one device or in equal split-0 shards, from
     ``2 ** 17`` rows a target and device on (the kernels of
     ``core/_pallas_select.py``); on any backend, f32 / f64 split 0 in equal
@@ -536,10 +576,7 @@ def percentile(
             eff_axis = axis
             if eff_axis is None and x.ndim == 1:
                 eff_axis = 0
-            form = "sort"
-            if eff_axis == 0 and x.ndim == 2 and not x._is_planar:
-                form = _selection_form(jax.default_backend(), x.dtype.jax_type(), x.gshape, eff_axis, x.split, x.comm.size,
-                                       len(set(qv.tolist())))
+            form = _form_of(x, eff_axis, qv)
         if form != "sort":
             shape = (x.gshape[1],) if scalar_q else (len(qv), x.gshape[1])
             if keepdims:

@@ -8,7 +8,11 @@ the reference's terms), ``RobustScaler``'s order statistics one
 ``ht.percentile`` call (along the sample axis an exact counting selection,
 no sort, where ``statistics._selection_form`` says so), and its
 ``transform`` / ``inverse_transform`` one program each (``_affine``: one
-read and one write of the table).
+read and one write of the table). ``RobustScaler.fit_transform`` is ONE
+program where the selection serves (``_robust_fit_transform_program``: the
+bodies of the selection, the two statistics and the transform in one
+launch); ``fit`` and ``transform`` called apart, and ``fit_transform`` where
+``percentile`` would sort, are the staged three.
 
 Every scaler accepts ``copy`` for reference parity and does not read it:
 arrays are immutable here, so a transform always returns a new array and the
@@ -33,6 +37,7 @@ from ..core.base import BaseEstimator, TransformMixin
 from ..core.communication import register_mesh_cache
 from ..core.dndarray import DNDarray
 from ..core.sanitation import sanitize_in
+from ..observability import telemetry as _telemetry
 from ..observability.instrument import observed_program_cache
 from ..observability.tracing import call_span as _call_span
 
@@ -102,16 +107,17 @@ def _affine_pass(n: int, d: int, inverse: bool, interpret: bool):
     return lambda x, shift, scale: call(x.T, shift[:, None], scale[:, None]).T
 
 
-@observed_program_cache("scaler.transform", maxsize=64)
-def _affine_program(shape, jdtype: str, out_jdtype: str, inverse: bool, shifts: bool, scales: bool, on_chip: bool,
-                    mesh=None, axis_name=None, interpret: bool = False):
-    """``(arr, shift, scale) -> (arr - shift) / scale`` in ``out_jdtype``
-    (``inverse``: ``arr * scale + shift``), ``shift`` and ``scale`` one value
-    a feature (ignored without ``shifts`` / ``scales``): ONE jitted program a
-    transform, one read of the table with the cast fused into it and one
-    write. ``on_chip`` (``_affine`` decides: ``tall_narrow_serves``) it is
-    the kernel ``_affine_pass``, under ``shard_map`` on a ``mesh`` as the
-    selection's passes are; elsewhere the same expression left to XLA."""
+def _affine_body(shape, jdtype: str, out_jdtype: str, inverse: bool, shifts: bool, scales: bool, on_chip: bool,
+                 mesh=None, axis_name=None, interpret: bool = False):
+    """The traced body of ``_affine_program``, for a larger program to call
+    (``_robust_fit_transform_program``): ``(arr, shift, scale) -> (arr -
+    shift) / scale`` in ``out_jdtype`` (``inverse``: ``arr * scale +
+    shift``), ``shift`` and ``scale`` one value a feature (ignored without
+    ``shifts`` / ``scales``): one read of the table with the cast fused into
+    it and one write. ``on_chip`` (``_affine_key`` decides:
+    ``tall_narrow_serves``) it is the kernel ``_affine_pass``, under
+    ``shard_map`` on a ``mesh`` as the selection's passes are; elsewhere the
+    same expression left to XLA."""
     out_dtype = jnp.dtype(out_jdtype)
     if on_chip:
         n, d = int(shape[0]), int(shape[1])
@@ -133,28 +139,41 @@ def _affine_program(shape, jdtype: str, out_jdtype: str, inverse: bool, shifts: 
             arr = arr - shift if shifts else arr
             return arr / scale if scales else arr
 
-    return jax.jit(run)
+    return run
+
+
+@observed_program_cache("scaler.transform", maxsize=64)
+def _affine_program(shape, jdtype: str, out_jdtype: str, inverse: bool, shifts: bool, scales: bool, on_chip: bool,
+                    mesh=None, axis_name=None, interpret: bool = False):
+    """``_affine_body`` as ONE jitted program a transform."""
+    return jax.jit(_affine_body(shape, jdtype, out_jdtype, inverse, shifts, scales, on_chip, mesh, axis_name, interpret))
 
 
 register_mesh_cache(_affine_program)
+
+
+def _affine_key(x: DNDarray, inverse: bool, shift_dtype, scale_dtype) -> tuple:
+    """``_affine_program``'s key for ``x`` and statistics of the two dtypes
+    (``None``: no such statistic): the result's dtype (the third), whether
+    the kernel serves (``on_chip``) and over which mesh."""
+    out = jnp.result_type(_float_of(x).jax_type(), *(t for t in (shift_dtype, scale_dtype) if t is not None))
+    devices = x.comm.size
+    on_chip = out == jnp.float32 and _tiles.tall_narrow_serves(
+        jax.default_backend(), x.dtype.jax_type(), x.gshape, x.split, devices)
+    mesh = x.comm.mesh if on_chip and devices > 1 else None
+    return (tuple(x.gshape), np.dtype(x.dtype.jax_type()).name, np.dtype(out).name, inverse,
+            shift_dtype is not None, scale_dtype is not None, on_chip, mesh,
+            x.comm.axis_name if mesh is not None and x.split == 0 else None)
 
 
 def _affine(x: DNDarray, shift, scale, inverse: bool = False) -> DNDarray:
     """``(x - shift) / scale`` (``inverse``: ``x * scale + shift``) along the
     feature axis as one program (``_affine_program``); ``shift`` / ``scale``
     may be ``None``. The result has ``x``'s split."""
-    out = jnp.result_type(_float_of(x).jax_type(), *(a.dtype for a in (shift, scale) if a is not None))
-    devices = x.comm.size
-    on_chip = out == jnp.float32 and _tiles.tall_narrow_serves(
-        jax.default_backend(), x.dtype.jax_type(), x.gshape, x.split, devices)
-    mesh = x.comm.mesh if on_chip and devices > 1 else None
-    split_over = x.split == 0 and devices > 1
-    prog = _affine_program(tuple(x.gshape), np.dtype(x.dtype.jax_type()).name, np.dtype(out).name, inverse,
-                           shift is not None, scale is not None, on_chip, mesh,
-                           x.comm.axis_name if mesh is not None and split_over else None)
-    none = np.zeros((), out)  # a host value: an argument the program ignores, no op dispatched to make it
-    arr = x._phys if on_chip and split_over else x.larray
-    return _like(x, prog(arr, none if shift is None else shift, none if scale is None else scale))
+    key = _affine_key(x, inverse, *(None if a is None else a.dtype for a in (shift, scale)))
+    none = np.zeros((), key[2])  # of the result's dtype; a host value: the program ignores it, no op dispatched to make it
+    # (where the kernel serves a split table its shards are equal: ``larray`` is then the physical array itself)
+    return _like(x, _affine_program(*key)(x.larray, none if shift is None else shift, none if scale is None else scale))
 
 
 def _like(x: DNDarray, arr) -> DNDarray:
@@ -266,22 +285,53 @@ class MaxAbsScaler(BaseEstimator, TransformMixin):
         return _like(y, y.larray * self.scale_)
 
 
-@observed_program_cache("scaler.robust_stats", maxsize=16)
-def _robust_stats_program(centering: bool, scaling: bool):
-    """``percentiles (q, ...) -> (center, iqr)``: the rows of
-    ``RobustScaler.fit``'s one ``percentile`` call as the scaler keeps them
-    (the range's two first, the median last; a range of 0 scales by 1), as
-    one small program: no op of the fit is dispatched by itself."""
+def _robust_stats_body(centering: bool, scaling: bool):
+    """The traced body of ``_robust_stats_program``, for a larger program to
+    call: ``percentiles (q, ...) -> (center, iqr)``, the rows of
+    ``RobustScaler``'s percentiles as the scaler keeps them (the range's two
+    first, the median last; a range of 0 scales by 1)."""
 
     def run(pct):
         iqr = pct[1] - pct[0] if scaling else None
         return pct[-1] if centering else None, None if iqr is None else jnp.where(iqr > 0, iqr, 1.0)
 
+    return run
+
+
+@observed_program_cache("scaler.robust_stats", maxsize=16)
+def _robust_stats_program(centering: bool, scaling: bool):
+    """``_robust_stats_body`` as one small program: no op of
+    ``RobustScaler.fit`` is dispatched by itself."""
+    return jax.jit(_robust_stats_body(centering, scaling))
+
+
+@observed_program_cache("scaler.robust_fit_transform", maxsize=64)
+def _robust_fit_transform_program(select_key: tuple, centering: bool, scaling: bool, affine_key: tuple):
+    """``arr -> (y, center, iqr)``: ``RobustScaler.fit_transform`` as ONE
+    jitted program, keyed like its parts (``statistics._selection_key``, the
+    two flags, ``_affine_key``) and made of their bodies under their own
+    scopes: the counting selection of the scaler's percentiles, the two
+    statistics, the transform by them. The outputs and the temporaries of
+    the whole call are placed at one launch and nothing returns to the host
+    between the fit and the transform; the device ops keep the names they
+    have in the staged programs. ``center`` / ``iqr`` are ``None`` without
+    ``centering`` / ``scaling``."""
+    select = statistics._percentile_select_body(*select_key)
+    stats = _robust_stats_body(centering, scaling)
+    affine = _affine_body(*affine_key)
+
+    def run(arr):
+        center, iqr = stats(select(arr))
+        return affine(arr, center, iqr), center, iqr
+
     return jax.jit(run)
 
 
+register_mesh_cache(_robust_fit_transform_program)
+
+
 class RobustScaler(BaseEstimator, TransformMixin):
-    """Scale by median and IQR: one ``ht.percentile`` call a fit (a counting selection on a tall table), one program a transform.
+    """Scale by median and IQR: one ``ht.percentile`` call a fit (a counting selection on a tall table), one program a transform, one a ``fit_transform``.
 
     (Reference: preprocessing.py:444 — uses the distributed percentile.)
     ``fit`` is one ``ht.percentile(x, [q_min,
@@ -289,8 +339,13 @@ class RobustScaler(BaseEstimator, TransformMixin):
     range only with ``with_scaling``): along the sample axis of a tall table
     an exact counting selection that finds all three in the same passes, not
     three sorts (``statistics.percentile`` says where). ``transform`` and
-    ``inverse_transform`` are one program each. ``copy`` is accepted and not
-    read."""
+    ``inverse_transform`` are one program each. ``fit_transform`` is ONE
+    program (the selection, the two statistics and the transform in one
+    launch, the same values bit for bit) where that selection serves and a
+    flag is set; where ``percentile`` would sort (small, integer, complex
+    tables, other widths) it is ``fit(x).transform(x)``, the staged three
+    programs, as ``fit`` and ``transform`` called by themselves always are.
+    ``copy`` is accepted and not read."""
 
     def __init__(
         self,
@@ -313,16 +368,48 @@ class RobustScaler(BaseEstimator, TransformMixin):
         self.center_ = None
         self.iqr_ = None
 
+    def _percentiles(self) -> list:
+        """What a fit asks of ``x``: the range's two (``with_scaling``), then the median (``with_centering``)."""
+        return (list(self.quantile_range) if self.with_scaling else []) + ([50.0] if self.with_centering else [])
+
+    def _keep(self, x: DNDarray, center, iqr) -> None:
+        """Set ``center_`` (a ``DNDarray``; left alone without ``with_centering``) and ``iqr_`` (a ``jax.Array``)."""
+        self.iqr_ = iqr
+        if self.with_centering:
+            self.center_ = DNDarray(center, tuple(center.shape), types.canonical_heat_type(center.dtype), None, x.device,
+                                    x.comm)
+
     def fit(self, x: DNDarray) -> "RobustScaler":
         with _call_span("ht.call.robustscaler.fit"):
             sanitize_in(x)
-            q = (list(self.quantile_range) if self.with_scaling else []) + ([50.0] if self.with_centering else [])
+            q = self._percentiles()
             if q:
                 pct = statistics.percentile(x, q, axis=0)
-                center, self.iqr_ = _robust_stats_program(self.with_centering, self.with_scaling)(pct.larray)
-                if self.with_centering:
-                    self.center_ = DNDarray(center, tuple(center.shape), pct.dtype, None, x.device, x.comm)
+                self._keep(x, *_robust_stats_program(self.with_centering, self.with_scaling)(pct.larray))
             return self
+
+    def fit_transform(self, x: DNDarray) -> DNDarray:
+        """``fit(x).transform(x)`` as ONE program where the fit's percentiles
+        come from the counting selection (``statistics._form_of``: what
+        ``ht.percentile`` itself asks), the staged three elsewhere; the same
+        ``center_``, ``iqr_`` and result bit for bit."""
+        with _call_span("ht.call.robustscaler.fit_transform"):
+            sanitize_in(x)
+            qv = np.asarray(self._percentiles(), np.float64)
+            form = statistics._form_of(x, 0, qv) if qv.size else "sort"
+            if form == "sort":
+                _telemetry.inc("scaler.fit_transform.staged")
+                return self.fit(x).transform(x)
+            _telemetry.inc("scaler.fit_transform.fused")
+            stat = x.dtype.jax_type()  # the selection's values have the table's dtype
+            prog = _robust_fit_transform_program(
+                statistics._selection_key(x, qv, "linear", form, (qv.size, x.gshape[1])), self.with_centering,
+                self.with_scaling, _affine_key(x, False, stat if self.with_centering else None,
+                                               stat if self.with_scaling else None))
+            statistics._count_selection(form)
+            y, center, iqr = prog(statistics._selection_operand(x))
+            self._keep(x, center, iqr)
+            return _like(x, y)
 
     def _shift_and_scale(self):
         return (self.center_.larray if self.with_centering and self.center_ is not None else None,

@@ -84,6 +84,7 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 | `ht.call.percentile` | the whole public `ht.percentile` / `ht.median` call (since PR 38; `RobustScaler.fit`'s one call nests in `ht.call.robustscaler.fit`) | `host_wrapper_ms_per_call` (self time) |
 | `ht.call.percentile.prepare`, `.wrap` | `sanitize_in`, `q` to the host (an `ht.sync.read` where it is a device value), which form (`_selection_form`); where the counting selection serves, the `DNDarray` of the result. Between them the lookup and call of the one program (`percentile.select`): the program spans nest in `ht.call.percentile` itself. A call that sorts has no `.wrap` | `host_wrapper_ms_per_call` |
 | `ht.call.robustscaler.fit`, `.transform`, `.inverse_transform` | `RobustScaler.fit` (one `percentile` call and the small program `scaler.robust_stats`), `transform` and `inverse_transform` (one program each, `scaler.transform`, and the result's placement) | `host_wrapper_ms_per_call` |
+| `ht.call.robustscaler.fit_transform` | the whole public `RobustScaler.fit_transform` call (since PR 39). Where the fit's percentiles come from the counting selection (`core.statistics._form_of`, the test `ht.percentile` itself makes: 2-D, axis 0, not planar, then `_selection_form` says `"pallas"` or `"xla"`) and a flag is set: the reckoning of the key on the host, the lookup and call of ONE program (`scaler.robust_fit_transform`: the selection, the two statistics and the transform; no `ht.call.percentile`, no `.fit`, no `.transform` inside it) and the result's placement. Everywhere else (`"sort"`; both flags off) the staged form: `ht.call.robustscaler.fit` and `.transform` nest in it | `host_wrapper_ms_per_call` |
 | `ht.op.binary`, `ht.op.unary`, `ht.op.reduce`, `ht.op.cum`, `ht.op.matmul`, `ht.op.transpose` | one eager op: lookup, call and wrapping | `host_wrapper_ms_per_call` |
 | `ht.program.hit` | entered right after a lookup that a builder's `lru_cache` served (`cache=` names the builder) | `host_launch_ms_per_call` |
 | `ht.program.miss` | a lookup that built; the builder's time | `program_cache_misses`, `host_launch_ms_per_call` |
@@ -96,7 +97,7 @@ from the local factors, the estimate). They are in every op's `op_name` metadata
 **The calling thread's counters** (since PR 36). The outermost span of a public call (`ht.call.hsvd_rank`,
 `ht.call.hsvd_rtol`, `ht.call.hsvd`, `ht.call.qr`, `ht.call.kmeans.fit`, `ht.call.kmedians.fit`,
 `ht.call.kmedoids.fit`, `ht.call.kmeans.predict`, `ht.call.percentile`, `ht.call.robustscaler.fit`,
-`.transform`, `.inverse_transform`) is entered through `ht.tracing.call_span`. Under a live
+`.transform`, `.inverse_transform`, `.fit_transform`) is entered through `ht.tracing.call_span`. Under a live
 profiler session, and only then (without one it costs the `is_enabled()` read every span pays), it reads two
 CPU clocks at entry and passes them as the annotation's arguments; the profiler keeps them as the event's
 integer stats (`ProfileData` event `.stats`; Perfetto shows them as the slice's arguments). Nothing is
@@ -132,7 +133,7 @@ Counters behind the telemetry switch (`ht.telemetry.enable()`): `<builder>.hit`,
 `hsvd.sketched_rank`, `hsvd.one_view_rank`, `hsvd.sketched`, `hsvd.local_svd`, `hsvd.dist_rank`,
 `hsvd.staged_rank_tail`, `hsvd.staged_oneview_tail`, `qr.tsqr`, `qr.local`, `kmeans.lloyd_step`,
 `kmeans.partial_fit_step`, `kcluster.fused_fit`, `kcluster.predict`, `percentile.select`,
-`scaler.robust_stats`, `scaler.transform`), `ht.jit.cache.hit`/`.miss`,
+`scaler.robust_stats`, `scaler.transform`, `scaler.robust_fit_transform`), `ht.jit.cache.hit`/`.miss`,
 `comm.shard.calls`/`.bytes`, `comm.reshard.calls`/`.bytes`, and `hsvd.pass2.one_dot` /
 `hsvd.pass2.tiled` (which form of the two-pass sketch's second pass a program was built with: one
 dot that reads f32 `A` once, where pass 1 was the Pallas kernel, or the tiled loop `_pass2_tiles`
@@ -225,6 +226,19 @@ not grow with them. `RobustScaler.transform` / `inverse_transform` (`scaler.tran
 read and one write of the table) run, where `core._pallas_select.tall_narrow_serves`, the kernel named
 `scaler.transform.pass` under `jax.named_scope("scaler.transform")`, which `scaler_transform_ms_per_call`
 and `scaler_transform_hbm_pct` read; elsewhere the same expression is XLA's fusion in the same program.
+
+`RobustScaler.fit_transform` as one program (since PR 39). Once a call, `scaler.fit_transform.fused` /
+`scaler.fit_transform.staged` says which form it took (as `kmeans.step.fused` / `.xla` do for a fit). Fused:
+the builder `scaler.robust_fit_transform` composes the traced bodies of `percentile.select`,
+`scaler.robust_stats` and `scaler.transform` into ONE jitted program `x -> (y, center, iqr)`, keyed like
+its parts, under `shard_map` on a mesh where they are; the outputs and temporaries of the whole call are
+placed at one launch and nothing returns to the host between the fit and the transform
+(`launches_per_call` 1). The device ops keep their scopes and names (`percentile.select.pass`,
+`.candidates`, `scaler.transform.pass`), so the readers above read the fused call as they read the staged
+one; the call counts `percentile.select.pallas` / `.xla` (and `.gather`) as its `percentile` call would
+have, and `center_`, `iqr_` and the result equal the staged call's bit for bit. Staged (`fit(x).transform(x)`:
+what `percentile` would sort, i.e. small, integer, complex, planar tables and other widths; both flags off)
+and `fit`, `transform`, `inverse_transform` called by themselves keep the three programs above.
 """,
 }
 

@@ -399,20 +399,25 @@ def test_l1_fit_program_is_the_one_before_the_selection_moved(one_chip, snap, pa
     assert hashlib.sha256(_canonical(text).encode()).hexdigest()[:16] == parents
 
 
-def _percentile_compiled(rows, d, chips, topo_mesh, sharding):
-    """``ht.percentile(x, [25, 75, 50], axis=0)``'s one program on the
-    kernels (``linear``), compiled for the described chip(s)."""
-    from heat_tpu.core import statistics as st
-
+def _percentile_key(rows, d, chips, topo_mesh):
+    """``_percentile_select_program``'s key for ``ht.percentile(x, [25, 75,
+    50], axis=0)`` on the kernels (``linear``), as ``_selection_key`` would
+    reckon it on the chip(s)."""
     n = rows * chips
     pos = np.array([25.0, 75.0, 50.0]) / 100.0 * (n - 1)
     ranks = tuple(zip(np.floor(pos).astype(int).tolist(), np.ceil(pos).astype(int).tolist()))
     mesh, axis = (topo_mesh, "d") if chips > 1 else (None, None)
+    return (n, d), "float32", True, ranks, tuple((pos - np.floor(pos)).tolist()), (3, d), mesh, axis
+
+
+def _percentile_compiled(rows, d, chips, topo_mesh, sharding):
+    """That call's one program, compiled for the described chip(s)."""
+    from heat_tpu.core import statistics as st
+
     st._percentile_select_program.cache_clear()
     try:
-        prog = st._percentile_select_program((n, d), "float32", True, ranks, tuple((pos - np.floor(pos)).tolist()), (3, d),
-                                             mesh, axis)
-        return prog.program.lower(jax.ShapeDtypeStruct((n, d), F32, sharding=sharding)).compile()
+        prog = st._percentile_select_program(*_percentile_key(rows, d, chips, topo_mesh))
+        return prog.program.lower(jax.ShapeDtypeStruct((rows * chips, d), F32, sharding=sharding)).compile()
     finally:
         st._percentile_select_program.cache_clear()
 
@@ -443,6 +448,46 @@ def test_percentile_select_at_the_cells_shape(one_chip, mesh4, chips):
         assert reads_x == (".pass" in name), (name, operands)
         assert name.startswith(("percentile.select.pass", "percentile.select.candidates")), name
     assert f"s32[{d},{ps.kept_lanes(rows, d, 3)}]" in txt and 60 * ps.kept_lanes(rows, d, 3) < rows
+    assert ("all-reduce" in txt) == (chips == 4)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "split0_2x2"])
+def test_robust_fit_transform_at_the_cells_shape(one_chip, mesh4, chips):
+    """``RobustScaler().fit_transform(x)`` at the cell's shard (and a quarter
+    of it on each chip of the 2 x 2) is ONE module (PR 39): the selection's
+    kernels under their own names and exactly one ``scaler.transform.pass``,
+    no sort; every kernel that reads ``X`` says ``.pass``; one output of
+    ``X``'s size (``y``) and no temporary near it (the kept keys: under an
+    eighth of ``X``), so the whole call's memory is asked for at one launch."""
+    from heat_tpu.preprocessing import preprocessing as pp
+
+    rows, d = (18_750_000, 64) if chips == 1 else (4_687_500, 64)
+    select_key = _percentile_key(rows, d, chips, mesh4)
+    shape, mesh, axis = select_key[0], select_key[6], select_key[7]
+    pp._robust_fit_transform_program.cache_clear()
+    try:
+        prog = pp._robust_fit_transform_program(select_key, True, True,
+                                                (shape, "float32", "float32", False, True, True, True, mesh, axis))
+        sharding = one_chip if chips == 1 else NamedSharding(mesh4, P("d", None))
+        compiled = prog.program.lower(jax.ShapeDtypeStruct(shape, F32, sharding=sharding)).compile()
+    finally:
+        pp._robust_fit_transform_program.cache_clear()
+    txt = compiled.as_text()
+    assert txt.count("HloModule") == 1 and " sort(" not in txt
+    kernels = _kernels(txt)
+    assert len(kernels) == txt.count("tpu_custom_call")
+    names = [name for name, _ in kernels]
+    for name, operands in kernels:
+        reads_x = f"f32[{d},{rows}]" in operands or f"f32[{rows},{d}]" in operands
+        assert reads_x == (".pass" in name), (name, operands)
+        assert name.startswith(("percentile.select.pass", "percentile.select.candidates", "scaler.transform.pass")), name
+    assert sum(name.startswith("scaler.transform.pass") for name in names) == 1
+    assert sum(name.startswith("percentile.select.pass") for name in names) >= 4  # first digit, counting, gathering, successor
+    assert any(name.startswith("percentile.select.candidates") for name in names)
+    sized = [m.group(0) for m in _X_SIZED_OP.finditer(txt) if {int(m.group(1)), int(m.group(2))} == {rows, d}]
+    assert sized == []  # no copy, transpose or cast of X or of y
+    memory, x_bytes = compiled.memory_analysis(), rows * d * 4  # a device's
+    assert memory.temp_size_in_bytes < x_bytes // 8 and x_bytes <= memory.output_size_in_bytes < x_bytes + (1 << 20)
     assert ("all-reduce" in txt) == (chips == 4)
 
 

@@ -32,9 +32,13 @@ def form(monkeypatch, request):
     if which == "pallas":
         monkeypatch.setattr(ps, "_GATHER_MIN_ROWS_A_CLUSTER", 0)
         monkeypatch.setattr(ps, "_GATHER_MOST_OF_X", 1)
-    ps.select_passes.cache_clear(), st._percentile_select_program.cache_clear()
+    def clear():
+        for cache in (ps.select_passes, st._percentile_select_program, pp._robust_fit_transform_program):
+            cache.cache_clear()
+
+    clear()
     yield which
-    ps.select_passes.cache_clear(), st._percentile_select_program.cache_clear()
+    clear()
 
 
 def _near(rng, shape, span, at=1.0):

@@ -972,8 +972,9 @@ def test_ring_still_gets_parented_spans_of_the_call():
 
 def _observed_builders():
     from heat_tpu.cluster import _kcluster, kmeans
-    from heat_tpu.core import _operations as ops
+    from heat_tpu.core import _operations as ops, statistics
     from heat_tpu.core.linalg import svdtools
+    from heat_tpu.preprocessing import preprocessing
 
     qr = importlib.import_module("heat_tpu.core.linalg.qr")  # the package exports the function under that name
     return {
@@ -991,6 +992,9 @@ def _observed_builders():
         "kmeans.partial_fit_step": kmeans._partial_fit_step,
         "kcluster.fused_fit": _kcluster._fused_fit_program,
         "kcluster.predict": _kcluster._predict_program,
+        "percentile.select": statistics._percentile_select_program,
+        "scaler.transform": preprocessing._affine_program,
+        "scaler.robust_fit_transform": preprocessing._robust_fit_transform_program,
     }
 
 
@@ -998,6 +1002,7 @@ def _observed_builders():
     "op.binary", "op.unary", "op.reduce", "op.cum", "hsvd.sketched_rank", "hsvd.one_view_rank", "hsvd.sketched",
     "hsvd.local_svd", "hsvd.staged_rank_tail", "hsvd.staged_oneview_tail", "qr.tsqr", "qr.local",
     "kmeans.lloyd_step", "kmeans.partial_fit_step", "kcluster.fused_fit", "kcluster.predict",
+    "percentile.select", "scaler.transform", "scaler.robust_fit_transform",
 ])
 def test_observed_builder_keeps_the_lru_cache_surface(name):
     builder = _observed_builders()[name]
